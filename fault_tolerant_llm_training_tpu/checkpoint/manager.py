@@ -183,7 +183,7 @@ def _manifest_files(step_dir: str) -> Dict[str, Dict[str, int]]:
     files: Dict[str, Dict[str, int]] = {}
     for root, _dirs, names in os.walk(step_dir):
         for name in names:
-            if name == MANIFEST_NAME or name == MANIFEST_NAME + ".tmp":
+            if name.startswith(MANIFEST_NAME):  # the manifest and its tmps
                 continue
             path = os.path.join(root, name)
             rel = os.path.relpath(path, step_dir)
@@ -194,10 +194,13 @@ def _manifest_files(step_dir: str) -> Dict[str, Dict[str, int]]:
 
 def write_manifest(step_dir: str, step: int) -> None:
     """Checksum every file of a FINALIZED step dir into integrity.json
-    (atomic tmp-rename write, fsync'd file and directory)."""
+    (atomic tmp-rename write, fsync'd file and directory). On a pod every
+    host sweeps the shared checkpoint root after a commit, so the tmp name
+    is per process: two hosts writing the same (identical) manifest must
+    not rename each other's tmp file away."""
     manifest = {"version": 1, "step": int(step),
                 "files": _manifest_files(step_dir)}
-    tmp = os.path.join(step_dir, MANIFEST_NAME + ".tmp")
+    tmp = os.path.join(step_dir, f"{MANIFEST_NAME}.tmp-{os.getpid()}")
     with open(tmp, "w") as fh:
         json.dump(manifest, fh)
         fh.flush()

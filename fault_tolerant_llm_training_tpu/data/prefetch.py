@@ -110,12 +110,31 @@ class DevicePrefetcher:
             return inputs, labels, state
         return item
 
-    def stop(self):
-        """Stop the background thread and drain the queue (used on fault
-        exits so the checkpoint write is not racing tokenization)."""
-        self._stop.set()
+    def _drain(self):
         try:
             while True:
                 self._q.get_nowait()
         except queue.Empty:
             pass
+
+    def stop(self):
+        """Tell the background thread to stop and drain the queue (used on
+        fault exits so the checkpoint write is not racing tokenization).
+        Does not wait for the thread: the fault-path save must not queue
+        behind a batch still being tokenized."""
+        self._stop.set()
+        self._drain()
+
+    def close(self):
+        """:meth:`stop`, then join the worker. Must run before the
+        interpreter finalizes: the worker is a daemon thread that holds
+        device arrays, and a daemon thread that drops a ``jax.Array``
+        (or sits in ``device_put``) once finalization has begun is
+        ``pthread_exit``-ed inside jaxlib's noexcept destructor — glibc
+        aborts the process (``FATAL: exception not rethrown``, rc 134),
+        which the scheduler reads as a failed job."""
+        self.stop()
+        t = self._thread
+        while t is not None and t.is_alive():
+            t.join(timeout=0.05)
+            self._drain()  # a worker blocked in put() needs room to leave

@@ -56,8 +56,11 @@ class ByteTokenizer:
         return {"input_ids": ids}
 
     def decode(self, ids) -> str:
+        # ids past the byte range exist whenever the model's vocab is
+        # padded wider than the tokenizer's (--vocab-size 50257 over
+        # bytes); they carry no text, like the specials
         data = bytes(int(i) - self._OFFSET for i in ids
-                     if int(i) >= self._OFFSET)
+                     if self._OFFSET <= int(i) < self.vocab_size)
         return data.decode("utf-8", errors="replace")
 
 

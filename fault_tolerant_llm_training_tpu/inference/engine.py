@@ -100,15 +100,14 @@ import numpy as np
 
 from ..models.configs import TransformerConfig
 from ..models.llama import Transformer, unstack_layer_params
+from ..ops.attention import describe_kernel_mode
 from ..parallel.mesh import use_mesh
 from ..parallel.sharding import param_shardings
 # Re-exported for backward compatibility: serve.py, scripts/decode_bench.py
 # and tests imported these from here before the cache wiring moved to
 # utils/ (so the trainer can use it without importing inference/).
-from ..utils.compile_cache import (  # noqa: F401
-    DEFAULT_COMPILE_CACHE_DIR,
-    enable_compilation_cache,
-)
+from ..utils.compile_cache import enable_compilation_cache  # noqa: F401
+from ..utils.device import describe_device
 from .kv_cache import (
     KVCache,
     PagedKVCache,
@@ -324,6 +323,8 @@ class InferenceEngine:
             raise ValueError("paged_kernel selection requires the paged "
                              "KV layout")
         self.paged_kernel = paged_kernel
+        logger.info(f"Device | {describe_device()}")
+        logger.info(f"Paged kernel | {describe_kernel_mode(paged_kernel)}")
         if cfg.layer_impl == "scan":
             params = unstack_layer_params(params, cfg.n_layers)
             cfg = cfg.replace(layer_impl="loop")

@@ -86,7 +86,6 @@ from ..utils.logging import (
     logger,
 )
 from .engine import (
-    DEFAULT_COMPILE_CACHE_DIR,
     InferenceEngine,
     enable_compilation_cache,
 )
@@ -326,10 +325,8 @@ def main(argv=None) -> None:
                     f"{bound_metrics_port}")
 
     with flag.deferred():  # block delivery across compile + Orbax restore
-        cache_dir = (DEFAULT_COMPILE_CACHE_DIR
-                     if args.compile_cache_dir is None
-                     else args.compile_cache_dir)
-        if enable_compilation_cache(cache_dir):
+        cache_dir = enable_compilation_cache(args.compile_cache_dir)
+        if cache_dir:
             logger.info(f"Compilation cache | {cache_dir}")
         tokenizer = load_tokenizer(args.tokenizer_name_or_path)
         vocab = args.vocab_size or tokenizer.vocab_size

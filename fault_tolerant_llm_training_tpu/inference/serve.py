@@ -58,7 +58,6 @@ from ..utils.logging import (
     logger,
 )
 from .engine import (
-    DEFAULT_COMPILE_CACHE_DIR,
     InferenceEngine,
     enable_compilation_cache,
     restore_params,
@@ -284,8 +283,9 @@ def get_serve_args(argv=None) -> argparse.Namespace:
     p.add_argument("--compile-cache-dir",
                    default=None,
                    help="JAX persistent compilation cache directory "
-                        "(default: ~/.cache/fault_tolerant_llm_training_tpu/"
-                        "xla-cache; '' disables). Warm engine builds skip "
+                        "(default: .jax_compile_cache in the checkout; '' "
+                        "disables; the JAX_COMPILATION_CACHE_DIR env var "
+                        "wins over this flag). Warm engine builds skip "
                         "the AOT prefill/decode compiles")
     p.add_argument("--spec-k", type=int, default=0,
                    help="speculative decoding: draft proposes k tokens per "
@@ -448,10 +448,8 @@ def main(argv=None) -> None:
     events.emit_audit(logger, AUDIT_SERVE_START, "start")
 
     with flag.deferred():  # block delivery across compile + Orbax restore
-        cache_dir = (DEFAULT_COMPILE_CACHE_DIR
-                     if args.compile_cache_dir is None
-                     else args.compile_cache_dir)
-        if enable_compilation_cache(cache_dir):
+        cache_dir = enable_compilation_cache(args.compile_cache_dir)
+        if cache_dir:
             logger.info(f"Compilation cache | {cache_dir}")
         tokenizer = load_tokenizer(args.tokenizer_name_or_path)
         vocab = args.vocab_size or tokenizer.vocab_size

@@ -31,7 +31,6 @@ from ..ops.rope import (
     precompute_rope,
     rope_cos_sin,
 )
-from ..parallel.mesh import mesh_axis_size
 from ..parallel.sharding import constrain
 from .configs import TransformerConfig
 
@@ -149,10 +148,12 @@ class Attention(nn.Module):
         # replaced (S=8192: 39.7k vs 40.3k tokens/s, −1.4% — BASELINE.md
         # round 5), so those keep the Dense projections.
         impl = cfg.attention_impl
-        ring = impl in ("auto", "ring") and mesh_axis_size("sequence") > 1
-        resolved = impl
-        if impl in ("auto", "ring"):
-            resolved = "pallas" if jax.default_backend() == "tpu" else "xla"
+        from ..ops.attention import (
+            resolve_attention_impl,
+            ring_attention_active,
+        )
+        ring = ring_attention_active(impl)
+        resolved = resolve_attention_impl(impl)
         from ..ops.flash_attention import rope_fused_profitable
         fused_rope_branch = (not ring and resolved == "pallas"
                              and positions is None and cache is None

@@ -104,7 +104,7 @@ class HeartbeatThread(threading.Thread):
         self.registry = registry or default_registry()
         self.interval = interval_seconds
         self.clock = clock
-        self._stop = threading.Event()
+        self._stop_event = threading.Event()
         self._age = self.registry.gauge(
             "ftl_host_heartbeat_age_seconds",
             "Seconds since each host last published a heartbeat")
@@ -123,12 +123,18 @@ class HeartbeatThread(threading.Thread):
             self._step.labels(host=str(host)).set(step)
 
     def run(self) -> None:
-        while not self._stop.is_set():
+        while not self._stop_event.is_set():
             try:
                 self.beat_once()
             except Exception:
                 pass  # observability must never take down training
-            self._stop.wait(self.interval)
+            self._stop_event.wait(self.interval)
 
     def stop(self) -> None:
-        self._stop.set()
+        """Stop and join: on a pod the beat is a KV-client call, and a
+        daemon thread still inside native code when the interpreter
+        finalizes aborts the process (see data/prefetch.py ``close``).
+        Bounded — a wedged KV channel must not hang the exit."""
+        self._stop_event.set()
+        if self.is_alive():
+            self.join(timeout=5.0)

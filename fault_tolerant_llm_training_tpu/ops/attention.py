@@ -23,6 +23,8 @@ bandwidth).
 import jax
 import jax.numpy as jnp
 
+from ..parallel.mesh import mesh_axis_size
+
 
 def _causal_mask(s_q: int, s_k: int, dtype=jnp.float32) -> jnp.ndarray:
     """Additive causal mask (s_q, s_k); query i attends keys <= i (+ offset)."""
@@ -290,6 +292,41 @@ def paged_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
                      f"(want 'gather' or 'pallas')")
 
 
+def resolve_attention_impl(impl: str) -> str:
+    """The one statement of the ``auto`` rule: pallas on a TPU backend, xla
+    elsewhere. ``ring`` resolves the same way — it only exists under a >1
+    'sequence' mesh axis, which the model layer checks first
+    (models/llama.py); with no axis to ring over it is the dense kernel."""
+    if impl in ("auto", "ring"):
+        return "pallas" if jax.default_backend() == "tpu" else "xla"
+    return impl
+
+
+def ring_attention_active(impl: str) -> bool:
+    """Ring attention runs iff it may be chosen AND the active mesh has a
+    'sequence' axis to ring over."""
+    return impl in ("auto", "ring") and mesh_axis_size("sequence") > 1
+
+
+def describe_attention_impl(impl: str) -> str:
+    """What ``impl`` resolves to under the active mesh, for the trainer's
+    start-up line: ``xla``, ``pallas (compiled)``, ``ring pallas (...)``."""
+    if ring_attention_active(impl):
+        return "ring " + describe_kernel_mode("pallas")  # ops/ring_flash.py
+    return describe_kernel_mode(resolve_attention_impl(impl))
+
+
+def describe_kernel_mode(impl: str) -> str:
+    """``impl`` as a start-up log states it: Pallas implementations say
+    whether the kernels are compiled for the chip or run interpreted (the
+    CPU test mode) — a run that quietly took interpret mode, or ``xla``,
+    on a chip is then visible in its own log."""
+    if impl != "pallas":
+        return impl
+    from .flash_attention import _interpret
+    return f"pallas ({'interpret' if _interpret() else 'compiled'})"
+
+
 def multihead_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                         impl: str = "auto", causal: bool = True) -> jnp.ndarray:
     """Dispatch to the requested attention implementation.
@@ -302,8 +339,7 @@ def multihead_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     call has no axis to ring over, so it gets the equivalent dense
     kernel instead of an opaque raise.
     """
-    if impl in ("auto", "ring"):
-        impl = "pallas" if jax.default_backend() == "tpu" else "xla"
+    impl = resolve_attention_impl(impl)
     if impl == "xla":
         return xla_attention(q, k, v, causal=causal)
     if impl == "pallas":

@@ -1093,9 +1093,57 @@ def _interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _per_shard(local, head_dim: int, q, k, v, *tables):
+    """Run the single-device kernel ``local`` on each device's (batch,
+    head) shard of the active mesh.
+
+    Mosaic kernels cannot be partitioned by the compiler: issued bare
+    under ``jit`` with sharded operands they fail to lower on any mesh of
+    more than one device. Attention is independent per batch row and per
+    head, so a ``shard_map`` over the batch axes ('data', 'fsdp') and the
+    head axis ('tensor') is exact, with no collective inside. Every other
+    mesh axis not already manual (the pipeline trunk is manual over
+    'pipe') is made manual with the operands replicated along it — the
+    kernel needs ALL axes manual. A dim its axes do not divide (the
+    batch-1 dummy of ``model.init``) is replicated instead, as
+    ops/ring_attention.py does. A 1-device mesh keeps the bare call.
+
+    ``head_dim``: index of the head dim (2 canonical, 1 head-major);
+    ``tables``: the replicated rope tables of the fused variant."""
+    from ..parallel.mesh import active_mesh
+
+    mesh = active_mesh()
+    if mesh is None or mesh.size == 1:
+        return local(q, k, v, *tables)
+    from jax.sharding import PartitionSpec as P
+
+    dp_total = mesh.shape["data"] * mesh.shape["fsdp"]
+    tp = mesh.shape["tensor"]
+    spec = [None] * q.ndim
+    if q.shape[0] % dp_total == 0:
+        spec[0] = ("data", "fsdp")
+    if q.shape[head_dim] % tp == 0 and k.shape[head_dim] % tp == 0:
+        spec[head_dim] = "tensor"
+    spec = P(*spec)
+    # nested in a partial-manual region (the pipeline trunk), shard_map
+    # must be handed that context's mesh, not the all-auto concrete one
+    ctx = jax.sharding.get_abstract_mesh()
+    fn = jax.shard_map(
+        local, mesh=ctx if ctx.manual_axes else mesh,
+        in_specs=(spec, spec, spec) + (P(),) * len(tables), out_specs=spec,
+        axis_names=set(mesh.axis_names) - set(ctx.manual_axes),
+        check_vma=False)
+    return fn(q, k, v, *tables)
+
+
 def flash_attention(q, k, v, causal=True):
     """Causal flash attention; q (B,S,H,D), k/v (B,S,K,D) -> (B,S,H,D)."""
+    return _per_shard(
+        lambda q, k, v: _flash_attention(q, k, v, causal), 2, q, k, v)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _flash_attention(q, k, v, causal):
     out, _ = _flash_fwd(q, k, v, causal, _interpret())
     return out
 
@@ -1110,10 +1158,9 @@ def _flash_attention_bwd(causal, residuals, g):
     return _flash_bwd(q, k, v, o, lse, g, causal, _interpret())
 
 
-flash_attention.defvjp(_flash_attention_fwd, _flash_attention_bwd)
+_flash_attention.defvjp(_flash_attention_fwd, _flash_attention_bwd)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
 def flash_attention_bhsd(q, k, v, causal=True):
     """Head-major entry: q (B,H,S,D), k/v (B,K,S,D) -> (B,H,S,D).
 
@@ -1125,6 +1172,12 @@ def flash_attention_bhsd(q, k, v, causal=True):
     out in the layout the rope backward wants. This is what eliminates
     the fp32 relayout-copy family at the custom-call boundary
     (BASELINE.md round-4)."""
+    return _per_shard(
+        lambda q, k, v: _flash_attention_bhsd(q, k, v, causal), 1, q, k, v)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _flash_attention_bhsd(q, k, v, causal):
     out, _ = _flash_fwd_t(q, k, v, causal, _interpret())
     return out
 
@@ -1139,11 +1192,10 @@ def _flash_attention_bhsd_bwd(causal, residuals, g):
     return _flash_bwd_t(q, k, v, o, lse, g, causal, _interpret())
 
 
-flash_attention_bhsd.defvjp(_flash_attention_bhsd_fwd,
-                            _flash_attention_bhsd_bwd)
+_flash_attention_bhsd.defvjp(_flash_attention_bhsd_fwd,
+                             _flash_attention_bhsd_bwd)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
 def flash_attention_rope(q, k, v, cos2, sin2, causal=True):
     """Flash attention with RoPE applied INSIDE the kernels.
 
@@ -1166,6 +1218,13 @@ def flash_attention_rope(q, k, v, cos2, sin2, causal=True):
     fed pre-rotated inputs (tested in tests/test_flash_attention.py);
     under bf16 the q side agrees to one rounding — the fused path rounds
     once where the XLA rope + prescale chain rounds twice (ADVICE r4)."""
+    return _per_shard(
+        lambda q, k, v, c, s: _flash_attention_rope(q, k, v, c, s, causal),
+        1, q, k, v, cos2, sin2)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _flash_attention_rope(q, k, v, cos2, sin2, causal):
     out, _ = _flash_fwd_t(q, k, v, causal, _interpret(), (cos2, sin2))
     return out
 
@@ -1183,5 +1242,5 @@ def _flash_attention_rope_bwd(causal, residuals, g):
     return dq, dk, dv, jnp.zeros_like(cos2), jnp.zeros_like(sin2)
 
 
-flash_attention_rope.defvjp(_flash_attention_rope_fwd,
-                            _flash_attention_rope_bwd)
+_flash_attention_rope.defvjp(_flash_attention_rope_fwd,
+                             _flash_attention_rope_bwd)

@@ -44,7 +44,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from ..utils.jax_compat import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from .cross_entropy import DEFAULT_BLOCK

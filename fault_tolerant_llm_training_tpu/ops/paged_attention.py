@@ -451,7 +451,9 @@ def _tree_kernel(tables_ref, offs_ref, *args, block_size: int, group: int,
     Row r (tree node ``r // group``) attends every committed key
     (``k_pos < offset``) and, inside the speculative window
     ``[offset, offset + s_q)``, exactly the keys of the nodes on its root
-    path: ``anc_ref[r // group, j]`` gates window key ``offset + j``.
+    path: ``anc_ref[r, j]`` gates window key ``offset + j`` (the wrapper
+    hands the mask in already expanded to one row per q row — Mosaic has
+    no layout for an in-kernel (s_q, group) -> (rows, 1) reshape).
     The mask is built by a static unroll over the s_q window nodes — an
     equality compare against each node's k_pos AND'd with that node's
     ancestor column — so sibling/cousin keys are NEG_INF'd and underflow
@@ -497,8 +499,7 @@ def _tree_kernel(tables_ref, offs_ref, *args, block_size: int, group: int,
             jnp.int32, (rows, block_size), 1)
         vis = k_pos < offset                               # committed keys
         for t_node in range(s_q):
-            col = jnp.broadcast_to(anc_ref[:, t_node:t_node + 1],
-                                   (s_q, group)).reshape(rows, 1)
+            col = anc_ref[:, t_node:t_node + 1]            # (rows, 1)
             vis = vis | ((k_pos == offset + t_node) & (col > 0))
         s = jnp.where(vis, s, NEG_INF)
         m_prev, l_prev = m_scr[:, 0], l_scr[:, 0]
@@ -559,7 +560,8 @@ def paged_tree_chunk_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
           .transpose(0, 2, 1, 3, 4).reshape(b, kv, rows, d))
     tables = block_tables.reshape(-1).astype(jnp.int32)
     offs = offsets.astype(jnp.int32)
-    anc = anc_mask.astype(jnp.int32)
+    # one mask row per q row (row r is tree node r // g)
+    anc = jnp.repeat(anc_mask.astype(jnp.int32), g, axis=0)
     kernel = functools.partial(_tree_kernel, block_size=bs, group=g,
                                s_q=s_q, scale=1.0 / math.sqrt(d),
                                quantized=bool(scale_ops))
@@ -571,7 +573,7 @@ def paged_tree_chunk_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
             in_specs=[
                 pl.BlockSpec((1, 1, rows, d),
                              lambda bi, hi, j, t, *pref: (bi, hi, 0, 0)),
-                pl.BlockSpec((s_q, s_q),
+                pl.BlockSpec((rows, s_q),
                              lambda bi, hi, j, t, *pref: (0, 0)),
                 pl.BlockSpec((1, 1, bs, d),
                              lambda bi, hi, j, t, *pref: (t[bi * nb + j],

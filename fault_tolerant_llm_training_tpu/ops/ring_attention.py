@@ -47,6 +47,7 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map as _shard_map
 from jax.sharding import PartitionSpec as P
 
 from ..parallel.mesh import active_mesh
@@ -59,8 +60,6 @@ from .ring_flash import (
     finalize_carry,
     fresh_carry,
 )
-
-from ..utils.jax_compat import shard_map as _shard_map
 
 NEG_INF = -1e30
 

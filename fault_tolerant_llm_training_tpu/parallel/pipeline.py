@@ -88,7 +88,7 @@ when M is small and memory is not binding.
 
 import jax
 import jax.numpy as jnp
-from ..utils.jax_compat import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from ..parallel.mesh import active_mesh

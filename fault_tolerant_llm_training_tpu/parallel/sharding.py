@@ -200,19 +200,13 @@ def constrain(x: jax.Array, *logical_axes) -> jax.Array:
     mesh = active_mesh()
     if mesh is None or len(logical_axes) != x.ndim or _CONSTRAINTS_SUSPENDED:
         return x
-    # get_abstract_mesh landed after 0.4.x; without it there is no
-    # Manual-context introspection (and no partial-manual tracing either),
-    # so the constraint is always safe to emit.
-    _get_abstract = getattr(jax.sharding, "get_abstract_mesh", None)
-    abstract = _get_abstract() if _get_abstract is not None else None
-    if abstract is not None and getattr(abstract, "shape_tuple", ()):
-        if any(str(kind) == "Manual" for kind in abstract.axis_types):
-            # Inside a partial-manual shard_map (the pipeline trunk,
-            # parallel/pipeline.py) constraints built on the all-auto
-            # concrete mesh clash with the Manual context (and rebuilt ones
-            # still break under autodiff replay); the auto axes' shardings
-            # propagate from the body's inputs, so skip the hint here.
-            return x
+    if jax.sharding.get_abstract_mesh().manual_axes:
+        # Inside a partial-manual shard_map (the pipeline trunk,
+        # parallel/pipeline.py) constraints built on the all-auto
+        # concrete mesh clash with the Manual context (and rebuilt ones
+        # still break under autodiff replay); the auto axes' shardings
+        # propagate from the body's inputs, so skip the hint here.
+        return x
     spec = _fit_spec(_resolve(logical_axes), x.shape, mesh)
     return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
 

@@ -45,12 +45,14 @@ from ..deploy.publish import Publisher
 from ..obs import events
 from ..obs.registry import REGISTRY
 from ..obs.trace import AutoTraceWindow, TraceWindow
+from ..ops.attention import describe_attention_impl
 from ..parallel.mesh import make_mesh, use_mesh
 from ..parallel.sharding import batch_pspec, param_pspecs
 from ..training.state import TrainState
 from ..training.step import make_eval_step, make_optimizer, make_train_step
 from ..utils.compile_cache import enable_compilation_cache
 from ..utils.config import JOBID, TrainConfig
+from ..utils.device import describe_device
 from ..utils.dtypes import PRECISION_STR_TO_DTYPE
 from ..utils.grad_clip import NonFiniteGradientError
 from ..utils.logging import (
@@ -62,6 +64,7 @@ from ..utils.logging import (
 )
 from ..utils.metrics import (
     Throughput,
+    device_memory_report,
     device_peak_flops,
     hbm_usage_str,
     mfu,
@@ -109,6 +112,12 @@ class Trainer:
             self.signal_flag.register()
 
         logger.info(f"Experiment args: {cfg}")  # ref: train.py:14
+        # Before the first jit of the process, so the init / restore
+        # programs cache too, not only the train step (on by default:
+        # utils/compile_cache.py says where it lives).
+        cache_dir = enable_compilation_cache(cfg.compile_cache_dir)
+        if cache_dir:
+            logger.info(f"Compilation cache | {cache_dir}")
 
         if cfg.distributed:
             # jax.distributed auto-detects Slurm/TPU-pod topologies; outside
@@ -294,6 +303,12 @@ class Trainer:
                     f"moe_experts {self.model_config.moe_experts} not "
                     f"divisible by --ep {cfg.ep}")
         self.model = Transformer(self.model_config)
+        # What this run resolved, in its own log: a job that quietly took
+        # the CPU, the XLA attention or interpret-mode kernels on a chip
+        # host must be visible without a profiler.
+        logger.info(f"Device | {describe_device()}")
+        logger.info(f"Attention | requested {cfg.attention_impl} | resolved "
+                    f"{describe_attention_impl(cfg.attention_impl)}")
         self.optimizer = make_optimizer(
             cfg.learning_rate, cfg.lr_warmup_steps,
             lr_schedule=cfg.lr_schedule,
@@ -325,6 +340,9 @@ class Trainer:
             n_params - self.model_config.vocab_size * self.model_config.dim,
             cfg.sequence_length, self.model_config.dim,
             self.model_config.n_layers, causal=True)
+        # resolved here, not at the first log line: a TPU whose peak is
+        # not on record fails before any training happens
+        self._peak_flops = device_peak_flops()
 
         if read_mngr is not None:
             t_restore = time.perf_counter()
@@ -380,15 +398,10 @@ class Trainer:
         # AOT-compile now, inside the signal-deferred setup window: a
         # preemption signal interrupting XLA compilation can wedge native
         # code, and compilation is the longest uninterruptible stretch
-        # (~35 s model build in the reference, SURVEY.md §3.2). With
-        # --compile-cache-dir a warm restart replaces the compile with a
-        # disk read; the timed "compile" flight-recorder event is how
-        # goodput reports distinguish cold from warm builds.
-        cache_on = False
-        if cfg.compile_cache_dir:
-            cache_on = enable_compilation_cache(cfg.compile_cache_dir)
-            if cache_on:
-                logger.info(f"Compilation cache | {cfg.compile_cache_dir}")
+        # (~35 s model build in the reference, SURVEY.md §3.2). The
+        # persistent cache makes a warm restart's compile a disk read; the
+        # timed "compile" flight-recorder event is how goodput reports
+        # distinguish cold from warm builds.
         batch_struct = jax.ShapeDtypeStruct(
             (cfg.batch_size, cfg.sequence_length), jnp.int32,
             sharding=self.batch_sharding)
@@ -401,9 +414,9 @@ class Trainer:
         # start/resume (tests/test_obs.py, goodput stitcher)
         self._compile_event = dict(step=self.training_step,
                                    dur=compile_secs,
-                                   cache=("on" if cache_on else "off"))
+                                   cache=("on" if cache_dir else "off"))
         logger.info(f"Train step compiled in {compile_secs:.2f}s "
-                    f"(cache {'on' if cache_on else 'off'})")
+                    f"(cache {cache_dir or 'off'})")
         self.prefetcher = DevicePrefetcher(
             self.loader, sharding=self.batch_sharding, depth=cfg.prefetch,
             chaos_on_batch=(self.chaos.on_batch if self.chaos else None),
@@ -721,8 +734,8 @@ class Trainer:
             self._dispatched += 1
             self._last_data_state = data_state
             # The jitted step pre-packs (loss, grad_norm) into one array so
-            # _consume pays ONE host round trip per step, not one per metric
-            # (each fetch is a full RPC on tunneled device transports).
+            # _consume pays ONE device-to-host transfer (and one sync) per
+            # step, not one per metric.
             self._inflight.append((self.training_step, metrics["packed"]))
             while len(self._inflight) >= max(1, cfg.inflight):
                 self._consume(*self._inflight.popleft())
@@ -930,11 +943,11 @@ class Trainer:
             if tps:
                 window = self.throughput.window_tag or "steady"
                 self._m_tps.labels(window=window).set(tps)
-                peak = device_peak_flops()
-                if peak:
+                if self._peak_flops:
                     self._m_mfu.set(mfu(tps / max(jax.process_count(), 1)
                                         / max(jax.local_device_count(), 1),
-                                        self._flops_per_token, peak))
+                                        self._flops_per_token,
+                                        self._peak_flops))
                 for dev, used, limit in per_device_memory_stats():
                     self._m_hbm_used.labels(device=dev).set(used)
                     if limit:
@@ -1104,7 +1117,14 @@ class Trainer:
         return step
 
     def close(self) -> None:
-        self.prefetcher.stop()
+        """Stop and JOIN everything this trainer started, and finish
+        Orbax's background work, while the interpreter is still whole —
+        the exit-0 contract (train.py) dies with any thread left inside
+        native code at finalization (data/prefetch.py ``close``)."""
+        memory = device_memory_report()
+        if memory:
+            logger.info(f"Device memory | {memory}")
+        self.prefetcher.close()
         self.ckpt_mngr.close()
         if self._trace is not None:
             self._trace.close()

@@ -4,59 +4,52 @@ Every preempt -> resubmit restart cold-compiles its AOT programs — the
 train step for the trainer, a decode program plus one prefill program per
 bucket (plus the speculative draft/verify pair) for the serving engine.
 Cold compiles are pure MTTR: nothing useful runs while XLA rebuilds code
-it already built last incarnation. Pointing ``jax_compilation_cache_dir``
-at a persistent path turns that wall into a disk read.
+it already built last incarnation. A persistent cache turns that wall
+into a disk read, so it is ON by default for every entry point.
+
+Where the cache lives, in order:
+
+1. ``JAX_COMPILATION_CACHE_DIR`` — JAX reads it itself; this module then
+   sets no directory in code, so whoever runs the program (a scheduler
+   prolog, a test harness, the machine image) places the cache.
+2. an explicit ``cache_dir`` (the ``--compile-cache-dir`` flags).
+3. :data:`DEFAULT_COMPILE_CACHE_DIR` — ONE fixed path inside the
+   checkout. The path is part of the cache's key, so a directory named
+   after a pid, a time or a temp dir would never hit.
 
 Lives in utils/ so the training loop does not import inference/ for it;
-inference/engine.py re-exports the names for backward compatibility
-(serve.py, scripts/decode_bench.py, tests).
+inference/engine.py re-exports the names (serve.py, scripts/decode_bench.py,
+tests).
 """
 
 import os
+from typing import Optional
 
 import jax
 
 DEFAULT_COMPILE_CACHE_DIR = os.path.join(
-    os.path.expanduser("~"), ".cache", "fault_tolerant_llm_training_tpu",
-    "xla-cache")
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_compile_cache")
 
 
-def enable_compilation_cache(cache_dir: str = DEFAULT_COMPILE_CACHE_DIR
-                             ) -> bool:
-    """Point JAX's persistent compilation cache at ``cache_dir``.
+def enable_compilation_cache(cache_dir: Optional[str] = None) -> str:
+    """Turn on JAX's persistent compilation cache and return the directory
+    in effect (``""`` = the cache stays off).
 
-    Engine builds AOT-compile a decode program plus one prefill program per
-    bucket; cold that dominates small-run wall time (16.8 s of the tiny CPU
-    bench), warm it is a disk read. No-ops (returns False) when ``cache_dir``
-    is empty, when the user already configured a cache (the
-    ``JAX_COMPILATION_CACHE_DIR`` env var / prior config.update wins), or on
-    jax versions without the option. Min-compile-time/entry-size floors drop
-    to 0 so even the tiny test programs cache.
+    ``cache_dir=None`` means the fixed default, ``""`` means off — but a
+    cache already configured (the ``JAX_COMPILATION_CACHE_DIR`` env var,
+    or an earlier call) wins over both and is left exactly as it is.
+    Where this function sets the directory it also drops the
+    min-compile-time / entry-size floors to 0, so the small prefill-bucket
+    programs cache too.
     """
-    if not cache_dir:
-        return False
-    try:
-        if getattr(jax.config, "jax_compilation_cache_dir", None):
-            return True  # already configured (env var or earlier call)
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-    except Exception:  # pragma: no cover - ancient jax
-        return False
-    for knob, val in (("jax_persistent_cache_min_compile_time_secs", 0.0),
-                      ("jax_persistent_cache_min_entry_size_bytes", 0)):
-        try:
-            jax.config.update(knob, val)
-        except Exception:  # pragma: no cover - knob absent on this jax
-            pass
-    try:
-        # jax latches cache state ("disabled") at the FIRST compile of the
-        # process; by the time a caller reaches here the trainer/engine has
-        # usually already jitted something (mesh setup, model init), so the
-        # new dir would silently never be read or written. reset_cache()
-        # returns the latch to pristine and the next compile re-initializes
-        # against the dir configured above.
-        from jax.experimental.compilation_cache import (
-            compilation_cache as _cc)
-        _cc.reset_cache()
-    except Exception:  # pragma: no cover - API drift across jax versions
-        pass
-    return True
+    configured = jax.config.jax_compilation_cache_dir
+    if configured:
+        return configured
+    if cache_dir == "":
+        return ""
+    cache_dir = cache_dir or DEFAULT_COMPILE_CACHE_DIR
+    jax.config.update("jax_compilation_cache_dir", cache_dir)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    return cache_dir

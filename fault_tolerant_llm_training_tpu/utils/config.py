@@ -142,11 +142,13 @@ class TrainConfig:
     # host publishes and sweeps regardless of --metrics-port — the age
     # gauges also feed the flight recorder, not just a scraper.
     heartbeat_seconds: float = 10.0
-    # JAX persistent compilation cache directory (utils/compile_cache.py);
-    # "" = off. A warm cache turns the restart-after-preemption compile
-    # into a disk read — the build time lands in the flight recorder
-    # either way, so goodput reports show cold vs warm directly.
-    compile_cache_dir: str = ""
+    # JAX persistent compilation cache directory (utils/compile_cache.py):
+    # None = the fixed in-checkout default, "" = off; the
+    # JAX_COMPILATION_CACHE_DIR env var beats both. A warm cache turns the
+    # restart-after-preemption compile into a disk read — the build time
+    # lands in the flight recorder either way, so goodput reports show
+    # cold vs warm directly.
+    compile_cache_dir: Optional[str] = None
     resubmit_command: str = ""  # override for tests; default: sbatch $WORKDIR/train.sh
     distributed: bool = False  # call jax.distributed.initialize() (multi-host pods)
 
@@ -390,11 +392,14 @@ def get_args(argv: Optional[list] = None) -> TrainConfig:
     parser.add_argument("--heartbeat-seconds", type=float, default=10.0,
                         help="Per-host heartbeat publish interval (KV "
                              "store; ftl_host_heartbeat_* gauges); 0 = off")
-    parser.add_argument("--compile-cache-dir", type=str, default="",
-                        help="JAX persistent compilation cache directory; "
-                             "'' = off. Warm restarts skip the train-step "
-                             "XLA compile; build time is logged cold vs "
-                             "warm through the flight recorder")
+    parser.add_argument("--compile-cache-dir", type=str, default=None,
+                        help="JAX persistent compilation cache directory "
+                             "(default: .jax_compile_cache in the checkout; "
+                             "'' = off; the JAX_COMPILATION_CACHE_DIR env "
+                             "var wins over this flag). Warm restarts skip "
+                             "the train-step XLA compile; build time is "
+                             "logged cold vs warm through the flight "
+                             "recorder")
     parser.add_argument("--resubmit-command", type=str, default="",
                         help="Override the self-resubmit command (tests); "
                              "default: sbatch $WORKDIR/train.sh $SLURM_JOB_ID")

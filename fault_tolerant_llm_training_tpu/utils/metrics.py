@@ -70,25 +70,41 @@ def mfu(tokens_per_sec: float, flops_per_token: float, peak_flops: float) -> flo
     return tokens_per_sec * flops_per_token / peak_flops
 
 
-V5E_BF16_PEAK = 197e12  # TPU v5e peak bf16 FLOP/s (public spec)
+# Peak dense bf16 FLOP/s of ONE chip, keyed by ``Device.device_kind`` as
+# JAX reports it (every listed generation is one JAX device per chip).
+# Source: Google Cloud TPU documentation, the "System architecture" page of
+# each version ("TPU v4", "TPU v5p", "TPU v5e", "TPU v6e"). A TPU that is
+# not listed is an error where MFU is asked for — never a default.
+PEAK_BF16_FLOPS = {
+    "TPU v4": 275e12,
+    "TPU v5": 459e12,       # v5p
+    "TPU v5 lite": 197e12,  # v5e
+    "TPU v6 lite": 918e12,  # v6e
+}
 
 
 def device_peak_flops() -> Optional[float]:
-    """Per-chip peak FLOP/s for MFU, or None off-TPU. Same convention as
-    bench.py: the constant is v5e-specific, so MFU is only claimed on an
-    actual TPU backend — a CPU 'MFU' against a TPU peak is noise."""
-    try:
-        import jax
-        backend = jax.default_backend()
-    except Exception:
+    """Per-chip peak bf16 FLOP/s for MFU, from :data:`PEAK_BF16_FLOPS` by
+    the first device's ``device_kind``. None off-TPU (a CPU 'MFU' against
+    a TPU peak is noise); an unlisted TPU kind raises."""
+    import jax
+
+    device = jax.devices()[0]
+    if device.platform != "tpu":
         return None
-    return V5E_BF16_PEAK if backend == "tpu" else None
+    try:
+        return PEAK_BF16_FLOPS[device.device_kind]
+    except KeyError:
+        raise RuntimeError(
+            f"no peak FLOP/s on record for device_kind "
+            f"{device.device_kind!r}; add it to PEAK_BF16_FLOPS "
+            f"(utils/metrics.py) with its source") from None
 
 
 def per_device_memory_stats() -> List[Tuple[str, Optional[int], Optional[int]]]:
     """``(device id string, bytes_in_use, bytes_limit)`` for every LOCAL
-    device; empty where the backend exposes no memory_stats (CPU; some
-    remote transports). Feeds the per-device HBM gauges in the metric
+    device; empty where the backend exposes no memory_stats (CPU). Feeds
+    the per-device HBM gauges in the metric
     registry — under pipeline/tensor sharding the devices are NOT
     symmetric (stage 0 holds the embedding, the last stage the LM head),
     and the loudest device is the one that OOMs."""
@@ -110,6 +126,26 @@ def per_device_memory_stats() -> List[Tuple[str, Optional[int], Optional[int]]]:
             continue
         out.append((str(getattr(d, "id", len(out))), used, limit))
     return out
+
+
+def device_memory_report() -> str:
+    """One line of every local device's ``bytes_in_use`` and
+    ``peak_bytes_in_use`` (the allocator's high-water mark for the whole
+    process), or '' without backend memory stats. Logged at teardown with
+    the train state still resident, so it shows whether the state is
+    spread over the mesh. On the v5e runtime the peak counts buffers only,
+    not an executable's scratch (PERF.md §7), so it is NOT the step's peak
+    HBM."""
+    import jax
+
+    parts = []
+    for d in jax.local_devices():
+        stats = d.memory_stats() or {}
+        if "bytes_in_use" in stats:
+            parts.append(
+                f"dev {d.id}: in use {stats['bytes_in_use'] / 1e9:.2f} GB, "
+                f"peak {stats.get('peak_bytes_in_use', 0) / 1e9:.2f} GB")
+    return " | ".join(parts)
 
 
 def device_memory_stats():

@@ -101,7 +101,6 @@ def main():
 
     from fault_tolerant_llm_training_tpu.data.tokenizer import load_tokenizer
     from fault_tolerant_llm_training_tpu.inference.engine import (
-        DEFAULT_COMPILE_CACHE_DIR,
         InferenceEngine,
         enable_compilation_cache,
     )
@@ -109,9 +108,7 @@ def main():
     from fault_tolerant_llm_training_tpu.models.configs import get_config
     from fault_tolerant_llm_training_tpu.models.llama import Transformer
 
-    cache_dir = (DEFAULT_COMPILE_CACHE_DIR if args.compile_cache_dir is None
-                 else args.compile_cache_dir)
-    cache_on = enable_compilation_cache(cache_dir)
+    cache_dir = enable_compilation_cache(args.compile_cache_dir)
 
     vocab = args.vocab_size or load_tokenizer("byte").vocab_size
     cfg = get_config(args.model, vocab_size=vocab,
@@ -169,7 +166,7 @@ def main():
         result = _adapter_serving(args, vocab)
     else:
         result = _uniform(args, build, reqs, backend)
-    result["compile_cache"] = cache_dir if cache_on else ""
+    result["compile_cache"] = cache_dir
 
     print(json.dumps(result))
     default_name = {"long_context": "BENCH_decode_paged",

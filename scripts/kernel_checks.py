@@ -494,6 +494,12 @@ def check_quantized_decode_parity(slots=8, kv=2, h=4, bs=16, nb=16, d=64,
 
 
 def main():
+    from fault_tolerant_llm_training_tpu.ops.flash_attention import _interpret
+    if _interpret():
+        sys.exit(f"kernel_checks: backend {jax.default_backend()!r} would run "
+                 f"the Pallas kernels interpreted; this script checks the "
+                 f"COMPILED kernels and runs on a TPU only (the CPU tests "
+                 f"cover interpret mode)")
     ok = True
     ok &= check_flash_parity(2048, 12, 12, 64)   # resident, bench shape
     ok &= check_flash_parity(4096, 4, 2, 64)     # streamed fwd + fused bwd, GQA

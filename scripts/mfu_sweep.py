@@ -1,6 +1,6 @@
 """A/B one train-step variant at the headline bench shape and print tokens/s.
 
-Same methodology as bench.py (mesh, donation, hard_sync, best-of-N passes)
+Same workload and sync as bench.py (mesh, donation, hard_sync; best-of-N passes)
 but parameterized so MFU experiments can be compared on the chip:
 
     python scripts/mfu_sweep.py --set fused_qkv=1

@@ -249,3 +249,57 @@ def test_lse_layout_dispatch(monkeypatch):
     monkeypatch.setenv("FTL_LSE_RESIDENT", "legacy")
     assert fa._lse_layout(2048, 64) == "legacy"    # opt-out knob
     assert fa._lse_layout(4096, 64) == "packed"    # knob is resident-only
+
+
+@pytest.mark.parametrize("mesh_kw", [dict(dp=4), dict(fsdp=4),
+                                     dict(fsdp=2, tp=2)],
+                         ids=["dp4", "fsdp4", "fsdp2-tp2"])
+@pytest.mark.parametrize("entry", ["canonical", "head_major", "rope_fused"])
+def test_flash_under_multi_device_mesh(mesh_kw, entry):
+    """Under a >1-device mesh every entry point runs its kernel per
+    (batch, head) shard inside a shard_map (Mosaic kernels cannot be
+    partitioned by the compiler): values AND gradients must equal the
+    bare single-device call — sharded jit inputs included, the form the
+    train step uses."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from fault_tolerant_llm_training_tpu.ops import flash_attention as fa
+    from fault_tolerant_llm_training_tpu.parallel.mesh import (
+        make_mesh,
+        use_mesh,
+    )
+
+    b, s, h, kv, d = 4, 256, 4, 2, 32
+    rng = np.random.default_rng(3)
+    if entry == "canonical":
+        shape = lambda heads: (b, s, heads, d)
+        spec = P(("data", "fsdp"), None, "tensor", None)
+        call = lambda q, k, v: fa.flash_attention(q, k, v, True)
+    else:
+        shape = lambda heads: (b, heads, s, d)
+        spec = P(("data", "fsdp"), "tensor", None, None)
+        if entry == "head_major":
+            call = lambda q, k, v: fa.flash_attention_bhsd(q, k, v, True)
+        else:
+            cos2 = jnp.asarray(rng.standard_normal((s, d)), jnp.float32)
+            sin2 = jnp.asarray(rng.standard_normal((s, d)), jnp.float32)
+            call = lambda q, k, v: fa.flash_attention_rope(q, k, v, cos2,
+                                                           sin2, True)
+    q = jnp.asarray(rng.standard_normal(shape(h)), jnp.float32)
+    k = jnp.asarray(rng.standard_normal(shape(kv)), jnp.float32)
+    v = jnp.asarray(rng.standard_normal(shape(kv)), jnp.float32)
+
+    def loss_and_grads(q, k, v):
+        return jax.value_and_grad(
+            lambda *a: jnp.sum(call(*a) ** 2), argnums=(0, 1, 2))(q, k, v)
+
+    want = loss_and_grads(q, k, v)  # no mesh: the bare kernel call
+    mesh = make_mesh(devices=jax.devices()[:4], **mesh_kw)
+    with use_mesh(mesh):
+        sharded = [jax.device_put(x, NamedSharding(mesh, spec))
+                   for x in (q, k, v)]
+        got = jax.jit(loss_and_grads)(*sharded)
+    for g, w in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                   rtol=1e-5, atol=1e-5)

@@ -68,3 +68,20 @@ def test_prefetcher_stop_drains():
     next(it)
     pf.stop()  # must not deadlock on a full queue
     assert pf._stop.is_set()
+
+
+@pytest.mark.parametrize("consumed", [0, 1, 50])
+def test_prefetcher_close_joins_the_worker(consumed):
+    """close() must leave no worker behind, wherever it was: blocked on a
+    full queue, mid-batch, or never started. A daemon thread that still
+    holds device arrays when the interpreter finalizes aborts the process
+    (rc 134) — the exit-0 contract depends on this join."""
+    pf = DevicePrefetcher(_StubLoader(n=100), depth=2)
+    if consumed:
+        it = iter(pf)
+        for _ in range(consumed):
+            next(it)
+    pf.close()
+    assert pf._thread is None or not pf._thread.is_alive()
+    assert pf._q.empty()
+    pf.close()  # idempotent: the exit handler and close() may both run
