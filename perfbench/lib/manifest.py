@@ -1,0 +1,100 @@
+"""Finds a cell's files by the names in ``BENCHMARK.json``.
+
+A cell is (configuration, traffic mix). The configuration is the file the
+manifest names; the traffic mix is ``perfbench/traffic/<traffic>.json``; a
+per-layer metric is ``perfbench/metrics/<name>.py``; the limits that decide
+``correct`` are ``perfbench/limits/<workload>.json``. Adding a cell or a
+metric adds files and entries and edits nothing here.
+"""
+
+import importlib.util
+import json
+import os
+import re
+
+BENCH_DIR = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+NAME_RE = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
+UNIT_RE = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
+SOURCES = ("device_trace", "program_span", "program_counter", "host_clock")
+
+
+def load_json(path: str) -> dict:
+    with open(path) as fh:
+        return json.load(fh)
+
+
+class Cell:
+    """Everything one run needs, resolved from names."""
+
+    def __init__(self, workload: str, root: str, bench_dir: str = BENCH_DIR):
+        self.root = root
+        self.bench_dir = bench_dir
+        self.manifest = load_json(os.path.join(root, "BENCHMARK.json"))
+        by_name = {w["name"]: w for w in self.manifest["workloads"]}
+        if workload not in by_name:
+            raise SystemExit(f"unknown workload {workload!r}; have "
+                             f"{sorted(by_name)}")
+        self.workload = by_name[workload]
+        self.name = workload
+        self.chips = int(self.workload["chips"])
+        cfg_entry = {c["name"]: c for c in self.manifest["configs"]}[
+            self.workload["config"]]
+        self.config_name = cfg_entry["name"]
+        self.config = load_json(os.path.join(root, cfg_entry["file"]))
+        self.traffic_name = self.workload["traffic"]
+        self.traffic = load_json(os.path.join(
+            bench_dir, "traffic", self.traffic_name + ".json"))
+        limits_path = os.path.join(bench_dir, "limits", workload + ".json")
+        self.limits = (load_json(limits_path)
+                       if os.path.exists(limits_path) else {})
+        self.kind = self.traffic["kind"]
+
+    def _reports(self, metric: dict, end_to_end: set) -> bool:
+        if "workloads" in metric:
+            return self.name in metric["workloads"]
+        # no list: every cell that reports the end-to-end metric it moves
+        return metric.get("moves", metric["name"]) in end_to_end
+
+    def end_to_end(self) -> list:
+        return [m for m in self.manifest["end_to_end"]
+                if "workloads" not in m or self.name in m["workloads"]]
+
+    def per_layer(self) -> list:
+        e2e = {m["name"] for m in self.end_to_end()}
+        return [m for m in self.manifest["per_layer"]
+                if self._reports(m, e2e)]
+
+    def work_dir(self) -> str:
+        """Scratch inside the checkout, at a fixed path (the compile
+        cache's key holds paths), emptied by the run that uses it."""
+        return os.path.join(self.root, ".perfbench_work", self.name)
+
+
+def load_reader(name: str, bench_dir: str = BENCH_DIR):
+    """The per-layer metric's own reader: ``metrics/<name>.py`` with
+    ``read(ctx) -> number | None``."""
+    path = os.path.join(bench_dir, "metrics", name + ".py")
+    spec = importlib.util.spec_from_file_location(
+        "perfbench_metric_" + re.sub(r"[^A-Za-z0-9_]", "_", name), path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod.read
+
+
+def check_names(manifest: dict) -> list:
+    """Every name/unit problem in a manifest (tests call this)."""
+    bad = []
+    for group in ("configs", "workloads", "end_to_end", "per_layer"):
+        for e in manifest[group]:
+            if not NAME_RE.match(e["name"]):
+                bad.append(f"{group}: name {e['name']!r}")
+            if "unit" in e and not UNIT_RE.match(e["unit"]):
+                bad.append(f"{group}: unit {e['unit']!r} of {e['name']}")
+            if "source" in e and group != "configs" and (
+                    e["source"] not in SOURCES):
+                bad.append(f"{group}: source {e['source']!r}")
+    for w in manifest["workloads"]:
+        for k in ("config", "traffic"):
+            if not NAME_RE.match(w[k]):
+                bad.append(f"workload {w['name']}: {k} {w[k]!r}")
+    return bad

@@ -1,0 +1,9 @@
+"""Device idle share of the traced training steps: 1 - busy union / window,
+from the device trace."""
+
+
+def read(ctx):
+    trace = ctx.get("trace")
+    if not ctx.get("train") or not trace or trace.get("busy_s") is None:
+        return None
+    return 100.0 * (1.0 - trace["busy_s"] / trace["window_s"])

@@ -1,0 +1,545 @@
+"""The benchmark's own arithmetic, on the CPU: trace reduction, FLOPs and
+bytes, recovery segments, the traffic generator, percentiles, data files."""
+
+import glob
+import json
+import math
+import os
+import sys
+
+import numpy as np
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+
+from perfbench.lib import (  # noqa: E402
+    flops,
+    manifest,
+    peaks,
+    recovery,
+    serve_compare,
+    stats,
+    trace_reduce,
+    traffic,
+    train_compare,
+    weights,
+)
+
+BENCH = os.path.join(ROOT, "perfbench")
+
+
+def config(name):
+    return weights.dims_of(manifest.load_json(
+        os.path.join(BENCH, "configs", name + ".json")))
+
+
+# ------------------------------------------------------------ trace reduction
+RECORDED = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                        "recorded_trace.json")
+
+
+def small_trace():
+    """Two devices; ms written as ns * 1e6. Device 0: ops at [0,4) [3,6)
+    [10,12); device 1: one op at [0,8). Host spans: decode [0,7),
+    sched_step [0,20), window [0,20)."""
+    ms = 1_000_000
+    dev = {
+        "/device:TPU:0": [("fusion.1", 0, 4 * ms), ("flash_fwd", 3 * ms,
+                                                    3 * ms),
+                          ("fusion.1", 10 * ms, 2 * ms)],
+        "/device:TPU:1": [("fusion.1", 0, 8 * ms)],
+    }
+    spans = [("pb:window", 0, 20 * ms), ("pb:sched_step", 0, 20 * ms),
+             ("pb:decode", 0, 7 * ms)]
+    return dev, spans
+
+
+def test_merge_and_gaps():
+    assert trace_reduce.merge([(5, 7), (0, 3), (2, 4), (7, 7)]) == [
+        [0, 4], [5, 7]]
+    assert trace_reduce.gaps([(0, 3), (2, 4), (5, 7)], 0, 10) == [
+        (4, 5), (7, 10)]
+    assert trace_reduce.busy_seconds([(0, 2e9), (1e9, 3e9)], 0, 10e9) == 3.0
+
+
+def test_busy_union_idle_share_and_kernel_time():
+    r = trace_reduce.reduce_events(*small_trace())
+    assert r["devices"] == 2
+    assert r["window_s"] == pytest.approx(0.020)
+    # device 0 busy 6 + 2 = 8 ms, device 1 busy 8 ms -> mean 8 ms
+    assert r["busy_s"] == pytest.approx(0.008)
+    assert 1 - r["busy_s"] / r["window_s"] == pytest.approx(0.6)
+    # by name, mean over devices: fusion.1 = (4 + 2 + 8) / 2, flash = 3 / 2
+    assert r["ops"]["fusion.1"]["s"] == pytest.approx(0.007)
+    assert r["ops"]["flash_fwd"]["s"] == pytest.approx(0.0015)
+    # gaps of device 0: [6,10) under sched_step only, [12,20) likewise
+    assert r["idle_gaps"] == [["pb:sched_step", pytest.approx(0.012)]]
+    bd = trace_reduce.breakdown(r)
+    assert bd["device_ops"][0][0] == "fusion.1"
+    assert len(bd["device_ops"]) <= 10
+
+
+def test_gap_goes_to_innermost_span_and_window_clips():
+    ms = 1_000_000
+    dev = {"/device:TPU:0": [("a", 0, 2 * ms), ("a", 8 * ms, 4 * ms)]}
+    spans = [("pb:window", 1 * ms, 10 * ms), ("pb:sched_step", 0, 20 * ms),
+             ("pb:prefill", 2 * ms, 6 * ms)]
+    r = trace_reduce.reduce_events(dev, spans)
+    assert r["window_s"] == pytest.approx(0.009)
+    assert r["busy_s"] == pytest.approx(0.003)   # [1,2) and [8,10)
+    assert r["idle_gaps"][0][0] == "pb:prefill"
+
+
+def test_no_device_op_reads_nothing_not_zero():
+    r = trace_reduce.reduce_events({}, [("pb:window", 0, 10)])
+    assert r["busy_s"] is None and r["window_s"] is None
+    from perfbench.lib.manifest import load_reader
+
+    for name in ("train_dev_idle_pct", "serve_dev_idle_pct",
+                 "flash_attn_roofline", "paged_attn_roofline"):
+        path = os.path.join(BENCH, "metrics", name + ".py")
+        if os.path.exists(path):
+            ctx = {"trace": r, "train": {}, "serve": {}, "peaks": None,
+                   "e2e": {}, "dims": config("tiny"), "traffic": {}}
+            assert load_reader(name)(ctx) is None
+
+
+@pytest.mark.skipif(not os.path.exists(RECORDED),
+                    reason="no recorded chip trace in the tree")
+def test_recorded_chip_trace_reduces():
+    with open(RECORDED) as fh:
+        rec = json.load(fh)
+    dev = {k: [tuple(e) for e in v] for k, v in rec["device_ops"].items()}
+    r = trace_reduce.reduce_events(dev, [tuple(s) for s in rec["spans"]])
+    assert 0 < r["busy_s"] <= r["window_s"]
+    assert r["busy_s"] == pytest.approx(rec["expect"]["busy_s"], rel=1e-9)
+    assert r["window_s"] == pytest.approx(rec["expect"]["window_s"],
+                                          rel=1e-9)
+
+
+# ------------------------------------------------------------- FLOPs, bytes
+def test_param_counts_match_the_published_models():
+    m4 = config("mistral-7b-v0.3-d4")
+    assert weights.param_count(m4) == 1_140_887_552
+    per_layer = (4096 * 4096 * 2 + 4096 * 1024 * 2 + 3 * 4096 * 14336
+                 + 2 * 4096)
+    assert weights.param_count(m4) == (4 * per_layer + 2 * 32768 * 4096
+                                       + 4096)
+    il = config("internlm2-1.8b")
+    assert weights.param_count(il) == 1_889_110_016
+    assert flops.kv_bytes_per_token(il) == 96 * 1024
+
+
+def test_train_flops_per_token_hand_worked():
+    d = config("mistral-7b-v0.3-d4")
+    mm = 4 * (4096 * (4096 + 2 * 1024) + 4096 * 4096 + 3 * 4096 * 14336) \
+        + 4096 * 32768
+    assert flops.matmul_params(d) == mm == 1_006_632_960
+    seq = 4096
+    attn_fwd_per_tok = 4 * (2 * 2 * 32 * 128 * seq * (seq + 1) / 2) / seq
+    want = 3 * (2 * mm + attn_fwd_per_tok)
+    assert flops.train_flops_per_token(d, seq) == pytest.approx(want)
+    # the program's own count (utils/metrics.transformer_flops_per_token)
+    assert want == pytest.approx(6 * mm + 12 * 4 * 4096 * seq / 2, rel=1e-3)
+    # 20,698 tokens/s/chip (ledger, PR 22) is then 67.7% of 197e12
+    assert 100 * want * 20698 / 197e12 == pytest.approx(67.7, abs=0.1)
+
+
+def test_flash_and_paged_counts_hand_worked():
+    d = config("mistral-7b-v0.3-d4")
+    f = flops.flash_attn_flops(d, 3, 4096)
+    assert f == pytest.approx(3 * 3 * 4 * 4 * 32 * 128 * 4096 * 4097 / 2)
+    b = flops.flash_attn_bytes(d, 3, 4096)
+    q = 3 * 4096 * 32 * 128 * 2
+    kv = 3 * 4096 * 8 * 128 * 2
+    assert b == 4 * (6 * q + 6 * kv)
+    il = config("internlm2-1.8b")
+    live = 8 * 8320
+    assert flops.paged_read_bytes(il, live) == live * 98304
+    p = peaks.peaks_of("TPU v5 lite")
+    assert flops.roofline_seconds(0, 819e9, p) == pytest.approx(1.0)
+    assert flops.roofline_seconds(197e12, 1, p) == pytest.approx(1.0)
+    with pytest.raises(KeyError):
+        peaks.peaks_of("TPU v99")
+
+
+def test_serve_flops_counts_each_token_once():
+    d = config("internlm2-1.8b")
+    one = flops.serve_flops(d, 1, 100)
+    assert one == 2 * flops.matmul_params(d) + 4 * 16 * 128 * 24 * 100
+    assert flops.serve_flops(d, 3, 300) == pytest.approx(3 * one)
+
+
+# ------------------------------------------------------- recovery stitching
+def two_cycle_log():
+    """A -> B -> C on one clock. Cycle 0: kill 100, notice 100.4, save
+    takes 15 (record at 116), exit reaped 118; B's Device line 150, restore
+    19 (record at 171), compile 3, first step done 178. Cycle 1 likewise,
+    shifted, with a longer hand-over."""
+    def job(name, t_kill=None, t_dev=None):
+        ev = []
+        if t_dev is not None:
+            ev += [{"kind": "ckpt_restore", "t": t_dev + 21, "dur": 19.0},
+                   {"kind": "resume", "t": t_dev + 21.1},
+                   {"kind": "compile", "t": t_dev + 24.2, "dur": 3.0}]
+        if t_kill is not None:
+            ev += [{"kind": "signal", "t": t_kill + 0.4, "signum": 10},
+                   {"kind": "ckpt_save", "t": t_kill + 16, "dur": 15.0,
+                    "fault": True, "blocking": True},
+                   {"kind": "exit", "t": t_kill + 16.1, "saved": True}]
+        return ev
+
+    a = job("A", t_kill=100)
+    b = job("B", t_kill=200, t_dev=150)
+    c = job("C", t_dev=262)
+    return a, b, c
+
+
+def test_recovery_segments_two_cycles():
+    a, b, c = two_cycle_log()
+    s0 = recovery.segments(100, 118, 150, 178, a, b)
+    assert s0["drain_s"] == 18 and s0["handover_s"] == 32
+    assert s0["resume_s"] == 28 and s0["cycle_s"] == 46
+    assert s0["notice_s"] == pytest.approx(0.4)
+    assert s0["save_s"] == 15.0
+    assert s0["save_other_s"] == pytest.approx(0.6)
+    assert s0["exit_s"] == pytest.approx(2.0)
+    assert s0["restore_s"] == 19.0 and s0["compile_s"] == 3.0
+    assert s0["pre_restore_s"] == pytest.approx(2.0)
+    assert s0["first_step_s"] == pytest.approx(4.0)
+    # the harness's own digests, stamped by the child, come out of drain
+    # and of resume (and of the segments they sit in)
+    h = recovery.segments(100, 118, 150, 178, a, b, drain_harness_s=0.5,
+                          resume_harness_s=0.25)
+    assert h["drain_s"] == 17.5 and h["resume_s"] == 27.75
+    assert h["save_other_s"] == pytest.approx(0.1)
+    assert h["first_step_s"] == pytest.approx(3.75)
+    assert h["handover_s"] == 32 and h["harness_s"] == 0.75
+    s1 = recovery.segments(200, 218, 262, 292, b, c)
+    assert s1["handover_s"] == 44       # the machine's, not in the cycle
+    assert s1["cycle_s"] == 48
+    # all the recovery time over all the recoveries
+    assert recovery.recover_cycle_s([s0, s1]) == pytest.approx(47.0)
+    assert recovery.mean_of([s0, s1], "handover_s") == 38
+    # B's own save must not be read as part of cycle 0's drain
+    assert s0["save_s"] == 15.0 and s1["save_s"] == 15.0
+
+
+def test_recovery_missing_records_give_none_not_zero():
+    s = recovery.segments(0, 10, 20, 30, [], [])
+    assert s["cycle_s"] == 20
+    for k in ("notice_s", "save_s", "restore_s", "compile_s",
+              "first_step_s"):
+        assert s[k] is None
+    assert recovery.mean_of([s], "save_s") is None
+
+
+# --------------------------------------------------------- traffic generator
+CHAT = manifest.load_json(os.path.join(BENCH, "traffic", "chat.json"))
+
+
+def test_open_loop_same_seed_same_schedule():
+    a = traffic.open_loop(CHAT, 7, 30.0, 92544)
+    b = traffic.open_loop(CHAT, 7, 30.0, 92544)
+    assert [r["t_due"] for r in a] == [r["t_due"] for r in b]
+    assert all((x["prompt"] == y["prompt"]).all() for x, y in zip(a, b))
+    assert [r["t_due"] for r in a] == sorted(r["t_due"] for r in a)
+    assert a[-1]["t_due"] < 30.0
+
+
+def test_open_loop_seeds_offer_the_same_work_with_other_tokens():
+    a = traffic.open_loop(CHAT, 7, 30.0, 92544)
+    b = traffic.open_loop(CHAT, 2 ** 31 + 12345, 30.0, 92544)
+    assert [r["t_due"] for r in a] == [r["t_due"] for r in b]
+    assert [len(r["prompt"]) for r in a] == [len(r["prompt"]) for r in b]
+    assert [r["max_new_tokens"] for r in a] == [r["max_new_tokens"]
+                                                for r in b]
+    assert not all((x["prompt"] == y["prompt"]).all() for x, y in zip(a, b))
+    # a longer horizon extends the same schedule
+    c = traffic.open_loop(CHAT, 7, 60.0, 92544)
+    assert [r["t_due"] for r in c[:len(a)]] == [r["t_due"] for r in a]
+    lens = [len(r["prompt"]) for r in a]
+    outs = [r["max_new_tokens"] for r in a]
+    assert min(lens) >= 32 and max(lens) <= 2048
+    assert min(outs) >= 16 and max(outs) <= 512
+    n = len(a)
+    assert n == pytest.approx(CHAT["rate_rps"] * 30.0, rel=0.35)
+    for r in a:
+        assert r["prompt"].min() >= 3 and r["prompt"].max() < 92544
+
+
+def test_closed_loop_sessions():
+    mix = manifest.load_json(os.path.join(BENCH, "traffic",
+                                          "longdecode.json"))
+    s = traffic.closed_loop(mix, 5, 92544)
+    assert len(s) == 8 and all(len(x["base"]) == 8192 for x in s)
+    assert all(len(t) == 32 for x in s for t in x["turn_new"])
+    assert all(o == 128 for x in s for o in x["turn_out"])
+    need = traffic.lengths_needed(mix)
+    assert need["total"] <= mix["server"]["max_len"]
+    assert not (s[0]["base"] == s[1]["base"]).all()
+
+
+class FakeSched:
+    """A server whose step stalls once: the open loop must keep timing the
+    requests that came due meanwhile from their due times."""
+
+    def __init__(self, clock, stall_at, stall_s):
+        self.queue, self.active, self.clock = [], {}, clock
+        self.stall_at, self.stall_s, self.stalled = stall_at, stall_s, False
+        self.submit_times = {}
+
+    def submit(self, req):
+        self.queue.append(req)
+        self.submit_times[req.id] = self.clock()
+
+    def pending(self):
+        return bool(self.queue)
+
+    def step(self):
+        if not self.stalled and self.clock() >= self.stall_at:
+            self.stalled = True
+            self.clock.advance(self.stall_s)
+        self.clock.advance(0.01)
+        out, self.queue = self.queue, []
+        now = self.clock()
+        return [type("C", (), dict(request_id=r.id, tokens=[1, 2],
+                                   first_token_at=now, finished_at=now))()
+                for r in out]
+
+
+class FakeClock:
+    def __init__(self):
+        self.t = 0.0
+
+    def __call__(self):
+        self.t += 1e-4
+        return self.t
+
+    def advance(self, s):
+        self.t += s
+
+
+def test_due_time_accounting_under_an_injected_stall(monkeypatch):
+    from perfbench.lib import kind_serve as ks
+
+    clock = FakeClock()
+    monkeypatch.setattr(ks.time, "sleep", lambda s: clock.advance(max(s, 1e-3)))
+    reqs = [{"id": f"r{i}", "t_due": 0.1 * i, "prompt": np.zeros(4, np.int32),
+             "max_new_tokens": 2} for i in range(1, 40)]
+    sched = FakeSched(clock, stall_at=1.0, stall_s=1.0)
+    tracker = ks.Tracker()
+    Req = type("Req", (), {"__init__": lambda self, **k:
+                           self.__dict__.update(k)})
+    done, late, offered = ks.run_open(sched, reqs, tracker, 0.0, 5.0, Req,
+                                      clock)
+    assert offered == len(reqs)
+    # requests due inside the stall were submitted late, and are timed
+    # from when they were due, not from when the generator got to them
+    stalled = [r for r in done if 1.05 < r["t_ref"] < 1.95]
+    assert stalled
+    for r in stalled:
+        assert r["first_token_at"] - r["t_ref"] > 0.05
+        assert sched.submit_times[r["id"]] > r["t_ref"]
+    assert max(late) > 0.8 and stats.percentile(late, 50) < 0.05
+
+
+# ----------------------------------------------------------------- statistics
+def test_percentile_and_tpot():
+    assert stats.percentile([], 95) is None
+    assert stats.percentile([3.0], 95) == 3.0
+    assert stats.percentile([1, 2, 3, 4, 5], 50) == 3
+    assert stats.percentile(list(range(101)), 95) == 95
+    assert stats.percentile([0, 10], 95) == pytest.approx(9.5)
+    assert stats.tpot_seconds([1.0]) is None
+    assert stats.tpot_seconds([1.0, 1.5, 2.0, 4.0]) == pytest.approx(1.0)
+
+
+def test_failed_requests_never_count_as_fast():
+    reqs = [
+        {"t_ref": 0.0, "token_times": [0.5, 0.6, 0.7], "done": True},
+        {"t_ref": 0.0, "token_times": [0.1], "done": False},   # unfinished
+        {"t_ref": 0.0, "token_times": [], "done": False},      # failed
+        {"t_ref": 1.0, "token_times": [1.2, 1.6], "done": True},
+    ]
+    lat = stats.request_latencies(reqs)
+    assert lat["ttft_s"] == pytest.approx([0.5, 0.2])
+    assert lat["tpot_s"] == pytest.approx([0.1, 0.4])
+
+
+def test_spread_is_the_bounds_rule():
+    vals = [100, 101, 102, 103, 104, 105]
+    import statistics
+
+    q = statistics.quantiles(vals, n=4)
+    assert stats.spread(vals) == pytest.approx((q[2] - q[0]) / 102.5)
+
+
+# ------------------------------------------------------- what correct compares
+def test_train_gaps_worst_leaf_against_median():
+    ref = {"loss": [10.0, 9.0],
+           "grad_norms": {"a": 1.0, "b": 0.5, "c": 1e-9},
+           "change_norms": {"a": 2.0, "b": 2.0, "c": 2.0}}
+    prog = {"loss": [10.01, 9.0],
+            "grad_norms": {"a": 1.1, "b": 0.5, "c": 2e-9},
+            "change_norms": {"a": 2.0, "b": 2.2, "c": 0.0}}
+    g = train_compare.gaps(prog, ref)
+    assert g["loss_gap"] == pytest.approx(0.001)
+    assert g["grad_norm_gap"] == pytest.approx(0.1)      # leaf a
+    # leaf c's gradient is nought to rounding: left out of the change,
+    # and measured against the median leaf in the gradient, not itself
+    assert g["left_out"] == ["c"]
+    assert g["change_norm_gap"] == pytest.approx(0.1)    # leaf b
+    same = train_compare.gaps(ref, ref)
+    assert same["grad_norm_gap"] == 0 and same["change_norm_gap"] == 0
+    assert g["frozen_unexpected"] == 0     # c is unmoved, and left out
+    # a state left unchanged reads 1
+    frozen = dict(prog, change_norms={"a": 0.0, "b": 0.0, "c": 0.0})
+    g = train_compare.gaps(frozen, ref)
+    assert g["change_norm_gap"] == 1.0 and g["frozen_unexpected"] == 2
+
+
+def test_train_gaps_frozen_leaves_are_read_apart():
+    """A leaf that bfloat16 storage cannot move is left out by a rule on the
+    reference; any other leaf left unmoved fails, however small it is."""
+    ref = {"loss": [10.0], "grad_norms": {"big": 1.0, "mid": 1.0,
+                                          "small": 1.0, "scale": 1.0},
+           "change_norms": {"big": 10.0, "mid": 8.0, "small": 0.4,
+                            "scale": 0.128},
+           "stuck_share": {"big": 0.0, "mid": 0.0, "small": 2e-5,
+                           "scale": 1.0}}
+    sound = {"loss": [10.0], "grad_norms": dict(ref["grad_norms"]),
+             "change_norms": {"big": 10.0, "mid": 8.0, "small": 0.4,
+                              "scale": 0.0}}
+    g = train_compare.gaps(sound, ref)
+    assert g["under_bf16_resolution"] == ["scale"]
+    assert g["frozen"] == ["scale"] and g["frozen_unexpected"] == 0
+    assert g["change_norm_gap"] == 0
+    # the small leaf frozen: 0.4 against the median leaf's 4.2 reads 0.095,
+    # under a limit of 0.1 — the count of its own catches it
+    fault = dict(sound, change_norms=dict(sound["change_norms"], small=0.0))
+    g = train_compare.gaps(fault, ref)
+    assert g["change_norm_gap"] == pytest.approx(0.4 / 4.2)
+    assert g["frozen_unexpected"] == 1
+    limits = {"change_norm_gap": 0.1, "frozen_unexpected": 0}
+    judged = train_compare.judge(g, limits)
+    assert judged["change_norm_gap"]["ok"] is True
+    assert judged["frozen_unexpected"]["ok"] is False
+    # float32 storage: no leaf is excused, the frozen scale counts
+    g = train_compare.gaps(sound, dict(ref, stuck_share={}))
+    assert g["frozen_unexpected"] == 1
+
+
+def test_reference_names_the_leaves_bfloat16_cannot_move():
+    import jax
+    import jax.numpy as jnp
+
+    from perfbench.lib import reference, weights
+
+    cfg = manifest.load_json(os.path.join(BENCH, "configs", "tiny.json"))
+    d = weights.dims_of(cfg)
+    rng = np.random.default_rng(3)
+    toks = rng.integers(3, 100, size=(2, 2, 17)).astype(np.int32)
+    batches = [(t[:, :-1], t[:, 1:]) for t in toks]
+    key = jax.random.PRNGKey(11)
+    out = reference.run_train_reference(key, d, 1e-3, 0, batches,
+                                        dtype=jnp.bfloat16)
+    scales = [p for p in out["stuck_share"] if p.endswith("scale")]
+    assert scales and all(out["stuck_share"][p] > 0.9 for p in scales)
+    # the scales take steps of 1e-3 at values near 1 (half an ulp 2e-3 ..
+    # 4e-3); the matrices' elements are far smaller, and move
+    for p, share in out["stuck_share"].items():
+        if p not in scales:
+            assert share < 0.2, (p, share)
+    f32 = reference.run_train_reference(key, d, 1e-3, 0, batches,
+                                        dtype=jnp.float32)
+    assert set(f32["stuck_share"].values()) == {0.0}
+
+
+def test_serve_gap_and_sample():
+    logits = np.array([[0.0, 2.0, 1.0], [5.0, 1.0, 4.5]])
+    assert serve_compare.gaps_of(logits, [1, 2]).tolist() == [0.0, 0.5]
+    fin = [{"prompt": [0] * p, "tokens": [1] * t} for p, t in
+           ((10, 5), (100, 50), (20, 10), (30, 20), (5, 5))]
+    s1 = serve_compare.pick_sample(fin, 3, 60, 4)
+    s2 = serve_compare.pick_sample(fin, 3, 60, 4)
+    assert s1 == s2 and s1[0] is fin[1]            # the longest is in it
+    assert sum(len(r["tokens"]) for r in s1) >= 60 or len(s1) == 4
+    assert serve_compare.pick_sample([], 3, 60, 4) == []
+
+
+# ------------------------------------------------------------------ data files
+def test_every_data_file_loads_and_names_are_permitted():
+    bench = manifest.load_json(os.path.join(ROOT, "BENCHMARK.json"))
+    assert manifest.check_names(bench) == []
+    assert set(bench) == {"command", "paths", "run_seconds", "configs",
+                          "workloads", "end_to_end", "per_layer"}
+    for path in glob.glob(os.path.join(BENCH, "*", "*.json")):
+        manifest.load_json(path)
+        rel = os.path.relpath(path, ROOT)
+        assert all(c.isalnum() or c in "_.-/" for c in rel), rel
+    e2e = {m["name"] for m in bench["end_to_end"]}
+    assert "setup_s" in e2e
+    for m in bench["end_to_end"]:
+        assert 0.01 <= m["bound"] <= 0.1 and m["source"] in (
+            "host_clock", "device_trace")
+    layers = set()
+    for m in bench["per_layer"]:
+        assert m["moves"] in e2e
+        assert os.path.exists(os.path.join(BENCH, "metrics",
+                                           m["name"] + ".py")), m["name"]
+        assert "\n" not in m["layer"] and len(m["layer"]) <= 200
+        layers.add(m["layer"])
+        if m["name"].endswith("_roofline"):
+            assert m["unit"] == "%"
+    for w in bench["workloads"]:
+        cell = manifest.Cell(w["name"], ROOT)
+        assert cell.end_to_end() and len(w["why"]) <= 200
+        names = {m["name"] for m in cell.end_to_end()}
+        assert "setup_s" in names and len(names) >= 2
+        assert cell.per_layer(), w["name"]
+        for m in cell.per_layer():
+            assert m["moves"] in names, (w["name"], m["name"])
+    for c in bench["configs"]:
+        cfg = manifest.load_json(os.path.join(ROOT, c["file"]))
+        assert cfg["source"] == c["source"]
+        assert sorted(cfg["reduced"]) == sorted(c["reduced"])
+        d = weights.dims_of(cfg)
+        assert d["head_dim"] * d["n_heads"] == d["dim"]
+        for key in c["reduced"]:
+            assert not key.endswith(("_dim", "_rank", "_size"))
+
+
+def test_full_check_fits_the_chip_time():
+    bench = manifest.load_json(os.path.join(ROOT, "BENCHMARK.json"))
+    s = bench["run_seconds"]
+    assert 1 <= s <= 51
+    cells = 24
+    total = (2 + 14 * cells) * (s + 60) + cells * 2 * 90 + 1200
+    assert total <= 43200
+    four = sum(w["chips"] == 4 for w in bench["workloads"])
+    assert four <= max(1, len(bench["workloads"]) // 4)
+
+
+def test_weights_are_a_function_of_seed_and_path_only():
+    import jax
+    import jax.numpy as jnp
+
+    d = config("tiny")
+    key = jax.random.PRNGKey(2 ** 31 + 5)
+    tree = weights.make_param_tree(key, d, jnp.float32)
+    flat = weights.flatten(tree)
+    assert set(flat) == set(weights.all_leaves(d))
+    again = weights.make_leaf(key, "layers_1/attention/wq/kernel",
+                              (64, 64), "dense", jnp.float32)
+    assert (flat["layers_1/attention/wq/kernel"] == again).all()
+    other = weights.make_leaf(jax.random.PRNGKey(1),
+                              "layers_1/attention/wq/kernel", (64, 64),
+                              "dense", jnp.float32)
+    assert not (other == again).all()
+    assert float(jnp.std(again)) == pytest.approx(1 / 8, rel=0.1)
+    assert weights.nest(weights.flatten(tree)).keys() == tree.keys()
+    assert math.prod((64, 64)) * 0 == 0
