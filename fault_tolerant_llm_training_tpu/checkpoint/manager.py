@@ -192,12 +192,12 @@ def _manifest_files(step_dir: str) -> Dict[str, Dict[str, int]]:
     return files
 
 
-def write_manifest(step_dir: str, step: int) -> None:
+def write_manifest(step_dir: str, step: int) -> int:
     """Checksum every file of a FINALIZED step dir into integrity.json
     (atomic tmp-rename write, fsync'd file and directory). On a pod every
     host sweeps the shared checkpoint root after a commit, so the tmp name
     is per process: two hosts writing the same (identical) manifest must
-    not rename each other's tmp file away."""
+    not rename each other's tmp file away. Returns the bytes checksummed."""
     manifest = {"version": 1, "step": int(step),
                 "files": _manifest_files(step_dir)}
     tmp = os.path.join(step_dir, f"{MANIFEST_NAME}.tmp-{os.getpid()}")
@@ -207,6 +207,7 @@ def write_manifest(step_dir: str, step: int) -> None:
         os.fsync(fh.fileno())
     os.replace(tmp, os.path.join(step_dir, MANIFEST_NAME))
     _fsync_dir(step_dir)
+    return sum(meta["size"] for meta in manifest["files"].values())
 
 
 def verify_step_dir(step_dir: str) -> Tuple[bool, str]:
@@ -214,27 +215,34 @@ def verify_step_dir(step_dir: str) -> Tuple[bool, str]:
     Missing manifest = legacy checkpoint, accepted. Extra files (e.g.
     later-version metadata) are ignored — only manifest-listed files are
     load-bearing for the restore."""
+    return _verify_step_dir(step_dir)[:2]
+
+
+def _verify_step_dir(step_dir: str) -> Tuple[bool, str, int]:
+    """:func:`verify_step_dir` plus the bytes it checksummed."""
     manifest_path = os.path.join(step_dir, MANIFEST_NAME)
     if not os.path.isdir(step_dir):
-        return False, "step directory missing"
+        return False, "step directory missing", 0
     if not os.path.isfile(manifest_path):
-        return True, "no manifest (legacy checkpoint)"
+        return True, "no manifest (legacy checkpoint)", 0
     try:
         with open(manifest_path) as fh:
             manifest = json.load(fh)
     except (OSError, json.JSONDecodeError) as e:
-        return False, f"unreadable manifest ({e})"
+        return False, f"unreadable manifest ({e})", 0
+    checked = 0
     for rel, meta in sorted(manifest.get("files", {}).items()):
         path = os.path.join(step_dir, rel)
         if not os.path.isfile(path):
-            return False, f"missing file {rel}"
+            return False, f"missing file {rel}", checked
         size = os.path.getsize(path)
         if size != meta["size"]:
             return False, (f"size mismatch {rel} "
-                           f"({size} != {meta['size']})")
+                           f"({size} != {meta['size']})"), checked
+        checked += size
         if _crc32_file(path) != meta["crc32"]:
-            return False, f"crc mismatch {rel}"
-    return True, "ok"
+            return False, f"crc mismatch {rel}", checked
+    return True, "ok", checked
 
 
 def _pytree_handler_kwargs() -> dict:
@@ -283,19 +291,25 @@ class CheckpointManager:
         the restore scan must never pick up."""
         if not os.path.isdir(self.directory):
             return
+        t0, written, checked = time.monotonic(), [], 0
         for name in sorted(os.listdir(self.directory)):
             path = os.path.join(self.directory, name)
             if not os.path.isdir(path):
                 continue
             if name.isdigit():
                 if not os.path.isfile(os.path.join(path, MANIFEST_NAME)):
-                    write_manifest(path, int(name))
+                    checked += write_manifest(path, int(name))
+                    written.append(int(name))
             elif "tmp" in name and name not in self._partial_audited:
                 self._partial_audited.add(name)
                 events.emit_audit(
                     logger, AUDIT_CKPT_PARTIAL_SKIPPED_FMT.format(name=name),
                     "ckpt_partial_skipped", name=name)
         _fsync_dir(self.directory)
+        if written:
+            events.emit("ckpt_manifest", step=written[-1],
+                        dur=time.monotonic() - t0, bytes=checked,
+                        steps=written)
 
     def save(self, step: int, state: Any, data_state: dict,
              wait: bool = False) -> int:
@@ -364,9 +378,11 @@ class CheckpointManager:
             # precision when the alternative is a crash loop.
             candidates = [s for s in steps if s <= step] or steps
         chosen = None
+        t0, checked = time.monotonic(), 0
         for cand in candidates:
-            ok, detail = verify_step_dir(
+            ok, detail, n = _verify_step_dir(
                 os.path.join(self.directory, str(cand)))
+            checked += n
             if ok:
                 chosen = cand
                 break
@@ -376,6 +392,8 @@ class CheckpointManager:
                 AUDIT_CKPT_VERIFY_FAILED_FMT.format(step=cand, detail=detail),
                 "ckpt_verify_failed", step=int(cand), detail=detail,
                 ok=False)
+        events.emit("ckpt_verify", step=chosen, dur=time.monotonic() - t0,
+                    bytes=checked, ok=chosen is not None)
         if chosen is None:
             raise CheckpointIntegrityError(
                 f"no checkpoint step in {self.directory} passed integrity "

@@ -22,6 +22,8 @@ from typing import Optional, Tuple
 import jax
 import numpy as np
 
+from ..obs.trace import span
+
 
 class DevicePrefetcher:
     """Wraps a DataLoader; yields ``(inputs_dev, labels_dev, data_state)``.
@@ -72,16 +74,19 @@ class DevicePrefetcher:
     def _worker(self):
         try:
             while not self._stop.is_set():
-                try:
-                    inputs, labels = next(self.loader)
-                except StopIteration:
-                    break
-                state = self.loader.get_state()
-                if self.stage_in_worker:
-                    inputs, labels = self._stage_pair(inputs, labels)
-                if self._chaos_on_batch is not None:
-                    self._chaos_on_batch(self._batch_index)
-                self._batch_index += 1
+                # one batch's host work; the wait for room in the queue
+                # below is not part of it
+                with span("ftl:data.prefetch", batch=self._batch_index):
+                    try:
+                        inputs, labels = next(self.loader)
+                    except StopIteration:
+                        break
+                    state = self.loader.get_state()
+                    if self.stage_in_worker:
+                        inputs, labels = self._stage_pair(inputs, labels)
+                    if self._chaos_on_batch is not None:
+                        self._chaos_on_batch(self._batch_index)
+                    self._batch_index += 1
                 self._q.put((inputs, labels, state))
         except BaseException as e:  # surfaced to the consumer
             self._exc = e

@@ -99,6 +99,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..models.configs import TransformerConfig
+from ..obs import events
+from ..obs.trace import span
 from ..models.llama import Transformer, unstack_layer_params
 from ..ops.attention import describe_kernel_mode
 from ..parallel.mesh import use_mesh
@@ -323,7 +325,9 @@ class InferenceEngine:
             raise ValueError("paged_kernel selection requires the paged "
                              "KV layout")
         self.paged_kernel = paged_kernel
-        logger.info(f"Device | {describe_device()}")
+        device = describe_device()
+        logger.info(f"Device | {device}")
+        events.emit("backend_ready", device=device)
         logger.info(f"Paged kernel | {describe_kernel_mode(paged_kernel)}")
         if cfg.layer_impl == "scan":
             params = unstack_layer_params(params, cfg.n_layers)
@@ -1523,6 +1527,23 @@ class InferenceEngine:
         """
         ids = np.asarray(token_ids, np.int32).reshape(-1)
         n = ids.size
+        def first_bucket():  # of the (usually only) chunk
+            m = min(self.prefill_buckets[-1], max(n - int(start_pos), 1))
+            return next(b for b in self.prefill_buckets if b >= m)
+
+        with span("ftl:engine.prefill", new_tokens=n - int(start_pos),
+                  start_pos=int(start_pos), bucket=first_bucket):
+            return self._prefill_spanned(
+                ids, slot, block_row, draft_block_row, temperature, top_p,
+                seed, stop_check, on_chunk, start_pos, draft_start_pos,
+                adapter_row, adapter_scale)
+
+    def _prefill_spanned(self, ids, slot, block_row, draft_block_row,
+                         temperature, top_p, seed, stop_check, on_chunk,
+                         start_pos, draft_start_pos, adapter_row,
+                         adapter_scale) -> Optional[int]:
+        """:meth:`prefill` inside its ``ftl:engine.prefill`` span."""
+        n = ids.size
         if start_pos and self.kv_layout != "paged":
             raise ValueError("start_pos requires the paged KV layout")
         if self.kv_layout != "paged":
@@ -1532,10 +1553,13 @@ class InferenceEngine:
             bucket = next(b for b in self.prefill_buckets if b >= n)
             padded = np.zeros((1, bucket), np.int32)
             padded[0, :n] = ids
-            self.cache, tok = self._prefill[bucket](
-                self.params, self.cache, padded, np.int32(slot), np.int32(n),
-                np.float32(temperature), np.float32(top_p), np.int32(seed))
-            return int(tok)
+            with span("ftl:engine.prefill.dispatch"):
+                self.cache, tok = self._prefill[bucket](
+                    self.params, self.cache, padded, np.int32(slot),
+                    np.int32(n), np.float32(temperature),
+                    np.float32(top_p), np.int32(seed))
+            with span("ftl:engine.prefill.sync"):
+                return int(tok)
         if not 0 < n <= self.max_len:
             raise ValueError(f"prompt length {n} outside (0, {self.max_len}]")
         if block_row is None:
@@ -1548,11 +1572,12 @@ class InferenceEngine:
             raise ValueError("spec-mode prefill requires draft_block_row")
         if not 0 <= start_pos < n:
             raise ValueError(f"start_pos {start_pos} outside [0, {n})")
-        tok = self._stream_chunks(False, row, ids, slot, temperature, top_p,
-                                  seed, stop_check, on_chunk,
-                                  start_pos=start_pos,
-                                  adapter_row=adapter_row,
-                                  adapter_scale=adapter_scale)
+        with span("ftl:engine.prefill.dispatch"):
+            tok = self._stream_chunks(False, row, ids, slot, temperature,
+                                      top_p, seed, stop_check, on_chunk,
+                                      start_pos=start_pos,
+                                      adapter_row=adapter_row,
+                                      adapter_scale=adapter_scale)
         if tok is None:
             return None
         if self.spec_k:
@@ -1577,11 +1602,15 @@ class InferenceEngine:
                 lengths[slot] = n
                 self.draft_cache = self.draft_cache.replace(
                     lengths=jnp.asarray(lengths))
-            elif self._stream_chunks(True, drow, ids, slot, temperature,
-                                     top_p, seed, stop_check, on_chunk,
-                                     start_pos=draft_start_pos) is None:
-                return None
-        return int(tok)
+            else:
+                with span("ftl:engine.prefill.dispatch"):
+                    draft_tok = self._stream_chunks(
+                        True, drow, ids, slot, temperature, top_p, seed,
+                        stop_check, on_chunk, start_pos=draft_start_pos)
+                if draft_tok is None:
+                    return None
+        with span("ftl:engine.prefill.sync"):
+            return int(tok)
 
     def prefill_packed(self, rows, bucket: int, adapter_rows=None,
                        adapter_scales=None):
@@ -1611,6 +1640,14 @@ class InferenceEngine:
             raise ValueError("engine built without the packed prefill lane "
                              "(prefill_batch < 2)")
         bucket = int(bucket)
+        with span("ftl:engine.prefill", rows=len(rows), bucket=bucket,
+                  new_tokens=lambda: sum(np.size(r[1]) for r in rows)):
+            return self._prefill_packed_spanned(rows, bucket, adapter_rows,
+                                                adapter_scales)
+
+    def _prefill_packed_spanned(self, rows, bucket: int, adapter_rows,
+                                adapter_scales):
+        """:meth:`prefill_packed` inside its ``ftl:engine.prefill`` span."""
         if bucket not in self.prefill_buckets:
             raise ValueError(f"bucket {bucket} not in compiled set "
                              f"{self.prefill_buckets}")
@@ -1660,10 +1697,12 @@ class InferenceEngine:
         elif adapter_rows is not None:
             raise ValueError("adapter rows given but engine built "
                              "without adapters (adapter_rank == 0)")
-        self.cache, out = self._packed_prefill[bucket](
-            self.params, self.cache, block_rows, toks, slots, starts, lens,
-            active, temp, tp, seeds, *ad)
-        return [int(t) for t in np.asarray(out)[:len(rows)]]
+        with span("ftl:engine.prefill.dispatch"):
+            self.cache, out = self._packed_prefill[bucket](
+                self.params, self.cache, block_rows, toks, slots, starts,
+                lens, active, temp, tp, seeds, *ad)
+        with span("ftl:engine.prefill.sync"):
+            return [int(t) for t in np.asarray(out)[:len(rows)]]
 
     def decode_step(self, tokens, active, temperature, top_p, seeds, steps,
                     block_tables=None, adapter_rows=None,
@@ -1673,25 +1712,56 @@ class InferenceEngine:
         blocks_per_slot) block tables, and adapter-enabled engines take
         each slot's adapter page row + scale (``adapter_rows`` (slots, P)
         / ``adapter_scales`` (slots,); None = all base-only)."""
-        if self.kv_layout == "paged":
-            if block_tables is None:
-                raise ValueError("paged decode requires block_tables")
-            self.cache, toks = self._decode(
-                self.params, self.cache,
-                np.asarray(block_tables, np.int32),
-                np.asarray(tokens, np.int32), np.asarray(active, bool),
-                np.asarray(temperature, np.float32),
-                np.asarray(top_p, np.float32),
-                np.asarray(seeds, np.int32), np.asarray(steps, np.int32),
-                *self._adapter_call_args(adapter_rows, adapter_scales))
-            return np.asarray(toks)
-        self.cache, toks = self._decode(
-            self.params, self.cache,
-            np.asarray(tokens, np.int32), np.asarray(active, bool),
-            np.asarray(temperature, np.float32),
-            np.asarray(top_p, np.float32),
-            np.asarray(seeds, np.int32), np.asarray(steps, np.int32))
-        return np.asarray(toks)
+        if self.kv_layout == "paged" and block_tables is None:
+            raise ValueError("paged decode requires block_tables")
+        with self._decode_span(active):
+            with span("ftl:engine.decode.dispatch"):
+                if self.kv_layout == "paged":
+                    self.cache, toks = self._decode(
+                        self.params, self.cache,
+                        np.asarray(block_tables, np.int32),
+                        np.asarray(tokens, np.int32),
+                        np.asarray(active, bool),
+                        np.asarray(temperature, np.float32),
+                        np.asarray(top_p, np.float32),
+                        np.asarray(seeds, np.int32),
+                        np.asarray(steps, np.int32),
+                        *self._adapter_call_args(adapter_rows,
+                                                 adapter_scales))
+                else:
+                    self.cache, toks = self._decode(
+                        self.params, self.cache,
+                        np.asarray(tokens, np.int32),
+                        np.asarray(active, bool),
+                        np.asarray(temperature, np.float32),
+                        np.asarray(top_p, np.float32),
+                        np.asarray(seeds, np.int32),
+                        np.asarray(steps, np.int32))
+            with span("ftl:engine.decode.sync"):
+                return np.asarray(toks)
+
+    def _decode_span(self, active, lengths=None, n: int = 1,
+                     window: int = 1):
+        """The ``ftl:engine.decode`` span of one decode round.
+        ``live_tokens`` is the KV the round's target-model passes attend
+        to: over the active slots and the ``n`` sequential iterations of
+        a burst, the slot's committed length plus the ``window`` positions
+        the pass itself writes (1 for a decode step; a speculative
+        verify's k + 1 or tree size; its draft passes read another
+        model's cache and are not counted). With no ``lengths`` from the
+        caller the slot lengths are read back from the device — one
+        (slots,) transfer of an array the previous round already
+        finished, and only while a profiler is running."""
+        def live_tokens():
+            act = np.asarray(active, bool)
+            lens = np.asarray(self.cache.lengths if lengths is None
+                              else lengths)[act]
+            # iteration i of a burst attends to i more positions
+            return int(n * (int(lens.sum()) + act.sum() * window)
+                       + act.sum() * (n * (n - 1) // 2))
+
+        return span("ftl:engine.decode", n=n, live_tokens=live_tokens,
+                    slots_active=lambda: int(np.count_nonzero(active)))
 
     def decode_logits(self, tokens, active, block_tables=None,
                       adapter_rows=None, adapter_scales=None) -> np.ndarray:
@@ -1733,14 +1803,18 @@ class InferenceEngine:
                                     adapter_rows=adapter_rows,
                                     adapter_scales=adapter_scales)[:, None]
         prog = self._burst_program(n)
-        self.cache, toks = prog(
-            self.params, self.cache, np.asarray(block_tables, np.int32),
-            np.asarray(tokens, np.int32), np.asarray(active, bool),
-            np.asarray(temperature, np.float32),
-            np.asarray(top_p, np.float32),
-            np.asarray(seeds, np.int32), np.asarray(steps, np.int32),
-            *self._adapter_call_args(adapter_rows, adapter_scales))
-        return np.asarray(toks)
+        with self._decode_span(active, n=n):
+            with span("ftl:engine.decode.dispatch"):
+                self.cache, toks = prog(
+                    self.params, self.cache,
+                    np.asarray(block_tables, np.int32),
+                    np.asarray(tokens, np.int32), np.asarray(active, bool),
+                    np.asarray(temperature, np.float32),
+                    np.asarray(top_p, np.float32),
+                    np.asarray(seeds, np.int32), np.asarray(steps, np.int32),
+                    *self._adapter_call_args(adapter_rows, adapter_scales))
+            with span("ftl:engine.decode.sync"):
+                return np.asarray(toks)
 
     def spec_round(self, tokens, lengths, active, temperature, top_p, seeds,
                    rounds, block_tables=None, draft_block_tables=None,
@@ -1782,14 +1856,19 @@ class InferenceEngine:
         tp = np.asarray(top_p, np.float32)
         sd = np.asarray(seeds, np.int32)
         rd = np.asarray(rounds, np.int32)
-        self.draft_cache, d_toks, d_probs = draft_prog(
-            self.draft_params, self.draft_cache,
-            np.asarray(draft_block_tables, np.int32), toks, lens, act, temp,
-            tp, sd, rd)
-        self.cache, out, acc = verify_prog(
-            self.params, self.cache, np.asarray(block_tables, np.int32),
-            toks, d_toks, d_probs, lens, act, temp, tp, sd, rd)
-        return np.asarray(out), np.asarray(acc)
+        with self._decode_span(act, lens,
+                               window=(self.spec_k if k is None else k) + 1):
+            with span("ftl:engine.decode.dispatch"):
+                self.draft_cache, d_toks, d_probs = draft_prog(
+                    self.draft_params, self.draft_cache,
+                    np.asarray(draft_block_tables, np.int32), toks, lens,
+                    act, temp, tp, sd, rd)
+                self.cache, out, acc = verify_prog(
+                    self.params, self.cache,
+                    np.asarray(block_tables, np.int32),
+                    toks, d_toks, d_probs, lens, act, temp, tp, sd, rd)
+            with span("ftl:engine.decode.sync"):
+                return np.asarray(out), np.asarray(acc)
 
     def spec_tree_round(self, refeed, refeed_len, lengths, active,
                         temperature, top_p, seeds, rounds,
@@ -1834,14 +1913,19 @@ class InferenceEngine:
         tp = np.asarray(top_p, np.float32)
         sd = np.asarray(seeds, np.int32)
         rd = np.asarray(rounds, np.int32)
-        self.draft_cache, t_toks, t_probs = draft_prog(
-            self.draft_params, self.draft_cache,
-            np.asarray(draft_block_tables, np.int32), rf, rl, lens, act,
-            temp, tp, sd, rd)
-        self.cache, out, acc, path = verify_prog(
-            self.params, self.cache, np.asarray(block_tables, np.int32),
-            t_toks, t_probs, lens, act, temp, tp, sd, rd)
-        return np.asarray(out), np.asarray(acc), np.asarray(path)
+        with self._decode_span(act, lens, window=shape.size):
+            with span("ftl:engine.decode.dispatch"):
+                self.draft_cache, t_toks, t_probs = draft_prog(
+                    self.draft_params, self.draft_cache,
+                    np.asarray(draft_block_tables, np.int32), rf, rl, lens,
+                    act, temp, tp, sd, rd)
+                self.cache, out, acc, path = verify_prog(
+                    self.params, self.cache,
+                    np.asarray(block_tables, np.int32),
+                    t_toks, t_probs, lens, act, temp, tp, sd, rd)
+            with span("ftl:engine.decode.sync"):
+                return (np.asarray(out), np.asarray(acc),
+                        np.asarray(path))
 
     def fork_slot(self, src_slot: int, dst_slot: int, length: int,
                   src_row, allocator):

@@ -68,6 +68,7 @@ from flax import struct
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..models.configs import TransformerConfig
+from ..obs.trace import scope
 
 
 class KVCache(struct.PyTreeNode):
@@ -173,6 +174,7 @@ def quantize_rows(rows: jax.Array, scale: jax.Array) -> jax.Array:
                     KV_QUANT_QMAX).astype(jnp.int8)
 
 
+@scope("kv_write")
 def _quantized_scatter(pool: QuantPool, blk: jax.Array, off: jax.Array,
                        rows: jax.Array) -> QuantPool:
     """Land fp32 ``rows`` (R, kv_heads, head_dim) at ``(blk[r], :, off[r],
@@ -240,6 +242,7 @@ def init_paged_cache(cfg: TransformerConfig, slots: int, max_len: int,
         lengths=jnp.zeros((slots,), jnp.int32))
 
 
+@scope("kv_write")
 def write_paged_kv(pool: jax.Array, new: jax.Array, block_tables: jax.Array,
                    start: jax.Array, valid: jax.Array) -> jax.Array:
     """Scatter ``new`` (B, K, S, D) into the block ``pool`` (N, K, bs, D) at
@@ -342,6 +345,7 @@ def copy_kv_block(pool: jax.Array, src: jax.Array, dst: jax.Array
     return pool.at[dst].set(pool[src])
 
 
+@scope("kv_write")
 def write_slot_kv(buf: jax.Array, new: jax.Array,
                   start: jax.Array) -> jax.Array:
     """Write ``new`` (B, K, S, D) into ``buf`` (B, K, T, D) at each slot's

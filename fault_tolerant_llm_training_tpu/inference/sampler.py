@@ -38,6 +38,8 @@ from typing import Dict, Iterable, Optional
 import jax
 import jax.numpy as jnp
 
+from ..obs.trace import scope
+
 
 def _top_k_filter(logits: jnp.ndarray, top_k: int) -> jnp.ndarray:
     """Keep the k highest logits, -inf the rest (static k: part of the
@@ -84,6 +86,7 @@ def slot_key(seed: jnp.ndarray, step: jnp.ndarray) -> jax.Array:
     return jax.random.fold_in(jax.random.PRNGKey(seed), step)
 
 
+@scope("sample")
 def sample_slot_tokens(logits: jnp.ndarray, seeds: jnp.ndarray,
                        steps: jnp.ndarray, temperature: jnp.ndarray,
                        top_p: jnp.ndarray, top_k: int = 0) -> jnp.ndarray:

@@ -112,6 +112,7 @@ from typing import Callable, Dict, List, Optional, Sequence
 import numpy as np
 
 from ..obs import events, reqtrace
+from ..obs.trace import span
 from ..obs.registry import (
     SPEC_TOKEN_BUCKETS,
     MetricRegistry,
@@ -354,6 +355,27 @@ class _SpilledRequest:
     bytes: int
 
 
+class _StepSeconds(deque):
+    """The newest decode-iteration wall times (for percentiles) and, in
+    ``total``, the sum of ALL appended since the last ``clear()`` — a
+    server appends one per step for its whole life, so the list is bounded
+    and the sum is kept running instead of re-added every step."""
+
+    KEEP = 65536
+
+    def __init__(self):
+        super().__init__(maxlen=self.KEEP)
+        self.total = 0.0
+
+    def append(self, seconds: float) -> None:
+        self.total += seconds
+        super().append(seconds)
+
+    def clear(self) -> None:
+        self.total = 0.0
+        super().clear()
+
+
 class Scheduler:
     """Continuous-batching loop over an :class:`~.engine.InferenceEngine`."""
 
@@ -384,7 +406,8 @@ class Scheduler:
         self.admission_open = True
         self.iterations = 0
         self.max_concurrent = 0
-        self.step_seconds: List[float] = []  # decode-iteration wall times
+        # decode-iteration wall times: the newest ones, plus their total
+        self.step_seconds = _StepSeconds()
         # Drain probe consulted BETWEEN prefill chunks (serve.py passes the
         # signal flag) so a mid-prompt SIGUSR1/SIGTERM aborts cleanly at a
         # chunk boundary; run(stop=...) installs its callable here too.
@@ -2397,11 +2420,18 @@ class Scheduler:
         In the packed-prefill lane, one packed chunk round runs before the
         decode round, so admitted prompts and active decodes interleave
         instead of prefill draining the queue first."""
+        with span("ftl:sched.step", active=len(self.active),
+                  queued=len(self.queue)):
+            return self._step()
+
+    def _step(self) -> List[Completion]:
         done: List[Completion] = []
         if self.admission_open:
-            self._admit(done)
+            with span("ftl:sched.admit"):
+                self._admit(done)
         if self._pending_prefill:
-            self._prefill_round(done)
+            with span("ftl:sched.prefill_round"):
+                self._prefill_round(done)
         self._m_queue.set(len(self.queue))
         self._m_occupancy.set(len(self.active) / max(self.engine.slots, 1))
         if self.kv_layout == "paged":
@@ -2416,19 +2446,20 @@ class Scheduler:
         if not self.active:
             return done
         slots = self.engine.slots
-        tokens = np.zeros((slots,), np.int32)
-        active = np.zeros((slots,), bool)
-        temperature = np.zeros((slots,), np.float32)
-        top_p = np.ones((slots,), np.float32)
-        seeds = np.zeros((slots,), np.int32)
-        steps = np.zeros((slots,), np.int32)
-        for s, st in self.active.items():
-            tokens[s] = st.tokens[-1]
-            active[s] = True
-            temperature[s] = st.request.temperature
-            top_p[s] = st.request.top_p
-            seeds[s] = st.request.seed
-            steps[s] = st.steps
+        with span("ftl:sched.pack"):
+            tokens = np.zeros((slots,), np.int32)
+            active = np.zeros((slots,), bool)
+            temperature = np.zeros((slots,), np.float32)
+            top_p = np.ones((slots,), np.float32)
+            seeds = np.zeros((slots,), np.int32)
+            steps = np.zeros((slots,), np.int32)
+            for s, st in self.active.items():
+                tokens[s] = st.tokens[-1]
+                active[s] = True
+                temperature[s] = st.request.temperature
+                top_p[s] = st.request.top_p
+                seeds[s] = st.request.seed
+                steps[s] = st.steps
         t0 = self.clock()
         burst_out = None
         if self.spec_k:
@@ -2437,9 +2468,10 @@ class Scheduler:
             # emitted token is the round's input and is written by the
             # draft/verify programs themselves). steps doubles as the
             # round counter that derives the per-round PRNG streams.
-            lengths = np.zeros((slots,), np.int32)
-            for s, st in self.active.items():
-                lengths[s] = len(st.request.prompt) + len(st.tokens) - 1
+            with span("ftl:sched.pack"):
+                lengths = np.zeros((slots,), np.int32)
+                for s, st in self.active.items():
+                    lengths[s] = len(st.request.prompt) + len(st.tokens) - 1
             round_k = self.spec_k
             if self.adaptive_k is not None:
                 round_k = self.adaptive_k.round_k(
@@ -2453,12 +2485,13 @@ class Scheduler:
                 tree_shape = (self.spec_tree if self.adaptive_k is None
                               else self.spec_tree.shrink_to(round_k))
                 r_w = self.engine._tree_refeed
-                refeed = np.zeros((slots, r_w), np.int32)
-                refeed_len = np.ones((slots,), np.int32)
-                for s, st in self.active.items():
-                    em = st.emitted[-r_w:]
-                    refeed[s, :len(em)] = em
-                    refeed_len[s] = len(em)
+                with span("ftl:sched.pack"):
+                    refeed = np.zeros((slots, r_w), np.int32)
+                    refeed_len = np.ones((slots,), np.int32)
+                    for s, st in self.active.items():
+                        em = st.emitted[-r_w:]
+                        refeed[s, :len(em)] = em
+                        refeed_len[s] = len(em)
                 out, acc, path = self.engine.spec_tree_round(
                     refeed, refeed_len, lengths, active, temperature,
                     top_p, seeds, steps, block_tables=self.block_tables,
@@ -2525,19 +2558,25 @@ class Scheduler:
         step_wall = self.clock() - t0
         self.step_seconds.append(step_wall)
         self._m_decode.observe(step_wall)
-        wall = sum(self.step_seconds)
+        wall = self.step_seconds.total
         if wall > 0:
             self._m_tps.set(self._m_tokens.value / wall)
         self.iterations += 1
-        if self.spec_k:
-            if self.spec_tree is not None:
-                self._bank_tree(out, acc, path, tree_shape, done)
+        with span("ftl:sched.bank"):
+            if self.spec_k:
+                if self.spec_tree is not None:
+                    self._bank_tree(out, acc, path, tree_shape, done)
+                else:
+                    self._bank_spec(out, acc, done, k=round_k)
+            elif burst_out is not None:
+                self._bank_burst(burst_out, done)
             else:
-                self._bank_spec(out, acc, done, k=round_k)
-            return done
-        if burst_out is not None:
-            self._bank_burst(burst_out, done)
-            return done
+                self._bank_tokens(next_tokens, done)
+        return done
+
+    def _bank_tokens(self, next_tokens: np.ndarray,
+                     done: List[Completion]) -> None:
+        """Bank one plain decode step's (slots,) tokens."""
         for s in list(self.active):
             st = self.active[s]
             tok = int(next_tokens[s])
@@ -2551,7 +2590,6 @@ class Scheduler:
                 self._finish(s, "eos", done)
             elif len(st.tokens) >= st.request.max_new_tokens:
                 self._finish(s, "length", done)
-        return done
 
     def _bank_burst(self, out: np.ndarray, done: List[Completion]) -> None:
         """Bank one fused burst's (slots, n) tokens, truncating each slot
@@ -2798,7 +2836,7 @@ class Scheduler:
         lat = np.asarray(self.step_seconds or [0.0])
         generated = sum(len(c.tokens) for c in self.completed) + sum(
             len(st.tokens) for st in self.active.values())
-        wall = float(lat.sum())
+        wall = self.step_seconds.total
         tps = generated / wall if wall > 0 else 0.0
         self._m_tps.set(tps)
         out = {

@@ -68,6 +68,8 @@ from .sampler import AdaptiveK
 from .scheduler import Request, Scheduler
 from .transport import make_transport, resolve_lane
 
+_IMPORTS_DONE_T = time.time()  # flight recorder: imports_done
+
 _DEMO_PROMPT = "alpha bravo charlie delta echo"
 
 _M_KV_BYTES_PER_BLOCK = REGISTRY.gauge(
@@ -418,6 +420,7 @@ def get_serve_args(argv=None) -> argparse.Namespace:
 
 
 def main(argv=None) -> None:
+    events.emit_startup(_IMPORTS_DONE_T)
     args = get_serve_args(argv)
     init_logger()
     flag = SignalFlag()

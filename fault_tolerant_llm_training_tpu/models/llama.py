@@ -24,6 +24,7 @@ import jax
 import jax.numpy as jnp
 from flax import linen as nn
 
+from ..obs.trace import scope
 from ..ops.attention import cached_attention, multihead_attention
 from ..ops.rope import (
     apply_rope,
@@ -548,7 +549,11 @@ class Transformer(nn.Module):
         return self.norm(x)
 
     def __call__(self, tokens, positions=None):
-        logits = self.output(self.hidden_states(tokens, positions))
+        hidden = self.hidden_states(tokens, positions)
+        # the training forward: its head matmul is read with the loss
+        # (serving's head() keeps the bare ``output`` name)
+        with scope("loss_head"):
+            logits = self.output(hidden)
         return constrain(logits, "batch", "seq", "vocab")
 
     def forward_with_cache(self, tokens, cache_k, cache_v, offsets,

@@ -55,6 +55,21 @@ from . import hlc
 #                        (payload: step chosen, rejected steps)
 #   ckpt_partial_skipped leftover non-finalized tmp dir seen (and never
 #                        restored) during the finalize sweep
+# Lifecycle of one process, in the order a resumed trainer emits them (the
+# restart's boundaries, read where the work happens):
+#   proc_start       t = the interpreter's start (:func:`emit_startup`)
+#   imports_done     the entry module finished importing (dur = since
+#                    proc_start: what every process pays before it can
+#                    touch the backend)
+#   backend_ready    the backend answered, where ``Device | `` is logged
+#                    (Trainer.__init__, InferenceEngine.__init__;
+#                    payload: device)
+#   ckpt_verify      integrity gate before a restore (dur = the CRC scan,
+#                    bytes = bytes checksummed; payload: step chosen)
+#   ckpt_manifest    integrity manifests written after a commit (dur,
+#                    bytes = bytes checksummed; payload: steps)
+#   first_step_done  the first step of this process whose metrics were read
+#                    back (payload: resumed true/false)
 
 
 class FlightRecorder:
@@ -134,11 +149,16 @@ _RECORDER = FlightRecorder()
 def configure(path: Optional[str], job: str = "local", host: int = 0,
               capacity: int = 512) -> FlightRecorder:
     """Swap in a configured recorder; prior ring contents carry over so
-    events emitted before configuration are not lost."""
+    events emitted before configuration are not lost. What the memory-only
+    default recorder collected (the process's start-up events, a signal
+    during setup) belongs to this process, so it takes this job and host:
+    the stitcher must not see a job of its own in it."""
     global _RECORDER
     old = _RECORDER
     rec = FlightRecorder(path, capacity=capacity, job=job, host=host)
-    rec.ring.extend(old.ring)
+    rec.ring.extend(
+        dict(ev, job=job, host=host) if old.path is None
+        and ev.get("job") == "local" else ev for ev in old.ring)
     if rec._fh is not None:
         for ev in rec.ring:  # replay pre-configuration events into the file
             try:
@@ -161,6 +181,30 @@ def emit(kind: str, step: Optional[int] = None,
 
 def flush() -> None:
     _RECORDER.flush()
+
+
+def process_start_time() -> float:
+    """Unix time at which this process started. Linux: the kernel's own
+    stamp (``/proc/self/stat`` field 22, ticks since boot), so it holds
+    wherever in the program it is asked; elsewhere: now."""
+    try:
+        with open("/proc/self/stat") as fh:
+            ticks = int(fh.read().rsplit(")", 1)[1].split()[19])
+        since_boot = time.clock_gettime(time.CLOCK_BOOTTIME)
+        return (time.time() - since_boot
+                + ticks / os.sysconf("SC_CLK_TCK"))
+    except (OSError, ValueError, IndexError, AttributeError):
+        return time.time()
+
+
+def emit_startup(imports_done_t: float) -> None:
+    """``proc_start`` and ``imports_done`` of this process. An entry point
+    stamps ``imports_done_t`` (``time.time()``) right below its imports
+    and calls this first thing in its main function; the events carry the
+    times they describe, not the time of the call."""
+    t0 = min(process_start_time(), imports_done_t)
+    emit("proc_start", t=t0)
+    emit("imports_done", dur=imports_done_t - t0, t=imports_done_t)
 
 
 def emit_audit(log, text: str, kind: str, step: Optional[int] = None,

@@ -1,13 +1,24 @@
-"""Windowed ``jax.profiler`` capture (``--trace-steps A:B``).
+"""Profiler capture and the program's one vocabulary of trace names.
 
-``--profile-dir`` alone traces the whole run — fine for a 5-step probe,
-useless for "step 400 regressed": a multi-hour trace is unloadably large.
-The window form arms the profiler at step A and disarms it after step B
-(inclusive), each captured step wrapped in a ``StepTraceAnnotation`` so
-XenseCope/TensorBoard group device ops per step. scripts/profile_step.py
-used to do this ad hoc with its own start/stop + parser; both now live
-here (:func:`capture`, :func:`parse_trace`) so the CLI window, the script,
-and the tests share one implementation.
+**Names.** Every host span and every device scope the program opens is
+named here, once: :data:`SPANS` (``ftl:`` host spans, written into the
+profiler's own trace by :func:`span`, so they share a clock with the device
+ops) and :data:`SCOPES` (``jax.named_scope`` names on device work, opened
+by :func:`scope`; they land in every op's ``op_name``, which the TPU
+profiler keeps as the ``tf_op`` stat of the op). Both refuse a name that is
+not in their table, and tests/test_trace_names.py holds the tables, the
+call sites and PERF.md §3 to each other. Per-request spans are another
+record (``obs/reqtrace.py``), lifecycle events a third (``obs/events.py``).
+
+**Capture.** ``--profile-dir`` alone traces the whole run — fine for a
+5-step probe, useless for "step 400 regressed": a multi-hour trace is
+unloadably large. The window form (``--trace-steps A:B``) arms the profiler
+at step A and disarms it after step B (inclusive), each captured step
+wrapped in a ``StepTraceAnnotation`` so XenseCope/TensorBoard group device
+ops per step. scripts/profile_step.py used to do this ad hoc with its own
+start/stop + parser; both now live here (:func:`capture`,
+:func:`parse_trace`) so the CLI window, the script, and the tests share one
+implementation.
 
 :class:`AutoTraceWindow` (``--auto-trace``) is the reactive form: instead
 of a pre-chosen window it arms itself, once per run, when a step's wall
@@ -23,6 +34,119 @@ import json
 import re
 import statistics
 from typing import Callable, Optional, Tuple
+
+import jax
+
+# ------------------------------------------------------------------- names
+# name -> (layer as PERF.md §3 lists it, what the span covers). A span's
+# children are the spans opened inside it on the same thread; what no child
+# covers is its self time.
+SPANS = {
+    "ftl:sched.step": (
+        "scheduler", "one Scheduler.step iteration (args active, queued); "
+        "self time = gauges, adapter sync"),
+    "ftl:sched.admit": (
+        "scheduler", "admission: queue -> slots, block allocation, prefix "
+        "cache, sequential prefill calls"),
+    "ftl:sched.prefill_round": (
+        "scheduler", "one packed prefill round of the pending prompts"),
+    "ftl:sched.pack": (
+        "scheduler", "building the per-slot host arrays of a decode round"),
+    "ftl:sched.bank": (
+        "scheduler", "committing a round's tokens to requests, finishing "
+        "requests"),
+    "ftl:engine.decode": (
+        "engine", "decode_step / decode_burst / spec_round / "
+        "spec_tree_round (args slots_active, live_tokens, n)"),
+    "ftl:engine.decode.dispatch": (
+        "engine", "the call into the compiled decode program(s) returns"),
+    "ftl:engine.decode.sync": (
+        "engine", "host blocked on the device for the round's tokens"),
+    "ftl:engine.prefill": (
+        "engine", "prefill / prefill_packed (args new_tokens, start_pos, "
+        "bucket)"),
+    "ftl:engine.prefill.dispatch": (
+        "engine", "the calls into the compiled prefill chunk programs "
+        "return"),
+    "ftl:engine.prefill.sync": (
+        "engine", "host blocked on the device for the first token"),
+    "ftl:train.step": (
+        "trainer", "one iteration of Trainer.run's step loop (arg step)"),
+    "ftl:train.signal_check": (
+        "trainer", "signal flag / cluster agreement at the step boundary"),
+    "ftl:train.fetch": (
+        "trainer", "waiting for the prefetcher's next batch (what "
+        "ftl_data_stall_seconds_total counts)"),
+    "ftl:train.dispatch": (
+        "trainer", "the call into the compiled train step returns"),
+    "ftl:train.consume": (
+        "trainer", "blocking read of an older step's packed metrics"),
+    "ftl:data.prefetch": (
+        "data", "one batch's host work on the prefetcher's thread: loader, "
+        "collate, device_put"),
+}
+
+# name -> (layer, what runs under it), in the ORDER a reader gives an op to
+# a bucket: the first name that is a component of the op's ``op_name``
+# wins, so the narrower scopes (opened by this program with :func:`scope`)
+# come before the flax module names they sit inside. The last seven are
+# flax's own module names, written into ``op_name`` by flax: they are
+# listed, never opened here, and never renamed (parameter tree, every
+# checkpoint).
+SCOPES = {
+    "kv_write": ("kernels, serve", "new K/V rows scattered into the cache "
+                 "(paged pool or slot ring, int8 quantize included)"),
+    "kv_read": ("kernels, serve", "attention over cached K/V: block "
+                "gather + masked attention, or the in-place Pallas "
+                "kernels"),
+    "rope": ("kernels", "rotary embedding of q and k outside a fused "
+             "kernel"),
+    "sample": ("kernels, serve", "token sampling epilogue over the "
+               "logits"),
+    "loss_head": ("kernels, train", "lm-head matmul of the training "
+                  "forward and the cross-entropy"),
+    "grad_clip": ("kernels, train", "global gradient norm and clip"),
+    "optimizer": ("kernels, train", "optax update and parameter apply"),
+    "feed_forward": ("kernels", "flax module: the MLP block"),
+    "attention": ("kernels", "flax module: projections + attention "
+                  "(flash kernels are named attention.N by it)"),
+    "tok_embeddings": ("kernels", "flax module: token embedding"),
+    "output": ("kernels, serve", "flax module: lm-head matmul outside "
+               "loss_head (serving)"),
+    "attention_norm": ("kernels", "flax module: RMSNorm before attention"),
+    "ffn_norm": ("kernels", "flax module: RMSNorm before the MLP"),
+    "norm": ("kernels", "flax module: final RMSNorm"),
+}
+_OPENED_HERE = ("kv_write", "kv_read", "rope", "sample", "loss_head",
+                "grad_clip", "optimizer")
+
+
+def tracing() -> bool:
+    """True while a profiler session is collecting host spans."""
+    return jax.profiler.TraceAnnotation.is_enabled()
+
+
+def span(name: str, **args):
+    """Host span in the profiler's own trace: a ``TraceAnnotation`` named
+    from :data:`SPANS`. With no profiler running it costs a flag test.
+    ``args`` land as stats on the event; they are values the caller
+    already holds. One that costs something to produce is passed as a
+    zero-argument callable, called only while a profiler runs."""
+    if name not in SPANS:
+        raise ValueError(f"span {name!r} is not in obs.trace.SPANS")
+    if args and tracing():
+        args = {k: v() if callable(v) else v for k, v in args.items()}
+    return jax.profiler.TraceAnnotation(name, **args)
+
+
+def scope(name: str):
+    """``jax.named_scope`` with a name from :data:`SCOPES` (only those this
+    program opens itself; flax writes its module names)."""
+    if name not in _OPENED_HERE:
+        raise ValueError(f"scope {name!r} is not one obs.trace.SCOPES "
+                         f"lets the program open")
+    return jax.named_scope(name)
+
 
 
 def parse_window(spec: str) -> Tuple[int, int]:
@@ -68,22 +192,16 @@ class TraceWindow:
     def on_step_start(self, step: int) -> None:
         if (not self.active and not self.done
                 and self.start_step <= step <= self.stop_step):
-            import jax
-
             jax.profiler.start_trace(self.trace_dir)
             self.active = True
 
     def annotate(self, step: int):
         if not self.active:
             return contextlib.nullcontext()
-        import jax
-
         return jax.profiler.StepTraceAnnotation("train", step_num=step)
 
     def on_step_end(self, step: int) -> None:
         if self.active and step >= self.stop_step:
-            import jax
-
             if self.drain is not None:
                 self.drain()
             jax.profiler.stop_trace()
@@ -93,8 +211,6 @@ class TraceWindow:
     def close(self) -> None:
         """Stop a still-armed trace (loop exited inside the window)."""
         if self.active:
-            import jax
-
             try:
                 if self.drain is not None:
                     self.drain()
@@ -148,16 +264,12 @@ class AutoTraceWindow:
         if self._start is not None:
             self._start(self.trace_dir)
             return
-        import jax
-
         jax.profiler.start_trace(self.trace_dir)
 
     def _profiler_stop(self) -> None:
         if self._stop is not None:
             self._stop()
             return
-        import jax
-
         jax.profiler.stop_trace()
 
     def observe(self, step: int, seconds: float) -> Optional[float]:
@@ -198,8 +310,6 @@ class AutoTraceWindow:
 @contextlib.contextmanager
 def capture(trace_dir: str):
     """Whole-scope trace (scripts/profile_step.py's form)."""
-    import jax
-
     jax.profiler.start_trace(trace_dir)
     try:
         yield

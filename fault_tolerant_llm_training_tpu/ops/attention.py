@@ -23,6 +23,7 @@ bandwidth).
 import jax
 import jax.numpy as jnp
 
+from ..obs.trace import scope
 from ..parallel.mesh import mesh_axis_size
 
 
@@ -57,6 +58,7 @@ def xla_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     return out.reshape(b, s_q, h, d).astype(q.dtype)
 
 
+@scope("kv_read")
 def cached_attention(q: jnp.ndarray, k_cache: jnp.ndarray, v_cache: jnp.ndarray,
                      offsets: jnp.ndarray) -> jnp.ndarray:
     """Grouped-query attention against a per-slot KV cache (prefill/decode).
@@ -94,6 +96,7 @@ def cached_attention(q: jnp.ndarray, k_cache: jnp.ndarray, v_cache: jnp.ndarray,
     return out.reshape(b, s_q, h, d).astype(q.dtype)
 
 
+@scope("kv_read")
 def tree_cached_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
                           v_cache: jnp.ndarray, offsets: jnp.ndarray,
                           anc_mask: jnp.ndarray) -> jnp.ndarray:
@@ -153,6 +156,7 @@ def dequant_kv(q_vals: jnp.ndarray, scale: jnp.ndarray,
     return (q_vals.astype(jnp.float32) * scale).astype(out_dtype)
 
 
+@scope("kv_read")
 def gather_kv_blocks(pool, block_tables: jnp.ndarray,
                      out_dtype=None) -> jnp.ndarray:
     """Assemble per-slot contiguous KV views from a paged block pool.
@@ -196,6 +200,7 @@ def gather_kv_blocks(pool, block_tables: jnp.ndarray,
     return jnp.transpose(g, (0, 2, 1, 3, 4)).reshape(b, k, nb * bs, d)
 
 
+@scope("kv_read")
 def paged_cached_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
                            v_pool: jnp.ndarray, block_tables: jnp.ndarray,
                            offsets: jnp.ndarray) -> jnp.ndarray:
@@ -228,6 +233,7 @@ def paged_cached_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
         gather_kv_blocks(v_pool, block_tables, q.dtype), offsets)
 
 
+@scope("kv_read")
 def paged_tree_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
                          v_pool: jnp.ndarray, block_tables: jnp.ndarray,
                          offsets: jnp.ndarray, anc_mask: jnp.ndarray,
@@ -256,6 +262,7 @@ def paged_tree_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
                      f"(want 'gather' or 'pallas')")
 
 
+@scope("kv_read")
 def paged_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
                     v_pool: jnp.ndarray, block_tables: jnp.ndarray,
                     offsets: jnp.ndarray, impl: str = "gather"

@@ -33,6 +33,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from ..obs.trace import scope
+
 # Vocab sizes at or above this use the blocked path automatically; below it
 # the dense optax-style CE is faster (one fused reduction, no loop carries).
 # 131072 (the reference's Mistral-Nemo vocab) is the motivating case.
@@ -56,6 +58,7 @@ def _block_update(sl, labels, v0, m, l, picked):
     return m_new, l, picked
 
 
+@scope("loss_head")
 def _lse_and_picked(logits, labels, block):
     b, s, v = logits.shape
     m = jnp.full((b, s), -jnp.inf, jnp.float32)
@@ -90,6 +93,7 @@ def _xent_fwd(logits, labels, block):
     return lse - picked, (logits, labels, lse)
 
 
+@scope("loss_head")
 def _xent_bwd(block, res, g):
     logits, labels, lse = res
     b, s, v = logits.shape

@@ -47,6 +47,7 @@ import jax.numpy as jnp
 from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
+from ..obs.trace import scope
 from .cross_entropy import DEFAULT_BLOCK
 
 # Auto-dispatch point (training/step.py): the fused form pays ~12% step
@@ -112,6 +113,7 @@ def _raw_stats(hidden, w, labels, block):
     return m, l, picked
 
 
+@scope("loss_head")
 def _fwd_stats(hidden, w, labels, block):
     m, l, picked = _raw_stats(hidden, w, labels, block)
     return m + jnp.log(l), picked
@@ -133,6 +135,7 @@ def _fx_fwd(hidden, w, labels, block):
     return lse - picked, (hidden, w, labels, lse)
 
 
+@scope("loss_head")
 def _bwd_accum(hidden, w, labels, lse, gf, block, dw_dtype=None):
     """Blocked backward of the head+CE: recompute each vocab block's logits,
     form ``dS_j = gf * (softmax_j - onehot_j)``, and contract immediately
@@ -236,6 +239,7 @@ def _sharded_fx(hidden, w, labels, block):
     return nll
 
 
+@scope("loss_head")
 def _sfx_fwd_impl(hidden, w, labels, block):
     from ..parallel.mesh import active_mesh
 
@@ -264,6 +268,7 @@ def _sfx_fwd(hidden, w, labels, block):
     return nll, (hidden, w, labels, lse)
 
 
+@scope("loss_head")
 def _sfx_bwd(block, res, g):
     from ..parallel.mesh import active_mesh
 

@@ -75,6 +75,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..obs.trace import scope
 from .flash_attention import LOG2E, NEG_INF, _interpret
 
 # m/l scratch rides full lanes: TPU VMEM tiles pad the trailing dim to
@@ -217,6 +218,7 @@ def _decode_kernel(tables_ref, offs_ref, *args, block_size: int,
                             / l_scr[lo:hi, :1]).astype(o_ref.dtype)
 
 
+@scope("kv_read")
 def paged_decode_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
                            v_pool: jnp.ndarray, block_tables: jnp.ndarray,
                            offsets: jnp.ndarray,
@@ -368,6 +370,7 @@ def _chunk_kernel(tables_ref, offs_ref, *args, block_size: int, group: int,
                             / l_scr[lo:hi, :1]).astype(o_ref.dtype)
 
 
+@scope("kv_read")
 def paged_chunk_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
                           v_pool: jnp.ndarray, block_tables: jnp.ndarray,
                           offsets: jnp.ndarray,
@@ -520,6 +523,7 @@ def _tree_kernel(tables_ref, offs_ref, *args, block_size: int, group: int,
         o_ref[0, 0] = (acc_scr[...] / l_scr[:, :1]).astype(o_ref.dtype)
 
 
+@scope("kv_read")
 def paged_tree_chunk_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
                                v_pool: jnp.ndarray,
                                block_tables: jnp.ndarray,

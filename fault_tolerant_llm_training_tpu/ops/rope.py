@@ -20,6 +20,8 @@ from typing import Tuple
 
 import jax.numpy as jnp
 
+from ..obs.trace import scope
+
 
 def rope_freqs(head_dim: int, theta: float) -> jnp.ndarray:
     """(D/2,) inverse frequencies theta^(-2j/d) (ref: model.py:67-69)."""
@@ -55,6 +57,7 @@ def rope_cos_sin(head_dim: int, theta: float, positions: jnp.ndarray
     return jnp.cos(angles), jnp.sin(angles)
 
 
+@scope("rope")
 def apply_rope(x: jnp.ndarray, cos: jnp.ndarray, sin: jnp.ndarray,
                positions: jnp.ndarray = None) -> jnp.ndarray:
     """Rotate ``x`` of shape (B, S, H, D) by the interleaved-pair convention.
@@ -85,6 +88,7 @@ def apply_rope(x: jnp.ndarray, cos: jnp.ndarray, sin: jnp.ndarray,
     return out.astype(orig_dtype)
 
 
+@scope("rope")
 def apply_rope_bhsd(x: jnp.ndarray, cos: jnp.ndarray, sin: jnp.ndarray
                     ) -> jnp.ndarray:
     """:func:`apply_rope` for head-major ``x`` of shape (B, H, S, D).

@@ -44,7 +44,7 @@ from ..models import Transformer, get_config
 from ..deploy.publish import Publisher
 from ..obs import events
 from ..obs.registry import REGISTRY
-from ..obs.trace import AutoTraceWindow, TraceWindow
+from ..obs.trace import AutoTraceWindow, TraceWindow, span
 from ..ops.attention import describe_attention_impl
 from ..parallel.mesh import make_mesh, use_mesh
 from ..parallel.sharding import batch_pspec, param_pspecs
@@ -306,7 +306,9 @@ class Trainer:
         # What this run resolved, in its own log: a job that quietly took
         # the CPU, the XLA attention or interpret-mode kernels on a chip
         # host must be visible without a profiler.
-        logger.info(f"Device | {describe_device()}")
+        device = describe_device()
+        logger.info(f"Device | {device}")
+        events.emit("backend_ready", device=device)
         logger.info(f"Attention | requested {cfg.attention_impl} | resolved "
                     f"{describe_attention_impl(cfg.attention_impl)}")
         self.optimizer = make_optimizer(
@@ -678,11 +680,25 @@ class Trainer:
         sync_freq = max(1, cfg.signal_sync_frequency)
         first_iteration = True
         while self.training_step < cfg.training_steps:
-            if self.chaos is not None:
-                # Sync-boundary faults (kv_delay / kv_fail) fire BEFORE the
-                # real agreement round below, modeling a slow or failed
-                # KV-store round at the exact point one would hurt.
-                self.chaos.on_sync_boundary(self, self.training_step)
+            with span("ftl:train.step", step=self.training_step):
+                self._iteration(it, sync_freq, first_iteration)
+            first_iteration = False
+        self._drain_inflight()
+        self._emit_tail_window()
+        if (self._compiled_eval is not None
+                and self.training_step % cfg.eval_frequency != 0):
+            self._evaluate()  # final eval unless the last step just ran one
+
+    def _iteration(self, it, sync_freq: int, first_iteration: bool) -> None:
+        """One pass of the step loop: boundary checks, next batch, dispatch,
+        metric consumption, periodic save / eval."""
+        cfg = self.cfg
+        if self.chaos is not None:
+            # Sync-boundary faults (kv_delay / kv_fail) fire BEFORE the
+            # real agreement round below, modeling a slow or failed
+            # KV-store round at the exact point one would hurt.
+            self.chaos.on_sync_boundary(self, self.training_step)
+        with span("ftl:train.signal_check"):
             if self._sync_signals:
                 # Host-side non-blocking poll FIRST: a peer's announced
                 # local fault must stop this host before it dispatches
@@ -718,66 +734,63 @@ class Trainer:
                         raise TrainingSignal(verdict)
             else:
                 self.signal_flag.check()
-            first_iteration = False
-            t_fetch = time.perf_counter()
+        t_fetch = time.perf_counter()
+        with span("ftl:train.fetch"):
             inputs, labels, data_state = next(it)
-            # Data-stall accounting: with the prefetcher healthy this is
-            # ~0; a growing counter at /metrics means the input pipeline,
-            # not the TPU, is the bottleneck.
-            self._m_stall.inc(time.perf_counter() - t_fetch)
-            if self._trace is not None:
-                self._trace.on_step_start(self.training_step)
-            with (self._trace.annotate(self.training_step)
-                  if self._trace is not None else contextlib.nullcontext()):
+        # Data-stall accounting: with the prefetcher healthy this is
+        # ~0; a growing counter at /metrics means the input pipeline,
+        # not the TPU, is the bottleneck.
+        self._m_stall.inc(time.perf_counter() - t_fetch)
+        if self._trace is not None:
+            self._trace.on_step_start(self.training_step)
+        with (self._trace.annotate(self.training_step)
+              if self._trace is not None else contextlib.nullcontext()):
+            with span("ftl:train.dispatch"):
                 self.state, metrics = self._compiled_step(self.state,
                                                           inputs, labels)
-            self._dispatched += 1
-            self._last_data_state = data_state
-            # The jitted step pre-packs (loss, grad_norm) into one array so
-            # _consume pays ONE device-to-host transfer (and one sync) per
-            # step, not one per metric.
-            self._inflight.append((self.training_step, metrics["packed"]))
-            while len(self._inflight) >= max(1, cfg.inflight):
+        self._dispatched += 1
+        self._last_data_state = data_state
+        # The jitted step pre-packs (loss, grad_norm) into one array so
+        # _consume pays ONE device-to-host transfer (and one sync) per
+        # step, not one per metric.
+        self._inflight.append((self.training_step, metrics["packed"]))
+        while len(self._inflight) >= max(1, cfg.inflight):
+            with span("ftl:train.consume"):
                 self._consume(*self._inflight.popleft())
-            # Deterministic fault injection (ref: train.py:112-113): the
-            # single training-loop injection site, fired while the counter
-            # still equals the entry's step, after the update. The legacy
-            # --raise-error flag is an alias for one 'exception' entry
-            # (chaos/injector.py from_config); signal, exception and
-            # checkpoint-corruption faults all originate here.
-            if self.chaos is not None:
-                self.chaos.on_train_step(self, self.training_step)
-            if self._trace is not None:
-                self._trace.on_step_end(self.training_step)
-            self.training_step += 1
-            if (cfg.checkpoint_frequency
-                    and self.training_step % cfg.checkpoint_frequency == 0):
-                # The FIRST periodic save blocks to measure the real
-                # write wall against the signal lead (the startup budget
-                # line only extrapolates a 128 MiB probe — ADVICE r3:
-                # on filesystems with throughput cliffs the estimate is
-                # optimistic and the operator must learn BEFORE the first
-                # preemption, not during it). Later saves are async.
-                first = not self._budget_observed
-                self._budget_observed = True
-                saved = self.save_checkpoint(wait=first, stop_prefetch=False)
-                if self._publisher is not None:
-                    # The pointer must never point at a step without its
-                    # integrity manifest (the watcher would reject it), so
-                    # an async save drains before publishing. That trades
-                    # the async overlap for a durable deployment point —
-                    # the cadence that wants both is a higher
-                    # --checkpoint-frequency, not a torn publish.
-                    self.ckpt_mngr.wait_until_finished()
-                    self._publisher.publish(saved)
-            if (self._compiled_eval is not None
-                    and self.training_step % cfg.eval_frequency == 0):
-                self._evaluate()
-        self._drain_inflight()
-        self._emit_tail_window()
+        # Deterministic fault injection (ref: train.py:112-113): the
+        # single training-loop injection site, fired while the counter
+        # still equals the entry's step, after the update. The legacy
+        # --raise-error flag is an alias for one 'exception' entry
+        # (chaos/injector.py from_config); signal, exception and
+        # checkpoint-corruption faults all originate here.
+        if self.chaos is not None:
+            self.chaos.on_train_step(self, self.training_step)
+        if self._trace is not None:
+            self._trace.on_step_end(self.training_step)
+        self.training_step += 1
+        if (cfg.checkpoint_frequency
+                and self.training_step % cfg.checkpoint_frequency == 0):
+            # The FIRST periodic save blocks to measure the real
+            # write wall against the signal lead (the startup budget
+            # line only extrapolates a 128 MiB probe — ADVICE r3:
+            # on filesystems with throughput cliffs the estimate is
+            # optimistic and the operator must learn BEFORE the first
+            # preemption, not during it). Later saves are async.
+            first = not self._budget_observed
+            self._budget_observed = True
+            saved = self.save_checkpoint(wait=first, stop_prefetch=False)
+            if self._publisher is not None:
+                # The pointer must never point at a step without its
+                # integrity manifest (the watcher would reject it), so
+                # an async save drains before publishing. That trades
+                # the async overlap for a durable deployment point —
+                # the cadence that wants both is a higher
+                # --checkpoint-frequency, not a torn publish.
+                self.ckpt_mngr.wait_until_finished()
+                self._publisher.publish(saved)
         if (self._compiled_eval is not None
-                and self.training_step % cfg.eval_frequency != 0):
-            self._evaluate()  # final eval unless the last step just ran one
+                and self.training_step % cfg.eval_frequency == 0):
+            self._evaluate()
 
     def _emit_tail_window(self) -> None:
         """Close the step-window accounting. Steps drained with
@@ -914,6 +927,10 @@ class Trainer:
                                                     step=step_no),
                         "trace_auto", step=step_no, ratio=ratio,
                         trace_dir=self._auto_trace.trace_dir)
+        else:
+            # the restart's far boundary: this process has a finished step
+            events.emit("first_step_done", step=step_no,
+                        resumed=self._resumed)
         self._last_consume_t = now
         self.last_loss = loss
         self._m_loss.set(loss)

@@ -13,6 +13,7 @@ import jax
 import jax.numpy as jnp
 import optax
 
+from ..obs.trace import scope
 from ..ops.ring_attention import zigzag_layout_active, zigzag_perm
 from ..parallel.mesh import mesh_axis_size
 from ..training.state import TrainState
@@ -21,6 +22,7 @@ from ..utils.grad_clip import clip_grads_with_norm
 IGNORE_INDEX = -100  # ref: dataset.py:50, train.py:94,101
 
 
+@scope("loss_head")
 def masked_mean_nll(nll, labels) -> Tuple[jax.Array, jax.Array]:
     """Sum per-token nll over non-ignored labels / their count (the
     reference's loss normalization, train.py:94,101-102) — the single
@@ -31,6 +33,7 @@ def masked_mean_nll(nll, labels) -> Tuple[jax.Array, jax.Array]:
     return loss, num_valid
 
 
+@scope("loss_head")
 def cross_entropy_loss(logits: jax.Array, labels: jax.Array,
                        ce_block: int | None = None
                        ) -> Tuple[jax.Array, jax.Array]:
@@ -179,8 +182,9 @@ def model_loss(model, params, inputs, labels, microbatches: int = 0,
         safe = jnp.where(labels == IGNORE_INDEX, 0, labels)
         xent = (sharded_fused_head_xent if vocab_shards > 1
                 else fused_head_xent)
-        nll = xent(out, head_w, safe,
-                   min(8192, head_w.shape[1] // vocab_shards))
+        with scope("loss_head"):
+            nll = xent(out, head_w, safe,
+                       min(8192, head_w.shape[1] // vocab_shards))
         loss, num_valid = masked_mean_nll(nll, labels)
     else:
         loss, num_valid = cross_entropy_loss(out, labels)
@@ -292,9 +296,10 @@ def make_train_step(model, optimizer: optax.GradientTransformation,
         (loss, num_tokens), grads = accum_value_and_grad(
             state.params, inputs, labels)
         grads, grad_norm = clip_grads_with_norm(grads, grad_max_norm)
-        updates, new_opt_state = optimizer.update(grads, state.opt_state,
-                                                  state.params)
-        new_params = optax.apply_updates(state.params, updates)
+        with scope("optimizer"):
+            updates, new_opt_state = optimizer.update(
+                grads, state.opt_state, state.params)
+            new_params = optax.apply_updates(state.params, updates)
         new_state = state.replace(step=state.step + 1, params=new_params,
                                   opt_state=new_opt_state)
         metrics = {"loss": loss, "grad_norm": grad_norm,

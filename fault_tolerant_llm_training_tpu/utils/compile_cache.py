@@ -41,8 +41,17 @@ def enable_compilation_cache(cache_dir: Optional[str] = None) -> str:
     or an earlier call) wins over both and is left exactly as it is.
     Where this function sets the directory it also drops the
     min-compile-time / entry-size floors to 0, so the small prefill-bucket
-    programs cache too.
+    programs cache too. In every case the cache key takes the op metadata
+    in (see below).
     """
+    # An executable read back from the cache keeps the op names and source
+    # lines it was compiled with. JAX's default leaves that metadata out of
+    # the key, so a program whose ops were merely renamed (a new
+    # obs/trace.py scope) would run under the OLD names and every profile
+    # of it would misattribute its device time. The key holds the metadata
+    # here, whoever placed the directory: a restart of unchanged code still
+    # hits; changed code compiles once.
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     configured = jax.config.jax_compilation_cache_dir
     if configured:
         return configured

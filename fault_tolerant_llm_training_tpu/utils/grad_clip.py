@@ -13,6 +13,8 @@ it pulls the ``grad_norm`` metric (you cannot raise from inside ``jit``).
 import jax
 import jax.numpy as jnp
 
+from ..obs.trace import scope
+
 
 class NonFiniteGradientError(RuntimeError):
     """Host-side equivalent of torch's ``error_if_nonfinite`` (ref: utils.py:61)."""
@@ -26,6 +28,7 @@ def global_norm(tree) -> jax.Array:
     )
 
 
+@scope("grad_clip")
 def clip_grads_with_norm(grads, max_norm: float):
     """Scale ``grads`` by ``min(max_norm / (norm + 1e-6), 1.0)``.
 

@@ -1,0 +1,11 @@
+"""Share of the device's busy time in the traced window under the program's
+``kv_read`` scope (block gather + attention over the cached K/V, or the in-
+place paged kernels): self time of the ops so named over the busy union."""
+
+from perfbench.metrics import _program_trace as pt
+
+
+def read(ctx):
+    if not ctx.get("serve"):
+        return None
+    return pt.share_pct(pt.summary_of(ctx), "kv_read")

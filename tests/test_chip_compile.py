@@ -173,3 +173,67 @@ def test_train_step_lowers_on_four_chip_fsdp_mesh(v5e):
                           out_shardings=(shardings, None)).lower(
             state, tokens, tokens)
     assert "tpu_custom_call" in lowered.as_text()
+
+
+def test_d4_train_step_keeps_scope_names_and_flash_call_names(v5e):
+    """The benchmark's training program (mistral-7b-v0.3-d4, seq 4096 x 3
+    rows) compiled for one chip of the described v5e: after XLA's fusion
+    every training scope of ``obs/trace.py`` ``SCOPES`` is still the
+    ``op_name`` of some instruction (the profiler reads device time by
+    model part from it), and the flash attention Mosaic calls are still
+    named ``attention.N`` — the name the benchmark's accepted kernel
+    readers find them by, which a scope opened between the ``attention``
+    module and the ``pallas_call`` would change."""
+    import json
+    import re
+    import sys
+    from pathlib import Path
+
+    root = Path(__file__).resolve().parent.parent
+    sys.path.insert(0, str(root))
+    from perfbench.lib import weights
+
+    from fault_tolerant_llm_training_tpu.models import Transformer
+    from fault_tolerant_llm_training_tpu.models import configs as mc
+    from fault_tolerant_llm_training_tpu.training.state import TrainState
+    from fault_tolerant_llm_training_tpu.training.step import (
+        make_optimizer,
+        make_train_step,
+    )
+
+    bench = root / "perfbench"
+    config = json.loads(
+        (bench / "configs" / "mistral-7b-v0.3-d4.json").read_text())
+    mix = json.loads((bench / "traffic" / "preempt.json").read_text())
+    seq, rows = mix["sequence_length"], mix["rows_per_chip"]
+    d = weights.dims_of(config)
+    cfg = mc.TransformerConfig(**weights.preset_kwargs(config), seq_len=seq,
+                               attention_impl="pallas")
+    model = Transformer(cfg)
+    opt = make_optimizer(mix["learning_rate"], mix["lr_warmup_steps"])
+
+    def init_fn(key):
+        params = weights.make_param_tree(key, d, jnp.bfloat16)
+        return TrainState(step=jnp.zeros((), jnp.int32), params=params,
+                          opt_state=opt.init(params))
+
+    one = SingleDeviceSharding(v5e.devices[0])
+    state = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one),
+        jax.eval_shape(init_fn, jax.random.PRNGKey(0)))
+    tokens = jax.ShapeDtypeStruct((rows, seq), jnp.int32, sharding=one)
+    hlo = jax.jit(make_train_step(model, opt, 1.0),
+                  donate_argnums=(0,)).lower(
+        state, tokens, tokens).compile().as_text()
+    words = {w for name in re.findall(r'op_name="([^"]+)"', hlo)
+             for w in re.findall(r"[A-Za-z_][A-Za-z0-9_]*", name)}
+    want = {"loss_head", "grad_clip", "optimizer", "attention",
+            "feed_forward", "tok_embeddings", "attention_norm", "ffn_norm",
+            "norm"}
+    assert want <= words, want - words
+    calls = [re.match(r"\s*(?:ROOT )?%([\w.\-]+) = ", line).group(1)
+             for line in hlo.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    # forward + backward kernels of each of the 4 layers
+    assert len(calls) >= 2 * d["n_layers"]
+    assert all(re.fullmatch(r"attention(\.\d+)?", c) for c in calls), calls

@@ -1,0 +1,365 @@
+"""The program's own tracing: host spans (``ftl:`` TraceAnnotations), device
+scope names and lifecycle events, and the one table that names them.
+
+- ``span()`` / ``scope()`` take only names from ``obs/trace.py``'s tables;
+- a tiny scheduler run and three tiny train steps, each under the profiler,
+  leave every span of their path in the xplane, children inside parents,
+  with the arguments the code held (``live_tokens`` is checked against the
+  sum the test computes from the scheduler's own state);
+- the lowered tiny train step and tiny paged decode program carry every
+  scope of ``SCOPES`` in their ``op_name``s;
+- a fault -> resume chain writes the lifecycle events in order;
+- the tables, the call sites and PERF.md §3 cannot drift apart.
+"""
+
+import re
+import sys
+from pathlib import Path
+
+import numpy as np
+import pytest
+
+REPO = Path(__file__).resolve().parent.parent
+sys.path.insert(0, str(REPO))  # perfbench/, the readers' own xplane decoder
+PKG = REPO / "fault_tolerant_llm_training_tpu"
+LIFECYCLE = ("proc_start", "imports_done", "backend_ready", "ckpt_verify",
+             "ckpt_manifest", "first_step_done")
+
+
+def ftl_spans(trace_dir):
+    """[name, start_ns, end_ns, thread, args] of the ``ftl:`` spans in the
+    newest xplane under ``trace_dir``, read the way the benchmark's readers
+    read them. A thread is a line of a plane, known by its place: two
+    threads may carry one name."""
+    from perfbench.lib import trace_reduce
+    from perfbench.metrics import _program_trace
+
+    raw = _program_trace.load_xplane(trace_reduce.newest_xplane(
+        str(trace_dir)))
+    return sorted((sp for sp in raw["spans"] if sp[0].startswith("ftl:")),
+                  key=lambda sp: (sp[1], -sp[2]))
+
+
+def inside(child, parent) -> bool:
+    return (child[3] == parent[3] and parent[1] <= child[1]
+            and child[2] <= parent[2])
+
+
+def assert_nested(spans, child_name, parent_name):
+    parents = [s for s in spans if s[0] == parent_name]
+    kids = [s for s in spans if s[0] == child_name]
+    assert kids, f"no {child_name} span"
+    for k in kids:
+        assert any(inside(k, p) for p in parents), (
+            f"{child_name} at {k[1]} outside every {parent_name}")
+
+
+# ------------------------------------------------------------ the two tables
+def test_span_and_scope_refuse_names_outside_the_tables():
+    from fault_tolerant_llm_training_tpu.obs import trace
+
+    with pytest.raises(ValueError, match="not in obs.trace.SPANS"):
+        trace.span("ftl:sched.nap")
+    with pytest.raises(ValueError, match="obs.trace.SCOPES"):
+        trace.scope("kv_peek")
+    # a flax module name is listed, never opened by the program
+    with pytest.raises(ValueError):
+        trace.scope("attention")
+    assert all(n.startswith("ftl:") for n in trace.SPANS)
+    with trace.span("ftl:sched.step", active=1,
+                    queued=lambda: pytest.fail("evaluated with no "
+                                               "profiler running")):
+        pass
+
+
+def test_tables_call_sites_and_perf_md_agree():
+    """Every name of SPANS, SCOPES and the new event kinds is in PERF.md §3;
+    every TraceAnnotation / named_scope / span() / scope() call in the
+    program uses a name from the tables (literal names only), and every
+    name the program may open is opened somewhere."""
+    from fault_tolerant_llm_training_tpu.obs import trace
+
+    perf = (REPO / "PERF.md").read_text()
+    section = perf.split("## 3.")[1].split("\n## 4.")[0]
+    for name in [*trace.SPANS, *trace.SCOPES, *LIFECYCLE]:
+        assert f"`{name}`" in section, f"{name} missing from PERF.md §3"
+    kinds = (PKG / "obs" / "events.py").read_text().split(
+        "class FlightRecorder")[0]
+    for kind in LIFECYCLE:
+        assert re.search(rf"^#   {kind}\b", kinds, re.M), kind
+
+    used_spans, used_scopes = set(), set()
+    sources = [*PKG.rglob("*.py"), REPO / "train.py"]
+    for path in sources:
+        text = path.read_text()
+        if path == PKG / "obs" / "trace.py":
+            # the wrappers themselves, and StepTraceAnnotation("train")
+            text = text.split("def tracing()")[0]
+        for call, arg in re.findall(
+                r"\b(span|scope|TraceAnnotation|named_scope)\(\s*([^,)\s]+)",
+                text):
+            assert arg[0] in "\"'", (
+                f"{path}: {call}({arg} ...) — names are literals")
+            name = arg.strip("\"'")
+            if call in ("span", "TraceAnnotation"):
+                assert name in trace.SPANS, f"{path}: span {name}"
+                used_spans.add(name)
+            else:
+                assert name in trace._OPENED_HERE, f"{path}: scope {name}"
+                used_scopes.add(name)
+    assert used_spans == set(trace.SPANS)
+    assert used_scopes == set(trace._OPENED_HERE)
+    assert set(trace._OPENED_HERE) < set(trace.SCOPES)
+
+
+# ------------------------------------------------------------- serving spans
+@pytest.fixture(scope="module")
+def tiny_engine():
+    import jax
+    import jax.numpy as jnp
+
+    from fault_tolerant_llm_training_tpu.inference.engine import (
+        InferenceEngine)
+    from fault_tolerant_llm_training_tpu.models.configs import get_config
+    from fault_tolerant_llm_training_tpu.models.llama import Transformer
+
+    # float32: this CPU's XLA refuses the bf16 x bf16 = f32 dot of the
+    # serving programs (the perfbench rehearsal cells do the same)
+    cfg = get_config("tiny", vocab_size=64, seq_len=64,
+                     layer_impl="loop").replace(dtype=jnp.float32,
+                                                param_dtype=jnp.float32)
+    params = Transformer(cfg).init(
+        jax.random.PRNGKey(0), jnp.zeros((1, cfg.seq_len), jnp.int32))[
+        "params"]
+    return cfg, InferenceEngine(cfg, params, slots=2, max_len=32,
+                                prefill_buckets=(8, 16), kv_layout="paged",
+                                kv_block_size=8, prefill_batch=2)
+
+
+def test_scheduler_run_leaves_every_serving_span(tiny_engine, tmp_path):
+    import jax
+
+    from fault_tolerant_llm_training_tpu.inference.scheduler import (
+        Request, Scheduler)
+    from fault_tolerant_llm_training_tpu.obs.trace import SPANS
+
+    cfg, engine = tiny_engine
+    engine.reset()
+    sched = Scheduler(engine, prefill_batch=2)
+    rng = np.random.default_rng(5)
+    plens = {"r0": 20, "r1": 9, "r2": 11}
+    for i, (plen, gen) in enumerate([(20, 5), (9, 6), (11, 4)]):
+        sched.submit(Request(
+            id=f"r{i}", max_new_tokens=gen,
+            prompt=rng.integers(3, cfg.vocab_size, size=plen).tolist()))
+    want_live, steps = [], 0
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        while sched.pending():
+            # what the decode round of this step will attend to: for each
+            # slot active when the round runs, its prompt and tokens so far
+            before = {s: len(st.request.prompt) + len(st.tokens)
+                      for s, st in sched.active.items()}
+            n_dec = sched.iterations
+            sched.step()
+            steps += 1
+            if sched.iterations > n_dec:
+                want_live.append(before)
+    finally:
+        jax.profiler.stop_trace()
+    spans = ftl_spans(tmp_path)
+    names = {s[0] for s in spans}
+    serving = {n for n in SPANS if n.startswith(("ftl:sched.",
+                                                 "ftl:engine."))}
+    assert serving <= names, serving - names
+    assert sum(1 for s in spans if s[0] == "ftl:sched.step") == steps
+    for child in ("admit", "prefill_round", "pack", "bank"):
+        assert_nested(spans, "ftl:sched." + child, "ftl:sched.step")
+    for top in ("decode", "prefill"):
+        assert_nested(spans, f"ftl:engine.{top}", "ftl:sched.step")
+        for leaf in ("dispatch", "sync"):
+            assert_nested(spans, f"ftl:engine.{top}.{leaf}",
+                          f"ftl:engine.{top}")
+    assert_nested(spans, "ftl:engine.prefill", "ftl:sched.prefill_round")
+    # arguments are what the code held; requests admitted by a step decode
+    # in the same step, so count what is active once admission is done
+    decodes = [s for s in spans if s[0] == "ftl:engine.decode"]
+    assert len(decodes) == sched.iterations
+    first = [s for s in spans if s[0] == "ftl:sched.step"][0]
+    assert int(first[4]["active"]) == 0 and int(first[4]["queued"]) == 3
+    lives = [int(s[4]["live_tokens"]) for s in decodes]
+    actives = [int(s[4]["slots_active"]) for s in decodes]
+    assert all(int(s[4]["n"]) == 1 for s in decodes)
+    # a slot that was active before the step attends to prompt + tokens
+    # positions (its committed length + the one it writes)
+    for live, act, before in zip(lives, actives, want_live):
+        if len(before) == act:
+            assert live == sum(before.values()), (live, before)
+    assert any(len(b) == a for b, a in zip(want_live, actives))
+    # every request's every decode position is in some round's live_tokens
+    total = sum(plens[c.request_id] * (len(c.tokens) - 1)
+                + sum(range(1, len(c.tokens))) for c in sched.completed)
+    assert sum(lives) == total
+    pre = [s for s in spans if s[0] == "ftl:engine.prefill"]
+    assert sum(int(s[4]["new_tokens"]) for s in pre) == 20 + 9 + 11
+    assert {int(s[4]["bucket"]) for s in pre} <= {8, 16}
+
+
+def test_step_seconds_is_bounded_with_a_running_total(tiny_engine):
+    from fault_tolerant_llm_training_tpu.inference.scheduler import (
+        Scheduler, _StepSeconds)
+
+    ss = _StepSeconds()
+    for i in range(_StepSeconds.KEEP + 10):
+        ss.append(0.5)
+    assert len(ss) == _StepSeconds.KEEP
+    assert ss.total == pytest.approx(0.5 * (_StepSeconds.KEEP + 10))
+    ss.clear()                       # what the benchmark harness calls
+    assert len(ss) == 0 and ss.total == 0.0 and not ss
+    sched = Scheduler(tiny_engine[1], prefill_batch=2)
+    assert isinstance(sched.step_seconds, _StepSeconds)
+    assert sched.metrics()["tokens_per_sec"] == 0.0
+
+
+# ------------------------------------------------------------- device scopes
+def op_names(lowered) -> set:
+    return set(re.findall(r'loc\("([^"]+)"', lowered.as_text(
+        debug_info=True)))
+
+
+def components(names) -> set:
+    return {tok for n in names
+            for tok in re.findall(r"[A-Za-z_][A-Za-z0-9_]*", n)}
+
+
+def test_lowered_programs_name_every_scope(tiny_engine):
+    import jax
+    import jax.numpy as jnp
+
+    from fault_tolerant_llm_training_tpu.models import Transformer, get_config
+    from fault_tolerant_llm_training_tpu.obs.trace import SCOPES
+    from fault_tolerant_llm_training_tpu.training.state import TrainState
+    from fault_tolerant_llm_training_tpu.training.step import (
+        make_optimizer, make_train_step)
+
+    cfg = get_config("tiny", vocab_size=259, seq_len=64)
+    model, opt = Transformer(cfg), make_optimizer(1e-3, 2)
+
+    def init_fn(key):
+        params = model.init(key, jnp.zeros((1, 64), jnp.int32))["params"]
+        return TrainState(step=jnp.zeros((), jnp.int32), params=params,
+                          opt_state=opt.init(params))
+
+    state = jax.eval_shape(init_fn, jax.random.PRNGKey(0))
+    tok = jax.ShapeDtypeStruct((2, 64), jnp.int32)
+    train = components(op_names(jax.jit(make_train_step(
+        model, opt, 1.0)).lower(state, tok, tok)))
+    train_scopes = {"loss_head", "grad_clip", "optimizer", "rope",
+                    "attention", "feed_forward", "tok_embeddings", "output",
+                    "attention_norm", "ffn_norm", "norm"}
+    assert train_scopes <= train, train_scopes - train
+
+    _, engine = tiny_engine
+    abstract = lambda t: jax.tree_util.tree_map(  # noqa: E731
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), t)
+    slots = engine.slots
+    vec = lambda dt: jax.ShapeDtypeStruct((slots,), dt)  # noqa: E731
+    decode = components(op_names(jax.jit(engine._paged_decode_fn).lower(
+        abstract(engine.params), abstract(engine.cache),
+        jax.ShapeDtypeStruct((slots, engine.max_blocks_per_slot),
+                             jnp.int32),
+        vec(jnp.int32), vec(jnp.bool_), vec(jnp.float32), vec(jnp.float32),
+        vec(jnp.int32), vec(jnp.int32))))
+    serve_scopes = {"kv_write", "kv_read", "sample", "rope", "attention",
+                    "feed_forward", "tok_embeddings", "output", "norm"}
+    assert serve_scopes <= decode, serve_scopes - decode
+    assert set(SCOPES) <= train | decode, set(SCOPES) - (train | decode)
+
+
+# ---------------------------------- train spans and lifecycle events, one chain
+@pytest.fixture(scope="module")
+def chain(tmp_path_factory):
+    """train.py: fault at step 3 -> save -> exit; resumed under
+    --profile-dir for three more steps."""
+    import pyarrow as pa
+    import pyarrow.parquet as pq
+
+    sys.path.insert(0, str(REPO / "tests"))
+    from test_fault_tolerance import _args, _run
+
+    tmp = tmp_path_factory.mktemp("trace_chain")
+    rng = np.random.default_rng(0)
+    docs = [" ".join(rng.choice(["alpha", "bravo", "charlie"],
+                                size=int(rng.integers(20, 120))))
+            for _ in range(64)]
+    parquet = str(tmp / "train_data.parquet")
+    pq.write_table(pa.table({"text": docs}), parquet)
+    small = {"--sequence-length": "64", "--logging-frequency": "1"}
+    rc, out = _run(_args(tmp, parquet, **small, **{
+        "--training-steps": "6", "--raise-error": "", "--error-step": "3"}),
+        job_id="tn1")
+    assert rc == 0 and "Checkpoint saved at step" in out, out[-3000:]
+    rc, out = _run(_args(tmp, parquet, **small, **{
+        "--training-steps": "7", "--checkpoint-id": "tn1",
+        "--profile-dir": str(tmp / "trace")}), job_id="tn2")
+    assert rc == 0 and "Training completed" in out, out[-3000:]
+    return tmp
+
+
+def test_resume_chain_writes_lifecycle_events_in_order(chain):
+    from fault_tolerant_llm_training_tpu.obs.events import read_events
+    from fault_tolerant_llm_training_tpu.obs.goodput import stitch
+
+    ev_dir = chain / "ckpts" / "events"
+    first = read_events(str(ev_dir / "events_tn1.jsonl"))
+    resumed = read_events(str(ev_dir / "events_tn2.jsonl"))
+    order = ["proc_start", "imports_done", "backend_ready", "ckpt_verify",
+             "ckpt_restore", "first_step_done"]
+    kinds = [e["kind"] for e in resumed]
+    at = [kinds.index(k) for k in order]          # each is there ...
+    assert at == sorted(at), list(zip(order, at))  # ... in this order
+    by = {k: resumed[i] for k, i in zip(order, at)}
+    ts = [by[k]["t"] for k in order]
+    assert ts == sorted(ts)
+    assert by["first_step_done"]["resumed"] is True
+    assert by["imports_done"]["dur"] == pytest.approx(
+        by["imports_done"]["t"] - by["proc_start"]["t"])
+    assert 0.5 < by["imports_done"]["dur"] < 300
+    assert by["ckpt_verify"]["bytes"] > 0 and by["ckpt_verify"]["dur"] > 0
+    assert by["ckpt_verify"]["step"] == by["ckpt_restore"]["step"]
+    assert by["ckpt_restore"]["dur"] >= by["ckpt_verify"]["dur"]
+    assert "device" in by["backend_ready"]
+    # the faulted job: a fresh start, and its save wrote the manifest the
+    # resumed job verified, over the same bytes
+    kinds1 = [e["kind"] for e in first]
+    assert kinds1[:4] == ["proc_start", "imports_done", "backend_ready",
+                          "start"]
+    done1 = first[kinds1.index("first_step_done")]
+    assert done1["resumed"] is False
+    manifest = first[kinds1.index("ckpt_manifest")]
+    assert manifest["bytes"] == by["ckpt_verify"]["bytes"]
+    assert kinds1.count("first_step_done") == kinds.count(
+        "first_step_done") == 1
+    # the stitcher keeps working on files that hold the new kinds
+    report = stitch(first + resumed)
+    assert len(report.restarts) == 1 and report.goodput_pct > 0
+
+
+def test_train_steps_leave_every_training_span(chain):
+    from fault_tolerant_llm_training_tpu.obs.trace import SPANS
+
+    spans = ftl_spans(chain / "trace")
+    names = {s[0] for s in spans}
+    training = {n for n in SPANS if n.startswith(("ftl:train.",
+                                                  "ftl:data."))}
+    assert training <= names, training - names
+    steps = [s for s in spans if s[0] == "ftl:train.step"]
+    assert [int(s[4]["step"]) for s in steps] == [4, 5, 6]
+    for child in ("signal_check", "fetch", "dispatch", "consume"):
+        assert_nested(spans, "ftl:train." + child, "ftl:train.step")
+        # --inflight 2: the first step has no older step to consume
+        assert sum(1 for s in spans if s[0] == "ftl:train." + child) == (
+            len(steps) - (child == "consume"))
+    # the prefetcher works on its own thread, outside the step spans
+    pre = [s for s in spans if s[0] == "ftl:data.prefetch"]
+    assert {s[3] for s in pre}.isdisjoint({s[3] for s in steps})

@@ -6,6 +6,7 @@ never marks the job failed (ref: train.py:119,129).
 """
 
 import sys
+import time
 
 from fault_tolerant_llm_training_tpu.ft.handler import (
     classify_exception,
@@ -21,8 +22,11 @@ from fault_tolerant_llm_training_tpu.utils.logging import (
     logger,
 )
 
+_IMPORTS_DONE_T = time.time()  # flight recorder: imports_done
+
 
 def train(cfg) -> None:
+    events.emit_startup(_IMPORTS_DONE_T)
     # Handlers installed before any setup work — a signal during the model
     # build is deferred to a phase boundary instead of being fatal
     # (the reference registers at train.py:89-90, after ~35 s of setup).
