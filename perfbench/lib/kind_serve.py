@@ -394,6 +394,10 @@ def run(cell, args, t0: float) -> int:
     # (last - first token commit) / (tokens - 1), the scheduler's stamps
     tpot = [(r["finished_at"] - r["first_token_at"]) / (len(r["tokens"]) - 1)
             for r in in_window if len(r["tokens"]) > 1]
+    # ``serve_tok_s`` is printed where the manifest lists the cell: a closed
+    # loop, or an open one at or above its knee. Under the knee the count is
+    # the schedule's own tokens plus the backlog carried over the window's
+    # two edges, which a faster server shrinks (tools/schedule_model.py).
     e2e = {"serve_tok_s": out_tokens / window_s, "setup_s": setup_s}
     if tpot:
         e2e["tpot_p95_ms"] = stats.percentile(tpot, 95) * 1e3

@@ -13,41 +13,32 @@ ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 KEYS = {"correct", "attempted", "failed", "metrics", "device"}
 
-E2E = [
-    {"name": "train_tok_s", "unit": "tokens/s/chip", "better": "higher",
-     "bound": 0.01, "source": "host_clock",
-     "workloads": ["tiny.tiny-preempt", "tiny.tiny-preempt1"]},
-    {"name": "serve_tok_s", "unit": "tokens/s", "better": "higher",
-     "bound": 0.01, "source": "host_clock",
-     "workloads": ["tiny.tiny-chat", "tiny.tiny-longdecode",
-                   "tiny2.tiny-chat2"]},
-    {"name": "tpot_p95_ms", "unit": "ms", "better": "lower", "bound": 0.05,
-     "source": "host_clock",
-     "workloads": ["tiny.tiny-chat", "tiny.tiny-longdecode",
-                   "tiny2.tiny-chat2"]},
-    {"name": "setup_s", "unit": "s", "better": "lower", "bound": 0.1,
-     "source": "host_clock"},
-]
+# the real cells and the tiny ones that stand for them: a metric is reported
+# by the tiny cells of the real cells that report it, end to end or per layer
+TINY = {"mistral7b-d4.preempt": ["tiny.tiny-preempt", "tiny.tiny-preempt1"],
+        "internlm2-1.8b.longdecode": ["tiny.tiny-longdecode"],
+        "internlm2-1.8b.chat": ["tiny.tiny-chat", "tiny2.tiny-chat2"]}
+
+
+def follow(metric: dict) -> dict:
+    m = dict(metric)
+    if "workloads" in m:
+        m["workloads"] = [t for w in m["workloads"] for t in TINY[w]]
+    return m
 
 
 def make_checkout(tmp) -> str:
-    """tmp/BENCHMARK.json + tmp/perfbench: the real manifest's per-layer
-    metrics re-pointed at tiny cells, plus one of each kind of file added."""
+    """tmp/BENCHMARK.json + tmp/perfbench: the real manifest's metrics, end
+    to end and per layer, re-pointed at tiny cells (so the tiny open loop
+    prints what ``chat`` prints and the tiny closed loop what ``longdecode``
+    does), plus one of each kind of file added."""
     root = str(tmp)
     bench = os.path.join(root, "perfbench")
     shutil.copytree(os.path.join(ROOT, "perfbench"), bench,
                     ignore=shutil.ignore_patterns("__pycache__"))
     with open(os.path.join(ROOT, "BENCHMARK.json")) as fh:
         real = json.load(fh)
-    train_cells = ["tiny.tiny-preempt", "tiny.tiny-preempt1"]
-    serve_cells = ["tiny.tiny-chat", "tiny.tiny-longdecode",
-                   "tiny2.tiny-chat2"]
-    per_layer = []
-    for m in real["per_layer"]:
-        m = dict(m)
-        m["workloads"] = (train_cells if "mistral7b-d4.preempt" in m[
-            "workloads"] else serve_cells)
-        per_layer.append(m)
+    per_layer = [follow(m) for m in real["per_layer"]]
     # --- added: a configuration, a traffic mix, a cell, a metric ----------
     with open(os.path.join(bench, "configs", "tiny.json")) as fh:
         tiny2 = json.load(fh)
@@ -84,8 +75,9 @@ def make_checkout(tmp) -> str:
         "workloads": [
             {"name": w, "config": w.split(".")[0],
              "traffic": w.split(".")[1], "chips": 1, "why": "rehearsal"}
-            for w in train_cells + serve_cells],
-        "end_to_end": E2E, "per_layer": per_layer}
+            for cells in TINY.values() for w in cells],
+        "end_to_end": [follow(m) for m in real["end_to_end"]],
+        "per_layer": per_layer}
     with open(os.path.join(root, "BENCHMARK.json"), "w") as fh:
         json.dump(manifest, fh)
     return root

@@ -2,6 +2,7 @@
 bytes, recovery segments, the traffic generator, percentiles, data files."""
 
 import glob
+import hashlib
 import json
 import math
 import os
@@ -27,6 +28,7 @@ from perfbench.lib import (  # noqa: E402
     train_compare,
     weights,
 )
+from perfbench.tools import schedule_model  # noqa: E402
 
 BENCH = os.path.join(ROOT, "perfbench")
 
@@ -345,6 +347,121 @@ def test_due_time_accounting_under_an_injected_stall(monkeypatch):
         assert r["first_token_at"] - r["t_ref"] > 0.05
         assert sched.submit_times[r["id"]] > r["t_ref"]
     assert max(late) > 0.8 and stats.percentile(late, 50) < 0.05
+
+
+# ------------------------------------------- the open loop and its throughput
+SCHEDULE_SHA = ("0648fee083f23e035e31edc020ec7d35ef6f6e7da01260d086c08bd1a1f5"
+                "06cd")      # of chat.json's schedule as PR 23 fixed it
+
+
+def schedule_sha(mix):
+    reqs = traffic.open_loop(mix, 7, 70.0, 92544)
+    rows = [[r["t_due"], len(r["prompt"]), r["max_new_tokens"]] for r in reqs]
+    return hashlib.sha256(json.dumps(rows).encode()).hexdigest()
+
+
+def test_descriptive_keys_leave_chats_schedule_byte_identical():
+    """``knee_rps`` (like ``rate_note``, ``shape_note``) says what the sweep
+    found; the generator does not read it: 83 arrivals in 70 s, the same due
+    times and lengths as before the key was there."""
+    assert CHAT["knee_rps"] == 1.5 and CHAT["rate_rps"] == 1.2
+    assert schedule_sha(CHAT) == SCHEDULE_SHA
+    bare = {k: v for k, v in CHAT.items()
+            if k not in ("knee_rps", "rate_note", "shape_note")}
+    assert schedule_sha(bare) == SCHEDULE_SHA
+    assert len(traffic.open_loop(CHAT, 7, 70.0, 92544)) == 83
+    moved = dict(CHAT, rate_rps=CHAT["knee_rps"])
+    assert schedule_sha(moved) != SCHEDULE_SHA    # what it does read, moves it
+
+
+def replay_chat(decode_step_ms):
+    """The ledger's ``decode_step_ms_p50`` of a side = the model's round +
+    5 ms of host time; a prefill of 30 ms + 0.09 ms a token at the parent's
+    round, scaled with the round."""
+    rnd = decode_step_ms - 5.0
+    k = rnd / 112.6
+    return schedule_model.replay(CHAT, 45.0, rnd, 5.0, (30.0 * k, 0.09 * k))
+
+
+def test_a_faster_server_reads_fewer_tokens_in_chats_window():
+    """Why ``chat`` left ``serve_tok_s``: from the parent's decode round to
+    PR 25's every number a user feels gets better and the windowed count
+    falls by more than its 1.2 % bound."""
+    slow, fast = replay_chat(117.6), replay_chat(66.6)
+    fall = 1 - fast["window_tok_s"] / slow["window_tok_s"]
+    assert 0.015 <= fall <= 0.03
+    assert fast["tpot_p95_ms"] < 0.6 * slow["tpot_p95_ms"]
+    assert fast["ttft_p95_ms"] < 0.6 * slow["ttft_p95_ms"]
+    assert fast["own_tok_s"] > slow["own_tok_s"] + 5
+    assert fast["carried_in_share_pct"] < slow["carried_in_share_pct"]
+    assert fast["in_flight_at_close"] < slow["in_flight_at_close"]
+    # the schedule's own offer is the same on both sides, and under both
+    assert slow["offered_tok_s"] == pytest.approx(fast["offered_tok_s"],
+                                                  rel=0.01)
+    assert slow["arrivals"] == 83 and slow["arrivals_in_window"] == 54
+    assert slow["offered_tok_s"] == pytest.approx(143.4, abs=0.3)
+
+
+@pytest.mark.parametrize("decode_step_ms,ledger_tok_s,ledger_tpot", [
+    (117.6, 150.315, 134.56),         # ledger, PR 25, parent (117.58)
+    (66.6, 147.216, 70.588),          # ledger, PR 25, change (66.584)
+])
+def test_schedule_model_lands_on_the_ledgers_pair(decode_step_ms,
+                                                  ledger_tok_s, ledger_tpot):
+    row = replay_chat(decode_step_ms)
+    assert row["window_tok_s"] == pytest.approx(ledger_tok_s, abs=0.5)
+    assert row["tpot_p95_ms"] == pytest.approx(ledger_tpot, rel=0.03)
+    assert row["window_s"] == pytest.approx(45.0, abs=0.2)
+
+
+def test_schedule_model_counts_every_token_once():
+    mix = dict(CHAT, preroll_s=0.0, rate_rps=0.2)
+    row = schedule_model.replay(mix, 600.0, 10.0, 0.0, (0.0, 0.0))
+    # far under capacity and a long window: what is offered is served, and
+    # a token a round is the tpot
+    assert row["window_tok_s"] == pytest.approx(row["offered_tok_s"],
+                                                rel=0.02)
+    assert row["tpot_p95_ms"] == pytest.approx(10.0, abs=0.5)
+    assert row["ttft_p95_ms"] < 10.0 + 2.0 + 0.5
+    assert row["carried_in_share_pct"] == 0.0
+
+
+SERVING = [w["name"] for w in manifest.load_json(os.path.join(
+    ROOT, "BENCHMARK.json"))["workloads"]
+    if manifest.Cell(w["name"], ROOT).kind == "serve"]
+
+
+@pytest.mark.parametrize("workload", SERVING)
+def test_throughput_is_judged_only_where_the_server_sets_it(workload):
+    """PERF.md section 2's rule: a closed loop reports ``serve_tok_s``; an
+    open loop only at or above its knee, where the count is the server's
+    capacity and not the schedule's arithmetic. Every serving cell reports
+    the inter-token tail, and time to first token wherever that is an
+    end-to-end metric at all."""
+    cell = manifest.Cell(workload, ROOT)
+    names = {m["name"] for m in cell.end_to_end()}
+    mix = cell.traffic
+    if mix["loop"] == "open":
+        assert "knee_rps" in mix, "an open loop states its knee as a number"
+        assert ("serve_tok_s" in names) == (
+            mix["rate_rps"] >= mix["knee_rps"]), workload
+    else:
+        assert "serve_tok_s" in names
+    assert "tpot_p95_ms" in names and "setup_s" in names
+    # on one side or the other, never both and never neither
+    layered = {m["name"] for m in cell.per_layer()}
+    assert ("ttft_p95_ms" in names) != ("ttft_p95_ms" in layered)
+
+
+def test_taking_chat_out_of_the_count_loosened_no_bound():
+    bench = manifest.load_json(os.path.join(ROOT, "BENCHMARK.json"))
+    by = {m["name"]: m for m in bench["end_to_end"]}
+    assert by["serve_tok_s"]["workloads"] == ["internlm2-1.8b.longdecode"]
+    assert {n: m["bound"] for n, m in by.items()} == {
+        "train_tok_s": 0.01, "serve_tok_s": 0.012, "tpot_p95_ms": 0.05,
+        "setup_s": 0.1}
+    why = {w["name"]: w["why"] for w in bench["workloads"]}
+    assert "not judged" in why["internlm2-1.8b.chat"]
 
 
 # ----------------------------------------------------------------- statistics
