@@ -51,7 +51,7 @@ per-(block, kv_head) fp32 scale pool, vLLM/KIVI-style symmetric per-block
 quantization. Halving bytes-per-position doubles ``kv_blocks_total`` at a
 fixed HBM budget — which the paged admission gate converts directly into
 concurrency. The scale invariant is deliberately simple (a block's scale is
-owned by the row at its local position 0; see ``_quantized_scatter``) so
+owned by the row at its local position 0; see ``_quantized_write``) so
 every write stays row-granular like the bf16 path and the within-dtype
 bit-exactness contracts survive unchanged.
 """
@@ -174,41 +174,6 @@ def quantize_rows(rows: jax.Array, scale: jax.Array) -> jax.Array:
                     KV_QUANT_QMAX).astype(jnp.int8)
 
 
-@scope("kv_write")
-def _quantized_scatter(pool: QuantPool, blk: jax.Array, off: jax.Array,
-                       rows: jax.Array) -> QuantPool:
-    """Land fp32 ``rows`` (R, kv_heads, head_dim) at ``(blk[r], :, off[r],
-    :)`` of an int8 pool, maintaining the scale invariant:
-
-    **A block's scale is owned by its local position 0.** A row landing at
-    block-local offset 0 SETS the block's per-head scale to its own
-    amax/127 — a plain overwrite, never a running max — and every row
-    landing at offset > 0 quantizes at the scale already in the pool,
-    clipped into [-127, 127]. Positions are committed in sequence order, so
-    a block's position 0 is always written before its higher offsets, and
-    existing content is NEVER requantized: a write stays row-granular
-    exactly like the bf16 scatter. That is the property the within-dtype
-    bit-exactness contracts (exact spec-verify, burst decode, packed
-    prefill, COW resume) lean on — a rejected speculative row can disturb a
-    scale only at an offset-0 position the committed stream's own next
-    write deterministically resets with identical inputs. Clipping rows
-    that outgrow their block's committed scale is the accuracy cost of that
-    determinism; the parity check's adversarial matrix bounds it.
-
-    Rows diverted to null block 0 (masked writes, and offset>0 rows' scale
-    lane below) may scribble scale[0]; harmless — null-block lanes are
-    additively masked to exactly zero attention weight, so scale[0] is
-    never read live."""
-    amax = jnp.max(jnp.abs(rows), axis=-1)            # (R, K)
-    setter = off == 0
-    scale_blk = jnp.where(setter, blk, 0)
-    new_scale = pool.scale.at[scale_blk, :].set(amax / KV_QUANT_QMAX)
-    row_scale = new_scale[blk]                        # post-update gather
-    return QuantPool(
-        q=pool.q.at[blk, :, off, :].set(quantize_rows(rows, row_scale)),
-        scale=new_scale)
-
-
 def init_paged_cache(cfg: TransformerConfig, slots: int, max_len: int,
                      block_size: int, num_blocks: Optional[int] = None,
                      dtype=None) -> PagedKVCache:
@@ -242,41 +207,149 @@ def init_paged_cache(cfg: TransformerConfig, slots: int, max_len: int,
         lengths=jnp.zeros((slots,), jnp.int32))
 
 
-@scope("kv_write")
-def write_paged_kv(pool: jax.Array, new: jax.Array, block_tables: jax.Array,
-                   start: jax.Array, valid: jax.Array) -> jax.Array:
-    """Scatter ``new`` (B, K, S, D) into the block ``pool`` (N, K, bs, D) at
-    each slot's positions ``start[b] + [0, S)``, translated through
-    ``block_tables`` (B, blocks_per_slot). Only the NEW tokens move — one
-    (B*S)-row scatter per call, never the whole cache. Positions with
-    ``valid`` (B, S) False (bucket padding past the prompt, inactive decode
-    slots) are redirected into null block 0, so a static-shape write can
-    never land in another request's blocks. Positions past the table's reach
-    (start + S can exceed blocks_per_slot * bs in a speculative verify round
-    whose draft overruns a nearly-full slot) also divert to the null block —
-    clipping them into the last table column would wrap the write onto the
-    slot's OWN committed KV at ``pos % bs`` and silently corrupt it. Valid
-    in-range positions map to distinct (block, offset) pairs (the allocator
-    hands each slot disjoint blocks), so the scatter is collision-free where
-    it matters.
+def _route_blocks(block_tables: jax.Array, logical: jax.Array,
+                  live: jax.Array) -> jax.Array:
+    """Pool block behind each logical block index of ``logical`` (B, X)
+    through the slot's table row: the table's entry where ``live`` (B, X)
+    holds and the index is within the table's reach, else null block 0."""
+    nb = block_tables.shape[1]
+    return jnp.where(
+        live & (logical < nb),
+        jnp.take_along_axis(block_tables, jnp.clip(logical, 0, nb - 1),
+                            axis=1),
+        0)
 
-    A :class:`QuantPool` takes the identical (block, offset) routing; the
-    rows quantize through :func:`_quantized_scatter` (offset-0 rows set
-    their block's scale, the rest quantize at it)."""
+
+# A program calls this twice a layer with the same shapes: under its own jit
+# it is traced and lowered once a program and called 48 times, not unrolled
+# 48 times (1.2 s of tracing a program at 24 layers, 7 s of a server's
+# set-up over its ladder of programs); XLA inlines the calls.
+@jax.jit
+def _write_block_rows(pool: jax.Array, new: jax.Array,
+                      block_tables: jax.Array, start: jax.Array,
+                      valid: jax.Array) -> jax.Array:
+    """Land ``new`` (B, K, S, D) in a plain (N, K, bs, D) pool array at
+    positions ``start[b] + [0, S)``: a block read-modify-write whose gather
+    and scatter index the pool on dim 0 ONLY.
+
+    The pool is stored with N outermost. A scatter of rows at ``[blk, :,
+    off, :]`` indexes dims 0 and 2 around the K window, and the TPU scatter
+    then takes the operand with K and bs swapped: every program that wrote
+    the donated pool held two pool-sized ``copy`` relayouts a layer-pool
+    (in and back out), ~0.5 ms each at 168 MB, to land a few 2 KiB rows.
+    Whole blocks ``[blk]`` with window (K, bs, D) are the layout's own
+    major dim, so the donated pool is updated in place.
+
+    Per slot the S rows fall in at most ``nblk`` consecutive logical
+    blocks. The rows are shifted into that window by ``start % bs`` (a
+    barrel shifter of static rolls: a per-slot dynamic_update_slice becomes
+    a scatter of its own, measured slower from 4 slots up), merged over the
+    blocks' current content by a select, and scattered back as whole
+    blocks. All of a call's rows are merged BEFORE the one scatter, so rows
+    that share a block (every prefill) all land. A window block with no
+    valid row is not written at all (its index is out of range and
+    dropped), so a block another slot may share is never touched; valid
+    rows behind a free table entry or past the table's reach land in null
+    block 0, which stays the scratch it was.
+    """
+    n, k, bs, d = pool.shape
+    b, _, s, _ = new.shape
+    nblk = (s + bs - 2) // bs + 1
+    w = nblk * bs
+    off = start % bs
+
+    def window(x, fill, axis):   # S -> W along ``axis``, rows at off[b]
+        pad = [(0, 0)] * x.ndim
+        pad[axis] = (0, w - s)
+        x = jnp.pad(x, pad, constant_values=fill)
+        sel = off.reshape((b,) + (1,) * (x.ndim - 1))
+        # off < bs <= w - s + 1, so what a roll wraps round is padding
+        for i in range((bs - 1).bit_length()):
+            x = jnp.where(sel & (1 << i) != 0, jnp.roll(x, 1 << i, axis), x)
+        return x
+
+    mask = window(valid, False, 1).reshape(b, nblk, bs)
+    live = jnp.any(mask, axis=2)
+    logical = (start // bs)[:, None] + jnp.arange(nblk, dtype=jnp.int32)
+    blk = _route_blocks(block_tables, logical, live).reshape(-1)
+    rows = jnp.swapaxes(window(new, 0, 2).reshape(b, k, nblk, bs, d), 1, 2)
+    merged = jnp.where(mask.reshape(-1, 1, bs, 1),
+                       rows.reshape(-1, k, bs, d), pool[blk])
+    dest = jnp.where(live.reshape(-1), blk, n)      # n: out of range
+    return pool.at[dest].set(merged, mode="drop")
+
+
+def _quantized_write(pool: QuantPool, new: jax.Array,
+                     block_tables: jax.Array, start: jax.Array,
+                     valid: jax.Array) -> QuantPool:
+    """Land fp32 ``new`` (B, K, S, D) at positions ``start[b] + [0, S)`` of
+    an int8 pool, maintaining the scale invariant:
+
+    **A block's scale is owned by its local position 0.** A row landing at
+    block-local offset 0 SETS the block's per-head scale to its own
+    amax/127 — a plain overwrite, never a running max — and every row
+    landing at offset > 0 quantizes at the scale already in the pool,
+    clipped into [-127, 127]. Positions are committed in sequence order, so
+    a block's position 0 is always written before its higher offsets, and
+    existing content is NEVER requantized: a write stays row-granular
+    exactly like the bf16 write. That is the property the within-dtype
+    bit-exactness contracts (exact spec-verify, burst decode, packed
+    prefill, COW resume) lean on — a rejected speculative row can disturb a
+    scale only at an offset-0 position the committed stream's own next
+    write deterministically resets with identical inputs. Clipping rows
+    that outgrow their block's committed scale is the accuracy cost of that
+    determinism; the parity check's adversarial matrix bounds it.
+
+    Rows diverted to null block 0 (masked writes, and offset>0 rows' scale
+    lane below) may scribble scale[0]; harmless — null-block lanes are
+    additively masked to exactly zero attention weight, so scale[0] is
+    never read live. The quantized rows land through
+    :func:`_write_block_rows` like a plain pool's."""
     bs = pool.shape[2]
     b, k, s, d = new.shape
     pos = start[:, None] + jnp.arange(s, dtype=jnp.int32)[None, :]   # (B, S)
-    raw = pos // bs
-    idx = jnp.clip(raw, 0, block_tables.shape[1] - 1)
-    in_table = raw < block_tables.shape[1]
-    blk = jnp.where(valid & in_table,
-                    jnp.take_along_axis(block_tables, idx, axis=1), 0)
-    off = pos % bs
-    upd = jnp.transpose(new, (0, 2, 1, 3)).reshape(b * s, k, d)
+    blk = _route_blocks(block_tables, pos // bs, valid).reshape(-1)
+    rows = jnp.transpose(new, (0, 2, 1, 3)).reshape(b * s, k, d)
+    amax = jnp.max(jnp.abs(rows), axis=-1)            # (R, K)
+    scale_blk = jnp.where((pos % bs).reshape(-1) == 0, blk, 0)
+    new_scale = pool.scale.at[scale_blk, :].set(amax / KV_QUANT_QMAX)
+    q_rows = quantize_rows(rows, new_scale[blk])      # post-update gather
+    q_rows = jnp.transpose(q_rows.reshape(b, s, k, d), (0, 2, 1, 3))
+    return QuantPool(
+        q=_write_block_rows(pool.q, q_rows, block_tables, start, valid),
+        scale=new_scale)
+
+
+@scope("kv_write")
+def write_paged_kv(pool: jax.Array, new: jax.Array, block_tables: jax.Array,
+                   start: jax.Array, valid: jax.Array) -> jax.Array:
+    """Write ``new`` (B, K, S, D) into the block ``pool`` (N, K, bs, D) at
+    each slot's positions ``start[b] + [0, S)``, translated through
+    ``block_tables`` (B, blocks_per_slot). Only the blocks the new tokens
+    fall in move — one gather and one scatter of at most ``B * (S // bs +
+    2)`` whole blocks per call (:func:`_write_block_rows`), never the whole
+    cache. Positions with ``valid`` False — (B, S), or (B, 1) for a whole
+    slot: bucket padding past the prompt, inactive decode slots — are never
+    written to an allocated block: what they would touch is routed to null
+    block 0, so a static-shape write can never land in another request's
+    blocks. Positions past the table's reach (start + S can exceed
+    blocks_per_slot * bs in a speculative verify round whose draft overruns
+    a nearly-full slot) also divert to the null block — clipping them into
+    the last table column would wrap the write onto the slot's OWN
+    committed KV at ``pos % bs`` and silently corrupt it. Valid in-range
+    positions of different slots fall in distinct blocks (the allocator
+    hands each slot disjoint blocks), so the scatter is collision-free
+    where it matters; every other byte of a written block is written back
+    as it was read.
+
+    A :class:`QuantPool` takes the identical routing; its rows quantize
+    first (:func:`_quantized_write`: offset-0 rows set their block's scale,
+    the rest quantize at it)."""
+    valid = jnp.broadcast_to(valid, (new.shape[0], new.shape[2]))
     if isinstance(pool, QuantPool):
-        return _quantized_scatter(pool, blk.reshape(-1), off.reshape(-1),
-                                  upd.astype(jnp.float32))
-    return pool.at[blk.reshape(-1), :, off.reshape(-1), :].set(upd)
+        return _quantized_write(pool, new.astype(jnp.float32), block_tables,
+                                start, valid)
+    return _write_block_rows(pool, new, block_tables, start, valid)
 
 
 def remap_paged_path(pool: jax.Array, block_tables: jax.Array,
@@ -291,40 +364,39 @@ def remap_paged_path(pool: jax.Array, block_tables: jax.Array,
     non-contiguous rows; the committed stream needs them at
     ``start[b] + 1 + j``. ``src_nodes`` (B, depth) holds the path's node
     row indices, ``accepted`` (B,) how many are live. Moves with
-    ``j >= accepted[b]`` divert to null block 0 (same discipline as
-    :func:`write_paged_kv`), so rejected branches simply rot as stale
-    bytes past the new committed length — the linear-spec rejected-suffix
-    story, no allocator traffic. Primary-chain moves (src == dst) are
-    harmless bitwise no-ops: every source row is gathered before the one
-    scatter writes. This runs as the tree-verify program's epilogue
-    (inference/engine.py), one gather+scatter per layer per pool.
+    ``j >= accepted[b]`` are dropped (the discipline of
+    :func:`write_paged_kv`, which lands the rows), so rejected branches
+    simply rot as stale bytes past the new committed length — the
+    linear-spec rejected-suffix story, no allocator traffic. Primary-chain
+    moves (src == dst) are harmless bitwise no-ops: every source row is
+    gathered before the one scatter writes. This runs as the tree-verify
+    program's epilogue (inference/engine.py), one gather+scatter per layer
+    per pool. A :class:`QuantPool`'s rows are dequantized at their SOURCE
+    blocks' scales and requantized at the destination (a move crossing into
+    a fresh block lands at local offset 0 and sets that block's scale, same
+    as a sequential write would have).
     """
     bs = pool.shape[2]
     b, depth = src_nodes.shape
-    nb = block_tables.shape[1]
     steps = jnp.arange(depth, dtype=jnp.int32)[None, :]
     src_pos = start[:, None] + src_nodes                        # (B, depth)
-    dst_pos = start[:, None] + 1 + steps
-    live = steps < accepted[:, None]
     src_blk = jnp.take_along_axis(
-        block_tables, jnp.clip(src_pos // bs, 0, nb - 1), axis=1)
-    dst_blk = jnp.where(live & (dst_pos // bs < nb),
-                        jnp.take_along_axis(
-                            block_tables, jnp.clip(dst_pos // bs, 0, nb - 1),
-                            axis=1), 0)
+        block_tables,
+        jnp.clip(src_pos // bs, 0, block_tables.shape[1] - 1),
+        axis=1).reshape(-1)
+    src_off = (src_pos % bs).reshape(-1, 1, 1, 1)
+
+    def rows_of(arr):   # whole source blocks on dim 0, then the row inside
+        return jnp.take_along_axis(arr[src_blk], src_off, axis=2)[:, :, 0, :]
+
     if isinstance(pool, QuantPool):
-        # Dequantize the gathered rows at their SOURCE blocks' scales, then
-        # requantize through the standard scatter at the destination (a
-        # move crossing into a fresh block lands at local offset 0 and sets
-        # that block's scale, same as a sequential write would have).
-        q_rows = pool.q[src_blk.reshape(-1), :, (src_pos % bs).reshape(-1), :]
-        src_scale = pool.scale[src_blk.reshape(-1)]
-        rows = q_rows.astype(jnp.float32) * src_scale[:, :, None]
-        return _quantized_scatter(pool, dst_blk.reshape(-1),
-                                  (dst_pos % bs).reshape(-1), rows)
-    vals = pool[src_blk.reshape(-1), :, (src_pos % bs).reshape(-1), :]
-    return pool.at[dst_blk.reshape(-1), :,
-                   (dst_pos % bs).reshape(-1), :].set(vals)
+        vals = (rows_of(pool.q).astype(jnp.float32)
+                * pool.scale[src_blk][:, :, None])
+    else:
+        vals = rows_of(pool)                                # (B*depth, K, D)
+    vals = jnp.swapaxes(vals.reshape(b, depth, *vals.shape[1:]), 1, 2)
+    return write_paged_kv(pool, vals, block_tables, start + 1,
+                          steps < accepted[:, None])
 
 
 def copy_kv_block(pool: jax.Array, src: jax.Array, dst: jax.Array

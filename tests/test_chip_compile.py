@@ -237,3 +237,104 @@ def test_d4_train_step_keeps_scope_names_and_flash_call_names(v5e):
     # forward + backward kernels of each of the 4 layers
     assert len(calls) >= 2 * d["n_layers"]
     assert all(re.fullmatch(r"attention(\.\d+)?", c) for c in calls), calls
+
+
+# InternLM2-1.8B as the serving cells run it (perfbench/configs), cut for
+# time where the pool is not involved: 2 of 24 layers, vocab 1024 of 92544
+# (the sampler's sort over the vocabulary is most of a 40 s compile).
+POOL_LAYERS, POOL_BLOCKS, POOL_BLOCK = 2, 5121, 16
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill_1x512",
+                                     "packed_4x512", "tree_verify"])
+def test_serving_program_writes_the_pool_in_its_own_layout(v5e, program):
+    """No serving program that writes the paged KV pool may hold a ``copy``
+    of the pool's shape, and every pool it returns aliases its input.
+
+    The pool is stored ``(N, K, bs, D)``, N outermost. A write indexed on
+    dims 0 and 2 at once (``pool.at[blk, :, off, :]``) makes the TPU scatter
+    take the operand as ``(N, bs, K, D)``: XLA then transposes each layer's
+    whole K and V pool into that layout and back — 4 copies of 168 MB a
+    layer, 96 a decode round at 24 layers, 53 ms of a 118 ms round on the
+    chip (PERF.md section 6, PR 27). Donation alone does not show it: the
+    aliases were there all along. ``write_paged_kv`` and
+    ``remap_paged_path`` therefore gather and scatter whole blocks."""
+    import json
+    import re
+    import sys
+    import types
+    from pathlib import Path
+
+    root = Path(__file__).resolve().parent.parent
+    sys.path.insert(0, str(root))
+    from perfbench.lib import weights
+
+    from fault_tolerant_llm_training_tpu.inference.engine import (
+        InferenceEngine,
+        TreeShape,
+    )
+    from fault_tolerant_llm_training_tpu.inference.kv_cache import (
+        init_paged_cache,
+    )
+    from fault_tolerant_llm_training_tpu.models import Transformer
+    from fault_tolerant_llm_training_tpu.models import configs as mc
+
+    config = json.loads((root / "perfbench" / "configs"
+                         / "internlm2-1.8b.json").read_text())
+    config.update(num_hidden_layers=POOL_LAYERS, vocab_size=1024)
+    d = weights.dims_of(config)
+    cfg = mc.TransformerConfig(**weights.preset_kwargs(config)).replace(
+        remat=False)
+    assert (cfg.kv_heads, cfg.head_dim) == (8, 128)
+    slots, per_slot = 8, 640                        # longdecode's server
+    sds = _shapes_on(v5e.devices[0])
+    on = lambda tree: jax.tree_util.tree_map(       # noqa: E731
+        lambda a: sds(a.shape, a.dtype), tree)
+    params = on(jax.eval_shape(
+        lambda k: weights.make_param_tree(k, d, jnp.bfloat16),
+        jax.random.PRNGKey(0)))
+    cache = on(jax.eval_shape(lambda: init_paged_cache(
+        cfg, slots, per_slot * POOL_BLOCK, POOL_BLOCK, POOL_BLOCKS)))
+    i32, f32 = jnp.int32, jnp.float32
+    vec = lambda dt, n=slots: sds((n,), dt)         # noqa: E731
+    model = Transformer(cfg)
+    # the engine's own program bodies over a stand-in that carries what
+    # they read of ``self`` (a whole engine would place real arrays)
+    stub = types.SimpleNamespace(model=model, top_k=0, slots=slots, cfg=cfg,
+                                 spec_verify_impl="chunk",
+                                 _adapter_operand=lambda *a: None)
+    if program == "decode":
+        fn = lambda *a: InferenceEngine._paged_decode_fn(stub, *a)  # noqa
+        args = (sds((slots, per_slot), i32), vec(i32), vec(jnp.bool_),
+                vec(f32), vec(f32), vec(i32), vec(i32))
+    elif program == "prefill_1x512":
+        fn = lambda *a: InferenceEngine._paged_prefill_fn(  # noqa: E731
+            stub, model, *a)
+        args = (sds((per_slot,), i32), sds((1, 512), i32), sds((), i32),
+                sds((), i32), sds((), i32), sds((), f32), sds((), f32),
+                sds((), i32))
+    elif program == "packed_4x512":
+        fn = lambda *a: InferenceEngine._packed_prefill_fn(  # noqa: E731
+            stub, model, *a)
+        args = (sds((4, per_slot), i32), sds((4, 512), i32), vec(i32, 4),
+                vec(i32, 4), vec(i32, 4), vec(jnp.bool_, 4), vec(f32, 4),
+                vec(f32, 4), vec(i32, 4))
+    else:
+        shape = TreeShape((2, 2, 1))
+        fn = lambda *a: InferenceEngine._tree_verify_fn(  # noqa: E731
+            stub, shape, *a)
+        args = (sds((slots, per_slot), i32), sds((slots, shape.size), i32),
+                sds((slots, shape.size, cfg.vocab_size), f32), vec(i32),
+                vec(jnp.bool_), vec(f32), vec(f32), vec(i32), vec(i32))
+    hlo = jax.jit(fn, donate_argnums=(1,)).lower(
+        params, cache, *args).compile().as_text()
+    pool = re.escape(f"[{POOL_BLOCKS},{cfg.kv_heads},{POOL_BLOCK},"
+                     f"{cfg.head_dim}]")
+    copies = re.findall(rf"= \w+{pool}\S* copy\(", hlo)
+    assert not copies, f"{len(copies)} pool-sized copies in {program}"
+    # the pool is still written there, in place: K and V of every layer,
+    # and lengths, come back in the buffers they were donated in
+    assert len(re.findall(rf"= \w+{pool}\S* fusion\(", hlo)) >= (
+        2 * POOL_LAYERS)
+    aliased = re.findall(r"\(\d+, \{[^}]*\}, (?:may|must)-alias\)", hlo)
+    assert len(aliased) == 2 * POOL_LAYERS + 1, aliased

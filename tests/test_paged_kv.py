@@ -110,6 +110,163 @@ def test_write_paged_kv_masks_invalid_positions():
     assert out[1].sum() == 0                   # unrelated block untouched
 
 
+# Geometry of the direct-write cases: 12 pool blocks of K=2 x bs=4 x D=8, a
+# block table of 3 columns (12 positions of reach). Each case is (tables,
+# start, lens, S): slot b's rows [0, lens[b]) are valid, the rest padding.
+_WRITE_CASES = {
+    # every slot one row, each at its own offset inside its own block
+    "decode_4x1": ([[1, 2, 3], [4, 5, 6], [7, 8, 9], [10, 11, 0]],
+                   [0, 5, 7, 3], [1, 1, 1, 1], 1),
+    # inactive slots (stale start, live table) touch no allocated block
+    "decode_inactive": ([[1, 2, 3], [4, 5, 6], [7, 8, 9], [10, 11, 0]],
+                        [2, 5, 11, 6], [1, 0, 1, 0], 1),
+    # S not a multiple of bs, start mid-block: rows share blocks
+    "prefill_1x7_midblock": ([[3, 9, 5]], [2], [7], 7),
+    "prefill_1x10_from_0": ([[3, 9, 5]], [0], [10], 10),
+    # bucket padding: the last real block keeps its tail as it was
+    "prefill_1x8_padded": ([[6, 2, 8]], [3], [5], 8),
+    "packed_3x7": ([[1, 2, 3], [4, 5, 6], [7, 8, 9]],
+                   [0, 3, 5], [7, 4, 0], 7),
+    "all_invalid": ([[1, 2, 3], [4, 5, 6]], [1, 6], [0, 0], 5),
+    # positions 12.. are past the table's reach; slot 1 also meets a free
+    # (0) table entry inside its reach
+    "past_reach": ([[1, 2, 3], [4, 0, 6]], [9, 2], [6, 6], 6),
+    # blocks 1 and 2 are a prefix both slots share read-only: slot 0 writes
+    # on in its own block 3, slot 1 is inactive with a start INSIDE block 2
+    "shared_prefix": ([[1, 2, 3], [1, 2, 4]], [8, 5], [3, 0], 3),
+    # a (B, 1) mask over S = 4 rows: the whole slot is live or is not
+    "verify_slot_mask": ([[1, 2, 3], [4, 5, 6]], [6, 3], [4, 0], 4),
+}
+
+
+def _write_case(name, rng):
+    tables, start, lens, s = _WRITE_CASES[name]
+    b = len(tables)
+    valid = np.arange(s)[None, :] < np.asarray(lens)[:, None]
+    if name == "verify_slot_mask":      # as the chunk verify passes it
+        valid = valid[:, :1]
+    new = rng.standard_normal((b, 2, s, 8)).astype(np.float32)
+    return (np.asarray(tables, np.int32), np.asarray(start, np.int32),
+            valid, new)
+
+
+def _rows_of(tables, start, valid, new, bs):
+    """The contract, row by row: (b, r, block, offset) of every row of
+    ``new`` (B, K, S, D) that lands in an allocated block, in sequence
+    order."""
+    valid = np.broadcast_to(valid, new.shape[0::2])
+    for b, r in np.ndindex(valid.shape):
+        pos = int(start[b]) + r
+        if valid[b, r] and pos // bs < tables.shape[1]:
+            blk = int(tables[b, pos // bs])
+            if blk:
+                yield b, r, blk, pos % bs
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("case", sorted(_WRITE_CASES))
+def test_write_paged_kv_matches_row_reference(case, dtype):
+    """``write_paged_kv`` against a NumPy row-by-row write, bit for bit on
+    every allocated block: valid rows land at (table[pos // bs], :,
+    pos % bs, :), rows that share a block all survive, and nothing else
+    moves — invalid rows, rows past the table's reach and rows behind a
+    free table entry change null block 0 at most, which stays finite."""
+    import jax.numpy as jnp
+
+    from fault_tolerant_llm_training_tpu.inference.kv_cache import (
+        write_paged_kv)
+
+    rng = np.random.default_rng(sorted(_WRITE_CASES).index(case))
+    tables, start, valid, new = _write_case(case, rng)
+    dt = jnp.dtype(dtype)
+    pool = jnp.asarray(rng.standard_normal((12, 2, 4, 8)), dt)
+    new = jnp.asarray(new, dt)
+    want = np.asarray(pool).copy()
+    for b, r, blk, off in _rows_of(tables, start, valid, new, 4):
+        want[blk, :, off, :] = np.asarray(new)[b, :, r, :]
+    got = np.asarray(write_paged_kv(pool, new, jnp.asarray(tables),
+                                    jnp.asarray(start), jnp.asarray(valid)))
+    assert got.dtype == want.dtype
+    np.testing.assert_array_equal(got[1:], want[1:])
+    assert np.isfinite(got[0].astype(np.float32)).all()
+
+
+@pytest.mark.parametrize("case", sorted(_WRITE_CASES))
+def test_write_paged_kv_int8_matches_row_reference(case):
+    """The int8 pool through the same cases: a row at block offset 0 sets
+    its block's per-head scale (amax / 127), every row quantizes at the
+    scale its block then holds, and no other block's bytes or scale move."""
+    import jax.numpy as jnp
+
+    from fault_tolerant_llm_training_tpu.inference.kv_cache import (
+        KV_QUANT_QMAX, QuantPool, write_paged_kv)
+
+    rng = np.random.default_rng(100 + sorted(_WRITE_CASES).index(case))
+    tables, start, valid, new = _write_case(case, rng)
+    q0 = rng.integers(-127, 128, (12, 2, 4, 8)).astype(np.int8)
+    s0 = (rng.random((12, 2)) + 0.5).astype(np.float32)
+    want_q, want_s = q0.copy(), s0.copy()
+    qmax = np.float32(KV_QUANT_QMAX)
+    for b, r, blk, off in _rows_of(tables, start, valid, new, 4):
+        row = new[b, :, r, :]
+        if off == 0:
+            want_s[blk] = np.abs(row).max(axis=-1) / qmax
+        safe = np.where(want_s[blk] > 0, want_s[blk], np.float32(1))
+        want_q[blk, :, off, :] = np.clip(
+            np.round(row / safe[:, None]), -qmax, qmax).astype(np.int8)
+    got = write_paged_kv(
+        QuantPool(q=jnp.asarray(q0), scale=jnp.asarray(s0)),
+        jnp.asarray(new), jnp.asarray(tables), jnp.asarray(start),
+        jnp.asarray(valid))
+    np.testing.assert_array_equal(np.asarray(got.q)[1:], want_q[1:])
+    np.testing.assert_array_equal(np.asarray(got.scale)[1:], want_s[1:])
+    assert np.isfinite(np.asarray(got.scale)[0]).all()
+
+
+# (tables, start, path rows (B, depth), accepted): the tree window's rows sit
+# at start + row, the winners move to start + 1 + j
+_REMAP_CASES = {
+    # a sibling at depth 1, then its line: sources and targets overlap
+    "sibling_path": ([[1, 2, 3]], [2], [[2, 4, 5]], [3]),
+    # the primary chain moves onto itself; slot 1 accepts nothing
+    "primary_and_none": ([[1, 2, 3], [4, 5, 6]], [3, 6],
+                         [[1, 2, 3], [2, 3, 5]], [3, 0]),
+    # rows move across a block edge, up to the last position in reach
+    "block_edge": ([[7, 8, 9], [1, 2, 3]], [7, 0],
+                   [[2, 3, 4], [1, 3, 6]], [3, 2]),
+}
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("case", sorted(_REMAP_CASES))
+def test_remap_paged_path_matches_row_reference(case, dtype):
+    """``remap_paged_path`` against a NumPy move that reads every source
+    row before it writes any: accepted rows land at start + 1 + j inside
+    the slot's own blocks, bit for bit, and no other allocated block
+    moves."""
+    import jax.numpy as jnp
+
+    from fault_tolerant_llm_training_tpu.inference.kv_cache import (
+        remap_paged_path)
+
+    tables, start, path, acc = (np.asarray(x, np.int32)
+                                for x in _REMAP_CASES[case])
+    rng = np.random.default_rng(7)
+    pool = jnp.asarray(rng.standard_normal((12, 2, 4, 8)), jnp.dtype(dtype))
+    before = np.asarray(pool)
+    want = before.copy()
+    for b in range(len(start)):
+        for j in range(int(acc[b])):
+            src, dst = int(start[b] + path[b, j]), int(start[b]) + 1 + j
+            want[tables[b, dst // 4], :, dst % 4, :] = before[
+                tables[b, src // 4], :, src % 4, :]
+    got = np.asarray(remap_paged_path(
+        pool, jnp.asarray(tables), jnp.asarray(start), jnp.asarray(path),
+        jnp.asarray(acc)))
+    np.testing.assert_array_equal(got[1:], want[1:])
+    assert np.isfinite(got[0].astype(np.float32)).all()
+
+
 # --------------------------------------------------------------- 2. allocator
 def test_block_allocator_contract():
     from fault_tolerant_llm_training_tpu.inference.scheduler import (
