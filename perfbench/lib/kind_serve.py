@@ -73,12 +73,10 @@ def build_server(cell, seed: int, spans: Spans):
 
     enable_compilation_cache(None)
     d = weights.dims_of(cell.config)
-    mc.PRESETS[cell.config_name] = mc.TransformerConfig(
-        **weights.preset_kwargs(cell.config))
+    mc.PRESETS[cell.config_name] = weights.family_of(d).preset(cell.config)
     # as inference/serve.py does: preset by name, vocab, layer_impl default
     cfg = mc.get_config(cell.config_name, vocab_size=d["vocab"],
                         layer_impl="loop")
-    assert cfg.ffn_hidden_dim == d["hidden"], (cfg.ffn_hidden_dim, d)
     server = cell.traffic["server"]
     dtype = jnp.float32 if server.get("dtype") == "fp32" else jnp.bfloat16
     if dtype == jnp.float32:

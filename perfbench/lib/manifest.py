@@ -3,10 +3,13 @@
 A cell is (configuration, traffic mix). The configuration is the file the
 manifest names; the traffic mix is ``perfbench/traffic/<traffic>.json``; a
 per-layer metric is ``perfbench/metrics/<name>.py``; the limits that decide
-``correct`` are ``perfbench/limits/<workload>.json``. Adding a cell or a
-metric adds files and entries and edits nothing here.
+``correct`` are ``perfbench/limits/<workload>.json``; the model family is
+``perfbench/families/<family>.py``, by the name in the configuration file's
+``program`` group. Adding a cell, a metric or a family adds files and
+entries and edits nothing here.
 """
 
+import functools
 import importlib.util
 import json
 import os
@@ -16,6 +19,7 @@ BENCH_DIR = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 NAME_RE = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT_RE = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 SOURCES = ("device_trace", "program_span", "program_counter", "host_clock")
+DEFAULT_FAMILY = "llama"  # of a configuration file that names none
 
 
 def load_json(path: str) -> dict:
@@ -41,6 +45,8 @@ class Cell:
             self.workload["config"]]
         self.config_name = cfg_entry["name"]
         self.config = load_json(os.path.join(root, cfg_entry["file"]))
+        self.family = family_name(self.config)
+        load_family(self.family, bench_dir)  # an unknown one ends the run
         self.traffic_name = self.workload["traffic"]
         self.traffic = load_json(os.path.join(
             bench_dir, "traffic", self.traffic_name + ".json"))
@@ -70,15 +76,41 @@ class Cell:
         return os.path.join(self.root, ".perfbench_work", self.name)
 
 
+def _load_file(path: str, module_name: str):
+    spec = importlib.util.spec_from_file_location(
+        re.sub(r"[^A-Za-z0-9_]", "_", module_name), path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
 def load_reader(name: str, bench_dir: str = BENCH_DIR):
     """The per-layer metric's own reader: ``metrics/<name>.py`` with
     ``read(ctx) -> number | None``."""
-    path = os.path.join(bench_dir, "metrics", name + ".py")
-    spec = importlib.util.spec_from_file_location(
-        "perfbench_metric_" + re.sub(r"[^A-Za-z0-9_]", "_", name), path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return _load_file(os.path.join(bench_dir, "metrics", name + ".py"),
+                      "perfbench_metric_" + name).read
+
+
+def family_name(config: dict) -> str:
+    """The family a configuration file names in its ``program`` group."""
+    return config.get("program", {}).get("family", DEFAULT_FAMILY)
+
+
+@functools.lru_cache(maxsize=None)
+def _family_at(path: str, name: str):
+    if not NAME_RE.match(name) or not os.path.isfile(path):
+        raise SystemExit(f"unknown model family {name!r}: no file {path}")
+    return _load_file(path, "perfbench_family_" + name)
+
+
+def load_family(name: str, bench_dir: str = None):
+    """The model family's own file, ``families/<name>.py`` under
+    ``bench_dir`` (this benchmark's own directory where none is given): its
+    sizes, the program's model for it, its leaves, its plain reference and
+    its counts (``families/llama.py`` lists the names). Loaded once a
+    process; needs no JAX until its reference runs."""
+    return _family_at(os.path.join(bench_dir or BENCH_DIR, "families",
+                                   name + ".py"), name)
 
 
 def check_names(manifest: dict) -> list:

@@ -40,16 +40,10 @@ def gaps_of(ref_logits: np.ndarray, tokens) -> np.ndarray:
 
 
 def reference_logits(key, d, sample, mm, dtype):
-    """Logits at every served position of every sampled request, layer by
-    layer (one layer's weights live at a time)."""
-    import functools
+    """Logits at every served position of every sampled request, by the
+    family's forward pass over the padded sequences."""
+    from . import weights
 
-    import jax
-    import jax.numpy as jnp
-
-    from . import reference as R
-
-    top = R.top_weights(key, d, dtype)
     seqs, wanted = [], []
     for r in sample:
         seq = np.concatenate([np.asarray(r["prompt"], np.int32),
@@ -58,16 +52,7 @@ def reference_logits(key, d, sample, mm, dtype):
         wanted.append(np.arange(first, first + len(r["tokens"])))
         pad = (-len(seq)) % PAD_TO
         seqs.append(np.concatenate([seq, np.zeros((pad,), np.int32)]))
-    xs = [top["tok_embeddings/embedding"][jnp.asarray(s)] for s in seqs]
-    blk = jax.jit(functools.partial(R.block, d=d, mm=mm))
-    for i in range(d["n_layers"]):
-        w = R.layer_weights(key, d, i, dtype)
-        xs = [blk(w, x) for x in xs]
-        del w
-    head = jax.jit(functools.partial(R.head_logits, d=d, mm=mm))
-    return [np.asarray(head(x[jnp.asarray(pos)], top["norm/scale"],
-                            top["output/kernel"]))
-            for x, pos in zip(xs, wanted)]
+    return weights.family_of(d).batch_logits(key, d, seqs, wanted, mm, dtype)
 
 
 def run(cell, seed: int, d: dict, finished: list, control: str = "") -> dict:

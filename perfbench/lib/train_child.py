@@ -6,9 +6,10 @@ It runs the program's own entry, ``train.train(get_args(argv))`` — the
 path, exit handler and checkpoint manager — and edits no program file. What
 the benchmark adds, it adds from here, around calls into the program:
 
-- the cell's configuration registered in ``models.configs.PRESETS``;
+- the cell's configuration registered in ``models.configs.PRESETS``, built
+  by the cell's model family (``perfbench/families/<family>.py``);
 - weights from the seed (``weights.make_param_tree``) inside the trainer's
-  one jitted init, in place of the program's initialiser;
+  one jitted init, in place of the initialiser of the family's model class;
 - a wrapper around the compiled step that opens and closes the measured
   window on this process's clock, and reads what ``correct`` compares;
 - a wrapper around ``_consume`` that stamps each step's completion.
@@ -54,12 +55,14 @@ def main() -> None:
     from perfbench.lib.result import memory_peak_bytes
 
     from fault_tolerant_llm_training_tpu.models import configs as mc
-    from fault_tolerant_llm_training_tpu.models.llama import Transformer
     from fault_tolerant_llm_training_tpu.training import loop as tl
     import train as program_entry
     from fault_tolerant_llm_training_tpu.utils.config import get_args
     from fault_tolerant_llm_training_tpu.utils.logging import init_logger
 
+    d = W.dims_of(spec["config"])
+    family = W.family_of(d)
+    model_class = family.model_class()  # its module imports here, ahead
     say("imported")
     if spec.get("wait_go"):
         go = json.loads(sys.stdin.readline() or "{}")
@@ -76,13 +79,8 @@ def main() -> None:
     say("backend", platform=platform,
         kind=jax.devices()[0].device_kind, count=len(jax.devices()))
 
-    d = W.dims_of(spec["config"])
-    preset = mc.TransformerConfig(
-        **W.preset_kwargs(spec["config"]),
-        seq_len=spec["traffic"]["sequence_length"])
-    assert preset.ffn_hidden_dim == d["hidden"], (preset.ffn_hidden_dim, d)
-    assert preset.head_dim == d["head_dim"]
-    mc.PRESETS[spec["preset_name"]] = preset
+    mc.PRESETS[spec["preset_name"]] = family.preset(
+        spec["config"], seq_len=spec["traffic"]["sequence_length"])
 
     role = spec["role"]
     seed_key = jax.random.PRNGKey(spec["seed"])
@@ -91,7 +89,7 @@ def main() -> None:
     fault = spec.get("fault", "")
 
     # ---- weights from the seed, inside the trainer's jitted init ---------
-    orig_model_init = Transformer.init
+    orig_model_init = model_class.init
 
     def seeded_init(self, key, *a, **k):
         want = jax.eval_shape(lambda kk: orig_model_init(self, kk, *a, **k),
@@ -105,7 +103,7 @@ def main() -> None:
             assert a_.shape == b_.shape and a_.dtype == b_.dtype, (a_, b_)
         return {"params": mine}
 
-    Transformer.init = seeded_init
+    model_class.init = seeded_init
 
     # ---- small jitted readers -------------------------------------------
     def _norms(tree):
@@ -148,7 +146,8 @@ def main() -> None:
 
         for path, p in flat.items():
             shape, kind = leaves[path]
-            p0 = W.make_leaf(seed_key, path, shape, kind, dtype)
+            p0 = W.make_leaf(seed_key, path, shape, kind, dtype,
+                             d["family"])
             out[path] = float(one(p, p0))
             del p0
         return out

@@ -6,80 +6,56 @@ reference takes nothing the program has made. Values are drawn in float32
 and rounded to the served/trained type (bfloat16) once; the reference
 upcasts those same bfloat16 values.
 
-Paths follow the checkpoint layout of the program's Llama-family model
-(``layers_<i>/attention/wq/kernel`` ...): that layout is the interface the
-benchmark feeds, exactly as a converted public checkpoint would be fed.
+Which leaves a model has, and what its sizes are, is its family's business
+(``perfbench/families/<family>.py``, found by the name the configuration
+file gives): :func:`dims_of` returns the family's sizes with the family's
+name among them (``d["family"]``), and everything here that takes ``d``
+asks that family. So sizes carry their family wherever they travel (the
+training child's spec, a reader's ``ctx["dims"]``).
 """
 
 import math
 import zlib
 
+from . import manifest
+
+
+def family_of(d: dict):
+    """The family file of sizes that :func:`dims_of` made."""
+    return manifest.load_family(d["family"])
+
 
 def dims_of(config: dict) -> dict:
-    """The sizes a Llama-family block needs, from a HF-style config dict."""
-    h = config["hidden_size"]
-    n_heads = config["num_attention_heads"]
-    return {
-        "dim": h,
-        "n_layers": config["num_hidden_layers"],
-        "n_heads": n_heads,
-        "n_kv_heads": config.get("num_key_value_heads", n_heads),
-        "head_dim": config.get("head_dim", h // n_heads),
-        "hidden": config["intermediate_size"],
-        "vocab": config["vocab_size"],
-        "rope_theta": float(config["rope_theta"]),
-        "norm_eps": float(config["rms_norm_eps"]),
-    }
+    """The family's sizes for a configuration file, and the family's name.
+    The harness itself reads ``vocab`` (token ids are drawn under it); every
+    other key is the family's own."""
+    name = manifest.family_name(config)
+    return dict(manifest.load_family(name).dims_of(config), family=name)
 
 
 def preset_kwargs(config: dict) -> dict:
-    """Keyword arguments of the program's ``TransformerConfig`` for a
-    configuration file (its ``program`` group carries the two numbers the
-    program derives the feed-forward width from)."""
-    d = dims_of(config)
-    return dict(dim=d["dim"], n_layers=d["n_layers"], n_heads=d["n_heads"],
-                n_kv_heads=d["n_kv_heads"],
-                ffn_dim_multiplier=config["program"]["ffn_dim_multiplier"],
-                multiple_of=config["program"]["multiple_of"],
-                norm_eps=d["norm_eps"], rope_theta=d["rope_theta"],
-                vocab_size=d["vocab"])
-
-
-def layer_leaves(d: dict) -> dict:
-    """path (inside one block) -> (shape, kind)."""
-    nq, nkv = d["n_heads"] * d["head_dim"], d["n_kv_heads"] * d["head_dim"]
-    return {
-        "attention/wq/kernel": ((d["dim"], nq), "dense"),
-        "attention/wk/kernel": ((d["dim"], nkv), "dense"),
-        "attention/wv/kernel": ((d["dim"], nkv), "dense"),
-        "attention/wo/kernel": ((nq, d["dim"]), "dense"),
-        "attention_norm/scale": ((d["dim"],), "scale"),
-        "feed_forward/w1/kernel": ((d["dim"], d["hidden"]), "dense"),
-        "feed_forward/w2/kernel": ((d["hidden"], d["dim"]), "dense"),
-        "feed_forward/w3/kernel": ((d["dim"], d["hidden"]), "dense"),
-        "ffn_norm/scale": ((d["dim"],), "scale"),
-    }
+    """Keyword arguments of the program's model configuration for a
+    configuration file."""
+    return manifest.load_family(manifest.family_name(config)).preset_kwargs(
+        config)
 
 
 def all_leaves(d: dict) -> dict:
     """Every leaf of the model: full path -> (shape, kind)."""
-    out = {"tok_embeddings/embedding": ((d["vocab"], d["dim"]), "embed")}
-    for i in range(d["n_layers"]):
-        for p, v in layer_leaves(d).items():
-            out[f"layers_{i}/{p}"] = v
-    out["norm/scale"] = ((d["dim"],), "scale")
-    out["output/kernel"] = ((d["dim"], d["vocab"]), "dense")
-    return out
+    return family_of(d).all_leaves(d)
 
 
 def path_id(path: str) -> int:
     return zlib.crc32(path.encode()) & 0x7FFFFFFF
 
 
-def make_leaf(key, path: str, shape, kind: str, dtype):
+def make_leaf(key, path: str, shape, kind: str, dtype, family: str = None):
     """One parameter leaf. ``key`` is ``jax.random.PRNGKey(seed)``; works
     traced (inside one jitted init) and eagerly (the reference, leaf by
-    leaf)."""
+    leaf). Three kinds are drawn here; any other is the named family's:
+    its ``draw_leaf(z, shape, kind)`` makes the float32 values from the
+    standard normal draw ``z`` (a leaf stacked over experts has its fan-in
+    on axis 1; a decay or a bias is neither a scale nor an embedding)."""
     import jax
     import jax.numpy as jnp
 
@@ -89,8 +65,14 @@ def make_leaf(key, path: str, shape, kind: str, dtype):
         w = 1.0 + 0.05 * z
     elif kind == "embed":
         w = 0.02 * z
-    else:  # dense (fan_in, fan_out): lecun normal, the program's own scale
+    elif kind == "dense":
+        # (fan_in, fan_out): lecun normal, the program's own scale
         w = z / math.sqrt(shape[0])
+    elif family is None:
+        raise ValueError(f"leaf {path}: kind {kind!r} is no kind of "
+                         f"weights.make_leaf and no family was named")
+    else:
+        w = manifest.load_family(family).draw_leaf(z, shape, kind)
     return w.astype(dtype)
 
 
@@ -119,7 +101,7 @@ def flatten(tree: dict, prefix: str = "") -> dict:
 
 def make_param_tree(key, d: dict, dtype):
     """The whole tree, nested as the program's ``params`` collection."""
-    return nest({p: make_leaf(key, p, shape, kind, dtype)
+    return nest({p: make_leaf(key, p, shape, kind, dtype, d["family"])
                  for p, (shape, kind) in all_leaves(d).items()})
 
 

@@ -1,10 +1,10 @@
 """Flash attention's share of its roofline in the traced training steps:
 the least time the chip could take for causal attention forward + backward
-of the traced steps (FLOPs and bytes from shapes, ``lib/flops.py``; at these
-shapes the FLOP bound is the larger) over the device time of the flash
-custom calls."""
+of the traced steps (FLOPs and bytes from shapes, the model family's count;
+at these shapes the FLOP bound is the larger) over the device time of the
+flash custom calls."""
 
-from perfbench.lib import flops
+from perfbench.lib import flops, weights
 from perfbench.metrics._kernels import kernel_seconds, FLASH
 
 
@@ -22,7 +22,8 @@ def read(ctx):
     steps = train["window"].get("traced_steps")
     if not steps:
         return None
+    family = weights.family_of(d)
     least = flops.roofline_seconds(
-        flops.flash_attn_flops(d, rows, seq) * steps,
-        flops.flash_attn_bytes(d, rows, seq) * steps, ctx["peaks"])
+        family.flash_attn_flops(d, rows, seq) * steps,
+        family.flash_attn_bytes(d, rows, seq) * steps, ctx["peaks"])
     return 100.0 * least / seconds

@@ -5,6 +5,7 @@ token in bfloat16, over the published HBM bandwidth) over the device time
 under the ``kv_read`` scope inside those spans. Bound by bytes; the bytes
 are the algorithm's, whatever implements the read."""
 
+from perfbench.lib import weights
 from perfbench.metrics import _program_trace as pt
 
 
@@ -14,7 +15,6 @@ def read(ctx):
     if not rounds or not rounds["kv_read_s"] or not ctx.get("peaks"):
         return None
     d = ctx["dims"]
-    kv_bytes_a_token = 2 * d["n_layers"] * d["n_kv_heads"] * d["head_dim"] * 2
-    least = (rounds["live_tokens"] * kv_bytes_a_token
+    least = (weights.family_of(d).paged_read_bytes(d, rounds["live_tokens"])
              / ctx["peaks"]["hbm_bytes_per_s"])
     return 100.0 * least / rounds["kv_read_s"]

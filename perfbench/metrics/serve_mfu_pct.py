@@ -2,7 +2,7 @@
 tokens processed, prefill and decode, + attention over each token's
 context) per second of window, over the published bf16 peak."""
 
-from perfbench.lib import flops
+from perfbench.lib import weights
 
 
 def read(ctx):
@@ -10,7 +10,7 @@ def read(ctx):
     if not serve or not ctx.get("peaks"):
         return None
     c = serve["counters"]
-    total = flops.serve_flops(ctx["dims"],
-                              c["decode_tokens"] + c["prefill_tokens"],
-                              c["decode_ctx"] + c["prefill_ctx"])
+    total = weights.family_of(ctx["dims"]).serve_flops(
+        ctx["dims"], c["decode_tokens"] + c["prefill_tokens"],
+        c["decode_ctx"] + c["prefill_ctx"])
     return 100.0 * total / serve["window_s"] / ctx["peaks"]["bf16_flops"]

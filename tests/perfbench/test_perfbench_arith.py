@@ -38,6 +38,11 @@ def config(name):
         os.path.join(BENCH, "configs", name + ".json")))
 
 
+def counts(d):
+    """The counts of a configuration's sizes are its model family's."""
+    return weights.family_of(d)
+
+
 # ------------------------------------------------------------ trace reduction
 RECORDED = os.path.join(os.path.dirname(os.path.abspath(__file__)),
                         "recorded_trace.json")
@@ -132,18 +137,18 @@ def test_param_counts_match_the_published_models():
                                        + 4096)
     il = config("internlm2-1.8b")
     assert weights.param_count(il) == 1_889_110_016
-    assert flops.kv_bytes_per_token(il) == 96 * 1024
+    assert counts(il).kv_bytes_per_token(il) == 96 * 1024
 
 
 def test_train_flops_per_token_hand_worked():
     d = config("mistral-7b-v0.3-d4")
     mm = 4 * (4096 * (4096 + 2 * 1024) + 4096 * 4096 + 3 * 4096 * 14336) \
         + 4096 * 32768
-    assert flops.matmul_params(d) == mm == 1_006_632_960
+    assert counts(d).matmul_params(d) == mm == 1_006_632_960
     seq = 4096
     attn_fwd_per_tok = 4 * (2 * 2 * 32 * 128 * seq * (seq + 1) / 2) / seq
     want = 3 * (2 * mm + attn_fwd_per_tok)
-    assert flops.train_flops_per_token(d, seq) == pytest.approx(want)
+    assert counts(d).train_flops_per_token(d, seq) == pytest.approx(want)
     # the program's own count (utils/metrics.transformer_flops_per_token)
     assert want == pytest.approx(6 * mm + 12 * 4 * 4096 * seq / 2, rel=1e-3)
     # 20,698 tokens/s/chip (ledger, PR 22) is then 67.7% of 197e12
@@ -152,15 +157,15 @@ def test_train_flops_per_token_hand_worked():
 
 def test_flash_and_paged_counts_hand_worked():
     d = config("mistral-7b-v0.3-d4")
-    f = flops.flash_attn_flops(d, 3, 4096)
+    f = counts(d).flash_attn_flops(d, 3, 4096)
     assert f == pytest.approx(3 * 3 * 4 * 4 * 32 * 128 * 4096 * 4097 / 2)
-    b = flops.flash_attn_bytes(d, 3, 4096)
+    b = counts(d).flash_attn_bytes(d, 3, 4096)
     q = 3 * 4096 * 32 * 128 * 2
     kv = 3 * 4096 * 8 * 128 * 2
     assert b == 4 * (6 * q + 6 * kv)
     il = config("internlm2-1.8b")
     live = 8 * 8320
-    assert flops.paged_read_bytes(il, live) == live * 98304
+    assert counts(il).paged_read_bytes(il, live) == live * 98304
     p = peaks.peaks_of("TPU v5 lite")
     assert flops.roofline_seconds(0, 819e9, p) == pytest.approx(1.0)
     assert flops.roofline_seconds(197e12, 1, p) == pytest.approx(1.0)
@@ -170,9 +175,9 @@ def test_flash_and_paged_counts_hand_worked():
 
 def test_serve_flops_counts_each_token_once():
     d = config("internlm2-1.8b")
-    one = flops.serve_flops(d, 1, 100)
-    assert one == 2 * flops.matmul_params(d) + 4 * 16 * 128 * 24 * 100
-    assert flops.serve_flops(d, 3, 300) == pytest.approx(3 * one)
+    one = counts(d).serve_flops(d, 1, 100)
+    assert one == 2 * counts(d).matmul_params(d) + 4 * 16 * 128 * 24 * 100
+    assert counts(d).serve_flops(d, 3, 300) == pytest.approx(3 * one)
 
 
 # ------------------------------------------------------- recovery stitching

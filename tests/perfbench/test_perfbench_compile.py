@@ -61,12 +61,12 @@ def for_the_chip(monkeypatch):
 
 
 def program_config(name, **over):
-    from fault_tolerant_llm_training_tpu.models import configs as mc
-
+    """Sizes and the program's model configuration, as the cells build
+    them: through the configuration's model family."""
     cfg = manifest.load_json(os.path.join(ROOT, "perfbench", "configs",
                                           name + ".json"))
-    return weights.dims_of(cfg), mc.TransformerConfig(
-        **weights.preset_kwargs(cfg), **over)
+    d = weights.dims_of(cfg)
+    return d, weights.family_of(d).preset(cfg, **over)
 
 
 def on(device, tree):

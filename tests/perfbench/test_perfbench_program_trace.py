@@ -19,7 +19,7 @@ if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
 import perfbench_rehearsal as R  # noqa: E402
-from perfbench.lib import manifest  # noqa: E402
+from perfbench.lib import manifest, weights  # noqa: E402
 from perfbench.metrics import _program_trace as pt  # noqa: E402
 
 RECORDED = os.path.join(HERE, "recorded_program_trace.json")
@@ -224,7 +224,8 @@ def test_paged_attn_roofline_and_shares_on_the_recorded_chip_trace(tmp_path):
     assert summary["decode"]["rounds"] == 2
     assert summary["decode"]["live_tokens"] == want["live_tokens"]
     # the readers, through their own door, on this summary
-    dims = {"n_layers": 24, "n_kv_heads": 8, "head_dim": 128}
+    dims = weights.dims_of(manifest.load_json(os.path.join(
+        ROOT, "perfbench", "configs", "internlm2-1.8b.json")))
     work = str(tmp_path)
     os.makedirs(os.path.join(work, "trace", "plugins", "profile", "x"))
     xplane = os.path.join(work, "trace", "plugins", "profile", "x",
