@@ -6,6 +6,7 @@ A family file holds five things, and the harness asks for them by these
 names (``PERF.md`` section 3, "how a family comes in"):
 
 - sizes: ``dims_of(config)``; the harness itself reads only ``vocab``;
+  optionally ``check_dims(d)``, what has to hold between them;
 - the program's side: ``preset_kwargs`` / ``preset`` build the program's
   model configuration, ``model_class`` names the class whose ``init`` the
   training child wraps;
@@ -62,6 +63,15 @@ def dims_of(config: dict) -> dict:
         "rope_theta": float(config["rope_theta"]),
         "norm_eps": float(config["rms_norm_eps"]),
     }
+
+
+def check_dims(d: dict) -> list:
+    """What has to hold between this family's sizes (tests call this over
+    every configuration that names the family): the problems, as text."""
+    if d["head_dim"] * d["n_heads"] != d["dim"]:
+        return [f"head_dim {d['head_dim']} x n_heads {d['n_heads']} is not "
+                f"dim {d['dim']}"]
+    return []
 
 
 # ------------------------------------------------------- the program's side

@@ -18,7 +18,7 @@ import time
 
 import numpy as np
 
-from . import result, stats, traffic, weights
+from . import program_records, result, stats, traffic, weights, window_notes
 from .peaks import peaks_of
 
 WARM_NEW_TOKENS = 2
@@ -313,10 +313,13 @@ def run(cell, args, t0: float) -> int:
     trace_seconds = float(mix.get("trace_seconds", 5.0))
     tracing = bool(args.trace)
     box = {"tracing": False}
+    gc_watch = window_notes.GcWatch()
 
     def open_window():
+        gc_watch.start()
         box["tokens0"] = committed_tokens(sched, tracker)
         box["counters0"] = dict(counters)
+        box["program_counters0"] = program_records.counters()
         for v in spans.records.values():
             v.clear()
         box["setup_s"] = time.time() - t0
@@ -330,9 +333,11 @@ def run(cell, args, t0: float) -> int:
         import shutil
 
         box["counters1"] = dict(counters)
+        box["program_counters1"] = program_records.counters()
         box["t_cut"] = clock()
         shutil.rmtree(trace_dir, ignore_errors=True)
         os.makedirs(trace_dir, exist_ok=True)
+        program_records.write_scopes(cell.work_dir())
         jax.profiler.start_trace(trace_dir)
         spans.annotate = True
         box["tracing"] = True
@@ -369,6 +374,7 @@ def run(cell, args, t0: float) -> int:
         completed = run_closed(sched, sess, tracker, box["t_open"],
                                args.seconds, clock, marks=trace_marks)
     t_close = clock()
+    gc_watch.stop()
     stop_trace()
     t_open, setup_s = box["t_open"], box["setup_s"]
     window_s = t_close - t_open
@@ -384,6 +390,9 @@ def run(cell, args, t0: float) -> int:
     layer_counters = {k: box.get("counters1", counters)[k]
                       - box["counters0"][k] for k in counters}
     layer_reqs = [r for r in in_window if r["finished_at"] <= t_cut]
+    program_counters = program_records.change(
+        box["program_counters0"],
+        box.get("program_counters1") or program_records.counters())
     drain(sched, tracker, clock)
     peak = result.memory_peak_bytes()
 
@@ -423,7 +432,9 @@ def run(cell, args, t0: float) -> int:
              "generator_late_max_ms": (max(late) if late else 0) * 1e3,
              "in_flight_at_close": in_flight,
              "unfinished_after_drain": unfinished,
-             "counters": window_counters}
+             "counters": window_counters,
+             "where_the_window_went": window_notes.of(
+                 spans.records, box["t_open"], t_close, gc_watch.pauses)}
 
     # ---- per-layer, from spans, counters and the trace --------------------
     breakdown = None
@@ -443,7 +454,9 @@ def run(cell, args, t0: float) -> int:
                "serve": {
                    "spans": {k: [x for x in v if x[1] <= t_cut]
                              for k, v in spans.records.items()},
-                   "counters": layer_counters, "window_s": t_cut - t_open,
+                   "counters": layer_counters,
+                   "program_counters": program_counters,
+                   "window_s": t_cut - t_open,
                    "ttft_s": [r["first_token_at"] - r["t_ref"]
                               for r in layer_reqs],
                    "tpot_s": [(r["finished_at"] - r["first_token_at"])

@@ -20,6 +20,16 @@ NAME_RE = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT_RE = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 SOURCES = ("device_trace", "program_span", "program_counter", "host_clock")
 DEFAULT_FAMILY = "llama"  # of a configuration file that names none
+# ``reduced`` may never name a width: a hidden, intermediate, expert, head,
+# latent, state or projection size, or the experts a token is routed to
+WIDTH_SUFFIXES = ("_dim", "_rank", "_size")
+WIDTH_KEYS = ("num_experts_per_tok",)
+# ... but the rows of the vocabulary held here are the chip's share of a
+# layer that a deployment divides over chips, as its experts are, not a
+# width (model-configs guide, section 4: "a sliced vocabulary is a smaller
+# vocabulary": ids, logits, sampling and loss are over the slice, the
+# embedding's and the head's width stay the published hidden size)
+SLICED_NOT_WIDTHS = ("vocab_size",)
 
 
 def load_json(path: str) -> dict:
@@ -111,6 +121,25 @@ def load_family(name: str, bench_dir: str = None):
     process; needs no JAX until its reference runs."""
     return _family_at(os.path.join(bench_dir or BENCH_DIR, "families",
                                    name + ".py"), name)
+
+
+def check_reduced(entry: dict, config: dict) -> list:
+    """Every problem with what a configuration says it cut (tests call
+    this): ``entry`` is its entry in ``BENCHMARK.json``, ``config`` its
+    file. The two lists agree, no key is a width, and the file states the
+    published value of each key under ``published``."""
+    bad = []
+    if sorted(entry["reduced"]) != sorted(config.get("reduced", [])):
+        bad.append(f"{entry['name']}: reduced {entry['reduced']} in the "
+                   f"manifest, {config.get('reduced')} in its file")
+    for key in entry["reduced"]:
+        if key in WIDTH_KEYS or (key.endswith(WIDTH_SUFFIXES)
+                                 and key not in SLICED_NOT_WIDTHS):
+            bad.append(f"{entry['name']}: reduced names the width {key!r}")
+        if key not in config.get("published", {}):
+            bad.append(f"{entry['name']}: its file states no published "
+                       f"value of {key!r} (\"published\": {{{key!r}: ...}})")
+    return bad
 
 
 def check_names(manifest: dict) -> list:

@@ -12,7 +12,10 @@ the benchmark adds, it adds from here, around calls into the program:
   one jitted init, in place of the initialiser of the family's model class;
 - a wrapper around the compiled step that opens and closes the measured
   window on this process's clock, and reads what ``correct`` compares;
-- a wrapper around ``_consume`` that stamps each step's completion.
+- a wrapper around ``_consume`` that stamps each step's completion;
+- two tables of the program's as plain data (``program_records.py``): its
+  scope names beside a traced window's trace, and in ``window.json`` the
+  change of every counter of its registry over the window.
 
 It talks to the parent in lines ``PERFBENCH {json}`` on stdout and in files
 in the work directory. Roles: ``first`` (fresh state, warm steps, window),
@@ -51,6 +54,7 @@ def main() -> None:
     import jax.numpy as jnp
     import numpy as np
 
+    from perfbench.lib import program_records
     from perfbench.lib import weights as W
     from perfbench.lib.result import memory_peak_bytes
 
@@ -197,9 +201,11 @@ def main() -> None:
                 jax.block_until_ready(state)
                 if spec["trace"]:
                     os.makedirs(spec["trace_dir"], exist_ok=True)
+                    program_records.write_scopes(work)
                     jax.profiler.start_trace(spec["trace_dir"])
                     box["trace_on"] = True
                     box["t_trace0"] = time.perf_counter()
+                box["counters0"] = program_records.counters()
                 box["t_open"] = time.perf_counter()
                 box["stall0"] = stall_total()
                 say("window_open", step=int(self.training_step))
@@ -224,6 +230,8 @@ def main() -> None:
                     "window_s": t_close - box["t_open"],
                     "memory_peak_bytes": memory_peak_bytes(),
                     "data_stall_s": stall_total() - box["stall0"],
+                    "counters": program_records.change(
+                        box["counters0"], program_records.counters()),
                     "done_t": box["done_t"],
                     "trace_window_s": box.get("trace_window_s"),
                     "traced_steps": box.get("traced_steps"),

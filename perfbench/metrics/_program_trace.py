@@ -10,7 +10,13 @@ field numbers of tsl's ``xplane.proto``, nothing else of it): JAX's
 ``ProfileData`` does not show an event's metadata stats, where ``tf_op``
 lives, and a reader that touches no JAX can run in the training cell's
 parent, which must stay off the chip. The summary is cached as JSON in the
-cell's work directory, keyed by the trace file.
+cell's work directory, keyed by the trace file and the bucket list.
+
+The bucket list is the program's own scope table, not a copy kept here: the
+process that traced (it imports the program anyway) wrote the table's names
+in their order, and which of them the program opens itself, beside its
+trace (``lib/program_records.py``). A scope the program adds to its table
+is a bucket from its next traced run on.
 
 Run against a program that writes no ``ftl:`` span and no scope (the parent
 commit of the PR that added them), every reader finds nothing and returns
@@ -22,17 +28,8 @@ import os
 import re
 import statistics
 
-from perfbench.lib import trace_reduce
+from perfbench.lib import program_records, trace_reduce
 
-# obs/trace.py SCOPES, in its order: an op belongs to the FIRST of these
-# that is a component of its scope path (tests hold the two lists equal)
-BUCKETS = ("kv_write", "kv_read", "rope", "sample", "loss_head", "grad_clip",
-           "optimizer", "feed_forward", "attention", "tok_embeddings",
-           "output", "attention_norm", "ffn_norm", "norm")
-# the scopes the program opens itself; the rest are flax's module names,
-# which a program from before the scopes (or an executable read back from
-# a compile cache that older code filled) carries too
-OPENED_BY_PROGRAM = BUCKETS[:7]
 UNSCOPED = "_unscoped_"
 SPAN_PREFIX = "ftl:"
 CACHE_NAME = "program_trace.json"
@@ -151,13 +148,14 @@ def load_xplane(path: str) -> dict:
 
 
 # ------------------------------------------------------------- arithmetic
-def bucket_of(scope: str) -> str:
-    """The first of ``BUCKETS`` that is a component of the scope path
+def bucket_of(scope: str, buckets) -> str:
+    """The first of ``buckets`` (the program's scope table, in its order)
+    that is a component of the scope path
     (``jit(f)/transpose(jvp(Transformer))/layers_0/attention/kv_read/…``:
     a backward or rematerialised op keeps its forward scope inside the
     wrapper), else ``UNSCOPED``."""
     words = set(_WORD.findall(scope))
-    for b in BUCKETS:
+    for b in buckets:
         if b in words:
             return b
     return UNSCOPED
@@ -221,16 +219,23 @@ def overlap(segments, intervals) -> int:
     return total
 
 
-def reduce(raw: dict) -> dict:
+def reduce(raw: dict, scopes: dict) -> dict:
     """Summary of one traced window: device seconds by bucket (mean over
     devices, self time of nested ops), the ops in no bucket, the ``ftl:``
     spans inside the window, and for the decode rounds their
-    ``live_tokens`` and the ``kv_read`` device seconds inside them."""
+    ``live_tokens`` and the ``kv_read`` device seconds inside them.
+    ``scopes`` is what the tracing process wrote of the program's table
+    (``program_records.scopes``): ``scopes`` the bucket list in its order,
+    ``opened`` those the program opens itself. The rest are flax's module
+    names, which a program from before the scopes (or an executable read
+    back from a compile cache that older code filled) carries too, so
+    ``share_pct`` reads only a summary that holds an opened one."""
     win = window_of(raw)
+    order, opened = scopes["scopes"], list(scopes["opened"])
     if win is None:
         return {"window_s": None, "busy_s": None, "buckets": {},
                 "unscoped_ops": [], "idle_gaps": [], "spans": [],
-                "decode": None, "devices": 0}
+                "decode": None, "devices": 0, "opened": opened}
     lo, hi = win
     spans = [sp for sp in raw["spans"] if sp[0].startswith(SPAN_PREFIX)
              and sp[1] >= lo and sp[2] <= hi]
@@ -239,7 +244,7 @@ def reduce(raw: dict) -> dict:
     devices = {k: v for k, v in raw["device_ops"].items() if v}
     buckets, unscoped, busy, kv_read_ns = {}, {}, 0.0, 0.0
     for evs in devices.values():
-        segs = self_segments([(s, s + d, (name, bucket_of(scope)))
+        segs = self_segments([(s, s + d, (name, bucket_of(scope, order)))
                               for name, scope, s, d in evs])
         kv = []
         for s, e, (name, bucket) in segs:
@@ -281,6 +286,7 @@ def reduce(raw: dict) -> dict:
                                       for sp in decode),
                    "kv_read_s": kv_read_ns / n / 1e9} if decode else None,
         "devices": len(devices),
+        "opened": opened,
     }
 
 
@@ -340,7 +346,7 @@ def share_pct(summary, *buckets):
     if not summary or not summary.get("busy_s"):
         return None
     named = summary["buckets"]
-    if not any(k in OPENED_BY_PROGRAM for k in named):
+    if not any(k in summary["opened"] for k in named):
         return None
     return 100.0 * sum(named.get(b, 0.0) for b in buckets) / summary[
         "busy_s"]
@@ -348,14 +354,18 @@ def share_pct(summary, *buckets):
 
 # ------------------------------------------------------------ the readers' door
 def summary_of(ctx):
-    """The cached summary of the cell's newest trace, or None."""
+    """The cached summary of the cell's newest trace, or None (no trace,
+    or no scope table beside it: nothing of the program's to sort by)."""
     work = ctx["cell"].work_dir()
+    scopes = program_records.read_scopes(work)
     try:
         path = trace_reduce.newest_xplane(os.path.join(work, "trace"))
     except (FileNotFoundError, OSError):
         return None
+    if scopes is None:
+        return None
     cache = os.path.join(work, CACHE_NAME)
-    stamp = [path, os.path.getmtime(path), os.path.getsize(path)]
+    stamp = [path, os.path.getmtime(path), os.path.getsize(path), scopes]
     try:
         with open(cache) as fh:
             cached = json.load(fh)
@@ -364,7 +374,7 @@ def summary_of(ctx):
     except (OSError, ValueError):
         pass
     try:
-        summary = reduce(load_xplane(path))
+        summary = reduce(load_xplane(path), scopes)
     except Exception as e:  # a reader never raises: the metric is left out
         import sys
 

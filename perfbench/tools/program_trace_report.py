@@ -6,8 +6,10 @@ and each ``ftl:`` parent span's time by child (they add up to the span).
     python3 perfbench/tools/program_trace_report.py <trace dir | summary.json>
         [--record out.json --rounds N]
 
-A trace directory is reduced afresh; a ``program_trace.json`` (the readers'
-cache in a cell's work directory) is read as it is. ``--record`` (trace
+A trace directory is reduced afresh, by the scope table the tracing process
+left beside it (``program_scopes.json``, ``lib/program_records.py``); a
+``program_trace.json`` (the readers' cache in a cell's work directory) is
+read as it is. ``--record`` (trace
 directory only) also writes the raw device ops and spans of the first N
 ``ftl:sched.step`` (or ``ftl:train.step``) spans, for the reduction's own
 test beside ``tests/perfbench/recorded_trace.json``.
@@ -21,7 +23,7 @@ import sys
 HERE = os.path.dirname(os.path.abspath(__file__))
 sys.path.insert(0, os.path.dirname(os.path.dirname(HERE)))
 
-from perfbench.lib import trace_reduce  # noqa: E402
+from perfbench.lib import program_records, trace_reduce  # noqa: E402
 from perfbench.metrics import _program_trace as pt  # noqa: E402
 
 PARENTS = ("ftl:sched.step", "ftl:engine.decode", "ftl:engine.prefill",
@@ -55,8 +57,13 @@ def main() -> int:
     ap.add_argument("--rounds", type=int, default=2)
     args = ap.parse_args()
     if os.path.isdir(args.source):
+        beside = os.path.dirname(os.path.abspath(args.source))
+        scopes = program_records.read_scopes(beside)
+        if scopes is None:
+            raise SystemExit(f"no {program_records.SCOPES_NAME} in {beside}: "
+                             f"the traced run writes it beside its trace")
         raw = pt.load_xplane(trace_reduce.newest_xplane(args.source))
-        summary = pt.reduce(raw)
+        summary = pt.reduce(raw, scopes)
         outer = sorted((sp[2] - sp[1]) / 1e6 for sp in raw["spans"]
                        if sp[0] == "pb:decode")
         if outer:  # the harness's own span around engine.decode_step

@@ -23,6 +23,7 @@ sys.path.insert(0, HERE)
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
+import perfbench_checks as C  # noqa: E402
 import perfbench_rehearsal as R  # noqa: E402
 from perfbench.lib import manifest, weights  # noqa: E402
 
@@ -44,9 +45,9 @@ def test_a_configuration_names_its_family_and_none_means_llama(tmp_path):
     named = dict(config_file("tiny"), program=dict(
         config_file("tiny")["program"], family="llama"))
     assert weights.dims_of(named) == weights.dims_of(config_file("tiny"))
-    bench = manifest.load_json(os.path.join(ROOT, "BENCHMARK.json"))
-    for w in bench["workloads"]:
-        assert manifest.Cell(w["name"], ROOT).family == "llama"
+    # the accepted cells are Llama's by name; a later cell is of the family
+    # its configuration names, which loads and fills the interface
+    C.check_families(ROOT)
     # a directory of the test's own
     os.makedirs(tmp_path / "families")
     shutil.copy(os.path.join(HERE, "toy_family.py"),
@@ -79,19 +80,49 @@ def test_an_unknown_family_names_the_file_it_looked_for(tmp_path):
     assert "mamba9.py" in str(e.value)
 
 
+@pytest.mark.parametrize("was,now,said", [
+    ("def paged_read_bytes(", "def paged_bytes(",
+     r"lists paged_attn_roofline: families/llama.py lacks "
+     r"\['paged_read_bytes'\]"),
+    ("def serve_flops(", "def flops_served(",
+     r"families/llama.py lacks \['serve_flops'\]"),
+])
+def test_a_family_that_lacks_part_of_the_interface_is_named(tmp_path, was,
+                                                            now, said):
+    root = C.copy_tree(ROOT, tmp_path)
+    path = os.path.join(root, "perfbench", "families", "llama.py")
+    with open(path) as fh:
+        text = fh.read()
+    assert was in text
+    with open(path, "w") as fh:
+        fh.write(text.replace(was, now))
+    with pytest.raises(AssertionError, match=said):
+        C.check_families(root)
+
+
 def test_the_training_cells_parent_stays_off_jax():
-    """``kind_train`` runs in the process that may not hold the chip: it
-    finds the family, reads its sizes and its counts, and imports no JAX."""
+    """``kind_train`` runs in the process that may not hold the chip: for
+    every training cell there is it finds the family, reads its sizes and
+    its counts and the scope table a traced child left, and imports no
+    JAX."""
+    trained = {cell.config_name for cell in C.cells_of(ROOT).values()
+               if cell.kind == "train"}
+    files = [os.path.join(ROOT, c["file"])
+             for c in C.manifest_of(ROOT)["configs"] if c["name"] in trained]
+    assert files
     code = (
         "import sys, json; sys.path.insert(0, %r)\n"
         "from perfbench.lib import kind_train, manifest, weights\n"
-        "d = weights.dims_of(manifest.load_json(%r))\n"
-        "weights.param_count(d)\n"
-        "weights.family_of(d).train_flops_per_token(d, 4096)\n"
+        "from perfbench.lib import program_records\n"
+        "for path in %r:\n"
+        "    d = weights.dims_of(manifest.load_json(path))\n"
+        "    weights.param_count(d)\n"
+        "    weights.family_of(d).train_flops_per_token(d, 4096)\n"
         "manifest.load_reader('train_mfu_pct')({'peaks': None, 'e2e': {}})\n"
+        "program_records.read_scopes('.')\n"
+        "from perfbench.metrics import _program_trace\n"
         "print(json.dumps(sorted(m for m in sys.modules if m == 'jax' or "
-        "m.startswith('jax.'))))\n" % (ROOT, os.path.join(
-            BENCH, "configs", "mistral-7b-v0.3-d4.json")))
+        "m.startswith('jax.'))))\n" % (ROOT, files))
     out = subprocess.run([sys.executable, "-c", code], capture_output=True,
                          text=True, timeout=120)
     assert out.returncode == 0, out.stderr[-2000:]

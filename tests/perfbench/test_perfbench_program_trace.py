@@ -1,7 +1,8 @@
 """The readers of the program's own records (``perfbench/metrics/
 _program_trace.py`` and the per-layer metrics built on it): their arithmetic
 on small hand-made traces and on a recorded slice of a chip trace, the
-bucket list held to the program's ``SCOPES``, and both kinds of cell
+bucket list taken from the program's ``SCOPES`` (no copy of it under
+``perfbench/``), the door for the program's counters, and both kinds of cell
 rehearsed on the CPU with ``--trace 1`` reporting the new names (host spans
 and events are there; a CPU trace has no device plane, so every device
 share is left out, never 0)."""
@@ -18,11 +19,15 @@ sys.path.insert(0, HERE)
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
+import perfbench_checks as C  # noqa: E402
 import perfbench_rehearsal as R  # noqa: E402
-from perfbench.lib import manifest, weights  # noqa: E402
+from perfbench.lib import manifest, program_records, weights  # noqa: E402
 from perfbench.metrics import _program_trace as pt  # noqa: E402
 
 RECORDED = os.path.join(HERE, "recorded_program_trace.json")
+# the program's scope table as it was when that trace was recorded (PR 24):
+# the buckets the accepted cells are read by, which a later table keeps
+RECORDED_SCOPES = C.ACCEPTED_SCOPES
 NEW_SERVE = {"sched_host_ms_per_step", "decode_dispatch_ms_p50",
              "kv_read_dev_share_pct", "kv_write_dev_share_pct",
              "sample_dev_share_pct", "paged_attn_roofline",
@@ -33,36 +38,127 @@ NEW_TRAIN = {"mlp_dev_share_pct", "head_ce_dev_share_pct",
 
 
 # ------------------------------------------------------------ the yardstick
-def test_buckets_are_the_programs_scopes_in_order():
-    from fault_tolerant_llm_training_tpu.obs.trace import SCOPES
+def test_buckets_are_the_programs_scopes_in_order(tmp_path):
+    from fault_tolerant_llm_training_tpu.obs.trace import (
+        _OPENED_HERE,
+        SCOPES,
+    )
 
+    C.check_buckets_follow(SCOPES, _OPENED_HERE, str(tmp_path))
+    # today's table keeps the buckets the recorded trace was sorted by, in
+    # their order, among whatever it has gained: every share of every
+    # accepted cell reads what it read
+    C.check_accepted_buckets_kept(program_records.scopes())
+
+
+def test_a_scope_the_programs_table_gains_is_a_bucket_by_itself(
+        tmp_path, monkeypatch):
+    """A program PR opens a scope: it adds the name to ``obs/trace.py`` and
+    nothing under ``perfbench/`` (which it may not edit) has to follow."""
+    table, opened = C.the_programs_table_gains(monkeypatch, "state_mixer")
+    C.check_buckets_follow(table, opened, str(tmp_path))
+    got = program_records.read_scopes(str(tmp_path))
+    C.check_accepted_buckets_kept(got)
+    # inside flax's module scope, as a mixer is: the narrower name wins,
+    # and it leaves the unscoped and the parent's share
+    path = "jit(train_step)/jvp(Model)/layers_1/attention/state_mixer/dot:"
+    assert pt.bucket_of(path, got["scopes"]) == "state_mixer"
+    assert pt.bucket_of(path, RECORDED_SCOPES["scopes"]) == "attention"
+
+
+def _table(change) -> dict:
+    got = {k: list(v) for k, v in RECORDED_SCOPES.items()}
+    change(got["scopes"], got["opened"])
+    return got
+
+
+@pytest.mark.parametrize("change", [
+    lambda scopes, opened: None,
+    lambda scopes, opened: scopes.insert(8, "state_mixer"),
+    lambda scopes, opened: (scopes.append("router"), opened.append("router")),
+], ids=["todays", "gained_in_the_middle", "gained_and_opened"])
+def test_a_table_that_gains_scopes_keeps_the_accepted_buckets(change):
+    C.check_accepted_buckets_kept(_table(change))
+
+
+@pytest.mark.parametrize("change", [
+    lambda scopes, opened: scopes.__setitem__(1, "kv_gather"),
+    lambda scopes, opened: scopes.remove("ffn_norm"),
+    lambda scopes, opened: scopes.insert(0, scopes.pop(8)),
+    lambda scopes, opened: opened.remove("optimizer"),
+    lambda scopes, opened: scopes.append("rope"),
+], ids=["renamed", "dropped", "reordered", "no_longer_opened", "twice"])
+def test_a_table_that_moves_an_accepted_bucket_is_refused(change):
+    """Under ``paths``, where a program PR cannot follow: renaming or
+    reordering a scope the accepted cells' shares are read by moves their
+    time between buckets, and is a change to the yardstick."""
+    with pytest.raises(AssertionError):
+        C.check_accepted_buckets_kept(_table(change))
+
+
+def test_no_copy_of_the_programs_scope_table_under_perfbench():
     from fault_tolerant_llm_training_tpu.obs.trace import _OPENED_HERE
 
-    assert pt.BUCKETS == tuple(SCOPES)
-    assert pt.OPENED_BY_PROGRAM == _OPENED_HERE
+    C.check_no_copy_of_the_programs_tables(ROOT, _OPENED_HERE)
+
+
+def test_a_copy_of_the_table_is_found_and_a_readers_few_names_are_not(
+        tmp_path):
+    from fault_tolerant_llm_training_tpu.obs.trace import _OPENED_HERE
+
+    root = C.copy_tree(ROOT, tmp_path)
+    metrics = os.path.join(root, "perfbench", "metrics")
+    with open(os.path.join(metrics, "mixers_dev_share_pct.py"), "w") as fh:
+        fh.write('MODULES = {"attention": 1, "output": 2, "norm": 3, '
+                 '"feed_forward": 4}\nSUMMED = ("kv_read", "rope", '
+                 '"sample", "state_mixer")\n')
+    C.check_no_copy_of_the_programs_tables(root, _OPENED_HERE)
+    with open(os.path.join(metrics, "_stale.py"), "w") as fh:
+        fh.write("\nBUCKETS = %r\n" % (tuple(RECORDED_SCOPES["scopes"]),))
+    with pytest.raises(AssertionError, match="_stale.py:2 holds"):
+        C.check_no_copy_of_the_programs_tables(
+            root, _OPENED_HERE + ("state_mixer",))
+
+
+def test_the_programs_counters_change_over_a_window():
+    """The door's arithmetic, on the program's own registry: every series
+    of kind counter, labelled ones by their labels, a gauge never."""
+    from fault_tolerant_llm_training_tpu.obs.registry import REGISTRY
+
+    plain = REGISTRY.counter("pb_test_assignments_total", "test")
+    by = REGISTRY.counter("pb_test_dropped_total", "test")
+    REGISTRY.gauge("pb_test_depth", "test").set(3)
+    plain.inc(5)
+    before = program_records.counters()
+    plain.inc(7)
+    by.labels(reason="capacity").inc(2)
+    delta = program_records.change(before, program_records.counters())
+    assert delta["pb_test_assignments_total"] == 7
+    assert delta["pb_test_dropped_total{reason=capacity}"] == 2
+    assert "pb_test_depth" not in delta
+    assert all(v == 0 for k, v in delta.items()
+               if not k.startswith("pb_test_"))
 
 
 def test_manifest_names_the_fourteen_readers_in_their_cells():
-    bench = manifest.load_json(os.path.join(ROOT, "BENCHMARK.json"))
-    assert manifest.check_names(bench) == []
+    C.check_program_trace_lists(ROOT)
+
+
+def test_a_list_grows_only_by_cells_of_the_kind_its_metric_reads(tmp_path):
+    root = C.copy_tree(ROOT, tmp_path)
+    path = os.path.join(root, "BENCHMARK.json")
+    bench = manifest.load_json(path)
     by_name = {m["name"]: m for m in bench["per_layer"]}
-    for name in NEW_SERVE:
-        assert by_name[name]["workloads"] == [
-            "internlm2-1.8b.longdecode", "internlm2-1.8b.chat"], name
-        assert by_name[name]["moves"] == "tpot_p95_ms"
-    for name in NEW_TRAIN:
-        assert by_name[name]["workloads"] == ["mistral7b-d4.preempt"], name
-    layers = {m["layer"] for m in bench["per_layer"]}
-    assert {by_name[n]["layer"] for n in NEW_SERVE | NEW_TRAIN} <= layers
-    for name in NEW_SERVE | NEW_TRAIN:
-        assert callable(manifest.load_reader(name))
-    # new entries went to the end: what was there is where it was
-    assert [m["name"] for m in bench["per_layer"]][:15] == [
-        "proc_start_s", "step_ms_p50", "recover_cycle_s", "compile_warm_s",
-        "save_s", "restore_s", "data_stall_pct", "flash_attn_roofline",
-        "attn_dev_share_pct", "train_mfu_pct", "train_dev_idle_pct",
-        "ttft_p95_ms", "decode_step_ms_p50", "serve_mfu_pct",
-        "serve_dev_idle_pct"]
+    by_name["mlp_dev_share_pct"]["workloads"].append("internlm2-1.8b.chat")
+    with open(path, "w") as fh:
+        json.dump(bench, fh)
+    with pytest.raises(AssertionError, match="mlp_dev_share_pct reads train"):
+        C.check_program_trace_lists(root)
+    by_name["mlp_dev_share_pct"]["workloads"] = ["internlm2-1.8b.chat"]
+    with open(path, "w") as fh:
+        json.dump(bench, fh)
+    with pytest.raises(AssertionError, match="no longer lists"):
+        C.check_program_trace_lists(root)        # ... and never shrinks
 
 
 @pytest.mark.parametrize("scope,bucket", [
@@ -90,7 +186,7 @@ def test_manifest_names_the_fourteen_readers_in_their_cells():
     ("jit(f)/normalize/renorm:", pt.UNSCOPED),
 ])
 def test_bucket_is_the_first_scope_that_is_a_component(scope, bucket):
-    assert pt.bucket_of(scope) == bucket
+    assert pt.bucket_of(scope, RECORDED_SCOPES["scopes"]) == bucket
 
 
 def test_nested_device_ops_give_each_instant_to_the_innermost():
@@ -150,7 +246,7 @@ def test_reduce_shares_roofline_inputs_and_idle_gaps():
         ["fusion.2 f32[8]", read, 2 * ms, 1 * ms],
     ]}
     summary = pt.reduce({"device_ops": ops, "spans": _spans() + [
-        ["pb:window", 0, 20 * ms, "0.0", {}]]})
+        ["pb:window", 0, 20 * ms, "0.0", {}]]}, RECORDED_SCOPES)
     assert summary["window_s"] == pytest.approx(0.020)
     assert summary["busy_s"] == pytest.approx(0.010)
     assert summary["buckets"] == pytest.approx(
@@ -177,7 +273,7 @@ def test_reduce_shares_roofline_inputs_and_idle_gaps():
 def test_no_scope_or_no_device_reads_nothing_not_zero():
     ms = 1_000_000
     bare = pt.reduce({"device_ops": {"/device:TPU:0": [
-        ["fusion.1 f32[8]", "", 0, 5 * ms]]}, "spans": []})
+        ["fusion.1 f32[8]", "", 0, 5 * ms]]}, "spans": []}, RECORDED_SCOPES)
     assert bare["busy_s"] == pytest.approx(0.005)
     # a program that opens no scope of its own (the parent commit's, or an
     # executable an older program left in the compile cache) still carries
@@ -186,15 +282,17 @@ def test_no_scope_or_no_device_reads_nothing_not_zero():
     assert pt.share_pct(bare, "kv_read") is None
     flax_only = pt.reduce({"device_ops": {"/device:TPU:0": [
         ["fusion.1 f32[8]", "jit(f)/Transformer/layers_0/attention/mul:", 0,
-         5 * ms], ["copy.1 f32[8]", "", 5 * ms, 5 * ms]]}, "spans": []})
+         5 * ms], ["copy.1 f32[8]", "", 5 * ms, 5 * ms]]}, "spans": []},
+        RECORDED_SCOPES)
     assert flax_only["buckets"] == pytest.approx(
         {"attention": 0.005, pt.UNSCOPED: 0.005})
     for bucket in ("kv_read", "feed_forward", "attention", pt.UNSCOPED):
         assert pt.share_pct(flax_only, bucket) is None
-    cpu = pt.reduce({"device_ops": {}, "spans": _spans()})
+    cpu = pt.reduce({"device_ops": {}, "spans": _spans()}, RECORDED_SCOPES)
     assert cpu["busy_s"] is None and pt.share_pct(cpu, "kv_read") is None
     assert pt.self_ms(cpu["spans"], "ftl:sched.step", ("ftl:engine.",))
-    assert pt.reduce({"device_ops": {}, "spans": []})["window_s"] is None
+    assert pt.reduce({"device_ops": {}, "spans": []},
+                     RECORDED_SCOPES)["window_s"] is None
     assert pt.share_pct(None, "kv_read") is None
 
 
@@ -212,7 +310,7 @@ def test_paged_attn_roofline_and_shares_on_the_recorded_chip_trace(tmp_path):
     them (device ops with their scope paths, ``ftl:`` and ``pb:`` spans)."""
     with open(RECORDED) as fh:
         rec = json.load(fh)
-    summary = pt.reduce(rec)
+    summary = pt.reduce(rec, RECORDED_SCOPES)
     want = rec["expect"]
     assert summary["devices"] == 1
     assert summary["busy_s"] == pytest.approx(want["busy_s"], rel=1e-9)
@@ -232,9 +330,11 @@ def test_paged_attn_roofline_and_shares_on_the_recorded_chip_trace(tmp_path):
                           "t.xplane.pb")
     with open(xplane, "wb") as fh:
         fh.write(b"not read: the cache below answers for it")
+    with open(os.path.join(work, program_records.SCOPES_NAME), "w") as fh:
+        json.dump(RECORDED_SCOPES, fh)
     with open(os.path.join(work, pt.CACHE_NAME), "w") as fh:
         json.dump({"stamp": [xplane, os.path.getmtime(xplane),
-                             os.path.getsize(xplane)],
+                             os.path.getsize(xplane), RECORDED_SCOPES],
                    "summary": summary}, fh)
     ctx = {"cell": _Cell(work), "serve": {"window_s": 45.0}, "dims": dims,
            "peaks": {"hbm_bytes_per_s": 819e9}}
@@ -260,6 +360,12 @@ def test_paged_attn_roofline_and_shares_on_the_recorded_chip_trace(tmp_path):
         want["decode_dispatch_ms_p50"])
     # a training reader in a serving cell, and any reader with no trace
     assert read("mlp_dev_share_pct") is None
+    # the cache is keyed on the table too: another table reads the trace
+    # afresh (this one cannot be read, so the reader finds nothing)
+    with open(os.path.join(work, program_records.SCOPES_NAME), "w") as fh:
+        json.dump(dict(RECORDED_SCOPES, scopes=["state_mixer"]
+                       + RECORDED_SCOPES["scopes"]), fh)
+    assert read("kv_read_dev_share_pct") is None
     assert manifest.load_reader("kv_read_dev_share_pct")(
         dict(ctx, cell=_Cell(str(tmp_path / "none")))) is None
 
@@ -270,6 +376,8 @@ def test_unreadable_trace_and_missing_events_read_nothing(tmp_path):
     with open(os.path.join(work, "trace", "plugins", "profile", "x",
                            "t.xplane.pb"), "wb") as fh:
         fh.write(b"\xff\xff\xff garbage, not an XSpace")
+    with open(os.path.join(work, program_records.SCOPES_NAME), "w") as fh:
+        json.dump(RECORDED_SCOPES, fh)
     ctx = {"cell": _Cell(work), "serve": {"window_s": 45.0}, "dims": {},
            "peaks": None,
            "train": {"cycles": [{"to_job": "pbB"}]}}
@@ -297,7 +405,17 @@ def test_unreadable_trace_and_missing_events_read_nothing(tmp_path):
 # ------------------------------------------- both kinds of cell, on the CPU
 @pytest.fixture(scope="module")
 def checkout(tmp_path_factory):
-    return R.make_checkout(tmp_path_factory.mktemp("pb_program_trace"))
+    """The rehearsal checkout, with two readers ADDED that read a counter
+    of the program's registry through the door, one for each kind of cell:
+    no file of ``lib/`` knows either counter's name."""
+    root = R.make_checkout(tmp_path_factory.mktemp("pb_program_trace"))
+    R.add_counter_reader(root, "tokens_trained_counted", "train",
+                         "ftl_train_tokens_total", "train_tok_s",
+                         ["tiny.tiny-preempt1"])
+    R.add_counter_reader(root, "tokens_generated_counted", "serve",
+                         "ftl_serve_tokens_generated_total", "tpot_p95_ms",
+                         ["tiny.tiny-chat"])
+    return root
 
 
 def test_serving_rehearsal_reports_the_programs_host_spans(checkout):
@@ -314,6 +432,12 @@ def test_serving_rehearsal_reports_the_programs_host_spans(checkout):
     assert not got & (NEW_SERVE - {"sched_host_ms_per_step",
                                    "decode_dispatch_ms_p50"})
     assert not got & NEW_TRAIN
+    # through the counters door: what the program's registry counted in
+    # the untraced part of the window, beside the harness's own count
+    assert line["metrics"]["tokens_generated_counted"]["value"] > 0
+    # the table the buckets come from lies beside the trace
+    C.check_scopes_beside_the_trace(os.path.join(
+        checkout, ".perfbench_work", "tiny.tiny-chat"))
     # the cached summary: children and self time add up to the step span
     with open(os.path.join(checkout, ".perfbench_work", "tiny.tiny-chat",
                            pt.CACHE_NAME)) as fh:
@@ -341,3 +465,17 @@ def test_training_rehearsal_reports_the_programs_lifecycle_events(checkout):
         cycle["resume_s"], abs=2.0)
     assert 0 < m["ckpt_verify_s"]["value"] <= m["restore_s"]["value"]
     assert 0.5 < m["import_s"]["value"] < 300
+    # through the counters door: every counter of the program's registry,
+    # its change over the window; the stall's own key reads the same counter
+    work = os.path.join(checkout, ".perfbench_work", "tiny.tiny-preempt1")
+    window = manifest.load_json(os.path.join(work, "window.json"))
+    assert window["counters"]["ftl_data_stall_seconds_total"] == (
+        pytest.approx(window["data_stall_s"], abs=1e-9))
+    assert m["data_stall_pct"]["value"] == pytest.approx(
+        100.0 * window["data_stall_s"] / window["window_s"])
+    counted = m["tokens_trained_counted"]["value"]
+    assert counted == window["counters"]["ftl_train_tokens_total"]
+    per_step = window["tokens"] / window["steps"]
+    assert counted % per_step == 0 and 0 < counted <= window["tokens"] + (
+        2 * per_step)
+    C.check_scopes_beside_the_trace(work)

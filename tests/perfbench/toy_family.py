@@ -17,6 +17,14 @@ def dims_of(config: dict) -> dict:
             "experts": config["experts"], "vocab": config["vocab_size"]}
 
 
+def check_dims(d: dict) -> list:
+    """Its own statement, not Llama's: no heads to multiply out; an expert
+    layer needs a layer after the leading dense one and an expert in it."""
+    return [f"{k} {d[k]} < {least}" for k, least in (("n_layers", 2),
+                                                     ("experts", 1))
+            if d[k] < least]
+
+
 # ------------------------------------------------------- the program's side
 class ToyModel:
     """Stands where a program's model class would: ``init`` makes a tree
