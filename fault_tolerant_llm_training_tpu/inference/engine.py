@@ -102,7 +102,8 @@ from ..models.configs import TransformerConfig
 from ..obs import events
 from ..obs.trace import span
 from ..models.llama import Transformer, unstack_layer_params
-from ..ops.attention import describe_kernel_mode
+from ..obs.registry import default_registry
+from ..ops.attention import describe_paged_kernel, resolve_paged_kernel
 from ..parallel.mesh import use_mesh
 from ..parallel.sharding import param_shardings
 # Re-exported for backward compatibility: serve.py, scripts/decode_bench.py
@@ -281,7 +282,7 @@ class InferenceEngine:
                  spec_verify_impl: str = "exact",
                  spec_tree=None,
                  prefix_cache: bool = True,
-                 paged_kernel: str = "gather",
+                 paged_kernel: str = "auto",
                  prefill_batch: int = 1,
                  kv_dtype: str = "bf16",
                  adapter_rank: int = 0,
@@ -314,21 +315,44 @@ class InferenceEngine:
                 raise ValueError("int8 cache_dtype requires the paged KV "
                                  "layout")
         self.kv_dtype = kv_dtype
-        if paged_kernel not in ("gather", "pallas"):
+        if paged_kernel not in ("auto", "gather", "pallas"):
             raise ValueError(
                 f"unknown paged_kernel {paged_kernel!r}: 'gather' "
                 f"(assemble blocks then run the ring kernel — the "
-                f"bit-exact reference) or 'pallas' (read pool blocks in "
+                f"bit-exact reference), 'pallas' (read pool blocks in "
                 f"place through the table, ops/paged_attention.py — equal "
-                f"within fp32 accumulation tolerance)")
-        if paged_kernel != "gather" and kv_layout != "paged":
+                f"within fp32 accumulation tolerance) or 'auto' (one of "
+                f"the two per program: in place for one-token queries on "
+                f"one TPU device, ops/attention.py resolve_paged_kernel)")
+        if paged_kernel == "pallas" and kv_layout != "paged":
             raise ValueError("paged_kernel selection requires the paged "
                              "KV layout")
         self.paged_kernel = paged_kernel
         device = describe_device()
         logger.info(f"Device | {device}")
         events.emit("backend_ready", device=device)
-        logger.info(f"Paged kernel | {describe_kernel_mode(paged_kernel)}")
+        # what the programs' paged reads resolve to, by query length (1,
+        # or 2 for every longer one): the start-up line, the dispatch
+        # counter and the scheduler's chunk counters all state this, not
+        # the option
+        with use_mesh(mesh):
+            self._read_kernel = {
+                s_q: ("inplace" if resolve_paged_kernel(
+                    paged_kernel, s_q, cfg.head_dim) == "pallas"
+                      else "gather") for s_q in (1, 2)}
+            logger.info(f"Paged kernel | "
+                        f"{describe_paged_kernel(paged_kernel, cfg.head_dim)}")
+        reads = default_registry().counter(
+            "paged_read_dispatches_total",
+            "Dispatched programs that read the paged KV pool, by the "
+            "kernel their reads resolved to (inplace = the Pallas kernels "
+            "of ops/paged_attention.py, gather = gather-then-ring) and "
+            "phase (decode = decode, burst and speculative rounds)")
+        self._m_reads = {
+            (phase, s_q): reads.labels(kernel=self._read_kernel[s_q],
+                                       phase=phase)
+            for phase, s_q in (("decode", 1), ("prefill", 1),
+                               ("prefill", 2))}
         if cfg.layer_impl == "scan":
             params = unstack_layer_params(params, cfg.n_layers)
             cfg = cfg.replace(layer_impl="loop")
@@ -507,6 +531,17 @@ class InferenceEngine:
                 self.draft_cache = (jax.device_put(dcache, dcs)
                                     if dcs is not None else dcache)
             self._build_programs()
+
+    @property
+    def decode_read_kernel(self) -> str:
+        """``"inplace"`` or ``"gather"``: what the one-token programs
+        (decode, burst, a draft's micro-steps) read the pool through."""
+        return self._read_kernel[1]
+
+    @property
+    def prefill_read_kernel(self) -> str:
+        """The same for the S > 1 programs (chunked and packed prefill)."""
+        return self._read_kernel[2]
 
     def _init_cache(self, dtype=None):
         if self.kv_layout == "paged":
@@ -1231,7 +1266,8 @@ class InferenceEngine:
                              f"base shape {self.spec_tree}")
         pair = self._tree_programs.get(shape.fanouts)
         if pair is None:
-            pair = self._compile_tree_pair(shape)
+            with use_mesh(self.mesh):  # traced as the build-time programs
+                pair = self._compile_tree_pair(shape)
             self._tree_programs[shape.fanouts] = pair
         return pair
 
@@ -1263,7 +1299,8 @@ class InferenceEngine:
             raise ValueError(f"burst width {n} outside [1, {self.max_len}]")
         prog = self._burst_programs.get(n)
         if prog is None:
-            prog = self._compile_burst(n)
+            with use_mesh(self.mesh):  # traced as the build-time programs
+                prog = self._compile_burst(n)
             self._burst_programs[n] = prog
         return prog
 
@@ -1281,7 +1318,8 @@ class InferenceEngine:
                              f"[1, {self.spec_k}]")
         pair = self._spec_programs.get(k)
         if pair is None:
-            pair = self._compile_spec_pair(k)
+            with use_mesh(self.mesh):  # traced as the build-time programs
+                pair = self._compile_spec_pair(k)
             self._spec_programs[k] = pair
         return pair
 
@@ -1466,6 +1504,7 @@ class InferenceEngine:
             args = (row, padded, np.int32(slot), np.int32(start),
                     np.int32(m), np.float32(temperature), np.float32(top_p),
                     np.int32(seed))
+            self._m_reads["prefill", min(bucket, 2)].inc()
             if draft:
                 self.draft_cache, tok = self._draft_prefill[bucket](
                     self.draft_params, self.draft_cache, *args)
@@ -1697,6 +1736,7 @@ class InferenceEngine:
         elif adapter_rows is not None:
             raise ValueError("adapter rows given but engine built "
                              "without adapters (adapter_rank == 0)")
+        self._m_reads["prefill", min(bucket, 2)].inc()
         with span("ftl:engine.prefill.dispatch"):
             self.cache, out = self._packed_prefill[bucket](
                 self.params, self.cache, block_rows, toks, slots, starts,
@@ -1760,6 +1800,8 @@ class InferenceEngine:
             return int(n * (int(lens.sum()) + act.sum() * window)
                        + act.sum() * (n * (n - 1) // 2))
 
+        if self.kv_layout == "paged":
+            self._m_reads["decode", 1].inc()
         return span("ftl:engine.decode", n=n, live_tokens=live_tokens,
                     slots_active=lambda: int(np.count_nonzero(active)))
 

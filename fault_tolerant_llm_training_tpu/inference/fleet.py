@@ -206,8 +206,8 @@ def get_fleet_args(argv=None) -> argparse.Namespace:
                         "artifact exported under one dtype is geometry-"
                         "rejected by the other and the migration falls "
                         "back to the committed-prefix replay")
-    p.add_argument("--paged-kernel", default="gather",
-                   choices=("gather", "pallas"))
+    p.add_argument("--paged-kernel", default="auto",
+                   choices=("auto", "gather", "pallas"))
     p.add_argument("--adapter-rank", type=int, default=0,
                    help="multi-tenant LoRA serving rank (serve.py "
                         "--adapter-rank); 0 = off. Every fleet host must "

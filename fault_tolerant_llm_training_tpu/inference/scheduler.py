@@ -691,12 +691,13 @@ class Scheduler:
             buckets=SPEC_TOKEN_BUCKETS)
         self._m_prefill_inplace = r.counter(
             "prefill_inplace_total",
-            "Prefill chunks dispatched through the in-place Pallas paged "
-            "kernel (--paged-kernel pallas, S>1 chunk grid)")
+            "Prefill chunks whose paged reads resolved to the in-place "
+            "Pallas kernel (--paged-kernel pallas, S>1 chunk grid)")
         self._m_prefill_gather = r.counter(
             "prefill_gather_total",
-            "Prefill chunks dispatched through the gather-then-ring "
-            "reference kernel (--paged-kernel gather)")
+            "Prefill chunks whose paged reads resolved to the gather-"
+            "then-ring reference kernel (--paged-kernel gather, and "
+            "auto's S>1)")
         self._m_spec_draft = r.counter(
             "ftl_spec_draft_tokens_total",
             "Draft-model tokens proposed (speculative decoding)")
@@ -1085,9 +1086,11 @@ class Scheduler:
     def _count_chunk(self) -> None:
         self.prefill_chunks += 1
         self._m_chunks.inc()
-        # which kernel the chunk's paged reads dispatched through — the
+        # which kernel the chunk's paged reads resolved to — the
         # serving-visible proof there is no silent gather under pallas
-        if getattr(self.engine, "paged_kernel", "gather") == "pallas":
+        # (under "auto" the engine says what its S>1 programs took)
+        if getattr(self.engine, "prefill_read_kernel",
+                   "gather") == "inplace":
             self.prefill_inplace_chunks += 1
             self._m_prefill_inplace.inc()
         else:

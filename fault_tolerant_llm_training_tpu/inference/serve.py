@@ -242,9 +242,15 @@ def get_serve_args(argv=None) -> argparse.Namespace:
                         "METADATA only, degrading to fs (then committed-"
                         "prefix replay) on any mismatch. serve.py is one "
                         "process, so 'mem' always applies here")
-    p.add_argument("--paged-kernel", default="gather",
-                   choices=("gather", "pallas"),
-                   help="paged attention kernel (paged layout): 'gather' "
+    p.add_argument("--paged-kernel", default="auto",
+                   choices=("auto", "gather", "pallas"),
+                   help="paged attention kernel (paged layout): 'auto' "
+                        "(default) takes one of the two per program — "
+                        "in place for one-token queries (decode) on one "
+                        "TPU device, the gather for prefill chunks, on "
+                        "several devices and off the chip; the start-up "
+                        "line 'Paged kernel | ...' prints what it "
+                        "resolved to; 'gather' "
                         "assembles each slot's blocks into a contiguous "
                         "view and runs the ring kernel on it — the "
                         "bit-exact reference; 'pallas' reads pool blocks "

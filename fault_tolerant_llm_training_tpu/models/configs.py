@@ -38,8 +38,11 @@ class TransformerConfig:
     # pool blocks in place through the block table — the decode kernel
     # for S=1, the chunk kernel for S>1 (ops/paged_attention.py; no
     # gathered copy either way; equal to gather within fp32 accumulation
-    # tolerance). Training never reads this field.
-    paged_kernel: str = "gather"
+    # tolerance). "auto", the default, takes one of the two per call by
+    # ops/attention.py resolve_paged_kernel's rule on shape and backend:
+    # in place for S=1 on one TPU device, the gather elsewhere. Training
+    # never reads this field.
+    paged_kernel: str = "auto"
     # Sequence layout under sequence parallelism: "zigzag" (each shard holds
     # one early + one mirrored late chunk — balances causal work around the
     # ring at ~2x fewer FLOPs; ops/ring_attention.py) or "contiguous".
@@ -161,7 +164,7 @@ class TransformerConfig:
                                ("rope_impl", ("xla", "fused")),
                                ("attention_impl",
                                 ("auto", "xla", "pallas", "ring")),
-                               ("paged_kernel", ("gather", "pallas")),
+                               ("paged_kernel", ("auto", "gather", "pallas")),
                                ("embed_impl", ("auto", "gather", "one_hot")),
                                ("moe_impl",
                                 ("auto", "capacity", "sorted"))):

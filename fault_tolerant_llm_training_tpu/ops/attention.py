@@ -24,7 +24,7 @@ import jax
 import jax.numpy as jnp
 
 from ..obs.trace import scope
-from ..parallel.mesh import mesh_axis_size
+from ..parallel.mesh import active_mesh, mesh_axis_size
 
 
 def _causal_mask(s_q: int, s_k: int, dtype=jnp.float32) -> jnp.ndarray:
@@ -239,7 +239,8 @@ def paged_tree_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
                          offsets: jnp.ndarray, anc_mask: jnp.ndarray,
                          impl: str = "gather") -> jnp.ndarray:
     """:func:`tree_cached_attention` against block-paged KV pools — the
-    tree-verify routing point, mirroring :func:`paged_attention`.
+    tree-verify routing point, mirroring :func:`paged_attention`
+    (``"auto"`` is the gather here: a tree is S > 1 rows).
 
     ``"gather"`` assembles each slot's blocks and runs the bit-exact
     reference above; ``"pallas"`` takes the ancestor-masked chunk kernel
@@ -249,6 +250,7 @@ def paged_tree_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
     tolerance and bitwise invariant to masked bytes, like every other
     pallas lane.
     """
+    impl = resolve_paged_kernel(impl, q.shape[1], q.shape[3])
     if impl == "gather":
         return tree_cached_attention(
             q, gather_kv_blocks(k_pool, block_tables, q.dtype),
@@ -259,7 +261,7 @@ def paged_tree_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
         return paged_tree_chunk_attention(q, k_pool, v_pool, block_tables,
                                           offsets, anc_mask)
     raise ValueError(f"unknown paged attention impl: {impl!r} "
-                     f"(want 'gather' or 'pallas')")
+                     f"(want 'auto', 'gather' or 'pallas')")
 
 
 @scope("kv_read")
@@ -283,7 +285,10 @@ def paged_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
       reorders the reduction) and bitwise invariant to masked bytes; the
       single statement of the positional-masking equivalence lives in
       ops/paged_attention.py's module docstring.
+    - ``"auto"`` — one of the two, by :func:`resolve_paged_kernel`'s rule
+      on this call's shape and the backend (the serving default).
     """
+    impl = resolve_paged_kernel(impl, q.shape[1], q.shape[3])
     if impl == "gather":
         return paged_cached_attention(q, k_pool, v_pool, block_tables,
                                       offsets)
@@ -296,7 +301,49 @@ def paged_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
         return paged_chunk_attention(q, k_pool, v_pool, block_tables,
                                      offsets)
     raise ValueError(f"unknown paged attention impl: {impl!r} "
-                     f"(want 'gather' or 'pallas')")
+                     f"(want 'auto', 'gather' or 'pallas')")
+
+
+def resolve_paged_kernel(impl: str, s_q: int, head_dim: int) -> str:
+    """The one statement of the paged ``auto`` rule, read off the call:
+    IN PLACE (``"pallas"``) iff the backend is a TPU, the query is one
+    token (S = 1: decode, a draft's micro-step, exact-mode verify), the
+    engine serves on one device, and the head fills the lane tile;
+    otherwise the gather. ``"gather"`` and ``"pallas"`` pass through.
+
+    Why each clause. Off the chip the kernels run interpreted, which is
+    a test mode, not a way to serve. At S = 1 the gather moves every
+    slot's whole table through HBM twice to read what is live once, and
+    the decode kernel reads the live pages where they lie; at S > 1 the
+    read is a small share of a prefill, and the chunk kernel's S x G-row
+    q block wants a tiling of its own first (ROADMAP S9). A Mosaic call
+    cannot be partitioned automatically, so a mesh of several devices
+    keeps the gather until the call is wrapped per shard as
+    ``flash_attention._per_shard`` is. And the decode kernel moves whole
+    pages only where Mosaic can slice them out of HBM
+    (``paged_attention.decode_pages_whole``); narrower heads would take
+    its slow per-page grid.
+    """
+    if impl != "auto":
+        return impl
+    from .paged_attention import decode_pages_whole
+    mesh = active_mesh()
+    in_place = (jax.default_backend() == "tpu" and s_q == 1
+                and (mesh is None or mesh.size == 1)
+                and decode_pages_whole(head_dim))
+    return "pallas" if in_place else "gather"
+
+
+def describe_paged_kernel(impl: str, head_dim: int) -> str:
+    """``impl`` as the server's start-up line states it, resolved for the
+    two query shapes a server dispatches (call it under the serving
+    mesh): ``gather``, ``pallas (compiled)``, or ``auto: decode pallas
+    (compiled), prefill gather``."""
+    if impl != "auto":
+        return describe_kernel_mode(impl)
+    decode, prefill = (describe_kernel_mode(
+        resolve_paged_kernel(impl, s_q, head_dim)) for s_q in (1, 2))
+    return f"auto: decode {decode}, prefill {prefill}"
 
 
 def resolve_attention_impl(impl: str) -> str:

@@ -6,20 +6,24 @@ copy and runs the ring kernel's einsum on it — correct by construction,
 but the gather is an extra full-cache pass per layer per dispatch. This
 module is the serving-side member of the repo's Pallas kernel family
 (flash_attention.py prefill, ring_flash.py sequence-parallel): the block
-table rides in as a scalar-prefetch operand, the grid's innermost axis
-walks a slot's logical blocks, and each step's BlockSpec index map sends
-the DMA straight at ``pool[tables[b, j]]`` — the pool is read THROUGH
-the table with no gathered intermediate, vLLM's PagedAttention fused
-with flash-decoding's split-KV online softmax.
+table rides in as a scalar-prefetch operand and every DMA is aimed
+straight at ``pool[tables[b, j]]`` — the pool is read THROUGH the table
+with no gathered intermediate, vLLM's PagedAttention fused with
+flash-decoding's split-KV online softmax.
 
-Two kernels share that structure, dispatched by ``ops/attention.py
-paged_attention(impl=)`` on the query length:
+Three kernels, dispatched by ``ops/attention.py paged_attention(impl=)``
+on the query length (and ``paged_tree_attention``):
 
 - :func:`paged_decode_attention` — S = 1 (the decode step; the query
-  row is the slot's GQA group, (G, D)).
+  rows of a kv head are its GQA group, (G, D)). The round's hot read: the
+  pools stay in HBM, the kernel's own DMAs move one whole ``(K, bs, D)``
+  pool block each — all kv heads of ``bs`` positions, contiguous in the
+  pool's layout — a few dozen pages a double-buffered step, and only the
+  pages under each slot's length: bytes follow the LIVE tokens.
 - :func:`paged_chunk_attention` — S > 1 (chunked prefill, chunk-mode
-  spec-verify): the same grid, the q block widened to the chunk's
-  S*G rows, the causal boundary applied per row.
+  spec-verify): a grid over (slot, kv head, table entry) whose BlockSpec
+  index maps aim one ``(bs, D)`` page of one head a step, the q block the
+  chunk's S*G rows, the causal boundary applied per row.
 - :func:`paged_tree_chunk_attention` — S > 1 TREE-verify (tree
   speculative decoding): the chunk kernel with the speculative window's
   causal rule replaced by a per-row ancestor mask, dispatched by
@@ -45,7 +49,8 @@ bytes.
 
 Numerics follow the house flash-decoding scheme (flash_attention.py):
 base-2 online softmax with ``log2(e)`` folded into the q prescale, fp32
-(m, l, acc) carried in VMEM scratch across the block axis, one rescale +
+(m, l, acc) carried across the block axis (VMEM scratch in the grid
+kernels, the page-group loop's carry in the decode kernel), one rescale +
 normalize at the last block. Accumulation order therefore differs from
 the gather path's full-row softmax — equality holds to fp32 accumulation
 tolerance, not bitwise, which is why the engine keeps the gather program
@@ -82,16 +87,19 @@ from .flash_attention import LOG2E, NEG_INF, _interpret
 # 128 anyway, and a (G, 128) broadcast store beats a strided (G, 1) one.
 _STAT_LANES = 128
 
-# Mosaic tile knobs (ROADMAP D=128 tile-tuning follow-up): how many kv
-# heads one grid step processes. A tile of T fuses T heads' (bs, D) KV
-# DMAs and dots into one step — fewer grid steps, larger VMEM tiles —
-# at T× the scratch. scripts/d128_tile_sweep.py sweeps these under
-# interpret mode; 1 is the recorded CPU-interpret-safe default (the
-# sweep found no CPU win above it, and 1 keeps each step's numerics and
-# scratch identical to the pre-knob kernels). A tile that does not
-# divide the pool's kv-head count falls back to 1.
-DECODE_HEAD_TILE = 1
+# Mosaic tile knob of the S>1 chunk kernel (ROADMAP D=128 tile-tuning
+# follow-up): how many kv heads one grid step processes. A tile of T fuses
+# T heads' (bs, D) KV DMAs and dots into one step — fewer grid steps,
+# larger VMEM tiles — at T× the scratch. 1 is the recorded
+# CPU-interpret-safe default (the sweep found no CPU win above it). A tile
+# that does not divide the pool's kv-head count falls back to 1.
 CHUNK_HEAD_TILE = 1
+
+# What the S=1 kernel sizes its page groups from (_decode_pages_per_step):
+# the positions one compute block wants, and the VMEM its four page
+# buffers may take (well inside the 16 MiB a v5e kernel may scope).
+_DECODE_SPAN = 512
+_DECODE_BUFFER_BYTES = 4 << 20
 
 
 def _split_quant_pools(k_pool, v_pool):
@@ -131,91 +139,173 @@ def _dequant_block(blk, scale_ref, pool_blk, kv_head, out_dtype):
             * scale_ref[pool_blk, kv_head]).astype(out_dtype)
 
 
-def _decode_kernel(tables_ref, offs_ref, *args, block_size: int,
-                   scale: float, head_tile: int = 1,
-                   quantized: bool = False):
-    """One (slot b, kv-head tile h, logical block j) grid step.
+def _decode_pages_per_step(nb: int, kv: int, bs: int, d: int,
+                           itemsize: int) -> int:
+    """How many pool blocks one step of the S=1 kernel moves and consumes.
 
-    k_ref/v_ref are the (1, head_tile, bs, D) pool slices the index map
-    already aimed at ``tables[b, j]`` — the kernel never sees a block id,
-    only the block's bytes. Carry (m, l, acc) lives in VMEM scratch
-    revisited across the innermost j axis (one (G, ·) band per tiled
-    head); j == 0 initializes, the last j emits. The head loop is a
-    static Python unroll, so ``head_tile == 1`` is instruction-for-
-    instruction the pre-knob kernel.
+    Read off the shapes, never set by a caller: the compute block wants
+    ``_DECODE_SPAN`` positions (a few hundred keys a matmul, so a long
+    slot is a few dozen steps and not one a page), cut down to the table's
+    width and to what four page buffers (K and V, double-buffered) may
+    take of VMEM. A page is counted as it lies in VMEM — lanes padded to
+    128, sublanes to the dtype's tile.
+    """
+    sublanes = 8 * 4 // itemsize
+    page = kv * -(-bs // sublanes) * sublanes * -(-d // 128) * 128 * itemsize
+    return max(1, min(nb, _DECODE_SPAN // bs,
+                      _DECODE_BUFFER_BYTES // (4 * page)))
+
+
+def _decode_kernel(tables_ref, offs_ref, *args, block_size: int, pages: int,
+                   nb: int, scale: float, quantized: bool = False):
+    """One slot a grid step; inside it a loop over the slot's LIVE page
+    groups, ``pages`` pool blocks each, all kv heads at once.
+
+    The pools stay in HBM. A page is one contiguous ``(K, bs, D)`` pool
+    block — every kv head's rows of ``bs`` positions — and one DMA lands
+    it whole in slot ``buf`` of the double-buffered ``(2, pages, K, bs,
+    D)`` VMEM scratch, aimed at ``tables[b, j]`` like the other kernels'
+    index maps. While group ``g`` is contracted, group ``g + 1`` of the
+    same slot — or group 0 of the next slot, across the grid step — is
+    already in flight into the other buffer (the parity rides in SMEM
+    scratch between steps).
+
+    LIVE-SIZED: slot ``b`` runs ``ceil(n / pages)`` groups for its ``n =
+    offsets[b] // bs + 1`` pages under its query position, a dynamic trip
+    count, and the last group starts (and waits for) only its live pages'
+    DMAs. Table entries past the slot's length are never followed and cost
+    nothing; an inactive slot costs one page. What a short group leaves of
+    the buffer is an older group's pool bytes or the zeros the first step
+    wrote — finite either way, and masked below.
+
+    Per group and kv head the arithmetic is the family's: (G, D) x (T, D)
+    scores in fp32 over the group's ``T = pages * bs`` positions,
+    positional mask ``k_pos <= offsets[b]``, base-2 online softmax on the
+    fp32 (m, l, acc) the group loop carries, probabilities cast to the
+    value dtype before ``p @ V``. The head loop is a static unroll, taken
+    phase by phase (every head's scores, then every head's softmax, ...)
+    so that the heads' dependent chains overlap: a third of the time of
+    head-by-head over VMEM scratch, on the chip.
 
     ``quantized`` (static) reads int8 pool blocks with two extra
     scalar-prefetch operands — the (N, K) fp32 k/v scale pools — and
-    dequantizes each block right after its DMA (:func:`_dequant_block`).
-    The positional mask is unchanged, so masked int8 garbage (null
-    block, stale tails — including pool rows whose scale[0] entry holds
-    junk from diverted null-row writes) still contributes exactly zero
-    probability: dequant keeps every lane finite (finite int8 × finite
-    fp32 scale), and finite lanes past the boundary underflow to 0.0.
+    dequantizes each page as it is taken out of the buffer
+    (:func:`_dequant_block`). The positional mask is unchanged, so masked
+    int8 garbage (null block, stale tails — including pool rows whose
+    scale[0] entry holds junk from diverted null-row writes) still
+    contributes exactly zero probability: dequant keeps every lane finite
+    (finite int8 x finite fp32 scale), and finite lanes past the boundary
+    underflow to 0.0.
     """
     if quantized:
-        (ksc_ref, vsc_ref, q_ref, k_ref, v_ref, o_ref,
-         m_scr, l_scr, acc_scr) = args
+        ksc_ref, vsc_ref, *args = args
     else:
-        q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr = args
         ksc_ref = vsc_ref = None
+    q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sems, parity = args
     b = pl.program_id(0)
-    ht_i = pl.program_id(1)
-    j = pl.program_id(2)
+    _, kv, g, d = q_ref.shape
+    span = pages * block_size
+    pools = ((k_hbm, kbuf, 0), (v_hbm, vbuf, 1))
 
-    @pl.when(j == 0)
-    def _init():
-        m_scr[...] = jnp.full(m_scr.shape, NEG_INF, jnp.float32)
-        l_scr[...] = jnp.zeros(l_scr.shape, jnp.float32)
-        acc_scr[...] = jnp.zeros(acc_scr.shape, jnp.float32)
+    def n_pages(slot):  # pages at or under the slot's query position
+        return jnp.minimum(offs_ref[slot] // block_size + 1, nb)
+
+    def for_live_pages(slot, grp, buf, act):
+        """``act`` (start or wait) the DMAs of group ``grp`` of ``slot``."""
+        base = slot * nb + grp * pages
+
+        def _page(i, _):
+            blk = tables_ref[base + i]
+            for hbm, vmem, which in pools:
+                act(pltpu.make_async_copy(hbm.at[blk], vmem.at[buf, i],
+                                          sems.at[which, buf]))
+
+        jax.lax.fori_loop(
+            0, jnp.minimum(n_pages(slot) - grp * pages, pages), _page, None)
+
+    def wait_group(grp, buf):
+        live = n_pages(b) - grp * pages
+
+        @pl.when(live >= pages)
+        def _whole():
+            # a group's pages all signal one semaphore: a whole group is
+            # waited for at once, by the buffer's size
+            for _, vmem, which in pools:
+                pltpu.make_async_copy(vmem.at[buf], vmem.at[buf],
+                                      sems.at[which, buf]).wait()
+
+        @pl.when(live < pages)
+        def _some():
+            for_live_pages(b, grp, buf, lambda c: c.wait())
+
+    @pl.when(b == 0)
+    def _first():
+        # p == 0 on a page no DMA has written must meet finite V
+        vbuf[...] = jnp.zeros(vbuf.shape, vbuf.dtype)
+        parity[0] = 0
+        for_live_pages(0, 0, 0, lambda c: c.start())
 
     offset = offs_ref[b]  # this slot's decode position (committed length)
-    g = acc_scr.shape[0] // head_tile
+    groups = (n_pages(b) + pages - 1) // pages
+    first_buf = parity[0]
+    heads = range(kv)
+    q2 = [(q_ref[0, h].astype(jnp.float32)
+           * (scale * LOG2E)).astype(q_ref.dtype) for h in heads]  # (G, D)
 
-    # Blocks whose first position is already past the query position are
-    # fully masked — skip them (freed/stale/null-table tail). The carry
-    # is untouched, exactly as an all -inf block contributes nothing.
-    @pl.when(j * block_size <= offset)
-    def _block():
-        for hh in range(head_tile):
-            lo, hi = hh * g, (hh + 1) * g
-            kb, vb = k_ref[0, hh], v_ref[0, hh]
-            if quantized:
-                blk = tables_ref[b * pl.num_programs(2) + j]
-                kvh = ht_i * head_tile + hh
-                kb = _dequant_block(kb, ksc_ref, blk, kvh, q_ref.dtype)
-                vb = _dequant_block(vb, vsc_ref, blk, kvh, q_ref.dtype)
-            q2 = (q_ref[0, hh].astype(jnp.float32)
-                  * (scale * LOG2E)).astype(q_ref.dtype)       # (G, D)
-            s = jax.lax.dot_general(                           # (G, bs) fp32
-                q2, kb, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            k_pos = j * block_size + jax.lax.broadcasted_iota(
-                jnp.int32, (g, block_size), 1)
-            s = jnp.where(k_pos <= offset, s, NEG_INF)
-            m_prev, l_prev = m_scr[lo:hi, 0], l_scr[lo:hi, 0]
-            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-            p = jnp.exp2(s - m_new[:, None])
-            alpha = jnp.exp2(m_prev - m_new)
-            l_new = l_prev * alpha + jnp.sum(p, axis=-1)
-            acc_scr[lo:hi, :] = (acc_scr[lo:hi, :] * alpha[:, None]
-                                 + jax.lax.dot_general(
-                                     p.astype(vb.dtype), vb,
-                                     (((1,), (0,)), ((), ())),
-                                     preferred_element_type=jnp.float32))
-            m_scr[lo:hi, :] = jnp.broadcast_to(
-                m_new[:, None], (g, m_scr.shape[1]))
-            l_scr[lo:hi, :] = jnp.broadcast_to(
-                l_new[:, None], (g, l_scr.shape[1]))
+    def _group(grp, carry):
+        m, l, acc = carry  # per kv head: (G, 1), (G, 1), (G, D) fp32
+        buf = (first_buf + grp) % 2
+        more = grp + 1 < groups
+        nxt_slot = jnp.where(more, b, b + 1)
 
-    @pl.when(j == pl.num_programs(2) - 1)
-    def _emit():
-        # l >= exp2(0) always: position ``offset`` itself is in range
-        # (the decode writes the query token's KV before attending).
-        for hh in range(head_tile):
-            lo, hi = hh * g, (hh + 1) * g
-            o_ref[0, hh] = (acc_scr[lo:hi, :]
-                            / l_scr[lo:hi, :1]).astype(o_ref.dtype)
+        @pl.when(nxt_slot < pl.num_programs(0))
+        def _prefetch():
+            for_live_pages(nxt_slot, jnp.where(more, grp + 1, 0), 1 - buf,
+                           lambda c: c.start())
+
+        wait_group(grp, buf)
+        if quantized:
+            # a page this group did not load keeps older int8 bytes and
+            # takes the scale of some entry of the slot's table: finite
+            last = b * nb + nb - 1
+            blks = [tables_ref[jnp.minimum(b * nb + grp * pages + i, last)]
+                    for i in range(pages)]
+
+            def dequant(ref, sc_ref, h):
+                return jnp.concatenate([
+                    _dequant_block(ref[buf, i, h], sc_ref, blks[i], h,
+                                   q_ref.dtype) for i in range(pages)],
+                    axis=0)
+            k = [dequant(kbuf, ksc_ref, h) for h in heads]
+            v = [dequant(vbuf, vsc_ref, h) for h in heads]
+        else:
+            k = [kbuf[buf, :, h].reshape(span, d) for h in heads]
+            v = [vbuf[buf, :, h].reshape(span, d) for h in heads]
+        visible = (grp * span + jax.lax.broadcasted_iota(
+            jnp.int32, (g, span), 1)) <= offset
+        s = [jnp.where(visible, jax.lax.dot_general(        # (G, T) fp32
+            q2[h], k[h], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32), NEG_INF) for h in heads]
+        m_new = [jnp.maximum(m[h], jnp.max(s[h], axis=-1, keepdims=True))
+                 for h in heads]
+        p = [jnp.exp2(s[h] - m_new[h]) for h in heads]
+        alpha = [jnp.exp2(m[h] - m_new[h]) for h in heads]
+        l_new = [l[h] * alpha[h] + jnp.sum(p[h], axis=-1, keepdims=True)
+                 for h in heads]
+        acc_new = [acc[h] * alpha[h] + jax.lax.dot_general(
+            p[h].astype(v[h].dtype), v[h], (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32) for h in heads]
+        return tuple(m_new), tuple(l_new), tuple(acc_new)
+
+    _, l, acc = jax.lax.fori_loop(0, groups, _group, (
+        tuple(jnp.full((g, 1), NEG_INF, jnp.float32) for _ in heads),
+        tuple(jnp.zeros((g, 1), jnp.float32) for _ in heads),
+        tuple(jnp.zeros((g, d), jnp.float32) for _ in heads)))
+    parity[0] = (first_buf + groups) % 2
+    # l >= exp2(0) always: position ``offset`` itself is in range (the
+    # decode writes the query token's KV before attending).
+    for h in heads:
+        o_ref[0, h] = (acc[h] / l[h]).astype(o_ref.dtype)
 
 
 @scope("kv_read")
@@ -236,7 +326,8 @@ def paged_decode_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
                   adversarial pool state).
 
     Returns (B, 1, H, D), equal to ``paged_cached_attention`` on the same
-    operands to fp32 accumulation tolerance.
+    operands to fp32 accumulation tolerance. Bytes moved follow the live
+    tokens, not the tables' width (:func:`_decode_kernel`).
 
     k/v_pool may be :class:`~..inference.kv_cache.QuantPool` (int8 data
     + (N, K) fp32 scales): the scales ride as two extra scalar-prefetch
@@ -244,49 +335,78 @@ def paged_decode_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
     positional masking, same tolerance against the (dequantizing)
     gather oracle.
     """
-    b, s_q, h, d = q.shape
-    if s_q != 1:
+    if q.shape[1] != 1:
         raise ValueError(f"paged_decode_attention is S=1-specialized, got "
-                         f"S={s_q} (multi-token shapes take "
+                         f"S={q.shape[1]} (multi-token shapes take "
                          f"paged_chunk_attention — ops/attention.py "
                          f"paged_attention routes)")
     k_pool, v_pool, scale_ops = _split_quant_pools(k_pool, v_pool)
+    interpret = _interpret() if interpret is None else interpret
+    if not (interpret or decode_pages_whole(q.shape[3])):
+        # the chunk kernel's per-page grid at S=1 (what this kernel was
+        # before it moved whole pages): in place, and slow. The
+        # interpreter has no such limit, so the CPU tests run the kernel
+        # below at every head size.
+        return _page_grid_attention(q, k_pool, v_pool, scale_ops,
+                                    block_tables, offsets, interpret)
+    _, kv, bs, d = k_pool.shape
+    return _paged_decode(
+        q, k_pool, v_pool, scale_ops, block_tables, offsets,
+        pages=_decode_pages_per_step(block_tables.shape[1], kv, bs, d,
+                                     k_pool.dtype.itemsize),
+        interpret=interpret)
+
+
+def decode_pages_whole(head_dim: int) -> bool:
+    """Whether the S=1 kernel can move whole pool blocks at this head size.
+
+    Its DMAs slice pages out of the HBM pool, and Mosaic (libtpu 0.0.34)
+    lays an HBM ref out in 128-lane tiles and refuses a slice whose last
+    dim is not a multiple of that — D = 64 pads to 128 in HBM and the
+    (K, bs, 64) page no longer divides it. Such heads keep the per-page
+    BlockSpec grid; ``paged_attention``'s ``"auto"`` rule reads this too.
+    """
+    return head_dim % 128 == 0
+
+
+# Called once a layer with the same shapes: under its own jit the kernel
+# body (a static unroll over the kv heads) is traced and lowered once a
+# program, not once a layer; XLA inlines the calls.
+@functools.partial(jax.jit, static_argnames=("pages", "interpret"))
+def _paged_decode(q, k_pool, v_pool, scale_ops, block_tables, offsets, *,
+                  pages: int, interpret: bool):
+    b, _, h, d = q.shape
     n, kv, bs, _ = k_pool.shape
     g = h // kv
     nb = block_tables.shape[1]
-    ht = DECODE_HEAD_TILE if kv % DECODE_HEAD_TILE == 0 else 1
-    qg = q.reshape(b, kv, g, d)  # head-major: (B, K, G, D)
-    tables = block_tables.reshape(-1).astype(jnp.int32)
-    offs = offsets.astype(jnp.int32)
-    kernel = functools.partial(_decode_kernel, block_size=bs,
-                               scale=1.0 / math.sqrt(d), head_tile=ht,
+    kernel = functools.partial(_decode_kernel, block_size=bs, pages=pages,
+                               nb=nb, scale=1.0 / math.sqrt(d),
                                quantized=bool(scale_ops))
+    slot_spec = pl.BlockSpec((1, kv, g, d),
+                             lambda bi, *pref: (bi, 0, 0, 0))
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2 + len(scale_ops),
-            grid=(b, kv // ht, nb),
-            in_specs=[
-                pl.BlockSpec((1, ht, g, d),
-                             lambda bi, hi, j, t, *pref: (bi, hi, 0, 0)),
-                pl.BlockSpec((1, ht, bs, d),
-                             lambda bi, hi, j, t, *pref: (t[bi * nb + j],
-                                                          hi, 0, 0)),
-                pl.BlockSpec((1, ht, bs, d),
-                             lambda bi, hi, j, t, *pref: (t[bi * nb + j],
-                                                          hi, 0, 0)),
-            ],
-            out_specs=pl.BlockSpec(
-                (1, ht, g, d), lambda bi, hi, j, t, *pref: (bi, hi, 0, 0)),
+            grid=(b,),
+            in_specs=[slot_spec,
+                      pl.BlockSpec(memory_space=pltpu.HBM),
+                      pl.BlockSpec(memory_space=pltpu.HBM)],
+            out_specs=slot_spec,
             scratch_shapes=[
-                pltpu.VMEM((ht * g, _STAT_LANES), jnp.float32),  # m
-                pltpu.VMEM((ht * g, _STAT_LANES), jnp.float32),  # l
-                pltpu.VMEM((ht * g, d), jnp.float32),            # acc
+                pltpu.VMEM((2, pages, kv, bs, d), k_pool.dtype),
+                pltpu.VMEM((2, pages, kv, bs, d), v_pool.dtype),
+                pltpu.SemaphoreType.DMA((2, 2)),      # (k|v, buffer)
+                pltpu.SMEM((1,), jnp.int32),          # buffer parity
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((b, kv, g, d), q.dtype),
-        interpret=_interpret() if interpret is None else interpret,
-    )(tables, offs, *scale_ops, qg, k_pool, v_pool)
+        # the prefetched group and the buffer parity cross grid steps
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+    )(block_tables.reshape(-1).astype(jnp.int32), offsets.astype(jnp.int32),
+      *scale_ops, q.reshape(b, kv, g, d), k_pool, v_pool)
     return out.reshape(b, 1, h, d)
 
 
@@ -395,11 +515,21 @@ def paged_chunk_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
     chunk's valid length read the same unwritten pool bytes both paths
     read — callers discard those rows).
     """
-    b, s_q, h, d = q.shape
-    if s_q < 2:
-        raise ValueError(f"paged_chunk_attention wants S > 1, got S={s_q} "
-                         f"(S=1 is paged_decode_attention's shape)")
+    if q.shape[1] < 2:
+        raise ValueError(f"paged_chunk_attention wants S > 1, got "
+                         f"S={q.shape[1]} (S=1 is paged_decode_attention's "
+                         f"shape)")
     k_pool, v_pool, scale_ops = _split_quant_pools(k_pool, v_pool)
+    return _page_grid_attention(
+        q, k_pool, v_pool, scale_ops, block_tables, offsets,
+        _interpret() if interpret is None else interpret)
+
+
+def _page_grid_attention(q, k_pool, v_pool, scale_ops, block_tables,
+                         offsets, interpret: bool):
+    """The chunk kernel's call, at any S: grid (slot, kv-head tile, table
+    entry), one (bs, D) page of one head a step."""
+    b, s_q, h, d = q.shape
     n, kv, bs, _ = k_pool.shape
     g = h // kv
     nb = block_tables.shape[1]
@@ -440,7 +570,7 @@ def paged_chunk_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((b, kv, rows, d), q.dtype),
-        interpret=_interpret() if interpret is None else interpret,
+        interpret=interpret,
     )(tables, offs, *scale_ops, qr, k_pool, v_pool)
     return (out.reshape(b, kv, s_q, g, d)
             .transpose(0, 2, 1, 3, 4).reshape(b, s_q, h, d))

@@ -214,7 +214,11 @@ def check_paged_decode_parity(slots=8, kv=2, h=4, bs=16, nb=16, d=64,
     entries aimed at orphaned blocks, two slots sharing prefix blocks, and
     offsets pinned to block boundaries. The CPU tests pin the same matrix
     in interpret mode (tests/test_paged_kernel.py); this pins the MOSAIC
-    lowering at the tuned head widths."""
+    lowering at the tuned head widths: D=128 takes the kernel that moves
+    whole pages (``nb`` past its 32-page group makes it loop, prefetch
+    across groups and slots, and end on short groups), D=64 its per-page
+    grid (``decode_pages_whole``). Compiled, the output must also not move
+    by a bit when the masked bytes are rewritten."""
     from fault_tolerant_llm_training_tpu.ops.attention import (
         paged_cached_attention,
     )
@@ -246,11 +250,23 @@ def check_paged_decode_parity(slots=8, kv=2, h=4, bs=16, nb=16, d=64,
     err = float(jnp.max(jnp.abs(got.astype(jnp.float32)
                                 - want.astype(jnp.float32))))
     scale = float(jnp.max(jnp.abs(want.astype(jnp.float32)))) or 1.0
-    ok = err / scale < 2e-2
+    # rewrite what the masks hide: every position no slot's query sees
+    # (the null block, the orphan, freed blocks, live blocks' tails)
+    live = np.zeros((n_pool, bs), bool)
+    for b, off in enumerate(np.asarray(offsets)):
+        pos = np.arange(int(off) + 1)
+        live[np.asarray(tables)[b, pos // bs], pos % bs] = True
+    hide = ~live[:, None, :, None]
+    again = jax.jit(paged_decode_attention)(
+        q, jnp.where(hide, 9.0, pool_k).astype(dtype),
+        jnp.where(hide, -9.0, pool_v).astype(dtype), tables, offsets)
+    invariant = bool(jnp.array_equal(got, again))
+    ok = err / scale < 2e-2 and invariant
     print(json.dumps({
         "check": (f"paged_decode_vs_gather_onchip slots={slots} kv={kv} "
                   f"h={h} bs={bs} nb={nb} d={d}"),
-        "max_abs_err": err, "rel": err / scale, "ok": ok,
+        "max_abs_err": err, "rel": err / scale,
+        "masked_bytes_invariant": invariant, "ok": ok,
     }), flush=True)
     return ok
 
@@ -501,6 +517,24 @@ def main():
                  f"COMPILED kernels and runs on a TPU only (the CPU tests "
                  f"cover interpret mode)")
     ok = True
+    if "--paged-only" not in sys.argv[1:]:
+        ok &= _training_kernel_checks()
+    ok &= check_paged_decode_parity()                       # serving, D=64
+    ok &= check_paged_decode_parity(h=8, kv=4, d=128)       # flagship width
+    # InternLM2's heads over tables past one page group: the loop
+    ok &= check_paged_decode_parity(h=16, kv=8, d=128, nb=80)
+    ok &= check_paged_chunk_parity()                        # S>1 chunk, D=64
+    ok &= check_paged_chunk_parity(h=8, kv=4, d=128)        # flagship width
+    ok &= check_tree_verify_parity()                        # tree spec, D=64
+    ok &= check_tree_verify_parity(h=8, kv=4, d=128)        # flagship width
+    ok &= check_quantized_decode_parity()                   # int8 KV, D=64
+    ok &= check_quantized_decode_parity(h=8, kv=4, d=128)   # flagship width
+    ok &= check_quantized_decode_parity(h=8, kv=4, d=128, nb=80)  # looped
+    sys.exit(0 if ok else 1)
+
+
+def _training_kernel_checks() -> bool:
+    ok = True
     ok &= check_flash_parity(2048, 12, 12, 64)   # resident, bench shape
     ok &= check_flash_parity(4096, 4, 2, 64)     # streamed fwd + fused bwd, GQA
     ok &= check_flash_parity(16384, 4, 2, 64)    # split streaming bwd, GQA
@@ -515,15 +549,7 @@ def main():
     ok &= check_rope_fused_parity(2048, 4, 2, 128)  # rope AT the boundary
     ok &= check_ring_carry_64k()
     ok &= check_ring_carry_64k(s=32768, sp=4, h=2, kv=2, d=128)
-    ok &= check_paged_decode_parity()                       # serving, D=64
-    ok &= check_paged_decode_parity(h=8, kv=4, d=128)       # flagship width
-    ok &= check_paged_chunk_parity()                        # S>1 chunk, D=64
-    ok &= check_paged_chunk_parity(h=8, kv=4, d=128)        # flagship width
-    ok &= check_tree_verify_parity()                        # tree spec, D=64
-    ok &= check_tree_verify_parity(h=8, kv=4, d=128)        # flagship width
-    ok &= check_quantized_decode_parity()                   # int8 KV, D=64
-    ok &= check_quantized_decode_parity(h=8, kv=4, d=128)   # flagship width
-    sys.exit(0 if ok else 1)
+    return ok
 
 
 if __name__ == "__main__":

@@ -125,6 +125,24 @@ def test_paged_kernel_compiles_for_v5e(v5e, width, kernel):
                  pool, tables, offsets)
 
 
+@pytest.mark.parametrize("cell,slots,blocks_per_slot",
+                         [("longdecode", 8, 640), ("chat", 32, 160)])
+def test_paged_decode_compiles_at_the_serving_cells_shapes(
+        v5e, cell, slots, blocks_per_slot):
+    """The S=1 kernel as the benchmark's serving cells call it: InternLM2
+    heads (16 over 8 of 128), the 5,121-block pool, each cell's slots and
+    table width — the page group its shape rule picks there (32 pages, 4
+    MiB of buffers) has to fit what a v5e kernel may scope."""
+    h, kv, d = 16, 8, 128
+    assert pa._decode_pages_per_step(blocks_per_slot, kv, POOL_BLOCK, d,
+                                     2) == 32
+    sds = _shapes_on(v5e.devices[0])
+    pool = sds((POOL_BLOCKS, kv, POOL_BLOCK, d))
+    _compile(pa.paged_decode_attention, sds((slots, 1, h, d)), pool, pool,
+             sds((slots, blocks_per_slot), jnp.int32),
+             sds((slots,), jnp.int32))
+
+
 def test_train_step_lowers_on_four_chip_fsdp_mesh(v5e):
     """The Mosaic-partition guard: the real train step at gpt2-125m widths
     (2 layers) must LOWER for a 4-device fsdp mesh with the Pallas flash
@@ -245,22 +263,11 @@ def test_d4_train_step_keeps_scope_names_and_flash_call_names(v5e):
 POOL_LAYERS, POOL_BLOCKS, POOL_BLOCK = 2, 5121, 16
 
 
-@pytest.mark.parametrize("program", ["decode", "prefill_1x512",
-                                     "packed_4x512", "tree_verify"])
-def test_serving_program_writes_the_pool_in_its_own_layout(v5e, program):
-    """No serving program that writes the paged KV pool may hold a ``copy``
-    of the pool's shape, and every pool it returns aliases its input.
-
-    The pool is stored ``(N, K, bs, D)``, N outermost. A write indexed on
-    dims 0 and 2 at once (``pool.at[blk, :, off, :]``) makes the TPU scatter
-    take the operand as ``(N, bs, K, D)``: XLA then transposes each layer's
-    whole K and V pool into that layout and back — 4 copies of 168 MB a
-    layer, 96 a decode round at 24 layers, 53 ms of a 118 ms round on the
-    chip (PERF.md section 6, PR 27). Donation alone does not show it: the
-    aliases were there all along. ``write_paged_kv`` and
-    ``remap_paged_path`` therefore gather and scatter whole blocks."""
+def _serving_program_hlo(v5e, program):
+    """The engine's own ``program`` body at InternLM2-1.8B widths, compiled
+    for one described v5e as ``longdecode``'s server shapes it (8 slots of
+    640 table entries, the 5,121-block pool); returns (HLO text, cfg)."""
     import json
-    import re
     import sys
     import types
     from pathlib import Path
@@ -326,8 +333,27 @@ def test_serving_program_writes_the_pool_in_its_own_layout(v5e, program):
         args = (sds((slots, per_slot), i32), sds((slots, shape.size), i32),
                 sds((slots, shape.size, cfg.vocab_size), f32), vec(i32),
                 vec(jnp.bool_), vec(f32), vec(f32), vec(i32), vec(i32))
-    hlo = jax.jit(fn, donate_argnums=(1,)).lower(
-        params, cache, *args).compile().as_text()
+    return jax.jit(fn, donate_argnums=(1,)).lower(
+        params, cache, *args).compile().as_text(), cfg
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill_1x512",
+                                     "packed_4x512", "tree_verify"])
+def test_serving_program_writes_the_pool_in_its_own_layout(v5e, program):
+    """No serving program that writes the paged KV pool may hold a ``copy``
+    of the pool's shape, and every pool it returns aliases its input.
+
+    The pool is stored ``(N, K, bs, D)``, N outermost. A write indexed on
+    dims 0 and 2 at once (``pool.at[blk, :, off, :]``) makes the TPU scatter
+    take the operand as ``(N, bs, K, D)``: XLA then transposes each layer's
+    whole K and V pool into that layout and back — 4 copies of 168 MB a
+    layer, 96 a decode round at 24 layers, 53 ms of a 118 ms round on the
+    chip (PERF.md section 6, PR 27). Donation alone does not show it: the
+    aliases were there all along. ``write_paged_kv`` and
+    ``remap_paged_path`` therefore gather and scatter whole blocks."""
+    import re
+
+    hlo, cfg = _serving_program_hlo(v5e, program)
     pool = re.escape(f"[{POOL_BLOCKS},{cfg.kv_heads},{POOL_BLOCK},"
                      f"{cfg.head_dim}]")
     copies = re.findall(rf"= \w+{pool}\S* copy\(", hlo)
@@ -338,3 +364,31 @@ def test_serving_program_writes_the_pool_in_its_own_layout(v5e, program):
         2 * POOL_LAYERS)
     aliased = re.findall(r"\(\d+, \{[^}]*\}, (?:may|must)-alias\)", hlo)
     assert len(aliased) == 2 * POOL_LAYERS + 1, aliased
+
+
+def test_decode_program_reads_the_pool_in_place(v5e, monkeypatch):
+    """On a TPU the default ``paged_kernel="auto"`` resolves the decode
+    program's S=1 read to the in-place kernel: its HLO holds no gather,
+    copy or transpose of a slot-table's worth of the pool — the ``slots x
+    max_len`` view ``[8,640,8,16,128]`` the gather assembled and the
+    ``[5120,8,16,128]`` it was transposed through, 48 + 48 a round and
+    ~50 of every ~61 ms of ``kv_read`` (PERF.md section 6, PR 30) — and
+    one Mosaic call a layer, under an ``op_name`` that has ``kv_read`` in
+    its path: that path is what puts the call's device time in the
+    ``kv_read`` bucket of the benchmark's scope reader."""
+    import re
+
+    # the described chip is not the default backend here: say it is, so
+    # that the rule reads what it would read on the chip
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    hlo, cfg = _serving_program_hlo(v5e, "decode")
+    assert cfg.paged_kernel == "auto"
+    views = (r"\[8,640,8,16,128\]", r"\[5120,8,16,128\]")
+    moved = [ln.strip()[:120] for ln in hlo.splitlines()
+             if re.search(rf"= \w+(?:{'|'.join(views)})\S* "
+                          rf"(?:gather|copy|transpose|fusion)\(", ln)]
+    assert not moved, moved
+    calls = [ln for ln in hlo.splitlines() if "tpu_custom_call" in ln]
+    assert len(calls) == POOL_LAYERS, len(calls)
+    assert all(re.search(r'op_name="[^"]*kv_read[^"]*"', ln)
+               for ln in calls), calls

@@ -41,13 +41,14 @@ def _tiny_cfg(**kw):
 
 # -------------------------------------------------------------------- 1. kernel
 def _attend(q, pool_k, pool_v, tables, offsets, impl):
+    import jax
     import jax.numpy as jnp
 
     from fault_tolerant_llm_training_tpu.ops.attention import paged_attention
 
-    return np.asarray(paged_attention(
-        jnp.asarray(q), jnp.asarray(pool_k), jnp.asarray(pool_v),
-        jnp.asarray(tables), jnp.asarray(offsets), impl=impl))
+    # tree_map: an int8 pool is a QuantPool of two arrays
+    return np.asarray(paged_attention(*jax.tree_util.tree_map(
+        jnp.asarray, (q, pool_k, pool_v, tables, offsets)), impl=impl))
 
 
 def _adversarial_pool(rng, dtype=np.float32):
@@ -80,31 +81,155 @@ def _adversarial_pool(rng, dtype=np.float32):
     return q, pool_k, pool_v, tables, offsets
 
 
-def test_pallas_kernel_matches_gather_on_adversarial_pools():
+def _grouped_pool(rng, offsets, width=(4, 2, 16), int8=False, shared=False):
+    """Slots over tables of 10 pages of 8, every live page a block of its
+    own (``shared``: slot 1's first pages are slot 0's), and past each
+    slot's length what an allocator leaves: null block 0. ``width`` is
+    (heads, kv heads, head dim). The decode cases below run it with the
+    kernel's page group patched to 4 pages = 32 positions, so 10 pages are
+    two whole groups and a short one."""
+    H, K, D = width
+    bs, NB, B = 8, 10, len(offsets)
+    N = B * NB + 1
+    pool_k = rng.standard_normal((N, K, bs, D)).astype(np.float32)
+    pool_v = rng.standard_normal((N, K, bs, D)).astype(np.float32)
+    tables = np.zeros((B, NB), np.int32)
+    for b, off in enumerate(offsets):
+        if off < 0:                    # an inactive slot: nothing allocated
+            continue
+        live = off // bs + 1
+        tables[b, :live] = 1 + b * NB + np.arange(live)
+    if shared:
+        tables[1, :2] = tables[0, :2]
+    offs = np.maximum(np.asarray(offsets, np.int32), 0)
+    q = rng.standard_normal((B, 1, H, D)).astype(np.float32)
+    if int8:
+        from fault_tolerant_llm_training_tpu.inference.kv_cache import (
+            QuantPool)
+
+        def quant(x):
+            scale = np.abs(x).max(axis=(2, 3)) / 127.0
+            return QuantPool(
+                q=np.clip(np.rint(x / scale[..., None, None]),
+                          -127, 127).astype(np.int8),
+                scale=scale.astype(np.float32))
+        pool_k, pool_v = quant(pool_k), quant(pool_v)
+    return q, pool_k, pool_v, tables, offs
+
+
+_GROUP = 32   # positions a page group of the patched kernel spans
+
+# name -> builder of (q, pool_k, pool_v, tables, offsets)
+DECODE_CASES = {
+    # the four-slot pool above, at the page group the shape rule gives it
+    "adversarial": _adversarial_pool,
+    # a length that ends on a page-group boundary, one short of it, one
+    # past it, and a table full to its last position (a short last group)
+    "group_edges": lambda rng: _grouped_pool(
+        rng, [_GROUP - 1, _GROUP - 2, _GROUP, 79]),
+    "two_groups_exact": lambda rng: _grouped_pool(
+        rng, [2 * _GROUP - 1, 2 * _GROUP, 2 * _GROUP + 1]),
+    # a slot of length 0 (one token) and an inactive slot beside a full one
+    "empty_beside_full": lambda rng: _grouped_pool(rng, [0, -1, 79, -1, 5]),
+    "shared_block": lambda rng: _grouped_pool(rng, [40, 17, 70],
+                                              shared=True),
+    "d64_g1": lambda rng: _grouped_pool(rng, [33, 79, 7], (4, 4, 64)),
+    "d64_g2": lambda rng: _grouped_pool(rng, [33, 79, 7], (4, 2, 64)),
+    "d64_g4": lambda rng: _grouped_pool(rng, [33, 79, 7], (8, 2, 64)),
+    "d128_g1": lambda rng: _grouped_pool(rng, [33, 79, 7], (2, 2, 128)),
+    "d128_g2": lambda rng: _grouped_pool(rng, [33, 79, 7], (4, 2, 128)),
+    "d128_g4": lambda rng: _grouped_pool(rng, [33, 79, 7], (8, 2, 128)),
+    "int8": lambda rng: _grouped_pool(rng, [_GROUP - 1, 79, 0, 45],
+                                      int8=True),
+    "int8_d128": lambda rng: _grouped_pool(rng, [64, 31, 9], (4, 2, 128),
+                                           int8=True),
+}
+
+
+@pytest.fixture
+def short_page_groups(monkeypatch):
+    """Let the decode kernel's shape rule come out at 4 pages of 8 (it
+    gives every table this small one whole group), so that 10-page tables
+    loop, prefetch across groups and slots, and end on short groups."""
+    from fault_tolerant_llm_training_tpu.ops import paged_attention as pa
+
+    monkeypatch.setattr(pa, "_DECODE_SPAN", _GROUP)
+    assert pa._decode_pages_per_step(10, 2, 8, 16, 4) == 4
+
+
+def _masked_bytes_rewritten(rng, pk, pv, tables, offs):
+    """The pools with every byte no slot's query may see rewritten: all of
+    a block that holds no live position (null block 0, stale, orphaned,
+    never allocated — an int8 block's scales with it), and the positions
+    past a slot's offset inside its last live block."""
+    from fault_tolerant_llm_training_tpu.inference.kv_cache import QuantPool
+
+    quantized = isinstance(pk, QuantPool)
+    data = (pk.q, pv.q) if quantized else (pk, pv)
+    n, _, bs, _ = data[0].shape
+    live = np.zeros((n, bs), bool)
+    for b in range(tables.shape[0]):
+        for pos in range(int(offs[b]) + 1):
+            live[tables[b, pos // bs], pos % bs] = True
+    out = []
+    for x in data:
+        noise = rng.standard_normal(x.shape) * 9.0
+        x2 = np.where(live[:, None, :, None], x,
+                      np.clip(noise * 40, -127, 127).astype(x.dtype)
+                      if quantized else noise.astype(x.dtype))
+        out.append(x2)
+    if not quantized:
+        return tuple(out)
+    dead = ~live.any(axis=1)
+    scales = [np.where(dead[:, None],
+                       rng.uniform(0.5, 50.0, p.scale.shape), p.scale)
+              .astype(np.float32) for p in (pk, pv)]
+    return tuple(QuantPool(q=x, scale=sc) for x, sc in zip(out, scales))
+
+
+@pytest.mark.parametrize("case", DECODE_CASES)
+def test_pallas_kernel_matches_gather_on_adversarial_pools(
+        case, short_page_groups):
     rng = np.random.default_rng(7)
-    q, pk, pv, tables, offs = _adversarial_pool(rng)
+    q, pk, pv, tables, offs = DECODE_CASES[case](rng)
     ref = _attend(q, pk, pv, tables, offs, "gather")
     out = _attend(q, pk, pv, tables, offs, "pallas")
-    np.testing.assert_allclose(out, ref, rtol=1e-5, atol=1e-6)
+    np.testing.assert_allclose(out, ref, rtol=1e-5, atol=2e-6)
 
 
-def test_pallas_kernel_output_invariant_to_masked_bytes():
+def test_lane_narrow_heads_take_the_page_grid(monkeypatch):
+    """Compiled for the chip, a head narrower than the 128-lane tile cannot
+    have its pages sliced out of HBM: the S=1 read then runs the chunk
+    kernel's per-page grid, still in place. Interpreted, that route is
+    steered here and must agree with the gather like the other."""
+    from fault_tolerant_llm_training_tpu.ops import paged_attention as pa
+
+    assert pa.decode_pages_whole(128) and pa.decode_pages_whole(256)
+    assert not pa.decode_pages_whole(64) and not pa.decode_pages_whole(192)
+    q, pk, pv, tables, offs = _adversarial_pool(np.random.default_rng(7))
+    routed = []
+    grid = pa._page_grid_attention
+    monkeypatch.setattr(pa, "_page_grid_attention",
+                        lambda *a: (routed.append(a[0].shape[1]),
+                                    grid(*a[:-1], True))[1])
+    out = np.asarray(pa.paged_decode_attention(q, pk, pv, tables, offs,
+                                               interpret=False))
+    assert routed == [1]
+    np.testing.assert_allclose(
+        out, _attend(q, pk, pv, tables, offs, "gather"),
+        rtol=1e-5, atol=1e-6)
+
+
+@pytest.mark.parametrize("case", DECODE_CASES)
+def test_pallas_kernel_output_invariant_to_masked_bytes(
+        case, short_page_groups):
     """Rewrite every byte the masks are supposed to hide — the null block,
     the orphaned stale blocks, the positions past each offset inside live
     blocks — and the kernel output must not move by a single bit."""
     rng = np.random.default_rng(8)
-    q, pk, pv, tables, offs = _adversarial_pool(rng)
+    q, pk, pv, tables, offs = DECODE_CASES[case](rng)
     base = _attend(q, pk, pv, tables, offs, "pallas")
-
-    pk2, pv2 = pk.copy(), pv.copy()
-    for blk in (0, 14, 15):                       # null + stale garbage
-        pk2[blk] = rng.standard_normal(pk[blk].shape)
-        pv2[blk] = rng.standard_normal(pv[blk].shape)
-    bs = pk.shape[2]
-    for b in range(tables.shape[0]):              # live-block tails past the
-        last = int(offs[b]) // bs                 # decode position itself
-        pk2[tables[b, last], :, int(offs[b]) % bs + 1:] = 9.0
-        pv2[tables[b, last], :, int(offs[b]) % bs + 1:] = -9.0
+    pk2, pv2 = _masked_bytes_rewritten(rng, pk, pv, tables, offs)
     np.testing.assert_array_equal(
         _attend(q, pk2, pv2, tables, offs, "pallas"), base)
 
@@ -236,6 +361,84 @@ def test_paged_attention_dispatch_routes_and_validates(monkeypatch):
     assert routed == ["paged_decode_attention", "paged_chunk_attention"]
     with pytest.raises(ValueError, match="impl"):
         _attend(q, pk, pv, tables, offs, "vllm")
+    # "auto" off the chip IS the gather, at every S: nothing routed, bitwise
+    del routed[:]
+    np.testing.assert_array_equal(_attend(q, pk, pv, tables, offs, "auto"),
+                                  ref)
+    np.testing.assert_array_equal(
+        _attend(qc, pkc, pvc, tablesc, offsc, "auto"),
+        _attend(qc, pkc, pvc, tablesc, offsc, "gather"))
+    assert routed == []
+
+
+@pytest.mark.parametrize("where,s_q,head_dim,want", [
+    ("cpu", 1, 128, "gather"),            # off the chip: the oracle
+    ("cpu", 5, 128, "gather"),
+    ("tpu", 1, 128, "pallas"),            # the decode read, in place
+    ("tpu", 1, 256, "pallas"),
+    ("tpu", 5, 128, "gather"),            # S > 1 stays on the gather
+    ("tpu", 1, 64, "gather"),             # heads narrower than a lane tile
+    ("tpu_mesh", 1, 128, "gather"),       # Mosaic cannot be partitioned
+    ("tpu_one_device_mesh", 1, 128, "pallas"),
+])
+def test_auto_paged_kernel_rule(monkeypatch, where, s_q, head_dim, want):
+    """``auto`` reads the backend, the query length, the head size and the
+    active mesh — and nothing a user sets. The explicit values pass."""
+    import jax
+
+    from fault_tolerant_llm_training_tpu.ops import attention as attn
+    from fault_tolerant_llm_training_tpu.parallel.mesh import (
+        make_mesh, use_mesh)
+
+    if where != "cpu":
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    mesh = {"tpu_mesh": lambda: make_mesh(dp=2, devices=jax.devices()[:2]),
+            "tpu_one_device_mesh": lambda: make_mesh(
+                dp=1, devices=jax.devices()[:1])}.get(where, lambda: None)()
+    with use_mesh(mesh):
+        assert attn.resolve_paged_kernel("auto", s_q, head_dim) == want
+        for explicit in ("gather", "pallas"):
+            assert attn.resolve_paged_kernel(explicit, s_q,
+                                             head_dim) == explicit
+        # the server's start-up line states the resolution, not the option
+        line = attn.describe_paged_kernel("auto", head_dim)
+        decode = attn.resolve_paged_kernel("auto", 1, head_dim)
+        mode = {"cpu": "interpret"}.get(where, "compiled")
+        assert line == (f"auto: decode "
+                        f"{f'pallas ({mode})' if decode == 'pallas' else decode}"
+                        f", prefill gather")
+        assert attn.describe_paged_kernel("gather", head_dim) == "gather"
+        assert attn.describe_paged_kernel(
+            "pallas", head_dim) == f"pallas ({mode})"
+
+
+def test_auto_routes_the_decode_read_in_place_on_a_tpu(monkeypatch):
+    """With the backend reading as a TPU (the kernels still interpreted),
+    ``auto`` sends S = 1 through the decode kernel and S > 1 through the
+    gather, and both agree with the oracle."""
+    import jax
+
+    from fault_tolerant_llm_training_tpu.ops import paged_attention as pa
+
+    q, pk, pv, tables, offs = _grouped_pool(np.random.default_rng(3),
+                                            [33, 79, 7], (4, 2, 128))
+    qc = np.repeat(q, 3, axis=1)
+    offsc = np.minimum(offs, 70)
+    want = _attend(q, pk, pv, tables, offs, "gather")
+    wantc = _attend(qc, pk, pv, tables, offsc, "gather")
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(pa, "_interpret", lambda: True)
+    routed = []
+    for name in ("paged_decode_attention", "paged_chunk_attention"):
+        orig = getattr(pa, name)
+        monkeypatch.setattr(
+            pa, name, lambda *a, _orig=orig, _n=name, **k: (
+                routed.append(_n), _orig(*a, **k))[1])
+    np.testing.assert_allclose(_attend(q, pk, pv, tables, offs, "auto"),
+                               want, rtol=1e-5, atol=2e-6)
+    np.testing.assert_array_equal(
+        _attend(qc, pk, pv, tables, offsc, "auto"), wantc)
+    assert routed == ["paged_decode_attention"]
 
 
 def test_multihead_attention_ring_impl_routes_dense():
@@ -262,6 +465,8 @@ def test_config_validates_paged_kernel():
     from fault_tolerant_llm_training_tpu.models.configs import get_config
 
     assert _tiny_cfg(paged_kernel="pallas").paged_kernel == "pallas"
+    assert _tiny_cfg(paged_kernel="gather").paged_kernel == "gather"
+    assert _tiny_cfg().paged_kernel == "auto"      # the default is the rule
     with pytest.raises(ValueError, match="paged_kernel"):
         get_config("tiny", paged_kernel="cuda")
 
@@ -309,6 +514,48 @@ def test_engine_rejects_bad_kernel_combinations():
         InferenceEngine(cfg, params, slots=1, max_len=16,
                         prefill_buckets=(8, 16), kv_layout="ring",
                         paged_kernel="pallas")
+    # the default is "auto": it asks for nothing, so the ring layout takes
+    # it, and off the chip every program's reads resolve to the gather
+    ring = InferenceEngine(cfg, params, slots=1, max_len=16,
+                           prefill_buckets=(8, 16), kv_layout="ring")
+    assert ring.paged_kernel == ring.cfg.paged_kernel == "auto"
+    assert (ring.decode_read_kernel, ring.prefill_read_kernel) == (
+        "gather", "gather")
+
+
+def test_paged_read_dispatch_counter_states_the_resolved_kernel(
+        paged_engines):
+    """``paged_read_dispatches_total{kernel,phase}`` counts every dispatched
+    program that reads the pool under the kernel its reads RESOLVED to: a
+    prefill chunk and a decode round each move one series, the gather
+    engine's under ``gather`` and the pallas engine's under ``inplace``."""
+    from fault_tolerant_llm_training_tpu.obs.registry import (
+        default_registry)
+
+    cfg, gather, pallas = paged_engines
+    reads = default_registry().counter("paged_read_dispatches_total")
+    series = [(k, ph) for k in ("gather", "inplace")
+              for ph in ("prefill", "decode")]
+
+    def now():
+        return {s: reads.labels(kernel=s[0], phase=s[1]).value
+                for s in series}
+
+    row = np.array([1, 2, 3, 4], np.int32)
+    for eng, kernel in ((gather, "gather"), (pallas, "inplace")):
+        assert (eng.decode_read_kernel, eng.prefill_read_kernel) == (
+            kernel, kernel)
+        eng.reset()
+        before = now()
+        # 20 tokens over buckets (8, 16): a chunk of 16, then one of 4
+        tok = eng.prefill(0, list(range(3, 23)), block_row=row)
+        eng.decode_step(np.array([tok, 0], np.int32),
+                        np.array([True, False]), np.zeros(2, np.float32),
+                        np.ones(2, np.float32), np.zeros(2, np.int32),
+                        np.ones(2, np.int32),
+                        block_tables=np.stack([row, np.zeros_like(row)]))
+        moved = {s: v - before[s] for s, v in now().items() if v != before[s]}
+        assert moved == {(kernel, "prefill"): 2, (kernel, "decode"): 1}
 
 
 def test_fused_sampler_bitmatches_host_sampler(paged_engines):
