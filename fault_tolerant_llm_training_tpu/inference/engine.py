@@ -106,9 +106,9 @@ from ..obs.registry import default_registry
 from ..ops.attention import describe_paged_kernel, resolve_paged_kernel
 from ..parallel.mesh import use_mesh
 from ..parallel.sharding import param_shardings
-# Re-exported for backward compatibility: serve.py, scripts/decode_bench.py
-# and tests imported these from here before the cache wiring moved to
-# utils/ (so the trainer can use it without importing inference/).
+# Re-exported for backward compatibility: serve.py and tests imported
+# these from here before the cache wiring moved to utils/ (so the trainer
+# can use it without importing inference/).
 from ..utils.compile_cache import enable_compilation_cache  # noqa: F401
 from ..utils.device import describe_device
 from .kv_cache import (

@@ -277,9 +277,9 @@ def get_fleet_args(argv=None) -> argparse.Namespace:
                         "(inference/transport.py). Fleet peers are "
                         "separate OS processes with no shared fabric, so "
                         "'mem' auto-detects down to 'fs' here (with a "
-                        "log line); the in-process transport drills "
-                        "(decode_bench/chaos_campaign 'transport') are "
-                        "where the mem lane actually engages")
+                        "log line); the in-process transport drill "
+                        "(chaos_campaign 'transport') is where the mem "
+                        "lane actually engages")
     p.add_argument("--role", default="both",
                    choices=("both", "prefill", "decode"),
                    help="disaggregated pipeline role: 'prefill' admits "

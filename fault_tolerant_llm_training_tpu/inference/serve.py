@@ -203,7 +203,7 @@ def get_serve_args(argv=None) -> argparse.Namespace:
                         "attention kernels (fused into the block DMA "
                         "under --paged-kernel pallas). Roughly halves "
                         "bytes/block, so the same HBM budget holds ~2x "
-                        "the blocks (see BENCH_kv_quant_cpu.json); "
+                        "the blocks (the [KV QUANT] drain line says); "
                         "greedy argmax ties may flip vs bf16 — the "
                         "within-dtype bit-exactness contracts (exact "
                         "spec-verify, burst, spill/handoff) all still "

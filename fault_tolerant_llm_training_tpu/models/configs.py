@@ -233,8 +233,8 @@ PRESETS = {
         dim=64, n_layers=2, n_heads=4, n_kv_heads=2,
         multiple_of=32, rope_theta=10000.0, vocab_size=512, seq_len=128,
     ),
-    # Hermetic 4-layer shape: the speculative-decoding bench/test target
-    # (scripts/decode_bench.py spec_decode — "tiny" is its natural draft).
+    # Hermetic 4-layer shape: a speculative-decoding target ("tiny" is its
+    # natural draft).
     "tiny-4l": TransformerConfig(
         dim=64, n_layers=4, n_heads=4, n_kv_heads=2,
         multiple_of=32, rope_theta=10000.0, vocab_size=512, seq_len=128,

@@ -18,8 +18,8 @@ Where the cache lives, in order:
    after a pid, a time or a temp dir would never hit.
 
 Lives in utils/ so the training loop does not import inference/ for it;
-inference/engine.py re-exports the names (serve.py, scripts/decode_bench.py,
-tests).
+inference/engine.py re-exports the names (serve.py and the tests import
+them from there).
 """
 
 import os

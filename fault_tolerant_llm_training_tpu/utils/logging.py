@@ -195,12 +195,12 @@ AUDIT_KV_XPORT_FMT = ("[KV XPORT] {action} lane {lane} request {id}: "
                       "{blocks} block(s), {detail}")
 
 # --- Fleet-wide observability plane audit trail (obs/federate.py,
-# scripts/fleet_timeline.py, scripts/bench_trend.py) — the aggregation
-# layer's grep surface: each federation sweep (hosts scraped, series
-# re-exported, fleet rollups derived), each HLC-ordered timeline fold
-# with its anomaly count, and the bench-regression sentinel's verdict.
+# scripts/fleet_timeline.py) — the aggregation layer's grep surface: each
+# federation sweep (hosts scraped, series re-exported, fleet rollups
+# derived) and each HLC-ordered timeline fold with its anomaly count.
 # ci_nightly's federation drill and tests/test_fleetscope.py grep these,
-# frozen in tests/test_audit_contract.py like the rest. ---
+# frozen in tests/test_audit_contract.py like the rest. The two TREND
+# formats have no writer (ROADMAP D5). ---
 AUDIT_FLEETSCOPE_FEDERATE_FMT = ("[FLEETSCOPE] Federated {hosts} host(s): "
                                  "{series} series, {rollups} fleet "
                                  "rollup(s), {stale} stale, {failures} "

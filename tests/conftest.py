@@ -11,6 +11,7 @@ Subprocesses spawned by tests inherit the env vars and stay hermetic too.
 import os
 import subprocess
 import sys
+import tempfile
 
 os.environ["JAX_PLATFORMS"] = "cpu"
 # Virtual devices serialize on few cores: a collective legitimately waits
@@ -67,6 +68,31 @@ os._exit(0)  # skip jax.distributed.shutdown: its barrier can stall atexit
 """
 
 
+def _once_per_jaxlib(name: str, probe) -> bool:
+    """A capability of this jaxlib's CPU backend, probed once and kept by
+    jaxlib version under ``tempfile.gettempdir()`` (the run's own TMPDIR
+    where it has one, so two checkouts never read each other's verdict):
+    later sessions and xdist workers read it instead of paying the probe's
+    subprocesses again. A probe that cannot decide raises, and nothing is
+    kept."""
+    import jaxlib
+
+    cache = os.path.join(tempfile.gettempdir(),
+                         f"_ftl_{name}_probe_{jaxlib.__version__}")
+    try:
+        with open(cache) as f:
+            return f.read() == "1"
+    except OSError:
+        pass
+    ok = probe()
+    try:
+        with open(cache, "w") as f:
+            f.write("1" if ok else "0")
+    except OSError:
+        pass
+    return ok
+
+
 def _probe_multiprocess_cpu_jit() -> bool:
     """The multi-host pod tests run real 2-process jax.distributed clusters
     on the CPU backend. Some jaxlibs cannot execute multiprocess XLA
@@ -75,19 +101,12 @@ def _probe_multiprocess_cpu_jit() -> bool:
     WEDGES inside the collective (and then the shutdown barrier burns its
     full 5-minute timeout). Each pod test would then eat its entire
     subprocess timeout x3 retries, starving the rest of the suite. Probe
-    the exact failing op (a cross-process sync) once per jaxlib version in
-    throwaway subprocesses and let the pod tests skip when it can't run."""
+    the exact failing op (a cross-process sync) in throwaway subprocesses
+    (once per jaxlib version: ``_once_per_jaxlib``) and let the pod tests
+    skip when it can't run."""
     import socket
     import time
 
-    import jaxlib
-
-    cache = f"/tmp/_ftl_multiprocess_cpu_probe_{jaxlib.__version__}"
-    try:
-        with open(cache) as f:
-            return f.read() == "1"
-    except OSError:
-        pass
     with socket.socket() as s:
         s.bind(("localhost", 0))
         coord = f"localhost:{s.getsockname()[1]}"
@@ -109,11 +128,6 @@ def _probe_multiprocess_cpu_jit() -> bool:
         if p.poll() is None:
             p.kill()  # a wedged collective ignores SIGTERM
             p.wait()
-    try:
-        with open(cache, "w") as f:
-            f.write("1" if ok else "0")
-    except OSError:
-        pass
     return ok
 
 
@@ -122,9 +136,69 @@ def multiprocess_cpu_jit():
     """Pod tests that jit XLA computations across a real 2-process CPU
     cluster declare this fixture; it skips them on jaxlibs whose CPU
     backend cannot run multiprocess programs (see the probe above)."""
-    if not _probe_multiprocess_cpu_jit():
+    if not _once_per_jaxlib("multiprocess_cpu",
+                            _probe_multiprocess_cpu_jit):
         pytest.skip("this jaxlib's CPU backend cannot execute multiprocess "
                     "XLA computations (capability probe failed)")
+
+
+_DOT_PROBE = """
+import os, sys
+os.environ['JAX_PLATFORMS'] = 'cpu'
+import jax, jax.numpy as jnp
+jax.config.update('jax_platforms', 'cpu')
+sys.path.insert(0, sys.argv[1])
+from _tiny import REFUSED_DOT, REFUSED_DOT_SHAPES
+p, v = (jnp.ones(s, jnp.bfloat16) for s in REFUSED_DOT_SHAPES)
+jnp.einsum(REFUSED_DOT, p, v,
+           preferred_element_type=jnp.float32).block_until_ready()
+"""
+
+
+def _probe_cpu_bf16_serving_dot() -> bool:
+    """Whether this XLA:CPU executes the bf16 x bf16 -> float32 einsum of
+    ``ops/attention.py`` ``cached_attention`` (``_tiny.REFUSED_DOT``), in a
+    throwaway subprocess. In-process tests do not need it (they build
+    float32 engines through ``_tiny.tiny_cfg``); the serve CLI has no model
+    dtype input and serves every checkpoint at the preset's bf16.
+
+    False only when the subprocess's stderr names the refusal; a timeout or
+    any other failure (an import error, an OOM) raises with that text, so
+    it is neither kept as a verdict nor read as one."""
+    from _tiny import REFUSED_DOT_ERRORS
+
+    try:
+        out = subprocess.run(
+            [sys.executable, "-c", _DOT_PROBE, os.path.dirname(__file__)],
+            stdout=subprocess.DEVNULL, stderr=subprocess.PIPE, text=True,
+            timeout=120)
+    except subprocess.TimeoutExpired as e:
+        raise RuntimeError(f"serving-dot probe undecided: {e}") from None
+    if out.returncode == 0:
+        return True
+    if any(text in out.stderr for text in REFUSED_DOT_ERRORS):
+        return False
+    raise RuntimeError(f"serving-dot probe undecided (exit code "
+                       f"{out.returncode}): {out.stderr[-2000:]}")
+
+
+@pytest.fixture(scope="session")
+def cpu_bf16_serving_dot():
+    """Tests that drive the serve CLI in a subprocess declare this fixture;
+    it skips them where XLA:CPU refuses the decode program's dot, and fails
+    them where the probe could not tell."""
+    try:
+        runs = _once_per_jaxlib("cpu_bf16_dot", _probe_cpu_bf16_serving_dot)
+    except RuntimeError as e:
+        pytest.fail(str(e))
+    if not runs:
+        from _tiny import REFUSED_DOT
+
+        pytest.skip(
+            f"this XLA:CPU refuses the serving decode dot ({REFUSED_DOT!r} "
+            "at S = 1, bf16 x bf16 -> float32) and the serve CLI serves "
+            "every checkpoint at bf16; runs on the chip in chip_smoke.py's "
+            "serve phase")
 
 
 @pytest.fixture(scope="session")

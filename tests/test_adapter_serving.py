@@ -27,12 +27,7 @@ import os
 import numpy as np
 import pytest
 
-
-def _tiny_cfg(vocab=64, seq_len=64):
-    from fault_tolerant_llm_training_tpu.models.configs import get_config
-
-    return get_config("tiny", vocab_size=vocab, seq_len=seq_len,
-                      layer_impl="loop")
+from _tiny import tiny_cfg
 
 
 def _init_params(cfg, seed=0):
@@ -48,7 +43,7 @@ def _init_params(cfg, seed=0):
 
 @pytest.fixture(scope="module")
 def cfg_params():
-    cfg = _tiny_cfg()
+    cfg = tiny_cfg()
     return cfg, _init_params(cfg)
 
 
@@ -191,6 +186,10 @@ def test_heterogeneous_batch_bitmatches_sequential(cfg_params, tmp_path):
     m = sched.metrics()
     assert sorted(m["adapters_resident"]) == ["ta", "tb"]
     assert m["adapters_served"] == 2
+    # one page-in an adapter however many dispatches gather its pages, and
+    # the default pool holds both: nothing evicted, nothing rejected
+    assert (m["adapter_pageins"], m["adapter_evictions"],
+            m["adapter_rejects"]) == (2, 0, 0)
 
     for r in reqs:
         one, _ = _serve(_engine(cfg, params), arts,
@@ -265,7 +264,7 @@ def test_verify_pointer_rejects_corrupt_adapter_publish(tmp_path):
     (step_dir / "payload.bin").write_bytes(b"weights" * 64)
     write_manifest(str(step_dir), 20)
 
-    layout = AdapterLayout.from_cfg(_tiny_cfg(), 4)
+    layout = AdapterLayout.from_cfg(tiny_cfg(), 4)
     art = _write_adapter(tmp_path, layout, "ta", seed=3)
     sub = adapter_pointer(str(tmp_path), "ta", art)
     assert sub is not None and sub["rank"] == 4

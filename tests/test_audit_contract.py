@@ -193,6 +193,37 @@ def test_audit_strings_emitted_only_through_emit_audit():
         "obs.events.emit_audit:\n" + "\n".join(offenders))
 
 
+def test_engine_tests_take_their_model_from_the_tiny_helper():
+    """A test file that builds an ``InferenceEngine`` builds no ``tiny``
+    configuration of its own: ``get_config("tiny", ...)`` defaults to bf16,
+    and a bf16 engine dies on this XLA:CPU before its first assertion
+    (``tests/_tiny.py`` has the dot and the reason). The one place that
+    decides the dtype of an engine-level CPU test is ``_tiny.tiny_cfg``.
+    Training-side files that call ``get_config("tiny"`` are not its
+    business; neither is ``tests/perfbench/`` (the benchmark's own)."""
+    import ast
+
+    def called(node):
+        f = node.func
+        return f.id if isinstance(f, ast.Name) else getattr(f, "attr", "")
+
+    offenders = []
+    for path in sorted((REPO / "tests").glob("test_*.py")):
+        calls = [n for n in ast.walk(ast.parse(path.read_text()))
+                 if isinstance(n, ast.Call)]
+        own = [n.lineno for n in calls
+               if called(n) == "get_config" and n.args
+               and isinstance(n.args[0], ast.Constant)
+               and n.args[0].value == "tiny"]
+        if own and any(called(n) == "InferenceEngine" for n in calls):
+            offenders += [f"tests/{path.name}:{ln}" for ln in own]
+    assert not offenders, (
+        "build the model of an engine-level test with "
+        "`from _tiny import tiny_cfg` (float32 on this CPU), not with a "
+        "get_config(\"tiny\", ...) of the file's own:\n"
+        + "\n".join(offenders))
+
+
 def test_emit_audit_pairs_one_event_per_emission(tmp_path):
     """Every emit_audit call: the audit text logged exactly once,
     byte-identical, plus exactly one structured event with matching step."""

@@ -25,6 +25,7 @@ from pathlib import Path
 import numpy as np
 import pytest
 
+from _tiny import tiny_cfg
 from fault_tolerant_llm_training_tpu.deploy.publish import (
     POINTER_NAME,
     Pointer,
@@ -189,13 +190,6 @@ def test_watcher_offers_each_publish_exactly_once(tmp_path):
 
 
 # ------------------------------------------------------------- the swap itself
-def _tiny_cfg(vocab=64, seq_len=64):
-    from fault_tolerant_llm_training_tpu.models.configs import get_config
-
-    return get_config("tiny", vocab_size=vocab, seq_len=seq_len,
-                      layer_impl="loop")
-
-
 def _init_params(cfg, seed=0):
     import jax
     import jax.numpy as jnp
@@ -252,7 +246,7 @@ def test_hot_reload_preserves_in_flight_and_bitmatches_fresh_restore(
     )
     from fault_tolerant_llm_training_tpu.inference.scheduler import Scheduler
 
-    cfg = _tiny_cfg()
+    cfg = tiny_cfg()
     params_a = _init_params(cfg, seed=0)
     params_b = _init_params(cfg, seed=1)
     _save_train_checkpoint(tmp_path, "pub", 20, params_b)
@@ -306,7 +300,7 @@ def test_reload_rejects_corrupt_publish_and_serving_continues(tmp_path):
     )
     from fault_tolerant_llm_training_tpu.inference.scheduler import Scheduler
 
-    cfg = _tiny_cfg()
+    cfg = tiny_cfg()
     params_a = _init_params(cfg, seed=0)
     params_b = _init_params(cfg, seed=1)
     _save_train_checkpoint(tmp_path, "pub", 20, params_b)
@@ -357,10 +351,10 @@ def test_engine_reload_rejects_mismatched_trees():
         InferenceEngine,
     )
 
-    cfg = _tiny_cfg()
+    cfg = tiny_cfg()
     engine = InferenceEngine(cfg, _init_params(cfg, seed=0), slots=1,
                              max_len=32)
-    bigger = _tiny_cfg(vocab=96)
+    bigger = tiny_cfg(vocab_size=96)
     with pytest.raises(ValueError, match="does not match"):
         engine.reload_params(_init_params(bigger, seed=1))
     with pytest.raises(ValueError, match="without a draft"):
@@ -423,7 +417,7 @@ def test_adaptive_spec_rounds_stream_matches_fixed_width(tmp_path):
     from fault_tolerant_llm_training_tpu.inference.sampler import AdaptiveK
     from fault_tolerant_llm_training_tpu.inference.scheduler import Scheduler
 
-    cfg = _tiny_cfg()
+    cfg = tiny_cfg()
     params = _init_params(cfg, seed=0)
     draft_params = _init_params(cfg, seed=3)
     prompt = [5, 9, 2, 14, 7]

@@ -24,6 +24,7 @@ import os
 
 import pytest
 
+from _tiny import tiny_cfg
 from fault_tolerant_llm_training_tpu.ft.lease import (
     FileKVStore,
     LeaseRegistry,
@@ -365,9 +366,8 @@ def test_migrated_stream_bitmatches_unfailed_run(tmp_path, temperature):
         Request,
         Scheduler,
     )
-    from fault_tolerant_llm_training_tpu.models.configs import get_config
 
-    cfg = get_config("tiny", vocab_size=64, seq_len=64, layer_impl="loop")
+    cfg = tiny_cfg()
     import jax
     import jax.numpy as jnp
 

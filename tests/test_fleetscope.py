@@ -2,8 +2,7 @@
 under injected clock skew, two-writer merge, fold determinism), HLC
 stamps on every recorder, the /metrics federation aggregator (per-host
 re-export, fleet rollups, histogram merges, stale-host gauge), the
-exposition parser's escaping roundtrip, and the bench-regression
-sentinel (green on committed receipts, red on a synthetic regression)."""
+exposition parser's escaping roundtrip, and the HLC-causal timeline."""
 
 import json
 import os
@@ -32,7 +31,7 @@ from fault_tolerant_llm_training_tpu.obs.registry import (
 REPO = Path(__file__).resolve().parent.parent
 sys.path.insert(0, str(REPO))
 
-from scripts import bench_trend, fleet_timeline  # noqa: E402
+from scripts import fleet_timeline  # noqa: E402
 
 
 @pytest.fixture(autouse=True)
@@ -358,77 +357,3 @@ def test_timeline_orders_by_hlc_not_wall_clock(tmp_path):
     # the pre-HLC record is flagged as wall-clock-ordered
     legacy_line = [ln for ln in text.splitlines() if " step" in ln][0]
     assert " ~ " in legacy_line
-
-
-# ------------------------------------------------------------- sentinel
-
-def _write_receipt(root, name, **fields):
-    with open(os.path.join(root, name), "w") as fh:
-        json.dump(dict({"bench": name}, **fields), fh)
-
-
-def test_bench_trend_green_then_regression(tmp_path, capsys):
-    receipts = tmp_path / "receipts"
-    receipts.mkdir()
-    _write_receipt(str(receipts), "BENCH_disagg_cpu.json", value=2.0)
-    _write_receipt(str(receipts), "BENCH_serving_latency_cpu.json",
-                   value=40.0)
-    history = str(tmp_path / "trend.jsonl")
-    rc = bench_trend.main(["--receipts-dir", str(receipts),
-                           "--history", history])
-    assert rc == 0
-    assert len(bench_trend.load_history(history)) == 1  # appended
-    # higher-is-better metric degrades 12% -> fail, metric named
-    degraded = tmp_path / "degraded"
-    degraded.mkdir()
-    _write_receipt(str(degraded), "BENCH_disagg_cpu.json", value=1.76)
-    rc = bench_trend.main(["--receipts-dir", str(receipts),
-                           "--history", history,
-                           "--current-dir", str(degraded)])
-    assert rc == 3
-    out = capsys.readouterr().out
-    assert "REGRESSION: BENCH_disagg_cpu.json value" in out
-    # --current-dir runs never pollute the history
-    assert len(bench_trend.load_history(history)) == 1
-    # lower-is-better: p99 latency UP 20% is also a regression
-    worse_lat = tmp_path / "lat"
-    worse_lat.mkdir()
-    _write_receipt(str(worse_lat), "BENCH_serving_latency_cpu.json",
-                   value=48.0)
-    assert bench_trend.main(["--receipts-dir", str(receipts),
-                             "--history", history,
-                             "--current-dir", str(worse_lat)]) == 3
-    # within tolerance passes
-    fine = tmp_path / "fine"
-    fine.mkdir()
-    _write_receipt(str(fine), "BENCH_disagg_cpu.json", value=1.95)
-    assert bench_trend.main(["--receipts-dir", str(receipts),
-                             "--history", history,
-                             "--current-dir", str(fine)]) == 0
-
-
-def test_bench_trend_baseline_is_best_ever_recorded(tmp_path):
-    receipts = tmp_path / "receipts"
-    receipts.mkdir()
-    _write_receipt(str(receipts), "BENCH_disagg_cpu.json", value=2.0)
-    history = tmp_path / "trend.jsonl"
-    history.write_text(json.dumps(
-        {"ts": 1.0, "metrics":
-         {"BENCH_disagg_cpu.json": {"value": 3.0}}}) + "\n")
-    base = bench_trend.baseline_from(
-        bench_trend.load_history(str(history)),
-        bench_trend.read_pinned(str(receipts)),
-        "BENCH_disagg_cpu.json", "value", "higher")
-    assert base == 3.0  # history high-water mark beats the committed one
-    # the committed 2.0 is a 33% regression against that baseline
-    rc = bench_trend.main(["--receipts-dir", str(receipts),
-                           "--history", str(history), "--no-history"])
-    assert rc == 3
-
-
-def test_bench_trend_pins_cover_committed_receipts():
-    committed = bench_trend.read_pinned(str(REPO))
-    # every pinned receipt that exists in the repo parses to >=1 metric
-    for receipt in committed:
-        assert committed[receipt], receipt
-    assert "BENCH_disagg_cpu.json" in committed

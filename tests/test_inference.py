@@ -24,18 +24,13 @@ from pathlib import Path
 import numpy as np
 import pytest
 
+from _tiny import tiny_cfg
+
 REPO = Path(__file__).resolve().parent.parent
 CACHE = "/tmp/jax_test_compile_cache"
 
 
 # --------------------------------------------------------------- 1. numerics
-def _tiny_cfg(layer_impl="loop", vocab=64, seq_len=64):
-    from fault_tolerant_llm_training_tpu.models.configs import get_config
-
-    return get_config("tiny", vocab_size=vocab, seq_len=seq_len,
-                      layer_impl=layer_impl)
-
-
 def _init_params(cfg, seed=0):
     import jax
     import jax.numpy as jnp
@@ -51,11 +46,18 @@ def test_cached_decode_bitmatches_uncached_forward():
     """Prefill writes the prompt's KV and decode extends it one token at a
     time; at EVERY position the cached logits must equal the teacher-forcing
     forward bitwise — same projections, same RoPE table values, same
-    fp32-softmax attention order (ops/attention.py cached_attention)."""
+    fp32-softmax attention order (ops/attention.py cached_attention).
+
+    A bf16 contract, so it asks the helper for bfloat16 (one slot: XLA:CPU
+    executes that dot). A one-token step is a matmul of another shape than
+    the 24-token forward, so its float32 accumulation order differs in the
+    last place; bf16 products are exact in float32 and every matmul's
+    float32 sum is rounded to 8 bits of mantissa, which takes that away.
+    At float32 only ``allclose`` would hold."""
     import jax
     import jax.numpy as jnp
 
-    cfg = _tiny_cfg("loop")
+    cfg = tiny_cfg(dtype="bfloat16")
     model, params = _init_params(cfg)
     rng = np.random.default_rng(0)
     T = 24
@@ -89,7 +91,7 @@ def test_engine_greedy_matches_uncached_autoregression(layer_impl):
 
     from fault_tolerant_llm_training_tpu.inference.engine import InferenceEngine
 
-    cfg = _tiny_cfg(layer_impl)
+    cfg = tiny_cfg(layer_impl=layer_impl)
     model, params = _init_params(cfg)
     rng = np.random.default_rng(1)
     prompt = rng.integers(3, cfg.vocab_size, size=9).tolist()
@@ -131,7 +133,7 @@ def test_generation_deterministic_across_engine_rebuilds():
     from fault_tolerant_llm_training_tpu.inference.scheduler import (
         Request, Scheduler)
 
-    cfg = _tiny_cfg("loop")
+    cfg = tiny_cfg()
     _, params = _init_params(cfg)
     prompt = [5, 17, 9, 33]
 
@@ -314,9 +316,12 @@ def _serve_argv(ckpt, extra):
             "--seed", "3"] + extra
 
 
-def test_serve_restores_checkpoint_and_completes(trained_ckpt):
+def test_serve_restores_checkpoint_and_completes(cpu_bf16_serving_dot,
+                                                 trained_ckpt):
     """Happy path: restore the trained checkpoint, run >= 2 concurrent
-    requests through the scheduler, finish every request, exit 0."""
+    requests through the scheduler, finish every request, exit 0. The
+    serve CLI runs at the preset's bf16 (no model-dtype input), hence the
+    capability fixture: skipped where XLA:CPU refuses the decode dot."""
     rc, out, _ = _run_serve(_serve_argv(trained_ckpt, [
         "--prompt", "alpha bravo", "--prompt", "charlie delta",
         "--prompt", "echo alpha", "--max-new-tokens", "8"]))
@@ -331,7 +336,8 @@ def test_serve_restores_checkpoint_and_completes(trained_ckpt):
     assert "[EXIT HANDLER]" not in out  # no drain on the happy path
 
 
-def test_serve_sigterm_drains_and_exits_zero(trained_ckpt):
+def test_serve_sigterm_drains_and_exits_zero(cpu_bf16_serving_dot,
+                                             trained_ckpt):
     """The receipt: SIGTERM mid-generation -> admission stops, in-flight
     requests finish, queued ones are reported unserved, process exits 0
     with the audit trail. Transcript saved to logs/serving_e2e.log."""

@@ -26,15 +26,10 @@ from pathlib import Path
 import numpy as np
 import pytest
 
+from _tiny import tiny_cfg
+
 REPO = Path(__file__).resolve().parent.parent
 CACHE = "/tmp/jax_test_compile_cache"
-
-
-def _tiny_cfg(vocab=64, seq_len=64):
-    from fault_tolerant_llm_training_tpu.models.configs import get_config
-
-    return get_config("tiny", vocab_size=vocab, seq_len=seq_len,
-                      layer_impl="loop")
 
 
 # ------------------------------------------------------------- 1. numerics
@@ -64,7 +59,8 @@ def test_int8_pool_halves_block_bytes():
         QuantPool, bf16_block_bytes, block_bytes, block_layout,
         init_paged_cache)
 
-    cfg = _tiny_cfg()
+    # bfloat16: the capacity receipt is int8 against a 2-byte pool
+    cfg = tiny_cfg(dtype="bfloat16")
     import jax.numpy as jnp
 
     bf16 = init_paged_cache(cfg, slots=2, max_len=32, block_size=8)
@@ -92,7 +88,7 @@ def test_scale_set_at_offset0_and_kept_at_higher_offsets():
     from fault_tolerant_llm_training_tpu.inference.kv_cache import (
         KV_QUANT_QMAX, init_paged_cache, write_paged_kv)
 
-    cfg = _tiny_cfg()
+    cfg = tiny_cfg()
     cache = init_paged_cache(cfg, slots=1, max_len=32, block_size=8,
                              dtype=jnp.int8)
     pool = cache.k[0]
@@ -123,7 +119,7 @@ def test_cow_copy_carries_scale_bitwise():
     from fault_tolerant_llm_training_tpu.inference.kv_cache import (
         QuantPool, copy_kv_block, init_paged_cache)
 
-    cfg = _tiny_cfg()
+    cfg = tiny_cfg()
     cache = init_paged_cache(cfg, slots=1, max_len=32, block_size=8,
                              dtype=jnp.int8)
     rng = np.random.default_rng(2)
@@ -168,7 +164,7 @@ def test_export_import_roundtrips_q_and_scale(tmp_path):
         export_blocks, import_blocks, init_paged_cache,
         verify_block_artifact)
 
-    cfg = _tiny_cfg()
+    cfg = tiny_cfg()
     cache = _filled_int8_cache(cfg)
     d = str(tmp_path / "art")
     man = export_blocks(cache, [3, 1, 2], d, length=17,
@@ -206,7 +202,7 @@ def test_import_reject_matrix_int8(tmp_path):
         BLOCK_MANIFEST_NAME, KVBlockIntegrityError, export_blocks,
         import_blocks, init_paged_cache)
 
-    cfg = _tiny_cfg()
+    cfg = tiny_cfg()
     cache = _filled_int8_cache(cfg)
     fresh = init_paged_cache(cfg, slots=2, max_len=32, block_size=8,
                              dtype=jnp.int8)
@@ -268,7 +264,7 @@ def test_mixed_dtype_import_rejected_both_ways(tmp_path):
         KVBlockIntegrityError, export_blocks, import_blocks,
         init_paged_cache)
 
-    cfg = _tiny_cfg()
+    cfg = tiny_cfg()
     int8_cache = _filled_int8_cache(cfg)
     bf16_cache = init_paged_cache(cfg, slots=2, max_len=32, block_size=8)
 
@@ -320,7 +316,7 @@ def test_engine_kv_dtype_validation():
     from fault_tolerant_llm_training_tpu.inference.engine import (
         InferenceEngine)
 
-    cfg = _tiny_cfg()
+    cfg = tiny_cfg()
     params = _init_params(cfg)
     with pytest.raises(ValueError, match="unknown kv_dtype"):
         InferenceEngine(cfg, params, slots=1, max_len=32, kv_dtype="fp8")
@@ -350,7 +346,7 @@ def test_int8_streams_deterministic_and_burst_bitmatches_per_token():
         Request, Scheduler)
 
     enable_compilation_cache(CACHE)
-    cfg = _tiny_cfg()
+    cfg = tiny_cfg()
     params = _init_params(cfg)
     rng = np.random.default_rng(5)
     reqs = [Request(id="g", prompt=rng.integers(3, 64, size=12).tolist(),
@@ -395,7 +391,7 @@ def test_int8_fused_sampler_bitmatches_host_sampler():
         sample_slot_tokens)
 
     enable_compilation_cache(CACHE)
-    cfg = _tiny_cfg()
+    cfg = tiny_cfg()
     params = _init_params(cfg)
     eng = InferenceEngine(cfg, params, slots=2, max_len=32,
                           prefill_buckets=(8, 16), kv_block_size=8,
@@ -442,7 +438,7 @@ def test_greedy_spec_stream_bitmatches_nonspec_under_int8():
         InferenceEngine, enable_compilation_cache)
 
     enable_compilation_cache(CACHE)
-    cfg = _tiny_cfg()
+    cfg = tiny_cfg()
     params = _init_params(cfg, seed=0)
     draft_params = _init_params(cfg, seed=9)
     rng = np.random.default_rng(6)
@@ -477,7 +473,7 @@ def test_spill_restore_bitwise_under_int8(tmp_path):
         Request, Scheduler)
 
     enable_compilation_cache(CACHE)
-    cfg = _tiny_cfg(seq_len=128)
+    cfg = tiny_cfg(seq_len=128)
     params = _init_params(cfg)
 
     def build(num_blocks=None):
@@ -531,7 +527,7 @@ def test_weights_artifact_publish_verify_reload(tmp_path):
     from fault_tolerant_llm_training_tpu.training.step import make_optimizer
 
     enable_compilation_cache(CACHE)
-    cfg = _tiny_cfg()
+    cfg = tiny_cfg()
     params_a = _init_params(cfg, seed=0)
     params_b = _init_params(cfg, seed=1)
     state = TrainState(step=jnp.asarray(20, jnp.int32), params=params_b,

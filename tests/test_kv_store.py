@@ -39,6 +39,7 @@ import os
 import numpy as np
 import pytest
 
+from _tiny import tiny_cfg
 from fault_tolerant_llm_training_tpu.ft.retry import (
     RetryDeadlineExceeded,
     retry_with_backoff,
@@ -133,11 +134,10 @@ def compiled_engine():
 
     from fault_tolerant_llm_training_tpu.inference.engine import (
         InferenceEngine, enable_compilation_cache)
-    from fault_tolerant_llm_training_tpu.models.configs import get_config
     from fault_tolerant_llm_training_tpu.models.llama import Transformer
 
     enable_compilation_cache(CACHE)
-    cfg = get_config("tiny", vocab_size=64, seq_len=64, layer_impl="loop")
+    cfg = tiny_cfg()
     params = Transformer(cfg).init(
         jax.random.PRNGKey(0), jnp.zeros((1, cfg.seq_len), jnp.int32)
     )["params"]
@@ -275,12 +275,16 @@ def test_fetched_stream_bitmatches_local_prefill(tmp_path, compiled_engine):
     _, cold = _serve(eng, reqs(), None)            # no store: pure local
 
     pub, _ = _serve(eng, reqs(), store)            # publisher host
-    assert pub.store_publishes >= 1 and pub.store_fetches == 0
+    # one train a distinct terminal hash: r's two blocks, s's one
+    assert (pub.store_publishes, pub.store_fetches) == (2, 0)
 
     fetch_store = BlockStore(str(tmp_path), writer="h1")
     con, warm = _serve(eng, reqs(), fetch_store)   # consumer host
-    assert con.store_fetches >= 1 and con.store_fetch_blocks >= 2
-    assert con.store_rejects == 0
+    # r's whole prompt comes over the store in ONE fetch of its 2 blocks
+    # (cross-host hit rate 32 / 32); s then hits r's first block locally,
+    # and nothing is published twice (content-addressed dedup)
+    assert (con.store_fetches, con.store_fetch_blocks) == (1, 2)
+    assert (con.store_publishes, con.store_rejects) == (0, 0)
     assert warm == cold                            # bit-exact streams
     m = con.metrics()
     assert m["kv_store_fetches"] == con.store_fetches

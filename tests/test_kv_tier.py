@@ -31,12 +31,7 @@ import os
 import numpy as np
 import pytest
 
-
-def _tiny_cfg(vocab=64, seq_len=128):
-    from fault_tolerant_llm_training_tpu.models.configs import get_config
-
-    return get_config("tiny", vocab_size=vocab, seq_len=seq_len,
-                      layer_impl="loop")
+from _tiny import tiny_cfg
 
 
 # ------------------------------------------------------------- 1. primitive
@@ -64,7 +59,7 @@ def test_block_roundtrip_bitwise(tmp_path):
         artifact_bytes, export_blocks, import_blocks, init_paged_cache,
         verify_block_artifact)
 
-    cfg = _tiny_cfg(seq_len=64)
+    cfg = tiny_cfg()
     cache = _filled_cache(cfg)
     d = str(tmp_path / "art")
     man = export_blocks(cache, [3, 1, 2], d, length=17,
@@ -92,7 +87,7 @@ def test_null_block_refused_both_ways(tmp_path):
     from fault_tolerant_llm_training_tpu.inference.kv_cache import (
         export_blocks, import_blocks)
 
-    cfg = _tiny_cfg(seq_len=64)
+    cfg = tiny_cfg()
     cache = _filled_cache(cfg)
     with pytest.raises(ValueError, match="null block"):
         export_blocks(cache, [0, 1], str(tmp_path / "a"), length=4)
@@ -112,7 +107,7 @@ def test_import_reject_matrix(tmp_path):
         BLOCK_MANIFEST_NAME, KVBlockIntegrityError, export_blocks,
         import_blocks, init_paged_cache)
 
-    cfg = _tiny_cfg(seq_len=64)
+    cfg = tiny_cfg()
     cache = _filled_cache(cfg)
     fresh = init_paged_cache(cfg, slots=2, max_len=32, block_size=8)
 
@@ -183,7 +178,7 @@ def tier_setup():
         Request, Scheduler)
     from fault_tolerant_llm_training_tpu.models.llama import Transformer
 
-    cfg = _tiny_cfg()
+    cfg = tiny_cfg(seq_len=128)
     model = Transformer(cfg)
     params = model.init(jax.random.PRNGKey(0),
                         jnp.zeros((1, cfg.seq_len), jnp.int32))["params"]
@@ -211,23 +206,31 @@ def tier_setup():
     return {"build": build, "reqs": reqs, "ref": ref}
 
 
-def _run_constrained(tier_setup, tmp_path, on_spill=None, num_blocks=18):
+def _run_constrained(tier_setup, tmp_path, on_spill=None, num_blocks=18,
+                     spill=True):
     from fault_tolerant_llm_training_tpu.inference.scheduler import Scheduler
 
     sched = Scheduler(tier_setup["build"](num_blocks=num_blocks),
-                      spill_dir=str(tmp_path / "tier"), on_spill=on_spill)
+                      spill_dir=str(tmp_path / "tier") if spill else None,
+                      on_spill=on_spill)
     for r in tier_setup["reqs"]:
         sched.submit(r)
     sched.run()
     return sched, {c.request_id: c.tokens for c in sched.completed}
 
 
-def test_spill_restore_bitwise(tier_setup, tmp_path):
+@pytest.mark.parametrize("spill", [True, False], ids=["spill", "wait"])
+def test_spill_restore_bitwise(tier_setup, tmp_path, spill):
     """17-usable-block pool vs three requests needing 20: the scheduler
     must spill, restore, and still produce the exact unconstrained
-    streams — with the cross-tier leak guard clean at drain."""
-    sched, out = _run_constrained(tier_setup, tmp_path)
-    assert sched.spill_exports >= 1 and sched.spill_restores >= 1
+    streams — with the cross-tier leak guard clean at drain. Without a
+    spill tier ("wait") the late request head-of-line waits instead:
+    nothing is exported and the streams are the same again."""
+    sched, out = _run_constrained(tier_setup, tmp_path, spill=spill)
+    if spill:
+        assert sched.spill_exports >= 1 and sched.spill_restores >= 1
+    else:
+        assert (sched.spill_exports, sched.spill_restores) == (0, 0)
     assert sched.spill_rejects == 0
     assert out == tier_setup["ref"]
     assert sched.audit_block_leaks(strict=True) == []
@@ -427,7 +430,7 @@ def test_router_verifies_artifact_before_shipping(tmp_path):
         export_blocks)
     from fault_tolerant_llm_training_tpu.inference.router import Router
 
-    cfg = _tiny_cfg(seq_len=64)
+    cfg = tiny_cfg()
     cache = _filled_cache(cfg)
     art = str(tmp_path / "handoff_rv_g0")
     export_blocks(cache, [1, 2], art, length=9)

@@ -29,14 +29,7 @@ Evidence ladder for the in-place decode path:
 import numpy as np
 import pytest
 
-
-def _tiny_cfg(**kw):
-    from fault_tolerant_llm_training_tpu.models.configs import get_config
-
-    kw.setdefault("vocab_size", 64)
-    kw.setdefault("seq_len", 64)
-    kw.setdefault("layer_impl", "loop")
-    return get_config("tiny", **kw)
+from _tiny import tiny_cfg
 
 
 # -------------------------------------------------------------------- 1. kernel
@@ -450,7 +443,7 @@ def test_multihead_attention_ring_impl_routes_dense():
     from fault_tolerant_llm_training_tpu.ops.attention import (
         multihead_attention, xla_attention)
 
-    cfg = _tiny_cfg(attention_impl="ring")    # admitted by __post_init__
+    cfg = tiny_cfg(attention_impl="ring")    # admitted by __post_init__
     assert cfg.attention_impl == "ring"
     rng = np.random.default_rng(11)
     q = jnp.asarray(rng.standard_normal((2, 16, 4, 8)), jnp.float32)
@@ -462,13 +455,11 @@ def test_multihead_attention_ring_impl_routes_dense():
 
 
 def test_config_validates_paged_kernel():
-    from fault_tolerant_llm_training_tpu.models.configs import get_config
-
-    assert _tiny_cfg(paged_kernel="pallas").paged_kernel == "pallas"
-    assert _tiny_cfg(paged_kernel="gather").paged_kernel == "gather"
-    assert _tiny_cfg().paged_kernel == "auto"      # the default is the rule
+    assert tiny_cfg(paged_kernel="pallas").paged_kernel == "pallas"
+    assert tiny_cfg(paged_kernel="gather").paged_kernel == "gather"
+    assert tiny_cfg().paged_kernel == "auto"      # the default is the rule
     with pytest.raises(ValueError, match="paged_kernel"):
-        get_config("tiny", paged_kernel="cuda")
+        tiny_cfg(paged_kernel="cuda")
 
 
 # -------------------------------------------------------------------- 3. engine
@@ -483,7 +474,7 @@ def paged_engines():
         InferenceEngine)
     from fault_tolerant_llm_training_tpu.models.llama import Transformer
 
-    cfg = _tiny_cfg()
+    cfg = tiny_cfg()
     model = Transformer(cfg)
     params = model.init(jax.random.PRNGKey(0),
                         jnp.zeros((1, cfg.seq_len), jnp.int32))["params"]
@@ -504,7 +495,7 @@ def test_engine_rejects_bad_kernel_combinations():
         InferenceEngine)
     from fault_tolerant_llm_training_tpu.models.llama import Transformer
 
-    cfg = _tiny_cfg()
+    cfg = tiny_cfg()
     params = Transformer(cfg).init(
         jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"]
     with pytest.raises(ValueError, match="paged_kernel"):
@@ -640,9 +631,16 @@ def test_burst_streams_bitmatch_sequential_across_kernels(paged_engines):
     _, b8 = _stream(gather, list(reqs), burst=8)
     assert seq == b4 == b8
 
-    _, pseq = _stream(pallas, list(reqs), burst=1)
+    ps, pseq = _stream(pallas, list(reqs), burst=1)
     _, pb4 = _stream(pallas, list(reqs), burst=4)
     assert pseq == pb4
+    # under "pallas" EVERY prefill chunk is read in place, under "gather"
+    # none (a nonzero gather count there = the S>1 fallback came back)
+    pm, gm = ps.metrics(), s4.metrics()
+    assert pm["prefill_inplace_chunks"] == pm["prefill_chunks"] > 0
+    assert pm["prefill_gather_chunks"] == 0
+    assert gm["prefill_gather_chunks"] == gm["prefill_chunks"] > 0
+    assert gm["prefill_inplace_chunks"] == 0
     # greedy slots bit-match across kernels (sampled slots are only fp32-close
     # in logit space, so a top-p boundary may legitimately flip)
     for r in ("r0", "r2"):

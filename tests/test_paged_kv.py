@@ -30,12 +30,7 @@ Evidence ladder for the block-paged serving cache:
 import numpy as np
 import pytest
 
-
-def _tiny_cfg(vocab=64, seq_len=64):
-    from fault_tolerant_llm_training_tpu.models.configs import get_config
-
-    return get_config("tiny", vocab_size=vocab, seq_len=seq_len,
-                      layer_impl="loop")
+from _tiny import tiny_cfg
 
 
 # --------------------------------------------------------------------- 1. ops
@@ -299,7 +294,7 @@ def engines():
         InferenceEngine)
     from fault_tolerant_llm_training_tpu.models.llama import Transformer
 
-    cfg = _tiny_cfg()
+    cfg = tiny_cfg()
     model = Transformer(cfg)
     params = model.init(jax.random.PRNGKey(0),
                         jnp.zeros((1, cfg.seq_len), jnp.int32))["params"]
@@ -350,16 +345,28 @@ def test_paged_stream_bitmatches_ring(engines):
     assert not paged_sched.block_tables.any()
 
 
-def test_chunked_prefill_logits_bitmatch_single_shot(engines):
+def test_chunked_prefill_logits_bitmatch_single_shot():
     """Model level, eager: feeding a 20-token prompt through the paged cache
     in two chunks (16 then 4) yields BITWISE the same last-chunk logits as
-    one single-shot 20-token call, and both equal the uncached forward."""
+    one single-shot 20-token call, and both equal the uncached forward.
+
+    A bf16 contract, so it asks the helper for bfloat16 (one slot: XLA:CPU
+    executes that dot). The chunks are matmuls of other shapes than the
+    single shot, so their float32 accumulation order differs (1.8e-6 at
+    float32, seen); bf16 products are exact in float32 and every matmul's
+    float32 sum is rounded to 8 bits of mantissa, which takes such
+    last-place differences away. At float32 only ``allclose`` would hold."""
+    import jax
     import jax.numpy as jnp
 
     from fault_tolerant_llm_training_tpu.inference.kv_cache import (
         init_paged_cache)
+    from fault_tolerant_llm_training_tpu.models.llama import Transformer
 
-    cfg, model, params, _, _ = engines
+    cfg = tiny_cfg(dtype="bfloat16")
+    model = Transformer(cfg)
+    params = model.init(jax.random.PRNGKey(0),
+                        jnp.zeros((1, cfg.seq_len), jnp.int32))["params"]
     rng = np.random.default_rng(2)
     ids = jnp.asarray(rng.integers(3, cfg.vocab_size, size=(1, 20)),
                       jnp.int32)
@@ -575,7 +582,8 @@ def _run_sched(engine, requests, prefill_batch=1):
     return sched, {c.request_id: c.tokens for c in sched.completed}
 
 
-def test_packed_prefill_streams_bitmatch_sequential(engines, packed_engine):
+def test_packed_prefill_streams_bitmatch_sequential(engines, packed_engine,
+                                                    monkeypatch):
     """Mixed greedy/sampled workload with multi-chunk prompts and a slot
     turnover: the packed lane's token streams must be BITWISE identical
     to sequential one-prompt-at-a-time prefill (same per-row chunk
@@ -606,6 +614,36 @@ def test_packed_prefill_streams_bitmatch_sequential(engines, packed_engine):
     # lands on the gather counter, none on the in-place one
     assert m["prefill_gather_chunks"] == m["prefill_chunks"]
     assert m["prefill_inplace_chunks"] == 0
+
+    # a FULL wave (P = 2 equal prompts of 16 + 8 tokens) fills every row of
+    # every packed round: 2 rounds x 2 rows, occupancy exactly 1
+    wave = [Request(id=f"w{i}", prompt=[5 + i] * 24, max_new_tokens=2)
+            for i in range(2)]
+    full, _ = _run_sched(packed_engine, wave, prefill_batch=2)
+    fm = full.metrics()
+    assert (fm["prefill_packed_rounds"], fm["prefill_packed_rows"]) == (2, 4)
+    assert fm["prefill_packed_occupancy"] == 1.0
+
+    # a packed round is bounded (P x bucket positions), so a short request
+    # decodes BETWEEN the packed rounds a long prompt still needs
+    timeline = []
+
+    def noting(tag, call):
+        def spy(*a, **k):
+            timeline.append(tag)
+            return call(*a, **k)
+        return spy
+
+    monkeypatch.setattr(packed_engine, "prefill_packed",
+                        noting("P", packed_engine.prefill_packed))
+    monkeypatch.setattr(packed_engine, "decode_step",
+                        noting("D", packed_engine.decode_step))
+    _run_sched(packed_engine,
+               [Request(id="short", prompt=[7] * 8, max_new_tokens=6),
+                Request(id="long", prompt=[9] * 24, max_new_tokens=2)],
+               prefill_batch=2)
+    last_p = len(timeline) - 1 - timeline[::-1].index("P")
+    assert "D" in timeline[timeline.index("P"):last_p], timeline
 
 
 def test_drain_mid_packed_prefill_frees_all_rows(packed_engine):

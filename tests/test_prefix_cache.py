@@ -38,6 +38,8 @@ import logging
 import numpy as np
 import pytest
 
+from _tiny import tiny_cfg
+
 CACHE = "/tmp/jax_test_compile_cache"
 
 
@@ -444,11 +446,10 @@ def compiled_engine():
 
     from fault_tolerant_llm_training_tpu.inference.engine import (
         InferenceEngine, enable_compilation_cache)
-    from fault_tolerant_llm_training_tpu.models.configs import get_config
     from fault_tolerant_llm_training_tpu.models.llama import Transformer
 
     enable_compilation_cache(CACHE)
-    cfg = get_config("tiny", vocab_size=64, seq_len=64, layer_impl="loop")
+    cfg = tiny_cfg()
     params = Transformer(cfg).init(
         jax.random.PRNGKey(0), jnp.zeros((1, cfg.seq_len), jnp.int32)
     )["params"]
@@ -492,8 +493,12 @@ def test_cached_streams_bitmatch_uncached(compiled_engine):
     ]
     on_sched, on_out = _run_streams(eng, reqs, cache_on=True)
     m = on_sched.metrics()
-    assert m["prefix_hits"] >= 3 and m["prefix_hit_tokens"] > 0
-    assert m["prefix_cow_copies"] >= 1          # the full-prompt repeats
+    # block accounting is exact: "sampled" hits the shared block (16), each
+    # full-prompt repeat hits 15 (its last token is computed again, on a
+    # COW copy); the rate is hit tokens over all prompt tokens looked up
+    assert (m["prefix_hits"], m["prefix_hit_tokens"]) == (3, 16 + 15 + 15)
+    assert m["prefix_hit_rate"] == 46 / sum(len(r.prompt) for r in reqs)
+    assert m["prefix_cow_copies"] == 2          # the full-prompt repeats
     assert on_sched.allocator.used_count == on_sched.prefix_cache.cached_blocks
 
     off_sched, off_out = _run_streams(eng, reqs, cache_on=False)
@@ -560,8 +565,8 @@ def test_packed_prefill_streams_bitmatch_sequential_with_hits(compiled_engine):
 def test_spec_exact_shared_prefix_stream_bitmatches(compiled_engine):
     """Speculative decoding (exact verify) with prefix caching on: shared
     and repeated prompts still produce the non-speculative engine's exact
-    greedy streams — the dual-pool admission (draft pool opts out of
-    caching) and the COW path compose without breaking the PR-4 bitwise
+    greedy streams — the dual-pool admission (the draft pool mirrors the
+    cache) and the COW path compose without breaking the PR-4 bitwise
     guarantee."""
     import jax
     import jax.numpy as jnp
@@ -594,7 +599,11 @@ def test_spec_exact_shared_prefix_stream_bitmatches(compiled_engine):
     m = spec_sched.metrics()
     assert m["spec_rounds"] > 0
     assert m["prefix_hits"] >= 2 and m["prefix_cow_copies"] >= 1
-    # draft pool opted out: fully free after drain, no cache interaction
+    # the draft pool mirrors the radix scheme (scheduler.py, "DRAFT-pool
+    # mirror"): after drain it holds its cached blocks and nothing else
+    assert (spec_sched.draft_allocator.used_count
+            == spec_sched.draft_prefix_cache.cached_blocks)
+    spec_sched.draft_prefix_cache.flush()
     assert (spec_sched.draft_allocator.free_count
             == spec_sched.draft_allocator.capacity)
     assert (spec_sched.allocator.used_count

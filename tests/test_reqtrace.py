@@ -316,7 +316,8 @@ def _save_tiny_checkpoint(tmp_path, job, step):
 
 
 @pytest.mark.slow
-def test_serve_e2e_live_metrics_scrape_and_trace_stitch(tmp_path):
+def test_serve_e2e_live_metrics_scrape_and_trace_stitch(
+        cpu_bf16_serving_dot, tmp_path):
     """The whole pipeline against a REAL serve.py process: requests flow
     in through --request-file (one with a caller-minted trace_id), the
     latency histograms are scraped LIVE from /metrics while the process

@@ -30,6 +30,8 @@ from pathlib import Path
 import numpy as np
 import pytest
 
+from _tiny import tiny_cfg
+
 REPO = Path(__file__).resolve().parent.parent
 CACHE = "/tmp/jax_test_compile_cache"
 
@@ -116,11 +118,9 @@ def test_verify_chunk_scores_agree_with_sequential_steps():
 
     from fault_tolerant_llm_training_tpu.inference.kv_cache import (
         init_paged_cache)
-    from fault_tolerant_llm_training_tpu.models.configs import get_config
     from fault_tolerant_llm_training_tpu.models.llama import Transformer
 
-    cfg = get_config("tiny", vocab_size=64, seq_len=64,
-                     dtype=jnp.float32, param_dtype=jnp.float32)
+    cfg = tiny_cfg()
     model = Transformer(cfg)
     params = model.init(jax.random.PRNGKey(0),
                         jnp.zeros((1, cfg.seq_len), jnp.int32))["params"]
@@ -170,11 +170,10 @@ def test_greedy_spec_stream_bitmatches_nonspec_paged():
         InferenceEngine, enable_compilation_cache)
     from fault_tolerant_llm_training_tpu.inference.scheduler import (
         Request, Scheduler)
-    from fault_tolerant_llm_training_tpu.models.configs import get_config
     from fault_tolerant_llm_training_tpu.models.llama import Transformer
 
     enable_compilation_cache(CACHE)
-    cfg = get_config("tiny", vocab_size=64, seq_len=64)
+    cfg = tiny_cfg()
     model = Transformer(cfg)
     params = model.init(jax.random.PRNGKey(0),
                         jnp.zeros((1, cfg.seq_len), jnp.int32))["params"]
@@ -467,11 +466,10 @@ def test_greedy_tree_spec_stream_bitmatches_nonspec_paged():
         InferenceEngine, enable_compilation_cache)
     from fault_tolerant_llm_training_tpu.inference.scheduler import (
         Request, Scheduler)
-    from fault_tolerant_llm_training_tpu.models.configs import get_config
     from fault_tolerant_llm_training_tpu.models.llama import Transformer
 
     enable_compilation_cache(CACHE)
-    cfg = get_config("tiny", vocab_size=64, seq_len=64)
+    cfg = tiny_cfg()
     model = Transformer(cfg)
     params = model.init(jax.random.PRNGKey(0),
                         jnp.zeros((1, cfg.seq_len), jnp.int32))["params"]
@@ -534,11 +532,10 @@ def test_fork_slot_cow_beam_contract():
         InferenceEngine, enable_compilation_cache)
     from fault_tolerant_llm_training_tpu.inference.scheduler import (
         BlockAllocator)
-    from fault_tolerant_llm_training_tpu.models.configs import get_config
     from fault_tolerant_llm_training_tpu.models.llama import Transformer
 
     enable_compilation_cache(CACHE)
-    cfg = get_config("tiny", vocab_size=64, seq_len=64)
+    cfg = tiny_cfg()
     params = Transformer(cfg).init(
         jax.random.PRNGKey(0), jnp.zeros((1, cfg.seq_len), jnp.int32)
     )["params"]

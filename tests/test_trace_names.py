@@ -19,6 +19,8 @@ from pathlib import Path
 import numpy as np
 import pytest
 
+from _tiny import tiny_cfg
+
 REPO = Path(__file__).resolve().parent.parent
 sys.path.insert(0, str(REPO))  # perfbench/, the readers' own xplane decoder
 PKG = REPO / "fault_tolerant_llm_training_tpu"
@@ -120,14 +122,9 @@ def tiny_engine():
 
     from fault_tolerant_llm_training_tpu.inference.engine import (
         InferenceEngine)
-    from fault_tolerant_llm_training_tpu.models.configs import get_config
     from fault_tolerant_llm_training_tpu.models.llama import Transformer
 
-    # float32: this CPU's XLA refuses the bf16 x bf16 = f32 dot of the
-    # serving programs (the perfbench rehearsal cells do the same)
-    cfg = get_config("tiny", vocab_size=64, seq_len=64,
-                     layer_impl="loop").replace(dtype=jnp.float32,
-                                                param_dtype=jnp.float32)
+    cfg = tiny_cfg()
     params = Transformer(cfg).init(
         jax.random.PRNGKey(0), jnp.zeros((1, cfg.seq_len), jnp.int32))[
         "params"]
@@ -236,13 +233,14 @@ def test_lowered_programs_name_every_scope(tiny_engine):
     import jax
     import jax.numpy as jnp
 
-    from fault_tolerant_llm_training_tpu.models import Transformer, get_config
+    from fault_tolerant_llm_training_tpu.models import Transformer
     from fault_tolerant_llm_training_tpu.obs.trace import SCOPES
     from fault_tolerant_llm_training_tpu.training.state import TrainState
     from fault_tolerant_llm_training_tpu.training.step import (
         make_optimizer, make_train_step)
 
-    cfg = get_config("tiny", vocab_size=259, seq_len=64)
+    # bfloat16: the trainer's step at the preset's dtype, lowered, not run
+    cfg = tiny_cfg(dtype="bfloat16", vocab_size=259)
     model, opt = Transformer(cfg), make_optimizer(1e-3, 2)
 
     def init_fn(key):

@@ -30,12 +30,7 @@ import os
 import numpy as np
 import pytest
 
-
-def _tiny_cfg(vocab=64, seq_len=128):
-    from fault_tolerant_llm_training_tpu.models.configs import get_config
-
-    return get_config("tiny", vocab_size=vocab, seq_len=seq_len,
-                      layer_impl="loop")
+from _tiny import tiny_cfg
 
 
 @pytest.fixture(scope="module")
@@ -51,7 +46,7 @@ def xport_setup():
         Request, Scheduler)
     from fault_tolerant_llm_training_tpu.models.llama import Transformer
 
-    cfg = _tiny_cfg()
+    cfg = tiny_cfg(seq_len=128)
     model = Transformer(cfg)
     params = model.init(jax.random.PRNGKey(0),
                         jnp.zeros((1, cfg.seq_len), jnp.int32))["params"]
