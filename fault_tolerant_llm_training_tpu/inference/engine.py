@@ -125,7 +125,9 @@ from .kv_cache import (
     remap_paged_path,
 )
 from .sampler import (
+    TIERS,
     draft_key,
+    epilogue_tier,
     sample_slot_tokens,
     sample_token,
     sample_token_with_probs,
@@ -353,6 +355,14 @@ class InferenceEngine:
                                        phase=phase)
             for phase, s_q in (("decode", 1), ("prefill", 1),
                                ("prefill", 2))}
+        # a series is born with its first round: a window of greedy
+        # traffic shows no sampled / nucleus series at all
+        self._m_tiers = default_registry().counter(
+            "sample_epilogue_rounds_total",
+            "Decode rounds (decode_step, decode_burst) by the tier of the "
+            "sampling epilogue their batch's temperature / top_p select "
+            "(sampler.py epilogue_tier: greedy = argmax only, sampled = no "
+            "sort, nucleus = the whole epilogue)")
         if cfg.layer_impl == "scan":
             params = unstack_layer_params(params, cfg.n_layers)
             cfg = cfg.replace(layer_impl="loop")
@@ -1754,6 +1764,7 @@ class InferenceEngine:
         / ``adapter_scales`` (slots,); None = all base-only)."""
         if self.kv_layout == "paged" and block_tables is None:
             raise ValueError("paged decode requires block_tables")
+        temperature, top_p = self._count_epilogue(temperature, top_p)
         with self._decode_span(active):
             with span("ftl:engine.decode.dispatch"):
                 if self.kv_layout == "paged":
@@ -1761,9 +1772,7 @@ class InferenceEngine:
                         self.params, self.cache,
                         np.asarray(block_tables, np.int32),
                         np.asarray(tokens, np.int32),
-                        np.asarray(active, bool),
-                        np.asarray(temperature, np.float32),
-                        np.asarray(top_p, np.float32),
+                        np.asarray(active, bool), temperature, top_p,
                         np.asarray(seeds, np.int32),
                         np.asarray(steps, np.int32),
                         *self._adapter_call_args(adapter_rows,
@@ -1772,13 +1781,21 @@ class InferenceEngine:
                     self.cache, toks = self._decode(
                         self.params, self.cache,
                         np.asarray(tokens, np.int32),
-                        np.asarray(active, bool),
-                        np.asarray(temperature, np.float32),
-                        np.asarray(top_p, np.float32),
+                        np.asarray(active, bool), temperature, top_p,
                         np.asarray(seeds, np.int32),
                         np.asarray(steps, np.int32))
             with span("ftl:engine.decode.sync"):
                 return np.asarray(toks)
+
+    def _count_epilogue(self, temperature, top_p):
+        """The float32 arrays a decode round dispatches, counted under the
+        tier its epilogue takes on the device: the program's branch and
+        this count evaluate the same rule on the same values."""
+        temperature = np.asarray(temperature, np.float32)
+        top_p = np.asarray(top_p, np.float32)
+        self._m_tiers.labels(
+            tier=TIERS[epilogue_tier(temperature, top_p)]).inc()
+        return temperature, top_p
 
     def _decode_span(self, active, lengths=None, n: int = 1,
                      window: int = 1):
@@ -1845,14 +1862,14 @@ class InferenceEngine:
                                     adapter_rows=adapter_rows,
                                     adapter_scales=adapter_scales)[:, None]
         prog = self._burst_program(n)
+        temperature, top_p = self._count_epilogue(temperature, top_p)
         with self._decode_span(active, n=n):
             with span("ftl:engine.decode.dispatch"):
                 self.cache, toks = prog(
                     self.params, self.cache,
                     np.asarray(block_tables, np.int32),
                     np.asarray(tokens, np.int32), np.asarray(active, bool),
-                    np.asarray(temperature, np.float32),
-                    np.asarray(top_p, np.float32),
+                    temperature, top_p,
                     np.asarray(seeds, np.int32), np.asarray(steps, np.int32),
                     *self._adapter_call_args(adapter_rows, adapter_scales))
             with span("ftl:engine.decode.sync"):

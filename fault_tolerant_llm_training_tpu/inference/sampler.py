@@ -8,7 +8,10 @@ reproducible across engine restarts exactly like greedy ones
 (tests/test_inference.py).
 
 ``temperature <= 0`` selects greedy argmax (the scheduler's default), so one
-decode program serves mixed greedy/sampled slots without recompilation.
+decode program serves mixed greedy/sampled slots without recompilation. How
+much of the epilogue runs follows what the batch asks for
+(:func:`epilogue_tier`): an all-greedy batch takes the argmax and nothing
+else, and only a batch that holds a nucleus request sorts the vocabulary.
 
 Speculative decoding (engine.py spec mode) adds two kernels on the same
 filtered distributions:
@@ -33,10 +36,12 @@ rule whose statistics drive it (the scheduler owns an instance when
 serving opts in with ``--adaptive-spec-k``).
 """
 
+from functools import partial
 from typing import Dict, Iterable, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..obs.trace import scope
 
@@ -51,7 +56,8 @@ def _top_k_filter(logits: jnp.ndarray, top_k: int) -> jnp.ndarray:
 def _top_p_filter(logits: jnp.ndarray, top_p: jnp.ndarray) -> jnp.ndarray:
     """Nucleus filter: keep the smallest prefix of the sorted distribution
     whose mass reaches ``top_p`` (always at least the argmax). ``top_p >= 1``
-    keeps everything, so the replicated decode program needs no branch."""
+    keeps everything: the input comes back as it is, so a slot that asked
+    for no nucleus draws the same token whether or not the sort ran."""
     sorted_logits = jnp.sort(logits)[::-1]
     probs = jax.nn.softmax(sorted_logits)
     cum = jnp.cumsum(probs)
@@ -59,7 +65,51 @@ def _top_p_filter(logits: jnp.ndarray, top_p: jnp.ndarray) -> jnp.ndarray:
     # is included); monotone cum makes this a prefix
     keep = jnp.sum((cum - probs < top_p).astype(jnp.int32))
     cutoff = sorted_logits[jnp.maximum(keep - 1, 0)]
-    return jnp.where(logits >= cutoff, logits, -jnp.inf)
+    # at top_p == 1.0 the test above still drops tail tokens once the
+    # float32 cumsum has rounded to 1.0
+    return jnp.where((top_p >= 1.0) | (logits >= cutoff), logits, -jnp.inf)
+
+
+TIERS = ("greedy", "sampled", "nucleus")
+
+
+def epilogue_tier(temperature, top_p):
+    """Index into :data:`TIERS` of the cheapest epilogue that serves every
+    slot of a batch (or the one row of a prefill): ``greedy`` — no slot has
+    ``temperature > 0``; ``sampled`` — some slot samples and no sampling
+    slot has ``top_p < 1``; ``nucleus`` — some sampling slot has
+    ``top_p < 1``. One rule for the device's branch (traced arrays) and the
+    host's counter (the NumPy arrays ``decode_step`` dispatches), so the
+    two cannot drift."""
+    xp = jnp if isinstance(temperature, jax.Array) or isinstance(
+        top_p, jax.Array) else np
+    sampling = xp.asarray(temperature) > 0.0
+    nucleus = sampling & (xp.asarray(top_p) < 1.0)
+    return (xp.any(sampling).astype(xp.int32)
+            + xp.any(nucleus).astype(xp.int32))
+
+
+def _greedy_token(logits, key, temperature, top_p, top_k=0):
+    return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+
+
+def _sampled_token(logits, key, temperature, top_p, top_k=0, nucleus=False):
+    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    scaled = logits / jnp.maximum(temperature, 1e-6)
+    if top_k:
+        scaled = _top_k_filter(scaled, top_k)
+    if nucleus:
+        scaled = _top_p_filter(scaled, top_p)
+    sampled = jax.random.categorical(key, scaled).astype(jnp.int32)
+    return jnp.where(temperature > 0.0, sampled, greedy)
+
+
+# one row's token under each tier, in TIERS' order. A tier never computes
+# another answer for a slot than a dearer tier would: greedy slots are the
+# argmax in all three, and a sampling slot with top_p >= 1 hands
+# ``categorical`` the same logits with and without the filter.
+_TIER_TOKEN = (_greedy_token, _sampled_token,
+               partial(_sampled_token, nucleus=True))
 
 
 def sample_token(logits: jnp.ndarray, key: jax.Array,
@@ -67,18 +117,15 @@ def sample_token(logits: jnp.ndarray, key: jax.Array,
                  top_k: int = 0) -> jnp.ndarray:
     """One next-token id (int32) from unnormalized ``logits`` (V,) fp32.
 
-    temperature/top_p are traced per-slot scalars; top_k is static.
-    Greedy (temperature <= 0) is computed unconditionally and selected with
-    a ``where`` — both paths are cheap relative to the forward, and the
-    single program keeps mixed-slot batches on one compiled decode step.
+    temperature/top_p are traced scalars; top_k is static. The row's own
+    :func:`epilogue_tier` picks the branch, so a greedy prefill sorts
+    nothing. (Batches go through :func:`sample_slot_tokens`: under a
+    ``vmap`` this switch would run every branch.)
     """
-    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    scaled = logits / jnp.maximum(temperature, 1e-6)
-    if top_k:
-        scaled = _top_k_filter(scaled, top_k)
-    scaled = _top_p_filter(scaled, top_p)
-    sampled = jax.random.categorical(key, scaled).astype(jnp.int32)
-    return jnp.where(temperature > 0.0, sampled, greedy)
+    return jax.lax.switch(
+        epilogue_tier(temperature, top_p),
+        [partial(f, top_k=top_k) for f in _TIER_TOKEN],
+        logits, key, temperature, top_p)
 
 
 def slot_key(seed: jnp.ndarray, step: jnp.ndarray) -> jax.Array:
@@ -96,14 +143,28 @@ def sample_slot_tokens(logits: jnp.ndarray, seeds: jnp.ndarray,
     This is THE sampling epilogue, fused and unfused alike: the decode
     programs (engine.py ``_paged_decode_fn``/``_decode_fn``, the burst
     loop's micro-steps) trace it in-program so the dispatch ends in token
-    ids, and the unfused path (``decode_logits`` + host-side sampling,
-    the bench's baseline) calls the very same function on the synced
-    logits. One definition, one PRNG schedule — which is why a fused
-    single step's streams bit-match the host-sampled ones.
+    ids, and the unfused path (``decode_logits`` + host-side sampling)
+    calls the very same function on the synced logits. One definition, one
+    PRNG schedule — which is why a fused single step's streams bit-match
+    the host-sampled ones.
+
+    The batch's :func:`epilogue_tier` is taken ONCE, outside the ``vmap``,
+    on the arrays the program is handed anyway, and each branch vmaps its
+    per-row function: a ``cond`` under the ``vmap`` would lower to a
+    ``select`` that runs every side, which is the whole-vocabulary sort
+    for every greedy round.
     """
-    keys = jax.vmap(slot_key)(seeds, steps)
-    return jax.vmap(sample_token, in_axes=(0, 0, 0, 0, None))(
-        logits, keys, temperature, top_p, top_k)
+    def batched(row_token):
+        def branch(logits, seeds, steps, temperature, top_p):
+            keys = jax.vmap(slot_key)(seeds, steps)
+            return jax.vmap(partial(row_token, top_k=top_k))(
+                logits, keys, temperature, top_p)
+        return branch
+
+    return jax.lax.switch(
+        epilogue_tier(temperature, top_p),
+        [batched(f) for f in _TIER_TOKEN],
+        logits, seeds, steps, temperature, top_p)
 
 
 def draft_key(seed: jnp.ndarray, step: jnp.ndarray) -> jax.Array:

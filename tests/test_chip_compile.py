@@ -263,10 +263,11 @@ def test_d4_train_step_keeps_scope_names_and_flash_call_names(v5e):
 POOL_LAYERS, POOL_BLOCKS, POOL_BLOCK = 2, 5121, 16
 
 
-def _serving_program_hlo(v5e, program):
+def _serving_program_hlo(v5e, program, vocab=1024):
     """The engine's own ``program`` body at InternLM2-1.8B widths, compiled
     for one described v5e as ``longdecode``'s server shapes it (8 slots of
-    640 table entries, the 5,121-block pool); returns (HLO text, cfg)."""
+    640 table entries, the 5,121-block pool); returns (HLO text, cfg).
+    ``vocab=92544`` is the real epilogue (a scratch reading, not a test)."""
     import json
     import sys
     import types
@@ -288,7 +289,7 @@ def _serving_program_hlo(v5e, program):
 
     config = json.loads((root / "perfbench" / "configs"
                          / "internlm2-1.8b.json").read_text())
-    config.update(num_hidden_layers=POOL_LAYERS, vocab_size=1024)
+    config.update(num_hidden_layers=POOL_LAYERS, vocab_size=vocab)
     d = weights.dims_of(config)
     cfg = mc.TransformerConfig(**weights.preset_kwargs(config)).replace(
         remat=False)
@@ -392,3 +393,86 @@ def test_decode_program_reads_the_pool_in_place(v5e, monkeypatch):
     assert len(calls) == POOL_LAYERS, len(calls)
     assert all(re.search(r'op_name="[^"]*kv_read[^"]*"', ln)
                for ln in calls), calls
+
+
+def _hlo_computations(hlo):
+    """{name: [instruction lines]} of an HLO module's text, and the entry
+    computation's name."""
+    import re
+
+    comps, entry, cur = {}, None, None
+    for ln in hlo.splitlines():
+        head = re.match(r"^(ENTRY )?%?([\w.\-]+) \(.*\{\s*$", ln)
+        if head:
+            cur = head.group(2)
+            comps[cur] = []
+            entry = cur if head.group(1) else entry
+        elif ln.startswith("}"):
+            cur = None
+        elif cur is not None:
+            comps[cur].append(ln)
+    return comps, entry
+
+
+def _reached(comps, root, through_conditionals):
+    """Computations ``root`` runs: those it calls (fusions, loops' bodies,
+    reducers), and a ``conditional``'s branches only if asked."""
+    import re
+
+    seen, todo = set(), [root]
+    while todo:
+        name = todo.pop()
+        if name in seen:
+            continue
+        seen.add(name)
+        for ln in comps[name]:
+            todo += re.findall(
+                r"(?:calls|to_apply|body|condition)=%?([\w.\-]+)", ln)
+            if through_conditionals:
+                todo += re.findall(
+                    r"(?:true|false)_computation=%?([\w.\-]+)", ln)
+                for group in re.findall(r"branch_computations=\{([^}]*)\}",
+                                        ln):
+                    todo += [b.strip().lstrip("%") for b in group.split(",")]
+    return seen
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill_1x512"])
+def test_sampling_epilogue_branches_once_outside_the_vmap(v5e, program):
+    """What a greedy round or a greedy prefill runs holds no sort over the
+    vocabulary and draws no noise over it: the epilogue's tiers
+    (``inference/sampler.py`` ``epilogue_tier``) are the branches of a
+    ``conditional`` in the entry computation, and the ``sort`` and the
+    Gumbel draws stand only in computations that ``conditional`` calls.
+
+    A branch taken under the ``vmap`` with a batched predicate lowers to a
+    ``select`` that runs every side — ``sort f32[32,92544]`` was 3.7 ms of
+    ``chat``'s 13.5 ms decode round with all 32 slots greedy (PERF.md
+    section 6, PR 32) — and this is where such an edit fails."""
+    import re
+
+    hlo, _ = _serving_program_hlo(v5e, program)
+    comps, entry = _hlo_computations(hlo)
+    switch = [re.search(r"branch_computations=\{([^}]*)\}", ln)
+              for ln in comps[entry] if " conditional(" in ln]
+    tiers = [[b.strip().lstrip("%") for b in m.group(1).split(",")]
+             for m in switch if m]
+    assert [len(t) for t in tiers] == [3], (
+        "the epilogue's three-way switch is not in the entry computation")
+    always = _reached(comps, entry, through_conditionals=False)
+    branches = _reached(comps, entry, through_conditionals=True) - always
+
+    def holding(names, pattern):
+        return sorted(n for n in names
+                      if any(re.search(pattern, ln) for ln in comps[n]))
+
+    # the Gumbel noise a categorical draws over the vocabulary (a key's
+    # derivation, ``slot_key``: two scalar hashes, may stand anywhere)
+    draws = r"rng-bit-generator|random_bits|_gumbel|_uniform"
+    assert not holding(always, r" sort\(")
+    assert not holding(always, draws)
+    assert holding(branches, r" sort\(")
+    assert holding(branches, draws)
+    # and the first branch, the greedy tier, holds neither
+    greedy = _reached(comps, tiers[0][0], through_conditionals=True)
+    assert not holding(greedy, r" sort\(") and not holding(greedy, draws)
