@@ -98,7 +98,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..models.configs import TransformerConfig
+from ..models import build_model
+from ..models.configs import LatentMoEConfig, TransformerConfig
 from ..obs import events
 from ..obs.trace import span
 from ..models.llama import Transformer, unstack_layer_params
@@ -113,6 +114,7 @@ from ..utils.compile_cache import enable_compilation_cache  # noqa: F401
 from ..utils.device import describe_device
 from .kv_cache import (
     KVCache,
+    LatentKVCache,
     PagedKVCache,
     blocks_per_slot,
     cache_shardings,
@@ -121,6 +123,7 @@ from .kv_cache import (
     import_block_batch,
     import_blocks,
     init_cache,
+    init_latent_cache,
     init_paged_cache,
     remap_paged_path,
 )
@@ -330,6 +333,18 @@ class InferenceEngine:
             raise ValueError("paged_kernel selection requires the paged "
                              "KV layout")
         self.paged_kernel = paged_kernel
+        # a model whose layers cache by kind (LatentMoEConfig: latent and
+        # index-key pools behind the block tables, window rings a slot)
+        # runs the same engine with programs of its own cache; what the
+        # engine cannot do for it yet is refused here, by name
+        self._latent = isinstance(cfg, LatentMoEConfig)
+        if self._latent:
+            self._refuse_for_latent(
+                kv_layout=kv_layout, kv_dtype=kv_dtype, spec_k=spec_k,
+                draft=draft_cfg is not None or draft_params is not None,
+                spec_tree=spec_tree, adapter_rank=adapter_rank,
+                prefill_batch=prefill_batch, paged_kernel=paged_kernel,
+                mesh=mesh)
         device = describe_device()
         logger.info(f"Device | {device}")
         events.emit("backend_ready", device=device)
@@ -338,12 +353,20 @@ class InferenceEngine:
         # counter and the scheduler's chunk counters all state this, not
         # the option
         with use_mesh(mesh):
-            self._read_kernel = {
-                s_q: ("inplace" if resolve_paged_kernel(
-                    paged_kernel, s_q, cfg.head_dim) == "pallas"
-                      else "gather") for s_q in (1, 2)}
-            logger.info(f"Paged kernel | "
-                        f"{describe_paged_kernel(paged_kernel, cfg.head_dim)}")
+            if self._latent:
+                # its reads are XLA gathers of rows and blocks
+                # (ops/latent_attention.py): no in-place kernel yet
+                self._read_kernel = {1: "gather", 2: "gather"}
+                logger.info("Paged kernel | latent caches: decode gather "
+                            "of selected rows, prefill gather by key block")
+            else:
+                self._read_kernel = {
+                    s_q: ("inplace" if resolve_paged_kernel(
+                        paged_kernel, s_q, cfg.head_dim) == "pallas"
+                          else "gather") for s_q in (1, 2)}
+                logger.info(
+                    f"Paged kernel | "
+                    f"{describe_paged_kernel(paged_kernel, cfg.head_dim)}")
         reads = default_registry().counter(
             "paged_read_dispatches_total",
             "Dispatched programs that read the paged KV pool, by the "
@@ -404,7 +427,13 @@ class InferenceEngine:
         # scheduler builds the radix tree only for engines that advertise
         # it. Paged-only — sharing is a property of the block indirection.
         self.enable_prefix_cache = bool(prefix_cache) and kv_layout == "paged"
-        self.model = Transformer(cfg)
+        self.model = build_model(cfg)
+        if self._latent:
+            from ..models.latent_moe import STAT_COUNTERS
+            self._stat_counters = {
+                phase: [default_registry().counter(name, text).labels(
+                    phase=phase) for name, text in STAT_COUNTERS.values()]
+                for phase in ("prefill", "decode")}
 
         # --- speculative decoding: second model lifecycle ------------------
         self.spec_k = int(spec_k)
@@ -553,7 +582,38 @@ class InferenceEngine:
         """The same for the S > 1 programs (chunked and packed prefill)."""
         return self._read_kernel[2]
 
+    @staticmethod
+    def _refuse_for_latent(*, kv_layout, kv_dtype, spec_k, draft, spec_tree,
+                           adapter_rank, prefill_batch, paged_kernel, mesh):
+        """What the engine cannot do yet for a model whose layers cache by
+        kind: each refused by its name, nothing falls back."""
+        what = "a LatentMoEConfig model (latent / index-key / window caches)"
+        for bad, why in (
+                (kv_layout != "paged",
+                 "kv_layout='ring': its full layers live in block pools"),
+                (kv_dtype != "bf16",
+                 "kv_dtype='int8': no quantized latent or index-key pool"),
+                (bool(spec_k) or draft or spec_tree is not None,
+                 "speculative decoding (spec_k / draft / spec_tree): no "
+                 "verify program over its caches"),
+                (bool(adapter_rank),
+                 "adapters (adapter_rank): no per-slot factors on its "
+                 "projections"),
+                (int(prefill_batch) > 1,
+                 "prefill_batch > 1: a chunk is one slot's"),
+                (paged_kernel == "pallas",
+                 "paged_kernel='pallas': no in-place kernel reads its "
+                 "pools"),
+                (mesh is not None and mesh.devices.size > 1,
+                 "a multi-device mesh: no sharding rules for its "
+                 "parameters and pools, no expert exchange")):
+            if bad:
+                raise ValueError(f"{what} does not support {why}")
+
     def _init_cache(self, dtype=None):
+        if self._latent:
+            return init_latent_cache(self.cfg, self.slots, self.block_size,
+                                     self.num_blocks, dtype=dtype)
         if self.kv_layout == "paged":
             return init_paged_cache(self.cfg, self.slots, self.max_len,
                                     self.block_size, self.num_blocks,
@@ -738,6 +798,48 @@ class InferenceEngine:
         lengths = cache.lengths + active.astype(jnp.int32)
         return PagedKVCache(k=nk, v=nv, lengths=lengths), toks
 
+    def _latent_prefill_fn(self, params, cache, block_row, tokens, slot,
+                           chunk_start, chunk_len, write_from, seq_from,
+                           temperature, top_p, seed):
+        """One prefill chunk of a ``LatentKVCache`` model: as
+        ``_paged_prefill_fn``, with two positions more. ``seq_from`` is
+        where this call began computing (the slot's window rings hold
+        nothing of the request before it); ``write_from`` keeps the
+        positions before it out of the full layers' pools: a call resumed
+        from a prefix hit at P starts ``cfg.rebuild_span`` earlier, so that
+        every sliding layer's window is exact from P on, and the rows in
+        between are the shared blocks' own. Returns the counts of
+        ``models/latent_moe.py`` ``STATS`` beside the token."""
+        valid = (jnp.arange(tokens.shape[1], dtype=jnp.int32)[None, :]
+                 < chunk_len)
+        logits, new, stats = self.model.apply(
+            {"params": params}, tokens, cache, chunk_start[None],
+            block_row[None, :], valid, slot[None], seq_from[None],
+            write_from=write_from[None], logits_at=(chunk_len - 1)[None],
+            method="forward_with_cache")
+        lengths = jax.lax.dynamic_update_slice(
+            cache.lengths, (chunk_start + chunk_len)[None], (slot,))
+        tok = sample_token(logits[0, 0].astype(jnp.float32),
+                           slot_key(seed, jnp.int32(0)),
+                           temperature, top_p, self.top_k)
+        return new.replace(lengths=lengths), tok, stats
+
+    def _latent_decode_fn(self, params, cache, block_tables, tokens, active,
+                          temperature, top_p, seeds, steps):
+        """One token for every slot of a ``LatentKVCache`` model: as
+        ``_paged_decode_fn`` (the same epilogue), returning the round's
+        counts beside the tokens."""
+        logits, new, stats = self.model.apply(
+            {"params": params}, tokens[:, None], cache, cache.lengths,
+            block_tables, active[:, None],
+            jnp.arange(self.slots, dtype=jnp.int32), cache.win_from,
+            method="forward_with_cache")
+        last = logits[:, 0].astype(jnp.float32)
+        toks = sample_slot_tokens(last, seeds, steps, temperature, top_p,
+                                  self.top_k)
+        lengths = cache.lengths + active.astype(jnp.int32)
+        return new.replace(lengths=lengths), toks, stats
+
     def _paged_logits_fn(self, params, cache, block_tables, tokens, active,
                          apool=None, arows=None, ascales=None):
         """UNFUSED decode step: the identical forward, but the program
@@ -817,6 +919,8 @@ class InferenceEngine:
         prefill inside its final shared block — the copy is bitwise, so
         the resumed stream stays bit-identical to an uncached run. The
         cache is donated: XLA rewrites one block row per pool in place."""
+        if isinstance(cache, LatentKVCache):
+            return cache.copy_block(src, dst)
         return PagedKVCache(
             k=tuple(copy_kv_block(p, src, dst) for p in cache.k),
             v=tuple(copy_kv_block(p, src, dst) for p in cache.v),
@@ -1130,6 +1234,22 @@ class InferenceEngine:
             # the paged programs; without adapters the arg tuples are
             # empty and the lowered programs are byte-identical to before
             ad_slots, ad_one = self._adapter_abstract()
+            if self._latent:
+                self._decode = jax.jit(
+                    self._latent_decode_fn, donate_argnums=(1,)).lower(
+                    p_abs, c_abs, tables_abs, slots_i, slots_b, slots_f,
+                    slots_f, slots_i, slots_i).compile()
+                self._cow = jax.jit(
+                    self._cow_fn, donate_argnums=(0,)).lower(
+                    c_abs, scalar_i, scalar_i).compile()
+                for b in self.prefill_buckets:
+                    tok_abs = jax.ShapeDtypeStruct((1, b), jnp.int32)
+                    self._prefill[b] = jax.jit(
+                        self._latent_prefill_fn, donate_argnums=(1,)).lower(
+                        p_abs, c_abs, row_abs, tok_abs, scalar_i, scalar_i,
+                        scalar_i, scalar_i, scalar_i, scalar_f, scalar_f,
+                        scalar_i).compile()
+                return
             self._decode = jax.jit(
                 self._paged_decode_fn, donate_argnums=(1,)).lower(
                 p_abs, c_abs, tables_abs, slots_i, slots_b, slots_f,
@@ -1304,6 +1424,9 @@ class InferenceEngine:
         if self.kv_layout != "paged":
             raise ValueError("burst decode requires the paged KV layout "
                              "(the loop writes KV through block tables)")
+        if self._latent:
+            raise ValueError("burst decode (n > 1) is not written for a "
+                             "LatentMoEConfig model")
         n = int(n)
         if not 1 <= n <= self.max_len:
             raise ValueError(f"burst width {n} outside [1, {self.max_len}]")
@@ -1400,8 +1523,7 @@ class InferenceEngine:
         device side of spill and handoff. ``length`` is captured from the
         live cache so the restore resumes the decode position exactly.
         Returns the artifact manifest."""
-        if self.kv_layout != "paged":
-            raise ValueError("block export requires the paged KV layout")
+        self._need_kv_blocks("block export")
         length = int(np.asarray(self.cache.lengths)[slot])
         return export_blocks(self.cache, blocks, out_dir,
                              length=length, meta=meta)
@@ -1413,8 +1535,7 @@ class InferenceEngine:
         restore ``slot``'s fill count from the manifest's recorded length.
         Raises ``KVBlockIntegrityError`` with the cache untouched on any
         mismatch. Returns the manifest."""
-        if self.kv_layout != "paged":
-            raise ValueError("block import requires the paged KV layout")
+        self._need_kv_blocks("block import")
         cache, manifest = import_blocks(self.cache, art_dir, dest_blocks)
         self.cache = cache.replace(
             lengths=cache.lengths.at[slot].set(
@@ -1428,8 +1549,7 @@ class InferenceEngine:
         shipment is resident, via :meth:`set_slot_length`. Raises
         ``KVBlockIntegrityError`` with the cache untouched on any
         mismatch. Returns the manifest."""
-        if self.kv_layout != "paged":
-            raise ValueError("block import requires the paged KV layout")
+        self._need_kv_blocks("block import")
         cache, manifest = import_blocks(self.cache, art_dir, dest_blocks)
         self.cache = cache
         return manifest
@@ -1445,12 +1565,22 @@ class InferenceEngine:
         the cache untouched on any mismatch (verification of every
         payload precedes the first device write). Returns the manifests
         in ``parts`` order."""
-        if self.kv_layout != "paged":
-            raise ValueError("block import requires the paged KV layout")
+        self._need_kv_blocks("block import")
         cache, manifests = import_block_batch(
             self.cache, parts, allow_partial=allow_partial)
         self.cache = cache
         return manifests
+
+    def _need_kv_blocks(self, what: str) -> None:
+        """Spill, handoff, shipments and the fleet store move K/V blocks
+        of a ``PagedKVCache``; no other cache has an artifact format."""
+        if self.kv_layout != "paged":
+            raise ValueError(f"{what} requires the paged KV layout")
+        if self._latent:
+            raise ValueError(
+                f"{what} is not written for a LatentMoEConfig model: its "
+                f"blocks hold latent and index-key rows and its sliding "
+                f"layers hold rings, and the artifact format is K/V blocks")
 
     def set_slot_length(self, slot: int, length: int) -> None:
         """Set ``slot``'s fill count directly (paged only) — the decode
@@ -1621,12 +1751,17 @@ class InferenceEngine:
             raise ValueError("spec-mode prefill requires draft_block_row")
         if not 0 <= start_pos < n:
             raise ValueError(f"start_pos {start_pos} outside [0, {n})")
+        stats = None
         with span("ftl:engine.prefill.dispatch"):
-            tok = self._stream_chunks(False, row, ids, slot, temperature,
-                                      top_p, seed, stop_check, on_chunk,
-                                      start_pos=start_pos,
-                                      adapter_row=adapter_row,
-                                      adapter_scale=adapter_scale)
+            if self._latent:
+                tok, stats = self._stream_latent_chunks(
+                    row, ids, slot, temperature, top_p, seed, stop_check,
+                    on_chunk, start_pos)
+            else:
+                tok = self._stream_chunks(
+                    False, row, ids, slot, temperature, top_p, seed,
+                    stop_check, on_chunk, start_pos=start_pos,
+                    adapter_row=adapter_row, adapter_scale=adapter_scale)
         if tok is None:
             return None
         if self.spec_k:
@@ -1659,7 +1794,60 @@ class InferenceEngine:
                 if draft_tok is None:
                     return None
         with span("ftl:engine.prefill.sync"):
-            return int(tok)
+            tok = int(tok)
+        if stats:
+            counts = self._count_stats("prefill", stats)
+            with span("ftl:engine.prefill.stats", **counts):
+                pass
+        return tok
+
+    def _stream_latent_chunks(self, row, ids, slot, temperature, top_p,
+                              seed, stop_check, on_chunk, start_pos):
+        """:meth:`_stream_chunks` for a ``LatentKVCache`` model. A call
+        that resumes at ``start_pos`` (a prefix-cache hit) begins
+        ``cfg.rebuild_span`` positions earlier: the full layers read those
+        positions from the cached blocks and write nothing before
+        ``start_pos``, the sliding layers recompute them, and from
+        ``start_pos`` on every layer's output is what an uncached prefill
+        gives — a sliding layer's window is exact once its input has been
+        for ``sliding_window - 1`` positions, and the full layers below
+        the first sliding layer are exact at once. Returns (the final
+        chunk's token or None, the chunks' counts, still on the device)."""
+        n = ids.size
+        chunk = self.prefill_buckets[-1]
+        resume = int(start_pos)
+        start = max(0, resume - self.cfg.rebuild_span)
+        seq_from, tok, stats = start, None, []
+        while start < n:
+            m = min(chunk, n - start)
+            bucket = next(b for b in self.prefill_buckets if b >= m)
+            padded = np.zeros((1, bucket), np.int32)
+            padded[0, :m] = ids[start:start + m]
+            self._m_reads["prefill", min(bucket, 2)].inc()
+            self.cache, tok, st = self._prefill[bucket](
+                self.params, self.cache, row, padded, np.int32(slot),
+                np.int32(start), np.int32(m), np.int32(resume),
+                np.int32(seq_from), np.float32(temperature),
+                np.float32(top_p), np.int32(seed))
+            stats.append(st)
+            start += m
+            if on_chunk is not None:
+                on_chunk()
+            if start < n and stop_check is not None and stop_check():
+                return None, stats
+        return tok, stats
+
+    def _count_stats(self, phase: str, stats) -> dict:
+        """The counts a ``LatentKVCache`` model's programs returned beside
+        their tokens (already read back with them), summed over ``stats``:
+        into the counters of ``phase`` and, by the names of
+        ``models/latent_moe.py`` ``STATS``, for the round's stats span."""
+        from ..models.latent_moe import STATS
+
+        total = np.sum([np.asarray(st, np.int64) for st in stats], axis=0)
+        for counter, value in zip(self._stat_counters[phase], total):
+            counter.inc(float(value))
+        return {name: int(v) for name, v in zip(STATS, total)}
 
     def prefill_packed(self, rows, bucket: int, adapter_rows=None,
                        adapter_scales=None):
@@ -1766,8 +1954,17 @@ class InferenceEngine:
             raise ValueError("paged decode requires block_tables")
         temperature, top_p = self._count_epilogue(temperature, top_p)
         with self._decode_span(active):
+            stats = None
             with span("ftl:engine.decode.dispatch"):
-                if self.kv_layout == "paged":
+                if self._latent:
+                    self.cache, toks, stats = self._decode(
+                        self.params, self.cache,
+                        np.asarray(block_tables, np.int32),
+                        np.asarray(tokens, np.int32),
+                        np.asarray(active, bool), temperature, top_p,
+                        np.asarray(seeds, np.int32),
+                        np.asarray(steps, np.int32))
+                elif self.kv_layout == "paged":
                     self.cache, toks = self._decode(
                         self.params, self.cache,
                         np.asarray(block_tables, np.int32),
@@ -1785,7 +1982,12 @@ class InferenceEngine:
                         np.asarray(seeds, np.int32),
                         np.asarray(steps, np.int32))
             with span("ftl:engine.decode.sync"):
-                return np.asarray(toks)
+                toks = np.asarray(toks)
+            if stats is not None:
+                counts = self._count_stats("decode", [stats])
+                with span("ftl:engine.decode.stats", **counts):
+                    pass
+            return toks
 
     def _count_epilogue(self, temperature, top_p):
         """The float32 arrays a decode round dispatches, counted under the
@@ -1830,8 +2032,9 @@ class InferenceEngine:
         programs trace — which is what pins the fused/unfused stream
         bit-match the bench asserts. Paged layout only (it exists as the
         fused epilogue's measured baseline)."""
-        if self.kv_layout != "paged":
-            raise ValueError("decode_logits requires the paged KV layout")
+        if self.kv_layout != "paged" or self._latent:
+            raise ValueError("decode_logits requires the paged KV layout "
+                             "of a K/V model")
         if block_tables is None:
             raise ValueError("paged decode requires block_tables")
         self.cache, logits = self._decode_logits(
@@ -2003,8 +2206,10 @@ class InferenceEngine:
         allocator path (shared blocks drop a ref, the private boundary
         block frees outright — tests/test_spec_decode.py pins the
         contract, double-free raise included)."""
-        if self.kv_layout != "paged":
-            raise ValueError("fork_slot requires the paged KV layout")
+        if self.kv_layout != "paged" or self._latent:
+            raise ValueError("fork_slot requires the paged KV layout of a "
+                             "K/V model (a fork would have to copy the "
+                             "source slot's window rings)")
         if not (0 <= src_slot < self.slots and 0 <= dst_slot < self.slots
                 and src_slot != dst_slot):
             raise ValueError("fork_slot: bad slot pair "
@@ -2040,7 +2245,8 @@ class InferenceEngine:
         a scheduler is per-stream, so resetting the engine and building a
         fresh ``Scheduler`` (fresh radix tree) is the supported pattern."""
         with use_mesh(self.mesh):
-            cache = self._init_cache(dtype=self.cache.k[0].dtype)
+            cache = self._init_cache(
+                dtype=None if self._latent else self.cache.k[0].dtype)
             cs = cache_shardings(cache, self.mesh)
             self.cache = (jax.device_put(cache, cs) if cs is not None
                           else cache)
@@ -2096,7 +2302,7 @@ def restore_params(checkpoint_path: str, job_id: str, cfg: TransformerConfig,
     from ..training.step import make_optimizer
     from jax.sharding import NamedSharding
 
-    model = Transformer(cfg)
+    model = build_model(cfg)
     # only the opt_state TREE matters (restored then dropped); any
     # schedule yields the same optax.adamw structure
     optimizer = make_optimizer(1e-4, 1)

@@ -124,6 +124,91 @@ class PagedKVCache(struct.PyTreeNode):
         return self.k[0].shape[2]
 
 
+class LatentKVCache(struct.PyTreeNode):
+    """The serving cache of a model whose layers cache by KIND
+    (models/latent_moe.py): full layers keep, a token, one latent row, one
+    rope key and one index key in pools addressed by the SAME host-side
+    block tables a :class:`PagedKVCache` uses (so the allocator, the prefix
+    cache and copy-on-write see blocks as before) — the index keys by
+    block, ``(N, 1, bs, d_i)``, read a table at a time; the latent rows and
+    rope keys row by row, ``(N * bs, r)`` and ``(N * bs, d_r)``, block b
+    being rows ``[b * bs, (b + 1) * bs)`` (ops/latent_attention.py says why
+    they differ); sliding
+    layers keep a ring of ``R >= window`` rows a slot, whatever the
+    context. ``win_from[slot]`` is the first position whose row the slot's
+    rings hold of the request in it (a resumed prefill starts there, not
+    at 0: ops/latent_attention.py, ``InferenceEngine.prefill``)."""
+
+    latent: Tuple[jax.Array, ...]   # a full layer: (N * bs, r), and
+    rope: Tuple[jax.Array, ...]     # (N * bs, d_r): 16-bit rows packed as
+    #                                 uint32 words
+    index: Tuple[jax.Array, ...]    # a full layer: (N, 1, bs, d_i)
+    window: Tuple[jax.Array, ...]   # a sliding layer: (slots, R, r + d_r)
+    win_from: jax.Array             # (slots,) int32
+    lengths: jax.Array              # (slots,) int32 tokens written per slot
+
+    @property
+    def slots(self) -> int:
+        return self.lengths.shape[0]
+
+    @property
+    def num_blocks(self) -> int:
+        return self.index[0].shape[0]
+
+    @property
+    def block_size(self) -> int:
+        return self.index[0].shape[2]
+
+    def copy_block(self, src, dst) -> "LatentKVCache":
+        """Copy-on-write of one pool block, in every full layer's two
+        pools (a sliding layer shares nothing)."""
+        bs = self.block_size
+
+        def rows(p):    # the block's bs rows of a latent pool
+            return jax.lax.dynamic_update_slice_in_dim(
+                p, jax.lax.dynamic_slice_in_dim(p, src * bs, bs, 0),
+                dst * bs, 0)
+
+        return self.replace(
+            latent=tuple(rows(p) for p in self.latent),
+            rope=tuple(rows(p) for p in self.rope),
+            index=tuple(copy_kv_block(p, src, dst) for p in self.index))
+
+    def resident_bytes(self) -> dict:
+        """Bytes held, by layer kind."""
+        size = lambda ps: int(sum(p.size * p.dtype.itemsize for p in ps))
+        return {"full": (size(self.latent) + size(self.rope)
+                         + size(self.index)),
+                "sliding": size(self.window)}
+
+
+def init_latent_cache(cfg, slots: int, block_size: int, num_blocks: int,
+                      dtype=None) -> LatentKVCache:
+    """Zero-filled pools and rings for a ``LatentMoEConfig``."""
+    dtype = cfg.dtype if dtype is None else dtype
+    if num_blocks < 2:
+        raise ValueError(f"num_blocks {num_blocks} < 2: block 0 is the "
+                         f"reserved null block")
+    from ..ops.latent_attention import packed_width
+
+    full, swa = cfg.mixer("full"), cfg.mixer("sliding")
+    pool = lambda c: jnp.zeros((num_blocks, 1, block_size, c), dtype)
+    # rows, not blocks, and a 16-bit row as uint32 words of whole lanes:
+    # they are written and gathered one by one
+    rows = lambda c: jnp.zeros(                               # noqa: E731
+        (num_blocks * block_size, packed_width(c, dtype)),
+        jnp.uint32 if jnp.dtype(dtype).itemsize == 2 else dtype)
+    return LatentKVCache(
+        latent=tuple(rows(full["kv_rank"]) for _ in cfg.full_layers),
+        rope=tuple(rows(full["rope"]) for _ in cfg.full_layers),
+        index=tuple(pool(cfg.index_head_dim) for _ in cfg.full_layers),
+        window=tuple(jnp.zeros((slots, cfg.window_ring,
+                                swa["kv_rank"] + swa["rope"]), dtype)
+                     for _ in cfg.sliding_layers),
+        win_from=jnp.zeros((slots,), jnp.int32),
+        lengths=jnp.zeros((slots,), jnp.int32))
+
+
 def blocks_per_slot(max_len: int, block_size: int) -> int:
     """Block-table row length covering ``max_len`` positions."""
     return -(-max_len // block_size)

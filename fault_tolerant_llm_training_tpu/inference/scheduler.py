@@ -398,6 +398,22 @@ class Scheduler:
                  pacing: Optional[Callable[[], Optional[int]]] = None,
                  kv_store_max_bytes: int = 0):
         self.engine = engine
+        if getattr(engine, "_latent", False):
+            # the tiers that move K/V blocks have no format for latent and
+            # index-key blocks or window rings (engine._need_kv_blocks);
+            # refused here, by name, before a request is taken
+            for bad, what in (
+                    (enable_spill or spill_dir, "the spill tier "
+                     "(enable_spill / spill_dir)"),
+                    (role != "both", f"role={role!r} (block shipments "
+                     f"between a prefill and a decode engine)"),
+                    (kv_store is not None, "the fleet KV store (kv_store)"),
+                    (int(decode_burst) > 1 or adaptive_burst,
+                     "decode_burst > 1"),
+                    (int(prefill_batch) > 1, "prefill_batch > 1")):
+                if bad:
+                    raise ValueError(
+                        f"a LatentMoEConfig model does not support {what}")
         self.eos_token_id = eos_token_id
         self.clock = clock
         self.queue: deque = deque()        # (Request, submitted_at)

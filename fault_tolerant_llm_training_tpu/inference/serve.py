@@ -170,7 +170,19 @@ def get_serve_args(argv=None) -> argparse.Namespace:
     p.add_argument("--step", type=int, default=None,
                    help="checkpoint step (default: latest)")
     p.add_argument("--model", default="tiny",
-                   help="model preset the checkpoint was trained with")
+                   help="model preset the checkpoint was trained with "
+                        "(models/configs.py PRESETS). The model class is "
+                        "found from the preset's type: the Llama-family "
+                        "presets build models/llama.py Transformer; "
+                        "tiny-latent-moe (latent attention of two widths, "
+                        "a top-k indexer, a sliding window, sigmoid-routed "
+                        "experts of which the engine is told the held "
+                        "range) builds models/latent_moe.py. For that class "
+                        "the engine refuses by name --kv-dtype int8, "
+                        "--kv-layout ring, --spec-k / --spec-tree, "
+                        "--adapter-rank, --prefill-batch > 1, "
+                        "--paged-kernel pallas, --decode-burst > 1, the "
+                        "spill tier and a multi-device mesh")
     p.add_argument("--vocab-size", type=int, default=0,
                    help="0 = take the tokenizer's vocab (training default)")
     p.add_argument("--tokenizer-name-or-path", default="byte")

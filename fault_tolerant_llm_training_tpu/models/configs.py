@@ -214,6 +214,137 @@ class TransformerConfig:
         return dataclasses.replace(self, **kw)
 
 
+
+@dataclasses.dataclass(frozen=True)
+class LatentMoEConfig:
+    """A decoder whose layers follow a per-layer schedule (models/
+    latent_moe.py ``LatentMoETransformer``): every mixer is latent
+    attention (a low-rank query, one cached latent + one shared rope key a
+    token, a head-wise sigmoid gate), of one of two kinds with its own
+    sizes — ``"full"`` layers attend to the ``index_topk`` positions a
+    learned indexer picks, ``"sliding"`` layers to the last
+    ``sliding_window`` positions (the query's own included) — and the FFN is
+    a dense SwiGLU in the first ``first_dense_layers`` layers and a
+    sigmoid-routed expert layer after them. The expert layer routes over
+    all ``n_routed_experts``, computes the ``held_experts`` =
+    (first, count) it is told it holds, and adds the shared expert: the
+    chip's share of an expert-parallel deployment, with no exchange.
+
+    Serving only (``inference/engine.py`` finds the class by this type);
+    the fields the engine reads of any configuration (``vocab_size``,
+    ``seq_len``, ``layer_impl``, ``remat``, ``paged_kernel``, the dtypes,
+    ``replace``) are here under the same names."""
+
+    dim: int = 5120
+    n_layers: int = 5
+    layer_types: tuple = ("full", "full", "sliding", "sliding", "sliding")
+    norm_eps: float = 1e-5
+    seq_len: int = 2048
+    vocab_size: int = -1
+    # full-attention mixer
+    n_heads: int = 128
+    q_lora_rank: int = 1024
+    kv_lora_rank: int = 512
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
+    rope_theta: float = 8e7
+    # its indexer
+    index_n_heads: int = 64
+    index_head_dim: int = 128
+    index_topk: int = 2048
+    # sliding-window mixer
+    swa_n_heads: int = 64
+    swa_q_lora_rank: int = 1024
+    swa_kv_lora_rank: int = 1024
+    swa_qk_nope_head_dim: int = 192
+    swa_qk_rope_head_dim: int = 64
+    swa_v_head_dim: int = 128
+    swa_rope_theta: float = 5e4
+    sliding_window: int = 513
+    # variance alignment of both latents (alpha = sqrt(dim / rank))
+    lora_rescale: bool = True
+    # FFNs
+    first_dense_layers: int = 1
+    dense_hidden_dim: int = 13824
+    moe_hidden_dim: int = 1536
+    n_routed_experts: int = 256
+    held_experts: tuple = (0, 32)
+    num_experts_per_tok: int = 8
+    n_shared_experts: int = 1
+    routed_scaling_factor: float = 1.0
+    norm_topk_prob: bool = True
+    # compute options, as TransformerConfig's
+    dtype: jnp.dtype = jnp.bfloat16
+    param_dtype: jnp.dtype = jnp.bfloat16
+    paged_kernel: str = "auto"
+    embed_impl: str = "auto"
+    layer_impl: str = "loop"
+    remat: bool = False
+
+    def __post_init__(self):
+        kinds = set(self.layer_types)
+        if len(self.layer_types) != self.n_layers or not kinds <= {
+                "full", "sliding"}:
+            raise ValueError(
+                f"layer_types {self.layer_types} must name 'full' or "
+                f"'sliding' for each of n_layers={self.n_layers}")
+        first, count = self.held_experts
+        if not (0 <= first and 0 < count
+                and first + count <= self.n_routed_experts):
+            raise ValueError(
+                f"held_experts {self.held_experts} outside the "
+                f"{self.n_routed_experts} routed experts")
+        if not 1 <= self.num_experts_per_tok <= self.n_routed_experts:
+            raise ValueError("num_experts_per_tok outside "
+                             "[1, n_routed_experts]")
+        if self.layer_impl != "loop":
+            raise ValueError("LatentMoEConfig: layer_impl='loop' only (the "
+                             "layers differ, there is no scan form)")
+        if self.index_topk < 1 or self.sliding_window < 1:
+            raise ValueError("index_topk and sliding_window must be >= 1")
+
+    def mixer(self, kind: str) -> dict:
+        """The latent-attention sizes of a layer kind."""
+        if kind == "full":
+            return dict(heads=self.n_heads, q_rank=self.q_lora_rank,
+                        kv_rank=self.kv_lora_rank,
+                        nope=self.qk_nope_head_dim,
+                        rope=self.qk_rope_head_dim, v=self.v_head_dim,
+                        theta=self.rope_theta)
+        return dict(heads=self.swa_n_heads, q_rank=self.swa_q_lora_rank,
+                    kv_rank=self.swa_kv_lora_rank,
+                    nope=self.swa_qk_nope_head_dim,
+                    rope=self.swa_qk_rope_head_dim, v=self.swa_v_head_dim,
+                    theta=self.swa_rope_theta)
+
+    @property
+    def full_layers(self) -> tuple:
+        return tuple(i for i, k in enumerate(self.layer_types)
+                     if k == "full")
+
+    @property
+    def sliding_layers(self) -> tuple:
+        return tuple(i for i, k in enumerate(self.layer_types)
+                     if k == "sliding")
+
+    @property
+    def window_ring(self) -> int:
+        """Rows a slot keeps of a sliding layer: the window, rounded up."""
+        return -(-self.sliding_window // 16) * 16
+
+    @property
+    def rebuild_span(self) -> int:
+        """Positions before a resume point that a prefill recomputes so
+        that every sliding layer's window is exact at the point: each
+        sliding layer needs its input exact ``sliding_window - 1`` further
+        back than its output."""
+        return (self.sliding_window - 1) * len(self.sliding_layers)
+
+    def replace(self, **kw) -> "LatentMoEConfig":
+        return dataclasses.replace(self, **kw)
+
+
 PRESETS = {
     # Exact reference trainer shape (ref: train.py:43-53); ~8.05B params at
     # the Mistral-Nemo vocab of 131072.
@@ -245,10 +376,23 @@ PRESETS = {
         multiple_of=32, rope_theta=10000.0, vocab_size=512, seq_len=128,
         moe_experts=4, moe_top_k=2,
     ),
+    # Hermetic shape of the latent-attention / indexer / window / expert
+    # class (models/latent_moe.py): every mechanism at a size the CPU tests
+    # can pass (top-k 8 and window 9 under contexts of a few dozen tokens).
+    "tiny-latent-moe": LatentMoEConfig(
+        dim=64, n_layers=5, n_heads=4, q_lora_rank=32, kv_lora_rank=16,
+        qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
+        index_n_heads=2, index_head_dim=16, index_topk=8,
+        swa_n_heads=2, swa_q_lora_rank=32, swa_kv_lora_rank=24,
+        swa_qk_nope_head_dim=24, swa_qk_rope_head_dim=8, swa_v_head_dim=16,
+        sliding_window=9, dense_hidden_dim=96, moe_hidden_dim=32,
+        n_routed_experts=8, held_experts=(0, 4), num_experts_per_tok=2,
+        vocab_size=512, seq_len=128,
+    ),
 }
 
 
-def get_config(name: str, **overrides) -> TransformerConfig:
+def get_config(name: str, **overrides):
     if name not in PRESETS:
         raise ValueError(f"unknown model preset {name!r}; have {sorted(PRESETS)}")
     return PRESETS[name].replace(**overrides)

@@ -282,6 +282,14 @@ class Trainer:
             moe_capacity_factor=cfg.moe_capacity_factor,
             moe_aux_weight=cfg.moe_aux_weight,
             moe_impl=cfg.moe_impl).items() if v is not None}
+        from ..models.configs import PRESETS, LatentMoEConfig
+        if isinstance(PRESETS.get(cfg.model), LatentMoEConfig):
+            raise ValueError(
+                f"--model {cfg.model} is a LatentMoEConfig preset "
+                f"(models/latent_moe.py): that class is served, not trained "
+                f"yet — no uncached training forward, no sharding rules for "
+                f"its paths, no flash kernel for its head widths (ROADMAP "
+                f"R7)")
         self.model_config = get_config(
             cfg.model, vocab_size=vocab, seq_len=cfg.sequence_length,
             dtype=dtype, param_dtype=param_dtype,

@@ -24,6 +24,7 @@ import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
 from fault_tolerant_llm_training_tpu.ops import flash_attention as fa
+from fault_tolerant_llm_training_tpu.ops import latent_attention as la
 from fault_tolerant_llm_training_tpu.ops import paged_attention as pa
 
 
@@ -45,6 +46,7 @@ def compiled_kernels_no_cache(monkeypatch):
     # both modules hold their own reference to the rule
     monkeypatch.setattr(fa, "_interpret", lambda: False)
     monkeypatch.setattr(pa, "_interpret", lambda: False)
+    monkeypatch.setattr(la, "_interpret", lambda: False)
     cache_was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     cc.reset_cache()
@@ -476,3 +478,193 @@ def test_sampling_epilogue_branches_once_outside_the_vmap(v5e, program):
     # and the first branch, the greedy tier, holds neither
     greedy = _reached(comps, tiers[0][0], through_conditionals=True)
     assert not holding(greedy, r" sort\(") and not holding(greedy, draws)
+
+
+# ------------------------------------ the accepted cells' programs, unchanged
+# sha256 of the LOWERED (StableHLO, no source locations) paged decode and
+# 1 x 64 prefill programs of the Llama class, recorded at commit e7a1277
+# (PR 32) by this same function: ``tiny`` as the CPU lowers it, InternLM2-1.8B
+# widths (2 layers, vocab 1024, longdecode's server shapes) as the described
+# v5e does. A PR that brings another model class through the engine (PR 33:
+# models/latent_moe.py) must leave these programs as they were; a PR that
+# means to change them, or a JAX upgrade, records the constants anew and says
+# so.
+SERVING_PROGRAMS_AS_RECORDED = {
+    "tiny": {
+        "decode": "a83e0353bfae5787df17214d3481bdb7078397c4be22a0acd2333b4c9c"
+                  "75429f",
+        "prefill": "0d30670fa1b69fff62daf6134a6726b5281d9ad86c5cbc6660d41613c"
+                   "e3f3d3b"},
+    "internlm2": {
+        "decode": "c277a36b8ea8c660e5c32c7aef55ed2dd83604f21ae23c867988f512fc"
+                  "a04b55",
+        "prefill": "0cb5064611870dadd96f990fc1ee28fea4928e3f11ad21a800b0824ad"
+                   "eb952da"}}
+
+
+def _lowered_serving_hashes(cfg, params, slots, per_slot, bs, blocks, sds):
+    import hashlib
+    import types
+
+    from fault_tolerant_llm_training_tpu.inference.engine import (
+        InferenceEngine,
+    )
+    from fault_tolerant_llm_training_tpu.inference.kv_cache import (
+        init_paged_cache,
+    )
+    from fault_tolerant_llm_training_tpu.models.llama import Transformer
+
+    cache = jax.tree_util.tree_map(
+        lambda a: sds(a.shape, a.dtype), jax.eval_shape(
+            lambda: init_paged_cache(cfg, slots, per_slot * bs, bs, blocks)))
+    model = Transformer(cfg)
+    stub = types.SimpleNamespace(model=model, top_k=0, slots=slots, cfg=cfg,
+                                 spec_verify_impl="chunk",
+                                 _adapter_operand=lambda *a: None)
+    i32, f32 = jnp.int32, jnp.float32
+    vec = lambda dt: sds((slots,), dt)                      # noqa: E731
+    texts = {
+        "decode": jax.jit(
+            lambda *a: InferenceEngine._paged_decode_fn(stub, *a),
+            donate_argnums=(1,)).lower(
+            params, cache, sds((slots, per_slot), i32), vec(i32),
+            vec(jnp.bool_), vec(f32), vec(f32), vec(i32),
+            vec(i32)).as_text(),
+        "prefill": jax.jit(
+            lambda *a: InferenceEngine._paged_prefill_fn(stub, model, *a),
+            donate_argnums=(1,)).lower(
+            params, cache, sds((per_slot,), i32), sds((1, 4 * bs), i32),
+            sds((), i32), sds((), i32), sds((), i32), sds((), f32),
+            sds((), f32), sds((), i32)).as_text()}
+    return {k: hashlib.sha256(v.encode()).hexdigest()
+            for k, v in texts.items()}
+
+
+def test_tiny_serving_programs_are_as_recorded():
+    import sys
+    from pathlib import Path
+
+    sys.path.insert(0, str(Path(__file__).resolve().parent))
+    from _tiny import tiny_cfg
+
+    from fault_tolerant_llm_training_tpu.models.llama import Transformer
+
+    cfg = tiny_cfg().replace(remat=False)
+    sds = lambda s, dt: jax.ShapeDtypeStruct(s, dt)         # noqa: E731
+    params = jax.tree_util.tree_map(
+        lambda a: sds(a.shape, a.dtype), jax.eval_shape(
+            lambda: Transformer(cfg).init(
+                jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))[
+                "params"]))
+    assert _lowered_serving_hashes(cfg, params, 2, 4, 8, 9, sds) == (
+        SERVING_PROGRAMS_AS_RECORDED["tiny"])
+
+
+def test_internlm2_serving_programs_are_as_recorded(v5e):
+    import json
+    import sys
+    from pathlib import Path
+
+    root = Path(__file__).resolve().parent.parent
+    sys.path.insert(0, str(root))
+    from perfbench.lib import weights
+
+    from fault_tolerant_llm_training_tpu.models import configs as mc
+
+    config = json.loads((root / "perfbench" / "configs"
+                         / "internlm2-1.8b.json").read_text())
+    config.update(num_hidden_layers=POOL_LAYERS, vocab_size=1024)
+    d = weights.dims_of(config)
+    cfg = mc.TransformerConfig(**weights.preset_kwargs(config)).replace(
+        remat=False)
+    on = _shapes_on(v5e.devices[0])
+    sds = lambda s, dt: on(s, dt)                           # noqa: E731
+    params = jax.tree_util.tree_map(
+        lambda a: sds(a.shape, a.dtype), jax.eval_shape(
+            lambda k: weights.make_param_tree(k, d, jnp.bfloat16),
+            jax.random.PRNGKey(0)))
+    assert _lowered_serving_hashes(cfg, params, 8, 640, POOL_BLOCK,
+                                   POOL_BLOCKS, sds) == (
+        SERVING_PROGRAMS_AS_RECORDED["internlm2"])
+
+
+def test_latent_decode_program_accesses_its_pools_in_place(v5e):
+    """The decode program of the latent / indexer / window / expert class
+    at the published widths (one full and one sliding layer, 2 held
+    experts, vocab 1024, 8 slots over longdecode's 5,121 blocks), compiled
+    for one described v5e: every pool and ring it returns aliases its input
+    and the program holds no ``copy`` of a pool's shape. A latent pool
+    stored by block, or with rows of 288 words, cost a pool-sized relayout
+    copy for every row access (ops/latent_attention.py)."""
+    import json
+    import re
+    import sys
+    import types
+    from pathlib import Path
+
+    root = Path(__file__).resolve().parent.parent
+    sys.path.insert(0, str(root))
+    from perfbench.lib import weights
+
+    from fault_tolerant_llm_training_tpu.inference.engine import (
+        InferenceEngine,
+    )
+    from fault_tolerant_llm_training_tpu.inference.kv_cache import (
+        init_latent_cache,
+    )
+
+    config = json.loads((root / "perfbench" / "configs"
+                         / "dots3-note-prev-d5-ep8.json").read_text())
+    config.update(num_hidden_layers=2, vocab_size=1024, n_routed_experts=2,
+                  first_k_dense_replace=0,
+                  layer_types=["full_attention", "sliding_attention"])
+    d = weights.dims_of(config)
+    fam = weights.family_of(d)
+    cfg = fam.preset(config)
+    slots, per_slot = 8, 640
+    sds = _shapes_on(v5e.devices[0])
+    on = lambda tree: jax.tree_util.tree_map(               # noqa: E731
+        lambda a: sds(a.shape, a.dtype), tree)
+    params = on(jax.eval_shape(
+        lambda k: weights.make_param_tree(k, d, jnp.bfloat16),
+        jax.random.PRNGKey(0)))
+    cache = on(jax.eval_shape(lambda: init_latent_cache(
+        cfg, slots, POOL_BLOCK, POOL_BLOCKS)))
+    stub = types.SimpleNamespace(model=fam.model_class()(cfg), top_k=0,
+                                 slots=slots, cfg=cfg)
+    i32, f32 = jnp.int32, jnp.float32
+    vec = lambda dt: sds((slots,), dt)                      # noqa: E731
+    compiled = jax.jit(
+        lambda *a: InferenceEngine._latent_decode_fn(stub, *a),
+        donate_argnums=(1,)).lower(
+        params, cache, sds((slots, per_slot), i32), vec(i32),
+        vec(jnp.bool_), vec(f32), vec(f32), vec(i32), vec(i32)).compile()
+    hlo = compiled.as_text()
+    rows = POOL_BLOCKS * POOL_BLOCK
+    # (the rope-key pool, 32 words a row and an eighth of the latent pool's
+    # bytes, is the compiler's to lay out: at this pool size it copies it,
+    # at the cell's 1,245,200 rows it does not)
+    pools = [rf"u32\[{rows},256\]",
+             rf"bf16\[{POOL_BLOCKS},1,{POOL_BLOCK},128\]",
+             rf"bf16\[{slots},528,1088\]"]
+    for shape in pools:
+        assert re.search(shape, hlo), shape      # the pool is in the program
+        assert not re.search(rf"= {shape}\S* copy\(", hlo), (
+            f"the decode program copies a whole {shape}")
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= sum(
+        a.size * a.dtype.itemsize for a in jax.tree_util.tree_leaves(cache)
+        if a.ndim > 1)          # every pool and ring is updated in place
+
+
+def test_masked_flash_kernel_compiles_at_the_cells_widths(v5e):
+    """The full layers' chunk read at the published widths (128 heads of
+    128 + 64 / 128, a 2,048-query chunk over a 19,456-row table: the
+    cell's own shapes), compiled by Mosaic for one described v5e."""
+    sds = _shapes_on(v5e.devices[0])
+    h, s, t = 128, 2048, 19456
+    _compile(
+        lambda qn, qr, kn, kr, v, keep, n: la.masked_flash_attention(
+            qn, qr, kn, kr, v, keep, n, 0.0722),
+        sds((h, s, 128)), sds((h, s, 64)), sds((h, t, 128)), sds((t, 64)),
+        sds((h, t, 128)), sds((s, t), jnp.int8), sds((), jnp.int32))
