@@ -264,6 +264,9 @@ class HotReloader:
                     self.adaptive_k.reset()
             if getattr(self.scheduler, "prefix_cache", None) is not None:
                 self.scheduler.prefix_cache.flush()
+                # rows of the old weights, like the cached blocks: a free
+                # slot's held window rings die with them
+                self.scheduler.held_rings.clear()
             self.engine.restored_step = ptr.step
             self.reloads += 1
         except Exception as e:  # a verified step should restore; if the

@@ -92,7 +92,7 @@ table values at absolute positions, and an attention kernel mirroring
 
 import functools
 import logging
-from typing import Callable, Optional, Sequence
+from typing import Callable, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -155,6 +155,53 @@ def default_prefill_buckets(max_len: int, smallest: int = 16
         b *= 2
     buckets.append(max_len)
     return tuple(buckets)
+
+
+def program_cost(compiled) -> Optional[Tuple[float, float]]:
+    """(flops, bytes accessed) of a compiled program as its compiler counts
+    them (``cost_analysis()``), or None where it gives neither. The two are
+    kept apart: the engine knows no peak of its device to weigh one against
+    the other, so a cover is only called cheaper when it is cheaper by
+    both (:func:`cover_plan`)."""
+    try:
+        counts = compiled.cost_analysis()
+    except (NotImplementedError, RuntimeError):
+        return None     # a backend without the analysis: no rule, no fault
+    if isinstance(counts, (list, tuple)):
+        counts = counts[0] if counts else None
+    if not counts or "flops" not in counts or "bytes accessed" not in counts:
+        return None
+    return float(counts["flops"]), float(counts["bytes accessed"])
+
+
+def cover_plan(rows: int, buckets: Sequence[int], cost=None) -> List[int]:
+    """The bucket of each call that covers ``rows`` prompt rows, in order.
+
+    The plain cover is the chunk loop's: whole chunks of the largest
+    bucket, then the first bucket that holds the remainder. ``cost`` maps
+    a bucket to its program's (flops, bytes) (:func:`program_cost`); where
+    it is given, k calls of a smaller bucket replace the one call of the
+    next chunk when their summed cost is lower by BOTH counts — so on any
+    device, whatever its balance of the two — and of several such covers
+    each has to beat the last one taken the same way, the fewer calls
+    first. A small remainder over a ladder with a missing rung (80 rows
+    over ``[64, 2048]``) then runs as two small calls; a large one, or any
+    remainder over a ladder with the rung, runs as before."""
+    buckets = sorted(buckets)
+    plan: List[int] = []
+    while rows > 0:
+        m = min(rows, buckets[-1])
+        pick = next(b for b in buckets if b >= m)
+        if cost is not None:
+            best = cost[pick]
+            for b in reversed([b for b in buckets if b < pick]):
+                calls = -(-m // b)
+                total = tuple(calls * c for c in cost[b])
+                if all(t < w for t, w in zip(total, best)):
+                    pick, best = b, total
+        plan.append(pick)
+        rows -= pick
+    return plan
 
 
 def _abstract(tree):
@@ -386,6 +433,15 @@ class InferenceEngine:
             "sampling epilogue their batch's temperature / top_p select "
             "(sampler.py epilogue_tier: greedy = argmax only, sampled = no "
             "sort, nucleus = the whole epilogue)")
+        rows = default_registry().counter(
+            "ftl_serve_prefill_rows_total",
+            "Rows of the chunk programs the sequential prefill loops (not "
+            "the packed lane) called, by kind: "
+            "new = real rows at or past the position the call resumed at, "
+            "recomputed = real rows before it (a window rebuild), padding = "
+            "a call's bucket less its real rows")
+        self._m_rows = {kind: rows.labels(kind=kind)
+                        for kind in ("new", "recomputed", "padding")}
         if cfg.layer_impl == "scan":
             params = unstack_layer_params(params, cfg.n_layers)
             cfg = cfg.replace(layer_impl="loop")
@@ -434,6 +490,10 @@ class InferenceEngine:
                 phase: [default_registry().counter(name, text).labels(
                     phase=phase) for name, text in STAT_COUNTERS.values()]
                 for phase in ("prefill", "decode")}
+            # the first position each slot's window rings hold of the
+            # request last prefilled into it: the host's copy of
+            # ``cache.win_from``, for a caller that resumes over held rings
+            self._ring_from = np.zeros((slots,), np.int64)
 
         # --- speculative decoding: second model lifecycle ------------------
         self.spec_k = int(spec_k)
@@ -1249,6 +1309,9 @@ class InferenceEngine:
                         p_abs, c_abs, row_abs, tok_abs, scalar_i, scalar_i,
                         scalar_i, scalar_i, scalar_i, scalar_f, scalar_f,
                         scalar_i).compile()
+                # what the chunk loop's cover rule goes by (cover_plan)
+                cost = {b: program_cost(p) for b, p in self._prefill.items()}
+                self._bucket_cost = (cost if all(cost.values()) else None)
                 return
             self._decode = jax.jit(
                 self._paged_decode_fn, donate_argnums=(1,)).lower(
@@ -1649,6 +1712,8 @@ class InferenceEngine:
                 self.draft_cache, tok = self._draft_prefill[bucket](
                     self.draft_params, self.draft_cache, *args)
             else:
+                self._m_rows["new"].inc(m)
+                self._m_rows["padding"].inc(bucket - m)
                 self.cache, tok = self._prefill[bucket](
                     self.params, self.cache, *args,
                     *self._prefill_adapter_args(adapter_row, adapter_scale))
@@ -1667,7 +1732,9 @@ class InferenceEngine:
                 start_pos: int = 0,
                 draft_start_pos: int = 0,
                 adapter_row=None,
-                adapter_scale: float = 0.0) -> Optional[int]:
+                adapter_scale: float = 0.0,
+                rings_held: Optional[Tuple[int, int]] = None
+                ) -> Optional[int]:
         """Prompt into ``slot``; returns the first generated token id.
 
         Ring layout: the prompt must fit the largest bucket (one shot).
@@ -1703,24 +1770,65 @@ class InferenceEngine:
         written, a cache-hit spec stream's proposals — and therefore the
         stream itself — are unchanged cache-on vs cache-off
         (tests/test_spec_decode.py asserts it).
+
+        ``rings_held`` (a ``LatentKVCache`` model only) is ``(length,
+        win_from)`` of the request that last ran in ``slot``, for a caller
+        that knows this prompt continues it: the slot's window rings still
+        hold that request's last ``cfg.window_ring`` positions before
+        ``length``, so a call resumed at ``start_pos`` recomputes nothing
+        to rebuild them (:meth:`rings_cover` says when, and the call
+        refuses otherwise). Without it a resumed call rebuilds the windows
+        (:meth:`_stream_latent_chunks`).
         """
         ids = np.asarray(token_ids, np.int32).reshape(-1)
         n = ids.size
+        begin = self._first_computed(start_pos, rings_held)
+
         def first_bucket():  # of the (usually only) chunk
+            if self._latent:
+                return cover_plan(max(n - begin, 1), self.prefill_buckets,
+                                  self._bucket_cost)[0]
             m = min(self.prefill_buckets[-1], max(n - int(start_pos), 1))
             return next(b for b in self.prefill_buckets if b >= m)
 
         with span("ftl:engine.prefill", new_tokens=n - int(start_pos),
-                  start_pos=int(start_pos), bucket=first_bucket):
+                  start_pos=int(start_pos), bucket=first_bucket,
+                  held=int(rings_held is not None),
+                  rebuilt_rows=int(start_pos) - begin):
             return self._prefill_spanned(
                 ids, slot, block_row, draft_block_row, temperature, top_p,
                 seed, stop_check, on_chunk, start_pos, draft_start_pos,
-                adapter_row, adapter_scale)
+                adapter_row, adapter_scale, rings_held)
+
+    def _first_computed(self, start_pos: int, rings_held) -> int:
+        """The first position a prefill resumed at ``start_pos`` computes:
+        ``start_pos`` itself, or — a ``LatentKVCache`` model with no held
+        rings to resume over — ``cfg.rebuild_span`` positions before it,
+        to rebuild the sliding layers' windows."""
+        if self._latent and rings_held is None:
+            return max(0, int(start_pos) - self.cfg.rebuild_span)
+        return int(start_pos)
+
+    def rings_cover(self, length: int, start_pos: int) -> bool:
+        """Whether a slot whose window rings were last written up to
+        position ``length`` (exclusive) still holds every row a prefill
+        resumed at ``start_pos`` reads: the ``sliding_window - 1``
+        positions before ``start_pos``. The ring keeps ``[length -
+        window_ring, length)``, and the rows of ``[start_pos, length)``
+        lie under every window the call reads and are overwritten as it
+        writes them."""
+        return (self._latent and 0 <= length - start_pos
+                <= self.cfg.window_ring - self.cfg.sliding_window)
+
+    def window_from(self, slot: int) -> int:
+        """The first position ``slot``'s window rings hold of the request
+        last prefilled into it (the host's copy of ``cache.win_from``)."""
+        return int(self._ring_from[slot])
 
     def _prefill_spanned(self, ids, slot, block_row, draft_block_row,
                          temperature, top_p, seed, stop_check, on_chunk,
                          start_pos, draft_start_pos, adapter_row,
-                         adapter_scale) -> Optional[int]:
+                         adapter_scale, rings_held=None) -> Optional[int]:
         """:meth:`prefill` inside its ``ftl:engine.prefill`` span."""
         n = ids.size
         if start_pos and self.kv_layout != "paged":
@@ -1751,12 +1859,25 @@ class InferenceEngine:
             raise ValueError("spec-mode prefill requires draft_block_row")
         if not 0 <= start_pos < n:
             raise ValueError(f"start_pos {start_pos} outside [0, {n})")
+        if rings_held is not None:
+            if not self._latent:
+                raise ValueError("rings_held: this model keeps no window "
+                                 "rings in its slots")
+            length, win_from = (int(v) for v in rings_held)
+            if not (self.rings_cover(length, start_pos)
+                    and 0 <= win_from <= start_pos):
+                raise ValueError(
+                    f"rings_held: rings written up to {length} from "
+                    f"{win_from} do not cover a resume at {start_pos} "
+                    f"(window {self.cfg.sliding_window}, ring "
+                    f"{self.cfg.window_ring})")
+            rings_held = (length, win_from)
         stats = None
         with span("ftl:engine.prefill.dispatch"):
             if self._latent:
                 tok, stats = self._stream_latent_chunks(
                     row, ids, slot, temperature, top_p, seed, stop_check,
-                    on_chunk, start_pos)
+                    on_chunk, start_pos, rings_held)
             else:
                 tok = self._stream_chunks(
                     False, row, ids, slot, temperature, top_p, seed,
@@ -1802,7 +1923,8 @@ class InferenceEngine:
         return tok
 
     def _stream_latent_chunks(self, row, ids, slot, temperature, top_p,
-                              seed, stop_check, on_chunk, start_pos):
+                              seed, stop_check, on_chunk, start_pos,
+                              rings_held=None):
         """:meth:`_stream_chunks` for a ``LatentKVCache`` model. A call
         that resumes at ``start_pos`` (a prefix-cache hit) begins
         ``cfg.rebuild_span`` positions earlier: the full layers read those
@@ -1811,19 +1933,29 @@ class InferenceEngine:
         ``start_pos`` on every layer's output is what an uncached prefill
         gives — a sliding layer's window is exact once its input has been
         for ``sliding_window - 1`` positions, and the full layers below
-        the first sliding layer are exact at once. Returns (the final
-        chunk's token or None, the chunks' counts, still on the device)."""
+        the first sliding layer are exact at once. Over ``rings_held`` =
+        (length, win_from), checked by the caller, it begins AT
+        ``start_pos``: the rings already hold the windows, of a request
+        whose rows begin at ``win_from``. The calls that cover the rows are
+        :func:`cover_plan`'s, by the compiled programs' own costs. Returns
+        (the final chunk's token or None, the chunks' counts, still on the
+        device)."""
         n = ids.size
-        chunk = self.prefill_buckets[-1]
         resume = int(start_pos)
-        start = max(0, resume - self.cfg.rebuild_span)
-        seq_from, tok, stats = start, None, []
-        while start < n:
-            m = min(chunk, n - start)
-            bucket = next(b for b in self.prefill_buckets if b >= m)
+        start = self._first_computed(resume, rings_held)
+        seq_from = start if rings_held is None else rings_held[1]
+        self._ring_from[slot] = seq_from
+        tok, stats = None, []
+        for bucket in cover_plan(n - start, self.prefill_buckets,
+                                 self._bucket_cost):
+            m = min(bucket, n - start)
             padded = np.zeros((1, bucket), np.int32)
             padded[0, :m] = ids[start:start + m]
             self._m_reads["prefill", min(bucket, 2)].inc()
+            again = min(max(resume - start, 0), m)
+            self._m_rows["recomputed"].inc(again)
+            self._m_rows["new"].inc(m - again)
+            self._m_rows["padding"].inc(bucket - m)
             self.cache, tok, st = self._prefill[bucket](
                 self.params, self.cache, row, padded, np.int32(slot),
                 np.int32(start), np.int32(m), np.int32(resume),
@@ -2250,6 +2382,8 @@ class InferenceEngine:
             cs = cache_shardings(cache, self.mesh)
             self.cache = (jax.device_put(cache, cs) if cs is not None
                           else cache)
+            if self._latent:
+                self._ring_from[:] = 0
             if self.spec_k:
                 dcache = self._init_draft_cache(
                     dtype=self.draft_cache.k[0].dtype)

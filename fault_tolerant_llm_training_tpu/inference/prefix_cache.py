@@ -4,25 +4,41 @@ Production traffic is dominated by shared prompt prefixes (system prompts,
 few-shot templates, multi-turn history). The paged layout already stores
 KV in global pool blocks addressed through per-slot tables
 (inference/kv_cache.py) — exactly the substrate vLLM's PagedAttention
-assumed and SGLang's RadixAttention built on: if two prompts share their
-first k*block_size tokens, their first k blocks hold bitwise-identical KV
-(same prefill programs, same shapes, same inputs), so the second request
-can point its table at the FIRST request's blocks and skip the prefill
-compute for them entirely.
+assumed and SGLang's RadixAttention built on: if two token streams share
+their first k*block_size tokens, their first k blocks hold the KV of the
+same tokens at the same positions, so the second request can point its
+table at the FIRST request's blocks and skip the prefill compute for them
+entirely.
 
-**Keying.** Each fully-committed (block-aligned) prompt block is keyed by a
+**What is cached, and when.** The whole blocks of a prompt, at admission,
+once its prefill has written them; and the whole blocks of what a request's
+slot WROTE — its prompt and every generated token but the last (sampled,
+never fed) — when the request ends normally (``Scheduler._finish``, reason
+``length`` / ``eos``), so a session's next turn hits up to the last whole
+block of its history and not only up to the last prompt. Blocks cached at
+admission hold rows a prefill program wrote: two prompts that share them
+get bitwise the rows an uncached prefill of either writes (same programs,
+same shapes, same inputs). Blocks cached at a finish also hold rows the
+DECODE program wrote, one token a round: the same model on the same
+tokens, equal to a prefill's rows to the rounding of the compute dtype and
+not bitwise. A stream served over them is the stream a re-prefill would
+serve up to that rounding (a near-tie of two logits may fall the other
+way); nothing else about a hit changes.
+
+**Keying.** Each fully-committed (block-aligned) block is keyed by a
 chain hash ``h_i = sha256(h_{i-1} || tokens of block i)`` — the key of
 block i commits the entire token prefix up to and including it, so a flat
 ``dict`` keyed by chain hash IS a radix tree over token-block paths
 (parent = the i-1 prefix, children = every cached one-block extension).
 Partial trailing blocks are never cached: a block's bytes are only
-reusable once every position in it is committed prompt content.
+reusable once every position in it is committed content.
 
 **Ownership protocol** (the part that must survive drain/eviction/chaos):
 the allocator's per-block refcount is the single source of truth.
 
 - The cache holds exactly ONE reference per cached node (taken at
-  ``insert``, dropped at ``evict``/``flush``).
+  ``insert`` — at admission or at a normal finish, BEFORE the slot's own
+  ``free`` — and dropped at ``evict``/``flush``).
 - Every slot whose table row contains the block holds one reference:
   fresh blocks are born at refcount 1 by ``alloc``; cache-hit blocks are
   increfed by ``acquire`` at admission. A slot's blocks are released by
@@ -94,7 +110,7 @@ class PrefixHit:
 
 
 class PrefixCache:
-    """Host-side radix tree of committed prompt blocks, refcounted through
+    """Host-side radix tree of committed token blocks, refcounted through
     the scheduler's :class:`~.scheduler.BlockAllocator` (see module
     docstring for the ownership protocol)."""
 
@@ -149,17 +165,21 @@ class PrefixCache:
         never free the prefix the slot is about to reuse."""
         self.allocator.incref(hit.blocks)
 
-    def insert(self, prompt: Sequence[int], slot_blocks: Sequence[int]
-               ) -> int:
-        """Cache the fully-committed blocks of a just-prefilled prompt:
-        ``slot_blocks[i]`` holds block i's KV. Already-cached keys are
+    def insert(self, prompt: Sequence[int], slot_blocks: Sequence[int],
+               keys: Optional[List[bytes]] = None) -> int:
+        """Cache the fully-committed blocks of ``prompt`` — a
+        just-prefilled prompt, or everything a finished request's slot
+        wrote: ``slot_blocks[i]`` holds block i's KV. Already-cached keys are
         skipped (their canonical block stays; a COW'd private copy is never
         re-inserted over it). Each NEW node takes the cache's own allocator
-        reference. Returns the number of nodes added."""
+        reference. ``keys`` are the prompt's :func:`chain_hashes`, for a
+        caller that already has them. Returns the number of nodes added."""
         added = 0
         parent: Optional[bytes] = None
         self._tick += 1
-        for i, key in enumerate(chain_hashes(prompt, self.block_size)):
+        if keys is None:
+            keys = chain_hashes(prompt, self.block_size)
+        for i, key in enumerate(keys):
             node = self._nodes.get(key)
             if node is None:
                 block = int(slot_blocks[i])

@@ -130,6 +130,7 @@ from ..utils.logging import (
 from .kv_cache import (
     BLOCK_MANIFEST_NAME,
     KVBlockIntegrityError,
+    LatentKVCache,
     artifact_bytes,
     block_bytes,
     export_blocks,
@@ -302,6 +303,20 @@ class _PendingPrefill:
     pos: int                # next absolute position to prefill
     eff: Sequence[int]      # effective prefill prompt (replay appends the
                             # committed prefix; == request.prompt otherwise)
+
+
+@dataclasses.dataclass(frozen=True)
+class _HeldRings:
+    """What a FREE slot's window rings still hold, recorded when the
+    request in it finished normally: the stream whose whole blocks end at
+    chain hash ``key``, written up to position ``length`` (exclusive), its
+    rows in the rings beginning at ``win_from``. A next turn of the same
+    stream, admitted into the same slot, resumes at the key's block
+    boundary and rebuilds no window (``InferenceEngine.prefill``
+    ``rings_held``)."""
+    key: bytes
+    length: int
+    win_from: int
 
 
 class _Slot:
@@ -882,6 +897,22 @@ class Scheduler:
             self.prefix_cache = PrefixCache(
                 self.allocator, engine.block_size,
                 evictions_counter=self._m_prefix_evictions)
+        # A model whose sliding layers keep a ring of rows a slot
+        # (LatentKVCache): a free slot's record of what its rings hold.
+        # Set at a normal finish, dropped by whatever else touches the slot
+        # (any admission into it, a drain roll-back, spill, restore, a
+        # weight swap).
+        self._keeps_rings = isinstance(getattr(engine, "cache", None),
+                                       LatentKVCache)
+        self.held_rings: Dict[int, _HeldRings] = {}
+        resumes = r.counter(
+            "ftl_serve_window_resumes_total",
+            "Prefix-hit admissions of a model that keeps window rings in "
+            "its slots, by how the windows were had: held = the slot's own "
+            "rings, left by the stream's last request; rebuilt = recomputed "
+            "before the hit")
+        self._m_resumes = {how: resumes.labels(how=how)
+                           for how in ("held", "rebuilt")}
         # DRAFT-pool mirror (module docstring): same radix scheme over the
         # draft allocator, fed the same insertions, so shared prompts skip
         # draft prefill too. Full-prompt draft hits skip the phase outright
@@ -1062,6 +1093,8 @@ class Scheduler:
         if self.kv_layout == "paged":
             blocks = self._slot_blocks.pop(slot, None)
             if blocks:
+                if reason in ("length", "eos"):
+                    self._keep_written(slot, st, blocks)
                 self.allocator.free(blocks)
                 self.block_tables[slot] = 0
         if self.spec_k:
@@ -1089,6 +1122,53 @@ class Scheduler:
         self._trace(st.request, "done", reason=reason,
                     tokens=len(c.tokens), ttft=c.ttft_seconds,
                     tpot=c.tpot_seconds)
+
+    def _keep_written(self, slot: int, st: _Slot,
+                      blocks: Sequence[int]) -> None:
+        """A request ended normally: the whole blocks of what its slot
+        WROTE — the prompt and every generated token but the last, which
+        was sampled and never fed — go into the prefix cache before the
+        slot's one ``allocator.free`` (the cache takes its own reference a
+        new node; blocks cached at admission are skipped), so the stream's
+        next turn hits up to its last whole block and not only up to this
+        request's prompt. A model that keeps window rings also records
+        what the slot's rings hold now."""
+        if self.prefix_cache is None:
+            return
+        written = np.concatenate([
+            np.asarray(st.request.prompt, np.int32).reshape(-1),
+            np.asarray(st.tokens[:-1], np.int32)])
+        keys = chain_hashes(written, self.engine.block_size)
+        self.prefix_cache.insert(written, blocks, keys=keys)
+        if self._keeps_rings and keys:
+            self.held_rings[slot] = _HeldRings(
+                keys[-1], int(written.size), self.engine.window_from(slot))
+
+    def _take_slot(self, free: List[int], hit) -> tuple:
+        """The slot an admission goes into, taken off ``free``, and — for
+        a model that keeps window rings — what ``engine.prefill`` may
+        resume over: ``(slot, rings_held or None)``. A prefix hit that
+        ends exactly at the last whole block a FREE slot's finished request
+        wrote goes back into that slot, whose rings still hold the windows
+        (counted ``held``); every other hit rebuilds them (``rebuilt``),
+        in the first free slot whose rings no stream is waiting for, if
+        there is one. Whatever record the taken slot had is dropped: its
+        rings are about to be written."""
+        slot = next((s for s in free if s not in self.held_rings), free[0])
+        held = None
+        if self._keeps_rings and hit is not None:
+            if not hit.full:
+                for s in free:
+                    rec = self.held_rings.get(s)
+                    if (rec is not None and rec.key == hit.keys[-1]
+                            and self.engine.rings_cover(rec.length,
+                                                        hit.tokens)):
+                        slot, held = s, (rec.length, rec.win_from)
+                        break
+            self._m_resumes["held" if held else "rebuilt"].inc()
+        free.remove(slot)
+        self.held_rings.pop(slot, None)
+        return slot, held
 
     def _trace(self, request: Request, span: str,
                dur: Optional[float] = None, **payload) -> None:
@@ -1305,7 +1385,7 @@ class Scheduler:
                             self.allocator.free(hit.blocks)
                         break
             self.queue.popleft()
-            slot = free.pop(0)
+            slot, rings_held = self._take_slot(free, hit)
             self._acquire_adapter(req, slot)
             self._trace(req, "queue", dur=self.clock() - submitted_at,
                         slot=slot)
@@ -1388,6 +1468,8 @@ class Scheduler:
                     spec_kw["adapter_row"] = self._adapter_rows[slot]
                     spec_kw["adapter_scale"] = float(
                         self._adapter_scales[slot])
+                if rings_held is not None:
+                    spec_kw["rings_held"] = rings_held
                 on_chunk = self._count_chunk
                 if self.role == "prefill":
                     # chunk-granular shipping: each finished chunk commits
@@ -1629,7 +1711,7 @@ class Scheduler:
                 # next blocks; younger ones don't overtake it
                 return
             if outcome == "restored":
-                free.pop(0)
+                self.held_rings.pop(free.pop(0), None)
 
     def _restore_one(self, rid: str, slot: int,
                      done: List[Completion]) -> str:
@@ -1826,7 +1908,7 @@ class Scheduler:
             self._handoff_reject(req, gen, str(e))
             return "fallback"
         self.queue.popleft()
-        free.pop(0)
+        self.held_rings.pop(free.pop(0), None)
         self._handoff_artifacts.pop(req.id, None)
         row = np.zeros((self.engine.max_blocks_per_slot,), np.int32)
         row[:len(blocks)] = blocks
@@ -2102,7 +2184,7 @@ class Scheduler:
         self.engine.set_slot_length(slot, len(eff))
         imp_dur = self.clock() - t0
         self.queue.popleft()
-        free.pop(0)
+        self.held_rings.pop(free.pop(0), None)
         self._shipments.pop(req.id, None)
         slot_blocks = (list(hit.blocks)[:n_use] if hit is not None
                        else []) + blocks

@@ -68,7 +68,9 @@ SPANS = {
         "moe_pairs, moe_touched, index_keys, latent_rows, window_rows)"),
     "ftl:engine.prefill": (
         "engine", "prefill / prefill_packed (args new_tokens, start_pos, "
-        "bucket)"),
+        "bucket; prefill also held = 1 when it resumed over the slot's "
+        "held window rings, rebuilt_rows = the rows before start_pos it "
+        "recomputed to rebuild them)"),
     "ftl:engine.prefill.dispatch": (
         "engine", "the calls into the compiled prefill chunk programs "
         "return"),

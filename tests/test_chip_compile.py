@@ -195,17 +195,10 @@ def test_train_step_lowers_on_four_chip_fsdp_mesh(v5e):
     assert "tpu_custom_call" in lowered.as_text()
 
 
-def test_d4_train_step_keeps_scope_names_and_flash_call_names(v5e):
+def _d4_train_step_lowered(v5e):
     """The benchmark's training program (mistral-7b-v0.3-d4, seq 4096 x 3
-    rows) compiled for one chip of the described v5e: after XLA's fusion
-    every training scope of ``obs/trace.py`` ``SCOPES`` is still the
-    ``op_name`` of some instruction (the profiler reads device time by
-    model part from it), and the flash attention Mosaic calls are still
-    named ``attention.N`` — the name the benchmark's accepted kernel
-    readers find them by, which a scope opened between the ``attention``
-    module and the ``pallas_call`` would change."""
+    rows) lowered for one chip of the described v5e, and its sizes."""
     import json
-    import re
     import sys
     from pathlib import Path
 
@@ -242,9 +235,23 @@ def test_d4_train_step_keeps_scope_names_and_flash_call_names(v5e):
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one),
         jax.eval_shape(init_fn, jax.random.PRNGKey(0)))
     tokens = jax.ShapeDtypeStruct((rows, seq), jnp.int32, sharding=one)
-    hlo = jax.jit(make_train_step(model, opt, 1.0),
-                  donate_argnums=(0,)).lower(
-        state, tokens, tokens).compile().as_text()
+    return jax.jit(make_train_step(model, opt, 1.0),
+                   donate_argnums=(0,)).lower(state, tokens, tokens), d
+
+
+def test_d4_train_step_keeps_scope_names_and_flash_call_names(v5e):
+    """The benchmark's training program compiled for one chip of the
+    described v5e: after XLA's fusion
+    every training scope of ``obs/trace.py`` ``SCOPES`` is still the
+    ``op_name`` of some instruction (the profiler reads device time by
+    model part from it), and the flash attention Mosaic calls are still
+    named ``attention.N`` — the name the benchmark's accepted kernel
+    readers find them by, which a scope opened between the ``attention``
+    module and the ``pallas_call`` would change."""
+    import re
+
+    lowered, d = _d4_train_step_lowered(v5e)
+    hlo = lowered.compile().as_text()
     words = {w for name in re.findall(r'op_name="([^"]+)"', hlo)
              for w in re.findall(r"[A-Za-z_][A-Za-z0-9_]*", name)}
     want = {"loss_head", "grad_clip", "optimizer", "attention",
@@ -499,7 +506,18 @@ SERVING_PROGRAMS_AS_RECORDED = {
         "decode": "c277a36b8ea8c660e5c32c7aef55ed2dd83604f21ae23c867988f512fc"
                   "a04b55",
         "prefill": "0cb5064611870dadd96f990fc1ee28fea4928e3f11ad21a800b0824ad"
-                   "eb952da"}}
+                   "eb952da"},
+    # the second class (test_dots3_serving_programs_are_as_recorded), at
+    # commit edfc238 (PR 33)
+    "dots3": {
+        "decode": "fb17c2630eadcdcbd115082885dd0d491904c9d2bc950971da74a4deafe5"
+                  "6035",
+        "prefill": "ce1fc9015c94c391a83007118052ee5030d0c103ca3a3cf1e6a7ad76353"
+                   "0aecd"}}
+# and the training cell's step (mistral-7b-v0.3-d4, seq 4096 x 3 rows), at
+# commit edfc238 (PR 33), by test_d4_train_step_is_as_recorded
+TRAIN_PROGRAM_AS_RECORDED = (
+    "517207568acc6f26a940b9129d8b062e2ec0a7cbf61c66a124c2e1b85bba2e3d")
 
 
 def _lowered_serving_hashes(cfg, params, slots, per_slot, bs, blocks, sds):
@@ -588,16 +606,20 @@ def test_internlm2_serving_programs_are_as_recorded(v5e):
         SERVING_PROGRAMS_AS_RECORDED["internlm2"])
 
 
-def test_latent_decode_program_accesses_its_pools_in_place(v5e):
-    """The decode program of the latent / indexer / window / expert class
-    at the published widths (one full and one sliding layer, 2 held
-    experts, vocab 1024, 8 slots over longdecode's 5,121 blocks), compiled
-    for one described v5e: every pool and ring it returns aliases its input
-    and the program holds no ``copy`` of a pool's shape. A latent pool
-    stored by block, or with rows of 288 words, cost a pool-sized relayout
-    copy for every row access (ops/latent_attention.py)."""
+# one full and one sliding layer of the dots3 configuration, 2 held experts
+_DOTS3_TWO_LAYERS = dict(
+    num_hidden_layers=2, vocab_size=1024, n_routed_experts=2,
+    first_k_dense_replace=0,
+    layer_types=["full_attention", "sliding_attention"])
+
+
+def _dots3_programs(v5e, slots, per_slot, blocks, buckets, **cut):
+    """The decode program and the prefill chunk programs (one a bucket) of
+    the latent / indexer / window / expert class as the ``dots3`` cell's
+    configuration file states it, ``cut`` overriding keys of it, LOWERED
+    for one described v5e: ``(decode, {bucket: prefill}, the cache's
+    shapes)``."""
     import json
-    import re
     import sys
     import types
     from pathlib import Path
@@ -615,13 +637,10 @@ def test_latent_decode_program_accesses_its_pools_in_place(v5e):
 
     config = json.loads((root / "perfbench" / "configs"
                          / "dots3-note-prev-d5-ep8.json").read_text())
-    config.update(num_hidden_layers=2, vocab_size=1024, n_routed_experts=2,
-                  first_k_dense_replace=0,
-                  layer_types=["full_attention", "sliding_attention"])
+    config.update(cut)
     d = weights.dims_of(config)
     fam = weights.family_of(d)
     cfg = fam.preset(config)
-    slots, per_slot = 8, 640
     sds = _shapes_on(v5e.devices[0])
     on = lambda tree: jax.tree_util.tree_map(               # noqa: E731
         lambda a: sds(a.shape, a.dtype), tree)
@@ -629,16 +648,104 @@ def test_latent_decode_program_accesses_its_pools_in_place(v5e):
         lambda k: weights.make_param_tree(k, d, jnp.bfloat16),
         jax.random.PRNGKey(0)))
     cache = on(jax.eval_shape(lambda: init_latent_cache(
-        cfg, slots, POOL_BLOCK, POOL_BLOCKS)))
+        cfg, slots, POOL_BLOCK, blocks)))
     stub = types.SimpleNamespace(model=fam.model_class()(cfg), top_k=0,
                                  slots=slots, cfg=cfg)
     i32, f32 = jnp.int32, jnp.float32
     vec = lambda dt: sds((slots,), dt)                      # noqa: E731
-    compiled = jax.jit(
+    one = lambda dt: sds((), dt)                            # noqa: E731
+    decode = jax.jit(
         lambda *a: InferenceEngine._latent_decode_fn(stub, *a),
         donate_argnums=(1,)).lower(
         params, cache, sds((slots, per_slot), i32), vec(i32),
-        vec(jnp.bool_), vec(f32), vec(f32), vec(i32), vec(i32)).compile()
+        vec(jnp.bool_), vec(f32), vec(f32), vec(i32), vec(i32))
+    prefill = {
+        b: jax.jit(
+            lambda *a: InferenceEngine._latent_prefill_fn(stub, *a),
+            donate_argnums=(1,)).lower(
+            params, cache, sds((per_slot,), i32), sds((1, b), i32),
+            one(i32), one(i32), one(i32), one(i32), one(i32), one(f32),
+            one(f32), one(i32))
+        for b in buckets}
+    return decode, prefill, cache
+
+
+def test_dots3_serving_programs_are_as_recorded(v5e):
+    """PR 34 changed which chunk programs the host calls and with what
+    positions, not the programs: at the widths of the in-place test below
+    (one full and one sliding layer, 2 held experts, vocab 1024, 8 slots)
+    the lowered decode and 1 x 64 prefill programs are the parent's."""
+    import hashlib
+
+    decode, prefill, _ = _dots3_programs(v5e, 8, 640, POOL_BLOCKS, (64,),
+                                         **_DOTS3_TWO_LAYERS)
+    got = {"decode": decode.as_text(), "prefill": prefill[64].as_text()}
+    assert {k: hashlib.sha256(v.encode()).hexdigest()
+            for k, v in got.items()} == SERVING_PROGRAMS_AS_RECORDED["dots3"]
+
+
+def test_d4_train_step_is_as_recorded(v5e):
+    """The training cell's step lowers to the parent's program. A Mosaic
+    call's serialized body holds the source locations of its kernel, the
+    checkout's path among them, so the bodies are cut out of the hashed
+    text: what they hold is ``ops/flash_attention.py``, which is held
+    where it runs (the tests of the kernel, the cell's ``correct``)."""
+    import hashlib
+    import re
+
+    lowered, _ = _d4_train_step_lowered(v5e)
+    text, cut = re.subn(r'(\\22body\\22: \\22)[^\\]*(\\22)',
+                        r"\1\2", lowered.as_text())
+    assert cut >= 8                 # forward + backward of each of 4 layers
+    assert hashlib.sha256(text.encode()).hexdigest() == (
+        TRAIN_PROGRAM_AS_RECORDED)
+
+
+def test_latent_chunk_loop_covers_the_cells_remainders_by_its_own_costs(v5e):
+    """The two chunk programs of ``dots3-d5-ep8.sessions16k`` at the cell's
+    own sizes (5 layers, 32 held experts, 64 slots of 19,456 positions, a
+    pool of 77,825 blocks; ladder ``[64, 2048]``), compiled for one
+    described v5e, and the engine's cover rule over the costs their
+    compiler counts: a turn's 64 new rows are one 64-row call, a later
+    turn's 80 are two, and what a window rebuild leaves (1,600-1,872 rows,
+    or 336 with the windows held and the output not cached) stays one
+    2,048-row call — never 25 to 30 small ones. A compile, not a chip run:
+    the counts are the compiler's, no time comes out of this."""
+    from fault_tolerant_llm_training_tpu.inference.engine import (
+        cover_plan,
+        program_cost,
+    )
+
+    slots, max_len, buckets = 64, 19456, (64, 2048)
+    _, prefill, _ = _dots3_programs(v5e, slots, max_len // POOL_BLOCK,
+                                    1245184 // POOL_BLOCK + 1, buckets)
+    cost = {b: program_cost(p.compile()) for b, p in prefill.items()}
+    assert all(cost.values()), cost
+    # the small program is the cheaper one by both counts, and not by the
+    # ratio of the rows: it reads the same weights
+    for small, large in zip(cost[64], cost[2048]):
+        assert large / 32 < small < large / 2, cost
+    assert cover_plan(64, buckets, cost) == [64]
+    assert cover_plan(80, buckets, cost) == [64, 64]
+    for rows in (336, 1600, 1616, 1872, 2048):
+        assert cover_plan(rows, buckets, cost) == [2048], rows
+    assert cover_plan(2048 + 80, buckets, cost) == [2048, 64, 64]
+
+
+def test_latent_decode_program_accesses_its_pools_in_place(v5e):
+    """The decode program of the latent / indexer / window / expert class
+    at the published widths (one full and one sliding layer, 2 held
+    experts, vocab 1024, 8 slots over longdecode's 5,121 blocks), compiled
+    for one described v5e: every pool and ring it returns aliases its input
+    and the program holds no ``copy`` of a pool's shape. A latent pool
+    stored by block, or with rows of 288 words, cost a pool-sized relayout
+    copy for every row access (ops/latent_attention.py)."""
+    import re
+
+    slots = 8
+    decode, _, cache = _dots3_programs(v5e, slots, 640, POOL_BLOCKS, (),
+                                       **_DOTS3_TWO_LAYERS)
+    compiled = decode.compile()
     hlo = compiled.as_text()
     rows = POOL_BLOCKS * POOL_BLOCK
     # (the rope-key pool, 32 words a row and an eighth of the latent pool's

@@ -29,6 +29,7 @@ import pytest
 REPO = Path(__file__).resolve().parent.parent
 sys.path.insert(0, str(REPO))
 
+from perfbench.lib import program_records  # noqa: E402
 from perfbench.lib import reference as R  # noqa: E402
 from perfbench.lib import weights  # noqa: E402
 
@@ -184,13 +185,285 @@ def test_scheduler_serves_turns_and_restarts_from_the_prefix_cache(tiny,
     hits0 = sched.prefix_cache.hit_tokens
     serve("turn", np.concatenate(
         [hist, rng.integers(3, 512, size=6).astype(np.int32)]), 12)
-    # the cache holds what a request PREFILLED (its prompt's whole blocks):
-    # a turn resumes from its predecessor's prompt, not from its output
-    assert sched.prefix_cache.hit_tokens - hits0 == 48
+    # the cache holds what the finished request's slot WROTE (50 prompt +
+    # 11 fed tokens = 61 positions, 7 whole blocks): a turn resumes from
+    # the last whole block of its history, not from its predecessor's
+    # prompt
+    assert sched.prefix_cache.hit_tokens - hits0 == 56
     hits1 = sched.prefix_cache.hit_tokens
     serve("restart", np.concatenate(
         [base, rng.integers(3, 512, size=5).astype(np.int32)]), 8)
     assert sched.prefix_cache.hit_tokens - hits1 == 48
+
+
+# ------------------------------- a session's next turn over the slot's rings
+def _counts():
+    return program_records.counters()
+
+
+def _moved(before):
+    """The program's counters that moved since ``before``, by the names
+    the benchmark's readers see them under."""
+    return {k: v for k, v in program_records.change(
+        before, program_records.counters()).items() if v}
+
+
+def _session(rng, turns=3, new=6):
+    """A 50-token context and the new tokens of ``turns`` turns after it."""
+    return (rng.integers(3, 512, size=50).astype(np.int32),
+            [rng.integers(3, 512, size=new).astype(np.int32)
+             for _ in range(turns)])
+
+
+def _serve_session(tiny, engine, how, base, news, gen=12):
+    """The context and its turns through a fresh ``Scheduler`` over
+    ``engine``, each turn = history + the last answer + its new tokens:
+    ``held`` as the scheduler serves them, ``rebuilt`` with the slots'
+    records cleared before every turn, ``uncached`` with no prefix cache.
+    Returns [(slot, start_pos, rings_held, tokens, the logits of the round
+    after the prefill)] a request."""
+    from fault_tolerant_llm_training_tpu.inference.scheduler import (
+        Request, Scheduler)
+
+    engine.reset()
+    sched = Scheduler(engine, eos_token_id=None)
+    if how == "uncached":
+        sched.prefix_cache = None
+    seen, inner = [], engine.prefill
+
+    def prefill(slot, ids, **kw):
+        first = inner(slot, ids, **kw)
+        toks = np.zeros(engine.slots, np.int32)
+        toks[slot] = first
+        seen.append((slot, kw.get("start_pos", 0), kw.get("rings_held"),
+                     _peek_logits(engine, sched.block_tables, toks)[slot]))
+        return first
+
+    engine.prefill = prefill
+    try:
+        out, prompt = [], base
+        for i, extra in enumerate([None, *news]):
+            if extra is not None:
+                prompt = np.concatenate([prompt, extra])
+            if how == "rebuilt":
+                sched.held_rings.clear()
+            sched.submit(Request(id=f"t{i}", prompt=prompt,
+                                 max_new_tokens=gen))
+            done = []
+            while sched.pending():
+                done += sched.step()
+            (c,) = done
+            out.append(seen[-1][:3] + (list(c.tokens), seen[-1][3]))
+            prompt = np.concatenate([prompt, np.asarray(c.tokens, np.int32)])
+    finally:
+        del engine.prefill
+    assert sched.audit_block_leaks(strict=True) == []
+    return out
+
+
+def test_a_sessions_turns_over_held_rings_are_the_rebuilt_and_uncached_turns(
+        tiny, engine):
+    """Three turns after a context: served over the slot's held rings, with
+    the windows rebuilt, and with no cache at all, every turn gives the
+    reference's tokens and, after its prefill, the same next-round logits
+    to float32 noise. The held turns go back into their stream's slot,
+    resume at the last whole block the slot wrote with that request's
+    ``win_from``, and recompute no row; the rebuilt ones recompute 24."""
+    base, news = _session(np.random.default_rng(17))
+    before = _counts()
+    held = _serve_session(tiny, engine, "held", base, news)
+    moved_held = _moved(before)
+    before = _counts()
+    rebuilt = _serve_session(tiny, engine, "rebuilt", base, news)
+    moved_rebuilt = _moved(before)
+    plain = _serve_session(tiny, engine, "uncached", base, news)
+
+    prompt = base
+    for i, (h, r, u) in enumerate(zip(held, rebuilt, plain)):
+        if i:
+            prompt = np.concatenate([prompt, news[i - 1]])
+        seq = np.concatenate([prompt, np.asarray(h[3][:-1], np.int32)])
+        ref = _ref_logits(tiny, seq, np.arange(len(prompt) - 1, len(seq)))
+        assert h[3] == r[3] == u[3] == [int(t) for t in ref.argmax(-1)], i
+        # the round after the prefill: position len(prompt), fed the first
+        want = _ref_logits(tiny, seq, [len(prompt)])[0]
+        for got in (h[4], r[4], u[4]):
+            assert np.abs(got - want).max() < TOL, i
+        prompt = np.concatenate([prompt, np.asarray(h[3], np.int32)])
+    # the context wrote 50 + 11 = 61 positions: turn 1 resumes at 56; each
+    # turn adds 6 + 12: 79 -> 72, 97 -> 96
+    assert [(t[1], t[2]) for t in held] == [
+        (0, None), (56, (61, 0)), (72, (79, 0)), (96, (97, 0))]
+    assert len({t[0] for t in held}) == 1, "a turn left its stream's slot"
+    assert [(t[1], t[2]) for t in rebuilt] == [
+        (0, None), (56, None), (72, None), (96, None)]
+    assert all(t[1] == 0 and t[2] is None for t in plain)
+    assert moved_held["ftl_serve_window_resumes_total{how=held}"] == 3
+    assert "ftl_serve_window_resumes_total{how=rebuilt}" not in moved_held
+    assert "ftl_serve_prefill_rows_total{kind=recomputed}" not in moved_held
+    # 50 context rows, then what each turn's prompt (the last + 12 + 6
+    # tokens) holds past its hit
+    assert moved_held["ftl_serve_prefill_rows_total{kind=new}"] == (
+        50 + (68 - 56) + (86 - 72) + (104 - 96))
+    assert moved_rebuilt["ftl_serve_window_resumes_total{how=rebuilt}"] == 3
+    assert moved_rebuilt["ftl_serve_prefill_rows_total{kind=recomputed}"] == (
+        3 * 24)
+    assert (moved_rebuilt["ftl_serve_prefill_rows_total{kind=new}"]
+            == moved_held["ftl_serve_prefill_rows_total{kind=new}"])
+
+
+@pytest.mark.parametrize("what", ["another_request", "drain_roll_back",
+                                  "shorter_hit"])
+def test_a_slots_record_is_dropped_and_the_turn_rebuilds(tiny, engine, what):
+    """The record of what a free slot's rings hold goes when another
+    request is prefilled into the slot, when a drain rolls the turn's own
+    prefill back, and does not apply to a hit that ends before the last
+    whole block the slot wrote: the turn is then served with the windows
+    rebuilt (in a slot that holds no stream's rings, where there is one),
+    and its tokens are the reference's."""
+    from fault_tolerant_llm_training_tpu.inference.scheduler import (
+        Request, Scheduler)
+
+    rng = np.random.default_rng(23)
+    base, (extra,) = _session(rng, turns=1, new=40)
+    engine.reset()
+    stop = {"on": False}
+    sched = Scheduler(engine, eos_token_id=None,
+                      stop_check=lambda: stop["on"])
+    starts, inner = [], engine.prefill
+
+    def prefill(slot, ids, **kw):
+        starts.append((slot, kw.get("start_pos", 0), kw.get("rings_held")))
+        if what == "drain_roll_back" and kw.get("rings_held"):
+            stop["on"] = True       # the signal lands inside this prefill
+        return inner(slot, ids, **kw)
+
+    engine.prefill = prefill
+
+    def serve(rid, prompt, n=12, also=()):
+        for i, other in enumerate([prompt, *also]):
+            sched.submit(Request(id=f"{rid}{i or ''}", prompt=other,
+                                 max_new_tokens=n))
+        done = []
+        while sched.pending():
+            done += sched.step()
+        return done
+
+    try:
+        (c,) = serve("ctx", base)
+        hist = np.concatenate([base, np.asarray(c.tokens, np.int32)])
+        (slot,) = sched.held_rings
+        assert sched.held_rings[slot].length == 61
+        turn = np.concatenate([hist, extra])
+        if what == "another_request":
+            # three at once: the first two go where no stream's rings are
+            # held, the third has only the stream's slot left and leaves a
+            # record of its own there
+            others = rng.integers(3, 512, size=(3, 20)).astype(np.int32)
+            assert len(serve("other", others[0], also=others[1:])) == 3
+            assert [s for s, _, _ in starts[-3:]] == [
+                s for s in range(engine.slots) if s != slot] + [slot]
+            assert sched.held_rings[slot].length == 20 + 11
+        elif what == "shorter_hit":
+            assert sched.prefix_cache.evict(1) == 1     # the deepest block
+        before = _counts()
+        done = serve("turn", turn)
+        if what == "drain_roll_back":
+            # the held attempt stopped between its chunks and was rolled
+            # back: the request is unserved, the slot's record gone
+            assert done == [] and starts[-1] == (slot, 56, (61, 0))
+            assert [r.id for r in sched.unserved()] == ["turn"]
+            assert sched.held_rings == {}
+            stop["on"] = False
+            sched.resume_admission()
+            while sched.pending():
+                done += sched.step()
+        (c,) = done
+    finally:
+        del engine.prefill
+    seq = np.concatenate([turn, np.asarray(c.tokens[:-1], np.int32)])
+    ref = _ref_logits(tiny, seq, np.arange(len(turn) - 1, len(seq)))
+    assert list(c.tokens) == [int(t) for t in ref.argmax(-1)]
+    if what == "shorter_hit":
+        # rebuilt where no stream's rings were held: the record stays
+        assert starts[-1] == (slot + 1, 48, None)
+        assert sched.held_rings[slot].length == 61
+    else:
+        assert starts[-1] == (slot, 56, None)
+    assert _moved(before)["ftl_serve_window_resumes_total{how=rebuilt}"] == 1
+    assert sched.audit_block_leaks(strict=True) == []
+
+
+def test_engine_refuses_rings_that_do_not_cover_the_resume(tiny, engine):
+    """``rings_held`` is checked, not trusted: rings written 8 positions
+    past the resume point have lost the first row the resumed call reads
+    (window 9, ring 16: 7 is the most), a record that ends before it holds
+    nothing of the rows in between, and a ``win_from`` past it is no
+    request's."""
+    engine.reset()
+    per = engine.max_blocks_per_slot
+    row = 1 + np.arange(per, dtype=np.int32)
+    ids = np.random.default_rng(29).integers(3, 512, size=70).astype(np.int32)
+    assert engine.rings_cover(63, 56) and not engine.rings_cover(64, 56)
+    assert not engine.rings_cover(55, 56)
+    for held in [(64, 0), (55, 0), (60, 57)]:
+        with pytest.raises(ValueError, match="rings_held"):
+            engine.prefill(0, ids, block_row=row, start_pos=56,
+                           rings_held=held)
+
+
+# ------------------------------------------------ the chunk loop's cover rule
+def _stand_in_cost(bucket):
+    """(flops, bytes) of a chunk program that reads its weights whatever
+    its rows: a fixed part and a part a row, the fixed part large in bytes
+    (12 GB of weights beside 24 MB a row) and small in flops."""
+    return 0.09 + 0.0033 * bucket, 11.7 + 0.0243 * bucket
+
+
+_LADDERS = {"cell": [64, 2048], "longdecode": [32, 256, 2048],
+            "chat": [64, 128, 256, 512, 1024, 2048]}
+# rows -> the calls, by ladder: a remainder just over the smallest bucket
+# of a ladder WITHOUT the next rung runs as small calls (2 x 13.3 GB under
+# the 61.5 GB of a 2,048-row call), a large one as one large call (6 x 13.3
+# GB for 336 rows would read more; 25-30 small calls more by both counts);
+# a ladder WITH the rung is covered as it always was
+_COVERS = {
+    "cell": {1: [64], 64: [64], 65: [64, 64], 80: [64, 64], 336: [2048],
+             1600: [2048], 1872: [2048], 2048: [2048], 2049: [2048, 64]},
+    "longdecode": {1: [32], 64: [256], 65: [256], 80: [256],
+                   336: [256, 256], 1600: [2048], 1872: [2048],
+                   2048: [2048], 2049: [2048, 32]},
+    "chat": {1: [64], 64: [64], 65: [128], 80: [128], 336: [512],
+             1600: [2048], 1872: [2048], 2048: [2048], 2049: [2048, 64]},
+}
+
+
+@pytest.mark.parametrize("rows", [1, 64, 65, 80, 336, 1600, 1872, 2048, 2049])
+@pytest.mark.parametrize("ladder", list(_LADDERS))
+def test_the_chunk_loop_covers_a_remainder_by_its_cheapest_calls(ladder,
+                                                                 rows):
+    from fault_tolerant_llm_training_tpu.inference.engine import cover_plan
+
+    buckets = _LADDERS[ladder]
+    cost = {b: _stand_in_cost(b) for b in buckets}
+    plan = cover_plan(rows, buckets, cost)
+    assert plan == _COVERS[ladder][rows]
+    # it covers the rows and no call of it is idle
+    assert sum(plan) >= rows > sum(plan) - plan[-1]
+    # never dearer than the plain cover, by either count; with no costs it
+    # IS the plain cover: whole largest chunks, then the first bucket that
+    # holds the rest
+    plain = cover_plan(rows, buckets)
+    whole, rest = divmod(rows, buckets[-1])
+    assert plain == [buckets[-1]] * whole + (
+        [next(b for b in buckets if b >= rest)] if rest else [])
+    for i in (0, 1):
+        assert (sum(cost[b][i] for b in plan)
+                <= sum(cost[b][i] for b in plain))
+    # cheaper by ONE count only is no reason: with flops alone lower (a
+    # program that reads 60 GB whatever its rows) the large call stays
+    lopsided = {b: (cost[b][0], 60.0) for b in buckets}
+    assert cover_plan(rows, buckets, lopsided) == plain
 
 
 def test_window_layers_hold_the_window_not_the_context(tiny, engine):

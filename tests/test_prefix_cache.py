@@ -387,6 +387,100 @@ def test_drain_mid_prefill_rolls_back_hit_references():
     assert sched.audit_block_leaks(strict=True) == []
 
 
+@pytest.mark.parametrize("how", ["length", "eos"])
+def test_a_normal_finish_caches_the_blocks_decode_wrote(how):
+    """A request that ends by its budget or by EOS leaves the whole blocks
+    of what its slot WROTE — prompt + every generated token but the last —
+    in the cache, taken BEFORE the slot's one free: the cache holds one
+    reference a block, nothing is shared, the leak guard is clean, and the
+    stream's continuation hits up to the last whole block."""
+    from fault_tolerant_llm_training_tpu.inference.prefix_cache import (
+        chain_hashes)
+    from fault_tolerant_llm_training_tpu.inference.scheduler import (
+        Request, Scheduler)
+
+    eng = _FakeCacheEngine(slots=2, max_len=64, block_size=8)
+    # the double's stream is 1, 2, 3, ...: EOS = 14 ends it at 14 tokens
+    sched = Scheduler(eng, eos_token_id=14 if how == "eos" else None)
+    prompt = list(range(100, 112))              # 12 tokens: 1 whole block
+    sched.submit(Request(id="a", prompt=list(prompt),
+                         max_new_tokens=14 if how == "length" else 40))
+    sched.step()                                # admitted: the prompt's block
+    assert sched.prefix_cache.cached_blocks == 1
+    table = sched.block_tables[0].copy()
+    (c,) = sched.run()
+    assert c.reason == how and c.tokens == list(range(1, 15))
+    # wrote 12 + 13 = 25 positions: 3 whole blocks, the slot's own
+    written = prompt + c.tokens[:-1]
+    assert sched.prefix_cache.cached_blocks == 3
+    hit = sched.prefix_cache.match(prompt + c.tokens + [7, 7, 7])
+    assert hit.tokens == 24 and hit.blocks == list(table[:3])
+    assert hit.keys == chain_hashes(written, 8)
+    assert all(sched.allocator.refcount(b) == 1 for b in hit.blocks)
+    assert sched.allocator.used_count == 3
+    assert sched.allocator.shared_count == 0
+    assert sched.audit_block_leaks(strict=True) == []
+    # the next turn resumes past everything the slot wrote in whole blocks
+    eng.prefilled_positions = 0
+    sched.submit(Request(id="b", prompt=prompt + c.tokens + [7, 7, 7],
+                         max_new_tokens=2))
+    sched.run()
+    assert eng.prefilled_positions == 12 + 14 + 3 - 24
+    # eviction frees them like any cached block: leaf first, all of them
+    n = sched.prefix_cache.cached_blocks
+    assert sched.prefix_cache.evict(99) == n
+    assert sched.allocator.free_count == sched.allocator.capacity
+
+
+def test_a_second_identical_finish_adds_no_node():
+    """The same stream finishing twice (the second over a full COW hit of
+    the first's blocks) caches nothing twice: the canonical blocks stay
+    the first's, and the second's private copies are freed with its slot."""
+    from fault_tolerant_llm_training_tpu.inference.scheduler import (
+        Request, Scheduler)
+
+    eng = _FakeCacheEngine(slots=1, max_len=64, block_size=8)
+    sched = Scheduler(eng, eos_token_id=None)
+    prompt = list(range(200, 216))              # 2 whole blocks
+    for rid in ("a", "b"):
+        sched.submit(Request(id=rid, prompt=list(prompt), max_new_tokens=10))
+        sched.run()
+        # 16 + 9 = 25 positions: 3 whole blocks, once
+        assert sched.prefix_cache.cached_blocks == 3
+        assert sched.allocator.used_count == 3
+        if rid == "a":
+            first = sched.prefix_cache.match(prompt + list(range(1, 10)))
+    again = sched.prefix_cache.match(prompt + list(range(1, 10)))
+    assert again.blocks == first.blocks and again.tokens == 24
+    assert sched.metrics()["prefix_cow_copies"] == 1
+    assert sched.audit_block_leaks(strict=True) == []
+
+
+@pytest.mark.parametrize("how", ["drain_roll_back", "one_token"])
+def test_no_other_end_of_a_request_caches_more_than_its_prompt(how):
+    """A prefill rolled back by a drain caches nothing of its request (the
+    request is unserved, its blocks freed exactly once), and a request
+    that ends straight out of its prefill wrote nothing past its prompt."""
+    from fault_tolerant_llm_training_tpu.inference.scheduler import (
+        Request, Scheduler)
+
+    eng = _FakeCacheEngine(slots=1, max_len=64, block_size=8, bucket=16)
+    fired = {"on": how == "drain_roll_back"}
+    sched = Scheduler(eng, eos_token_id=None, stop_check=lambda: fired["on"])
+    sched.submit(Request(id="r", prompt=list(range(300, 340)),
+                         max_new_tokens=1 if how == "one_token" else 8))
+    while sched.pending():
+        sched.step()
+    if how == "drain_roll_back":
+        assert [r.id for r in sched.unserved()] == ["r"]
+        assert sched.prefix_cache.cached_blocks == 0
+        assert sched.allocator.free_count == sched.allocator.capacity
+    else:
+        assert [c.reason for c in sched.completed] == ["length"]
+        assert sched.prefix_cache.cached_blocks == 5    # 40 // 8, the prompt
+    assert sched.audit_block_leaks(strict=True) == []
+
+
 def test_leak_guard_audits_once_and_raises_strict(caplog):
     from fault_tolerant_llm_training_tpu.inference.scheduler import (
         Request, Scheduler)
