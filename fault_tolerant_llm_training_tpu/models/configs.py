@@ -227,13 +227,22 @@ class LatentMoEConfig:
     a dense SwiGLU in the first ``first_dense_layers`` layers and a
     sigmoid-routed expert layer after them. The expert layer routes over
     all ``n_routed_experts``, computes the ``held_experts`` =
-    (first, count) it is told it holds, and adds the shared expert: the
+    (first, count) it is told it holds, and adds the shared experts: the
     chip's share of an expert-parallel deployment, with no exchange.
 
-    Serving only (``inference/engine.py`` finds the class by this type);
-    the fields the engine reads of any configuration (``vocab_size``,
-    ``seq_len``, ``layer_impl``, ``remat``, ``paged_kernel``, the dtypes,
-    ``replace``) are here under the same names."""
+    What a published model leaves out is a value of these fields, not a
+    flag: ``q_lora_rank=None`` projects the query straight from the
+    hidden state (one ``wq``); ``index_n_heads=0`` gives the full layers
+    no indexer (dense causal attention); ``head_gate=False`` drops the
+    head-wise output gate; ``lora_rescale=False`` the latent rescale.
+
+    Served (``inference/engine.py`` finds the class by this type) where the
+    full layers have an indexer; trained (``training/loop.py``, the
+    uncached forward of :attr:`trains`) where every layer is a full layer
+    without one. The fields the engine and the trainer read of any
+    configuration (``vocab_size``, ``seq_len``, ``layer_impl``, ``remat``,
+    ``attention_impl``, ``paged_kernel``, the dtypes, ``replace``) are
+    here under the same names."""
 
     dim: int = 5120
     n_layers: int = 5
@@ -243,13 +252,13 @@ class LatentMoEConfig:
     vocab_size: int = -1
     # full-attention mixer
     n_heads: int = 128
-    q_lora_rank: int = 1024
+    q_lora_rank: Optional[int] = 1024     # None: the query from x directly
     kv_lora_rank: int = 512
     qk_nope_head_dim: int = 128
     qk_rope_head_dim: int = 64
     v_head_dim: int = 128
     rope_theta: float = 8e7
-    # its indexer
+    # its indexer (0 heads: none, the full layers attend densely)
     index_n_heads: int = 64
     index_head_dim: int = 128
     index_topk: int = 2048
@@ -264,6 +273,8 @@ class LatentMoEConfig:
     sliding_window: int = 513
     # variance alignment of both latents (alpha = sqrt(dim / rank))
     lora_rescale: bool = True
+    # head-wise sigmoid gate on the attention output, both mixers
+    head_gate: bool = True
     # FFNs
     first_dense_layers: int = 1
     dense_hidden_dim: int = 13824
@@ -281,6 +292,10 @@ class LatentMoEConfig:
     embed_impl: str = "auto"
     layer_impl: str = "loop"
     remat: bool = False
+    # the uncached training forward's attention: "auto" = the Pallas
+    # kernel (ops/flash_attention.py flash_attention_bhsd) on a TPU, XLA
+    # elsewhere; "pallas" / "xla" force one
+    attention_impl: str = "auto"
 
     def __post_init__(self):
         kinds = set(self.layer_types)
@@ -303,6 +318,18 @@ class LatentMoEConfig:
                              "layers differ, there is no scan form)")
         if self.index_topk < 1 or self.sliding_window < 1:
             raise ValueError("index_topk and sliding_window must be >= 1")
+        if self.lora_rescale and self.q_lora_rank is None:
+            raise ValueError("lora_rescale needs a query latent "
+                             "(q_lora_rank)")
+        if self.attention_impl not in ("auto", "pallas", "xla"):
+            raise ValueError(f"attention_impl {self.attention_impl!r}: "
+                             f"auto, pallas or xla")
+
+    @property
+    def trains(self) -> bool:
+        """Whether the uncached training forward covers this schedule:
+        every layer full, with no indexer (dense causal attention)."""
+        return not self.index_n_heads and not self.sliding_layers
 
     def mixer(self, kind: str) -> dict:
         """The latent-attention sizes of a layer kind."""
@@ -388,6 +415,19 @@ PRESETS = {
         sliding_window=9, dense_hidden_dim=96, moe_hidden_dim=32,
         n_routed_experts=8, held_experts=(0, 4), num_experts_per_tok=2,
         vocab_size=512, seq_len=128,
+    ),
+    # Hermetic shape of the class as it trains: no query latent, no
+    # indexer, no head gate, no latent rescale, two shared experts, 4 of
+    # 16 routed experts held, top-3 (the shape of the kanana2 cell's
+    # configuration at a size the CPU tests can pass).
+    "tiny-latent-train": LatentMoEConfig(
+        dim=64, n_layers=3, layer_types=("full",) * 3, norm_eps=1e-6,
+        n_heads=4, q_lora_rank=None, kv_lora_rank=16, qk_nope_head_dim=16,
+        qk_rope_head_dim=8, v_head_dim=16, rope_theta=1e6,
+        index_n_heads=0, lora_rescale=False, head_gate=False,
+        dense_hidden_dim=96, moe_hidden_dim=32, n_routed_experts=16,
+        held_experts=(0, 4), num_experts_per_tok=3, n_shared_experts=2,
+        routed_scaling_factor=2.448, vocab_size=512, seq_len=128,
     ),
 }
 

@@ -14,7 +14,8 @@ untied head), with u a mixer's normed input:
   c_kv W_kvb``; softmax over the layer's visible set of ``(q_n . k_n + q_r
   . k_r) / sqrt(d_n + d_r)``; ``out = concat_h(sigmoid(u W_g)_h o_h) W_o``.
   ``a = sqrt(dim / rank)`` (``lora_rescale``). The cache holds ``c_kv`` and
-  ``k_r`` a token.
+  ``k_r`` a token. With ``q_lora_rank=None`` the query is ``u W_q`` (no
+  latent); with ``head_gate=False`` there is no gate.
 - the indexer of a full layer: ``q^I_j = c_q W^I_qb`` and ``k^I =
   LayerNorm(u W^I_k)``, RoPE on the first ``d_r`` values of each, ``w = (u
   W^I_w) / sqrt(H_I d_I)``, score ``I[t, s] = sum_j w[t, j] relu(q^I[t, j] .
@@ -29,11 +30,15 @@ untied head), with u a mixer's normed input:
   and adds the shared expert. No exchange: what the absent experts would
   add is the other chips' part.
 
-Serving only. One forward, :meth:`LatentMoETransformer.forward_with_cache`,
+Two forwards. Serving: :meth:`LatentMoETransformer.forward_with_cache`,
 through ``inference/kv_cache.py`` ``LatentKVCache``: a one-token query a
 slot (decode: the indexer's indices, rows gathered, the up-projection
 absorbed) or one slot's chunk (prefill: the same sets as masks, rows
 expanded a key block at a time). ``ops/latent_attention.py`` holds the reads.
+Training (a schedule of full layers with no indexer): ``__call__``, batched
+and uncached, the attention ``ops/flash_attention.py``
+``flash_attention_bhsd`` (query/key and value of their own widths), the
+held experts' grouped matmuls differentiated through ``ragged_dot``.
 """
 
 import math
@@ -156,14 +161,17 @@ class LatentAttention(nn.Module):
         ``write_valid`` (B, S): real rows; ``pool_valid`` (B, S): of those,
         the rows a full layer writes (a resumed prefill recomputes rows the
         pools already hold and must not write them: they may be shared).
-        Returns (out (B, S, dim), new cache part)."""
+        Returns (out (B, S, dim), new cache part). ``cache`` None is the
+        uncached training forward of a full layer without an indexer:
+        whole rows from position 0, dense causal attention, any B; the
+        cache part returned is None."""
         from ..inference.kv_cache import write_paged_kv
 
         cfg, m = self.cfg, self.cfg.mixer(self.kind)
         h, rq, r = m["heads"], m["q_rank"], m["kv_rank"]
         dn, dr, dv = m["nope"], m["rope"], m["v"]
         b, s = u.shape[:2]
-        if s > 1 and b != 1:
+        if cache is not None and s > 1 and b != 1:
             raise ValueError("a chunk (S > 1) is one slot's: packed "
                              "prefill is not written for this model")
         dense = dict(use_bias=False, dtype=cfg.dtype,
@@ -173,10 +181,15 @@ class LatentAttention(nn.Module):
         pos = offsets[:, None] + jnp.arange(s, dtype=jnp.int32)[None, :]
         cos, sin = rope_cos_sin(dr, m["theta"], pos)
 
-        c_q = RMSNorm(rq, cfg.norm_eps, cfg.param_dtype, name="q_norm")(
-            nn.Dense(rq, name="wq_a", **dense)(u)) * a_q
-        q = nn.Dense(h * (dn + dr), name="wq_b", **dense)(c_q).reshape(
-            b, s, h, dn + dr)
+        if rq is None:      # no query latent: q straight from u
+            c_q = None
+            q = nn.Dense(h * (dn + dr), name="wq", **dense)(u).reshape(
+                b, s, h, dn + dr)
+        else:
+            c_q = RMSNorm(rq, cfg.norm_eps, cfg.param_dtype, name="q_norm")(
+                nn.Dense(rq, name="wq_a", **dense)(u)) * a_q
+            q = nn.Dense(h * (dn + dr), name="wq_b", **dense)(c_q).reshape(
+                b, s, h, dn + dr)
         q_n, q_r = q[..., :dn], apply_rope(q[..., dn:], cos, sin)
         ckr = nn.Dense(r + dr, name="wkv_a", **dense)(u)
         c_kv = RMSNorm(r, cfg.norm_eps, cfg.param_dtype, name="kv_norm")(
@@ -186,8 +199,10 @@ class LatentAttention(nn.Module):
         w_kvb = _Kernel((r, h * (dn + dv)), cfg.param_dtype,
                         name="wkv_b")().astype(cfg.dtype).reshape(
             r, h, dn + dv)
-        gate = jax.nn.sigmoid(nn.Dense(h, name="wg", **dense)(u).astype(
-            jnp.float32))
+        gate = None
+        if cfg.head_gate:
+            gate = jax.nn.sigmoid(nn.Dense(h, name="wg", **dense)(u).astype(
+                jnp.float32))
         scale = (dn + dr) ** -0.5
 
         def absorbed():     # one query a slot: q_n W_kvb_k^T
@@ -198,7 +213,11 @@ class LatentAttention(nn.Module):
                               w_kvb[..., dn:],
                               preferred_element_type=jnp.float32)[:, None]
 
-        if self.kind == "full":
+        if cache is None:
+            o = causal_latent_attention(q_n, q_r, c_kv, k_r, w_kvb, dn,
+                                        cfg.attention_impl)
+            new_cache = None
+        elif self.kind == "full":
             latent_pool, rope_pool, index_pool = cache
             q_i, k_i, w_i = Indexer(cfg, name="indexer")(u, c_q, cos, sin)
             bs = index_pool.shape[2]
@@ -244,8 +263,42 @@ class LatentAttention(nn.Module):
                 ring = la.write_window_rows(ring, row, slot_ids, offsets,
                                             write_valid)
             new_cache = (ring,)
-        out = (o * gate[..., None]).astype(cfg.dtype).reshape(b, s, h * dv)
+        if gate is not None:
+            o = o * gate[..., None]
+        out = o.astype(cfg.dtype).reshape(b, s, h * dv)
         return nn.Dense(cfg.dim, name="wo", **dense)(out), new_cache
+
+
+def causal_latent_attention(q_n, q_r, c_kv, k_r, w_kvb, dn: int,
+                            impl: str = "auto"):
+    """The uncached causal attention of a full layer without an indexer:
+    q_n (B, S, H, dn), q_r (B, S, H, dr) roped, c_kv (B, S, r), k_r (B, S,
+    dr) roped, w_kvb (r, H, dn + dv). Each head's key is ``[k_n | k_r]``,
+    one 192-wide contraction at Kanana's widths with the one rope key all
+    heads share; the value keeps its own width. Returns (B, S, H, dv).
+    ``impl``: "auto" runs the Pallas kernel on a TPU and XLA elsewhere."""
+    from ..ops.flash_attention import flash_attention_bhsd
+
+    b, s, h, _ = q_n.shape
+    dr = q_r.shape[-1]
+    kv = jnp.einsum("bsr,rhe->bhse", c_kv, w_kvb)         # (B, H, S, dn+dv)
+    k = jnp.concatenate([kv[..., :dn], jnp.broadcast_to(
+        k_r[:, None], (b, h, s, dr)).astype(kv.dtype)], axis=-1)
+    v = kv[..., dn:]
+    q = jnp.transpose(jnp.concatenate([q_n, q_r.astype(q_n.dtype)], axis=-1),
+                      (0, 2, 1, 3))                        # (B, H, S, dn+dr)
+    if impl == "pallas" or (impl == "auto"
+                            and jax.default_backend() == "tpu"):
+        o = flash_attention_bhsd(q, k, v)
+    else:
+        sc = jnp.einsum("bhqd,bhkd->bhqk", q, k,
+                        preferred_element_type=jnp.float32) * (
+            q.shape[-1] ** -0.5)
+        keep = jnp.arange(s)[:, None] >= jnp.arange(s)[None, :]
+        p = jax.nn.softmax(jnp.where(keep, sc, -jnp.inf), axis=-1)
+        o = jnp.einsum("bhqk,bhkd->bhqd", p.astype(v.dtype), v,
+                       preferred_element_type=jnp.float32).astype(v.dtype)
+    return jnp.transpose(o, (0, 2, 1, 3))
 
 
 class ExpertLayer(nn.Module):
@@ -264,10 +317,21 @@ class ExpertLayer(nn.Module):
             self.shared = SwiGLU(d, hdn * cfg.n_shared_experts, cfg.dtype,
                                  cfg.param_dtype)
 
-    def parts(self, x, token_valid):
+    def parts(self, x, token_valid, train: bool = False):
         """(routed (B, S, dim), shared (B, S, dim), pairs, touched): the
         held experts' part of the layer, the shared expert's, the (token,
-        held expert) pairs computed and the held experts with a token."""
+        held expert) pairs computed and the held experts with a token.
+
+        ``train``: the layer is differentiated. On a TPU ``ragged_dot``
+        leaves the rows past its groups undefined, in the forward and in
+        the transposes the backward runs alike; the backward would scatter
+        the undefined rows of the activations' gradient into the tokens'
+        own. So the training path zeroes the rows past the held pairs after
+        the gather (its transpose zeroes them in that gradient) and after
+        the first two grouped matmuls (no undefined row enters the products
+        the backward's transposes read). The last one's rows past the
+        groups need no select: the combine's select drops them in the
+        forward, and its transpose gives them exact zeros."""
         cfg = self.cfg
         first, held_n = cfg.held_experts
         k = cfg.num_experts_per_tok
@@ -297,9 +361,14 @@ class ExpertLayer(nn.Module):
             pair_w = jnp.where(held, weight, 0.0)               # (N, k)
         with scope("moe_experts"):
             w1, w3, w2 = self.experts()
-            xs = jnp.take(x_flat, token_of, axis=0)
-            gate = jax.lax.ragged_dot(xs, w1.astype(cfg.dtype), sizes)
-            up = jax.lax.ragged_dot(xs, w3.astype(cfg.dtype), sizes)
+            defined = lambda a: a                          # noqa: E731
+            if train:
+                rows = (jnp.arange(n * k) < jnp.sum(sizes))[:, None]
+                defined = lambda a: jnp.where(rows, a, 0)  # noqa: E731
+            xs = defined(jnp.take(x_flat, token_of, axis=0))
+            gate = defined(jax.lax.ragged_dot(xs, w1.astype(cfg.dtype),
+                                              sizes))
+            up = defined(jax.lax.ragged_dot(xs, w3.astype(cfg.dtype), sizes))
             out = jax.lax.ragged_dot(
                 (jax.nn.silu(gate) * up).astype(cfg.dtype),
                 w2.astype(cfg.dtype), sizes)
@@ -317,8 +386,8 @@ class ExpertLayer(nn.Module):
         return (routed, shared, jnp.sum(sizes),
                 jnp.sum(sizes > 0, dtype=jnp.int32))
 
-    def __call__(self, x, token_valid):
-        routed, shared, pairs, touched = self.parts(x, token_valid)
+    def __call__(self, x, token_valid, train: bool = False):
+        routed, shared, pairs, touched = self.parts(x, token_valid, train)
         return routed + shared, pairs, touched
 
 
@@ -379,7 +448,7 @@ class LatentMoEBlock(nn.Module):
             pairs = touched = jnp.int32(0)
         else:
             ffn, pairs, touched = ExpertLayer(cfg, name="feed_forward")(
-                normed, write_valid)
+                normed, write_valid, cache is None)
         return h + ffn, new_cache, pairs, touched
 
 
@@ -391,7 +460,9 @@ class LatentMoETransformer(nn.Module):
     def setup(self):
         cfg = self.cfg
         self.tok_embeddings = TokenEmbed(cfg)
-        self.layers = [LatentMoEBlock(cfg, i) for i in range(cfg.n_layers)]
+        # a block's activations are recomputed in the backward under remat
+        block = nn.remat(LatentMoEBlock) if cfg.remat else LatentMoEBlock
+        self.layers = [block(cfg, i) for i in range(cfg.n_layers)]
         self.norm = RMSNorm(cfg.dim, cfg.norm_eps, cfg.param_dtype)
         self.output = nn.Dense(cfg.vocab_size, use_bias=False,
                                dtype=cfg.dtype, param_dtype=cfg.param_dtype,
@@ -462,12 +533,26 @@ class LatentMoETransformer(nn.Module):
             window=tuple(window), win_from=win_from), stats
 
     def __call__(self, tokens):
-        """Logits (B, S, vocab) of whole sequences, each row through a
-        scratch cache of its own as one chunk from position 0 — the
-        uncached forward, for ``init`` and for tests; serving goes through
+        """Logits (B, S, vocab) of whole sequences from position 0.
+
+        Where the configuration :attr:`~LatentMoEConfig.trains`: the
+        batched, uncached training forward — every block over all B rows
+        at once, dense causal attention, the expert layers dropless over
+        the held experts — differentiable, each block rematerialised under
+        ``cfg.remat``. It sows ``stats/moe`` = (pairs, touched), the
+        (token, held expert) pairs computed and the held experts with a
+        token, summed over expert layers (``training/step.py`` reads them
+        with ``mutable=["stats"]``). The head matmul is read with the loss
+        (``loss_head``), as the Llama class's is.
+
+        Otherwise (an indexer or sliding layers): each row through a
+        scratch cache of its own as one chunk from position 0, for
+        ``init`` and for tests; serving goes through
         :meth:`forward_with_cache`."""
         from ..inference.kv_cache import init_latent_cache
 
+        if self.cfg.trains:
+            return self._train_forward(tokens)
         b, s = tokens.shape
         bs = 16
         nb = -(-s // bs)
@@ -481,3 +566,18 @@ class LatentMoETransformer(nn.Module):
                 jnp.zeros((1,), jnp.int32))
             rows.append(logits)
         return jnp.concatenate(rows, axis=0)
+
+    def _train_forward(self, tokens):
+        b, s = tokens.shape
+        offsets = jnp.zeros((b,), jnp.int32)
+        valid = jnp.ones((b, s), jnp.bool_)
+        x = self.tok_embeddings(tokens)
+        pairs = touched = jnp.int32(0)
+        for layer in self.layers:
+            x, _, p, t = layer(x, offsets, None, None, valid, valid, None,
+                               None)
+            pairs, touched = pairs + p, touched + t
+        self.sow("stats", "moe", jnp.stack([pairs, touched]))
+        x = self.norm(x)
+        with scope("loss_head"):
+            return self.output(x)

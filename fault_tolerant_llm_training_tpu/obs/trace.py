@@ -116,11 +116,13 @@ SCOPES = {
                   "forward and the cross-entropy"),
     "grad_clip": ("kernels, train", "global gradient norm and clip"),
     "optimizer": ("kernels, train", "optax update and parameter apply"),
-    "moe_route": ("kernels, serve", "expert layer: router matmul, sigmoid, "
+    "moe_route": ("kernels", "expert layer: router matmul, sigmoid, "
                   "top-k, grouping of (token, expert) pairs by held expert"),
-    "moe_experts": ("kernels, serve", "expert layer: the grouped matmuls "
-                    "over the held experts and the weighted combine"),
-    "moe_shared": ("kernels, serve", "expert layer: the shared expert"),
+    "moe_experts": ("kernels", "expert layer: the grouped matmuls "
+                    "over the held experts and the weighted combine (the "
+                    "compiler's ragged-dot calls carry no scope: readers "
+                    "tell them by name)"),
+    "moe_shared": ("kernels", "expert layer: the shared expert"),
     "index_select": ("kernels, serve", "indexer of a full latent layer: "
                      "its query projections, scores over the cached index "
                      "keys, top-k (decode) or k-th-largest mask (chunk)"),

@@ -435,7 +435,7 @@ def _fwd_kernel(*refs, block_k: int, scale: float, causal: bool,
 
     init = (jnp.full((block_q,), NEG_INF, jnp.float32),
             jnp.zeros((block_q,), jnp.float32),
-            jnp.zeros((block_q, d), jnp.float32))
+            jnp.zeros((block_q, v_ref.shape[-1]), jnp.float32))
     carry = jax.lax.fori_loop(
         0, n_full, functools.partial(body, masked=False), init)
     m, l, acc = jax.lax.fori_loop(
@@ -797,11 +797,14 @@ def _flash_fwd(q, k, v, causal, interpret):
 
 
 def _flash_fwd_t(qt, kt, vt, causal, interpret, rope_tables=None):
-    # Head-major (B, H, S, D) operands — heads are a grid axis.
+    # Head-major (B, H, S, D) operands — heads are a grid axis. The value
+    # (and so the output) may be narrower than the query/key: D is q/k's
+    # width, dv v's (latent attention: 192 / 128).
     # rope_tables: optional (cos2, sin2) interleave-duplicated (S, D) fp32
     # tables — the kernels then apply RoPE to q/k tiles in VMEM
     # (flash_attention_rope); q/k arrive RAW.
     b, h, s, d = qt.shape
+    dv = vt.shape[-1]
     kv_heads = kt.shape[1]
     group = h // kv_heads
     block_q, block_k = _blocks(s, *_active_tiles(s)[0])
@@ -820,11 +823,12 @@ def _flash_fwd_t(qt, kt, vt, causal, interpret, rope_tables=None):
         lse_spec = pl.BlockSpec((1, 1, block_q, 1),
                                 lambda bi, hi, qi, *_: (bi, hi, qi, 0))
     out_shape = [
-        jax.ShapeDtypeStruct(qt.shape, qt.dtype),
+        jax.ShapeDtypeStruct((b, h, s, dv), qt.dtype),
         jax.ShapeDtypeStruct(lse_shape, jnp.float32),
     ]
     out_specs = [
-        pl.BlockSpec((1, 1, block_q, d), lambda bi, hi, qi, *_: (bi, hi, qi, 0)),
+        pl.BlockSpec((1, 1, block_q, dv),
+                     lambda bi, hi, qi, *_: (bi, hi, qi, 0)),
         lse_spec,
     ]
 
@@ -837,7 +841,7 @@ def _flash_fwd_t(qt, kt, vt, causal, interpret, rope_tables=None):
             pl.BlockSpec((1, 1, block_q, d),
                          lambda bi, hi, qi: (bi, hi, qi, 0)),
             pl.BlockSpec((1, 1, s, d), lambda bi, hi, qi: (bi, hi // group, 0, 0)),
-            pl.BlockSpec((1, 1, s, d), lambda bi, hi, qi: (bi, hi // group, 0, 0)),
+            pl.BlockSpec((1, 1, s, dv), lambda bi, hi, qi: (bi, hi // group, 0, 0)),
         ]
         operands = (qt, kt, vt)
         scratch = []
@@ -878,11 +882,11 @@ def _flash_fwd_t(qt, kt, vt, causal, interpret, rope_tables=None):
 
             def ck_idx(bi, hi, qi, ki):
                 return (ki, 0)
-        kv_spec = pl.BlockSpec((1, 1, block_k, d), kv_idx)
         in_specs = [
             pl.BlockSpec((1, 1, block_q, d),
                          lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
-            kv_spec, kv_spec,
+            pl.BlockSpec((1, 1, block_k, d), kv_idx),
+            pl.BlockSpec((1, 1, block_k, dv), kv_idx),
         ]
         operands = (qt, kt, vt)
         if rope:
@@ -900,7 +904,7 @@ def _flash_fwd_t(qt, kt, vt, causal, interpret, rope_tables=None):
             scratch_shapes=[
                 pltpu.VMEM((block_q, 1), jnp.float32),
                 pltpu.VMEM((block_q, 1), jnp.float32),
-                pltpu.VMEM((block_q, d), jnp.float32),
+                pltpu.VMEM((block_q, dv), jnp.float32),
             ],
             interpret=interpret,
         )(*operands)
@@ -928,8 +932,10 @@ def _flash_bwd_t(qt, kt, vt, ot, lse, dot, causal, interpret,
     grid that accumulates the GQA group in-kernel.
 
     rope_tables: optional (cos2, sin2) (S, D) fp32 — in-kernel RoPE mode
-    (q/k and the saved residuals are RAW; dq/dk come back w.r.t. raw)."""
+    (q/k and the saved residuals are RAW; dq/dk come back w.r.t. raw).
+    v, o, do and dv take the value's own width dv (D is q/k's)."""
     b, h, s, d = qt.shape
+    dv = vt.shape[-1]
     kv_heads = kt.shape[1]
     group = h // kv_heads
     (_, __), (dq_q, dq_k), (dkv_q, dkv_k) = _active_tiles(s)
@@ -948,6 +954,10 @@ def _flash_bwd_t(qt, kt, vt, ot, lse, dot, causal, interpret,
         # the forward emitted the packed lse layout.
         q_spec = pl.BlockSpec((1, 1, dq_bq, d), lambda bi, hi, qi: (bi, hi, qi, 0))
         kv_full = pl.BlockSpec((1, 1, s, d), lambda bi, hi, qi: (bi, hi // group, 0, 0))
+        o_spec = pl.BlockSpec((1, 1, dq_bq, dv),
+                              lambda bi, hi, qi: (bi, hi, qi, 0))
+        v_full = pl.BlockSpec((1, 1, s, dv),
+                              lambda bi, hi, qi: (bi, hi // group, 0, 0))
         if layout == "packed":
             row_spec = pl.BlockSpec((1, 1, 1, dq_bq),
                                     lambda bi, hi, qi: (bi, hi, 0, qi))
@@ -957,10 +967,10 @@ def _flash_bwd_t(qt, kt, vt, ot, lse, dot, causal, interpret,
         else:
             row_spec = pl.BlockSpec((1, 1, dq_bq, 1),
                                     lambda bi, hi, qi: (bi, hi, qi, 0))
-        in_specs = [q_spec, kv_full, kv_full, q_spec, row_spec, q_spec]
+        in_specs = [q_spec, kv_full, v_full, o_spec, row_spec, o_spec]
         operands = (qt, kt, vt, dot, lse, ot)
         scratch = [pltpu.VMEM((s, d), jnp.float32),
-                   pltpu.VMEM((s, d), jnp.float32)]
+                   pltpu.VMEM((s, dv), jnp.float32)]
         if rope:
             cq_spec = pl.BlockSpec((dq_bq, d), lambda bi, hi, qi: (qi, 0))
             ck_spec = pl.BlockSpec((s, d), lambda bi, hi, qi: (0, 0))
@@ -975,7 +985,7 @@ def _flash_bwd_t(qt, kt, vt, ot, lse, dot, causal, interpret,
             in_specs=in_specs,
             out_specs=[pl.BlockSpec((1, 1, dq_bq, d),
                                     lambda bi, hi, qi: (bi, hi, qi, 0)),
-                       kv_full, kv_full],
+                       kv_full, v_full],
             out_shape=[jax.ShapeDtypeStruct(qt.shape, qt.dtype),
                        jax.ShapeDtypeStruct(kt.shape, kt.dtype),
                        jax.ShapeDtypeStruct(vt.shape, vt.dtype)],
@@ -993,13 +1003,16 @@ def _flash_bwd_t(qt, kt, vt, ot, lse, dot, causal, interpret,
             def dq_kv_idx(bi, hi, qi, ki):
                 return (bi, hi // group, ki, 0)
         kv_spec = pl.BlockSpec((1, 1, dq_bk, d), dq_kv_idx)
+        v_spec = pl.BlockSpec((1, 1, dq_bk, dv), dq_kv_idx)
+        o_spec = pl.BlockSpec((1, 1, dq_bq, dv),
+                              lambda bi, hi, qi, ki: (bi, hi, qi, 0))
         if layout == "packed":
             row_spec = pl.BlockSpec((1, 1, 1, dq_bq),
                                     lambda bi, hi, qi, ki: (bi, hi, 0, qi))
         else:
             row_spec = pl.BlockSpec((1, 1, dq_bq, 1),
                                     lambda bi, hi, qi, ki: (bi, hi, qi, 0))
-        in_specs = [q_spec, kv_spec, kv_spec, q_spec, row_spec, q_spec]
+        in_specs = [q_spec, kv_spec, v_spec, o_spec, row_spec, o_spec]
         operands = (qt, kt, vt, dot, lse, ot)
         scratch = [pltpu.VMEM((dq_bq, d), jnp.float32),
                    pltpu.VMEM((dq_bq, 1), jnp.float32),
@@ -1037,6 +1050,8 @@ def _flash_bwd_t(qt, kt, vt, ot, lse, dot, causal, interpret,
         # only: the resident family's fused kernel produced dk/dv above.)
         kv_spec = pl.BlockSpec((1, 1, dkv_bk, d),
                                lambda bi, hi, ki, qi: (bi, hi, ki, 0))
+        v_spec = pl.BlockSpec((1, 1, dkv_bk, dv),
+                              lambda bi, hi, ki, qi: (bi, hi, ki, 0))
         if causal:  # steps before the diagonal are no-ops: pin their q fetch
             def dkv_q_idx(bi, hi, ki, qi):
                 return (bi, hi, jnp.maximum(qi, ki * dkv_bk // dkv_bq), 0)
@@ -1050,15 +1065,16 @@ def _flash_bwd_t(qt, kt, vt, ot, lse, dot, causal, interpret,
             def dkv_row_idx(bi, hi, ki, qi):
                 return (bi, hi, 0, qi)
         qgrp_spec = pl.BlockSpec((1, group, dkv_bq, d), dkv_q_idx)
+        ogrp_spec = pl.BlockSpec((1, group, dkv_bq, dv), dkv_q_idx)
         rowgrp_spec = (
             pl.BlockSpec((1, group, 1, dkv_bq), dkv_row_idx)
             if layout == "packed"
             else pl.BlockSpec((1, group, dkv_bq, 1), dkv_q_idx))
-        in_specs = [qgrp_spec, kv_spec, kv_spec, qgrp_spec, rowgrp_spec,
-                    qgrp_spec]
+        in_specs = [qgrp_spec, kv_spec, v_spec, ogrp_spec, rowgrp_spec,
+                    ogrp_spec]
         operands = (qt, kt, vt, dot, lse, ot)
         scratch = [pltpu.VMEM((dkv_bk, d), jnp.float32),
-                   pltpu.VMEM((dkv_bk, d), jnp.float32)]
+                   pltpu.VMEM((dkv_bk, dv), jnp.float32)]
         if rope:
             if causal:
                 def dkv_cq_idx(bi, hi, ki, qi):
@@ -1078,7 +1094,7 @@ def _flash_bwd_t(qt, kt, vt, ot, lse, dot, causal, interpret,
                               lse_layout=layout, rope=rope),
             grid=(b, kv_heads, s // dkv_bk, s // dkv_bq),
             in_specs=in_specs,
-            out_specs=[kv_spec, kv_spec],
+            out_specs=[kv_spec, v_spec],
             out_shape=[
                 jax.ShapeDtypeStruct(kt.shape, kt.dtype),
                 jax.ShapeDtypeStruct(vt.shape, vt.dtype),
@@ -1162,7 +1178,9 @@ _flash_attention.defvjp(_flash_attention_fwd, _flash_attention_bwd)
 
 
 def flash_attention_bhsd(q, k, v, causal=True):
-    """Head-major entry: q (B,H,S,D), k/v (B,K,S,D) -> (B,H,S,D).
+    """Head-major entry: q/k (B,H,S,D) / (B,K,S,D), v (B,K,S,Dv) ->
+    (B,H,S,Dv). The value's width may differ from the query/key's (latent
+    attention, models/latent_moe.py: 192 / 128); nothing is padded.
 
     Identical kernels and math to :func:`flash_attention`, minus the
     (B,S,H,D) <-> (B,H,S,D) transposes at entry and exit — the caller
@@ -1244,3 +1262,4 @@ def _flash_attention_rope_bwd(causal, residuals, g):
 
 _flash_attention_rope.defvjp(_flash_attention_rope_fwd,
                              _flash_attention_rope_bwd)
+

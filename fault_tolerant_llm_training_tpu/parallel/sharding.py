@@ -241,6 +241,14 @@ def param_pspecs(params) -> dict:
         flat[1], [specs["/".join(_key_str(k) for k in kp)] for kp, _ in flat[0]])
 
 
+def replicated_pspecs(tree) -> dict:
+    """Every leaf whole on every device: the latent / expert class's state
+    (models/latent_moe.py), which trains on one device (training/loop.py
+    refuses a mesh of more for it)."""
+    return jax.tree_util.tree_map(lambda leaf: P(*([None] * leaf.ndim)),
+                                  tree)
+
+
 def param_shardings(params, mesh=None):
     """NamedSharding pytree for ``params`` on ``mesh`` (default: active mesh)."""
     mesh = mesh or active_mesh()

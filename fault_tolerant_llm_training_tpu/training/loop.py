@@ -40,14 +40,14 @@ from ..data.tokenizer import load_tokenizer
 from ..ft import multihost
 from ..ft.multihost import PeerHostError, barrier
 from ..ft.signals import SignalFlag, TrainingSignal
-from ..models import Transformer, get_config
+from ..models import build_model, get_config
 from ..deploy.publish import Publisher
 from ..obs import events
 from ..obs.registry import REGISTRY
 from ..obs.trace import AutoTraceWindow, TraceWindow, span
 from ..ops.attention import describe_attention_impl
 from ..parallel.mesh import make_mesh, use_mesh
-from ..parallel.sharding import batch_pspec, param_pspecs
+from ..parallel.sharding import batch_pspec, param_pspecs, replicated_pspecs
 from ..training.state import TrainState
 from ..training.step import make_eval_step, make_optimizer, make_train_step
 from ..utils.compile_cache import enable_compilation_cache
@@ -283,25 +283,23 @@ class Trainer:
             moe_aux_weight=cfg.moe_aux_weight,
             moe_impl=cfg.moe_impl).items() if v is not None}
         from ..models.configs import PRESETS, LatentMoEConfig
-        if isinstance(PRESETS.get(cfg.model), LatentMoEConfig):
-            raise ValueError(
-                f"--model {cfg.model} is a LatentMoEConfig preset "
-                f"(models/latent_moe.py): that class is served, not trained "
-                f"yet — no uncached training forward, no sharding rules for "
-                f"its paths, no flash kernel for its head widths (ROADMAP "
-                f"R7)")
-        self.model_config = get_config(
-            cfg.model, vocab_size=vocab, seq_len=cfg.sequence_length,
-            dtype=dtype, param_dtype=param_dtype,
-            attention_impl=cfg.attention_impl, embed_impl=cfg.embed_impl,
-            sp_layout=cfg.sp_layout, layer_impl=cfg.layer_impl,
-            pp_schedule=cfg.pp_schedule,
-            pp_stage_unroll=cfg.pp_stage_unroll,
-            remat=cfg.remat, **moe_over)
-        if cfg.ep > 1 and not self.model_config.moe_experts:
-            raise ValueError("--ep needs an MoE model (--model tiny-moe or "
-                             "--moe-experts N)")
-        if self.model_config.moe_experts:
+        self._latent = isinstance(PRESETS.get(cfg.model), LatentMoEConfig)
+        if self._latent:
+            self.model_config = self._latent_config(cfg, vocab, dtype,
+                                                    param_dtype, moe_over)
+        else:
+            self.model_config = get_config(
+                cfg.model, vocab_size=vocab, seq_len=cfg.sequence_length,
+                dtype=dtype, param_dtype=param_dtype,
+                attention_impl=cfg.attention_impl, embed_impl=cfg.embed_impl,
+                sp_layout=cfg.sp_layout, layer_impl=cfg.layer_impl,
+                pp_schedule=cfg.pp_schedule,
+                pp_stage_unroll=cfg.pp_stage_unroll,
+                remat=cfg.remat, **moe_over)
+            if cfg.ep > 1 and not self.model_config.moe_experts:
+                raise ValueError("--ep needs an MoE model (--model tiny-moe "
+                                 "or --moe-experts N)")
+        if not self._latent and self.model_config.moe_experts:
             if cfg.pp > 1 and cfg.pp_schedule == "gpipe":
                 raise ValueError("--pp-schedule gpipe with an MoE model is "
                                  "not supported (its forward drops the "
@@ -310,7 +308,14 @@ class Trainer:
                 raise ValueError(
                     f"moe_experts {self.model_config.moe_experts} not "
                     f"divisible by --ep {cfg.ep}")
-        self.model = Transformer(self.model_config)
+        self.model = build_model(self.model_config)
+        if self._latent:
+            # the expert layers' counts of a step, from the packed metrics
+            # the loop reads each step anyway (the engine counts the same
+            # under phase="decode"|"prefill")
+            from ..models.latent_moe import STAT_COUNTERS
+            self._m_moe = [REGISTRY.counter(*STAT_COUNTERS[k]).labels(
+                phase="train") for k in ("moe_pairs", "moe_touched")]
         # What this run resolved, in its own log: a job that quietly took
         # the CPU, the XLA attention or interpret-mode kernels on a chip
         # host must be visible without a profiler.
@@ -333,7 +338,8 @@ class Trainer:
                               opt_state=opt_state)
 
         abstract = jax.eval_shape(init_fn, jax.random.PRNGKey(cfg.seed))
-        specs = param_pspecs(abstract)
+        specs = (replicated_pspecs(abstract) if self._latent
+                 else param_pspecs(abstract))
         self.state_shardings = jax.tree_util.tree_map(
             lambda s: NamedSharding(self.mesh, s), specs,
             is_leaf=lambda x: isinstance(x, jax.sharding.PartitionSpec))
@@ -346,6 +352,14 @@ class Trainer:
         # input-embedding gather; attention FLOPs causal-masked.
         n_params = sum(int(np.prod(l.shape))
                        for l in jax.tree_util.tree_leaves(abstract.params))
+        if self._latent:    # a token meets k of the routed experts' share
+            mc = self.model_config
+            held = sum(int(np.prod(l.shape)) for p, l in
+                       jax.tree_util.tree_flatten_with_path(abstract.params)[0]
+                       if "experts" in jax.tree_util.keystr(p)
+                       and "shared" not in jax.tree_util.keystr(p))
+            n_params -= held - held * mc.num_experts_per_tok // (
+                mc.n_routed_experts)
         self._flops_per_token = transformer_flops_per_token(
             n_params - self.model_config.vocab_size * self.model_config.dim,
             cfg.sequence_length, self.model_config.dim,
@@ -561,6 +575,35 @@ class Trainer:
         # (wall clock, last step) already covered by a step event; the next
         # event's dur/steps are deltas against this.
         self._step_window_start = None
+
+    def _latent_config(self, cfg, vocab, dtype, param_dtype, moe_over):
+        """The latent / expert class (models/latent_moe.py) as it trains: on
+        one device, its whole state replicated. A mesh of more is refused:
+        the expert exchange between the chips that share a layer, and
+        sharding rules for the class's paths, are not written (ROADMAP
+        R1). The Llama class's MoE and layout options do not apply."""
+        what = (f"--model {cfg.model} is a LatentMoEConfig preset "
+                f"(models/latent_moe.py)")
+        if self.mesh.size > 1:
+            raise ValueError(
+                f"{what}: it trains on one device; a mesh of "
+                f"{self.mesh.size} needs the expert exchange and sharding "
+                f"rules for its paths, which are not written (ROADMAP R1)")
+        if moe_over or cfg.ep > 1 or cfg.grad_accum > 1 or (
+                cfg.layer_impl != "loop"):
+            raise ValueError(f"{what}: --moe-*, --ep, --grad-accum and "
+                             f"--layer-impl scan are not written for it")
+        mc = get_config(cfg.model, vocab_size=vocab,
+                        seq_len=cfg.sequence_length, dtype=dtype,
+                        param_dtype=param_dtype, remat=cfg.remat,
+                        attention_impl=cfg.attention_impl,
+                        embed_impl=cfg.embed_impl)
+        if not mc.trains:
+            raise ValueError(
+                f"{what}: its schedule has an indexer or sliding layers, "
+                f"which are served, not trained (the uncached training "
+                f"forward covers full layers without an indexer)")
+        return mc
 
     def _warn_if_state_exceeds_hbm(self, abstract_sharded) -> None:
         """Pre-flight capacity estimate: warn (don't fail — remat and fusion
@@ -915,6 +958,9 @@ class Trainer:
         vals = self._guarded_wait(lambda _cancelled: np.asarray(packed),
                                   f"metric wait for step {step_no}")
         loss, grad_norm = float(vals[0]), float(vals[1])
+        if len(vals) > 2:   # the latent / expert class's (pairs, touched)
+            for counter, v in zip(self._m_moe, vals[2:]):
+                counter.inc(float(v))
         if not math.isfinite(grad_norm):
             # ref: utils.py:61 error_if_nonfinite -> routed as code error (-1)
             # grad_norm is a replicated global value: every host raises here

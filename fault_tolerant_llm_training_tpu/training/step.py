@@ -113,8 +113,19 @@ def model_loss(model, params, inputs, labels, microbatches: int = 0,
     with weight ``cfg.moe_aux_weight``; eval reports pure CE.
 
     Returns (mean loss, num_valid_tokens)."""
-    sp = mesh_axis_size("sequence")
+    loss, (num_valid, _) = loss_and_stats(model, params, inputs, labels,
+                                          microbatches, train)
+    return loss, num_valid
+
+
+def loss_and_stats(model, params, inputs, labels, microbatches: int = 0,
+                   train: bool = True):
+    """:func:`model_loss`'s forward + CE, returning also what the forward
+    sowed into the 'stats' collection: (mean loss, (num_valid_tokens, a
+    tuple of the sown arrays, empty where the model sows none)). The
+    latent / expert class sows its expert layers' (pairs, touched)."""
     cfg = getattr(model, "cfg", None)
+    sp = mesh_axis_size("sequence")
     if (cfg is not None and cfg.layer_impl == "scan"
             and mesh_axis_size("pipe") > 1):
         if cfg.moe_experts and train:
@@ -129,7 +140,8 @@ def model_loss(model, params, inputs, labels, microbatches: int = 0,
         from ..parallel.pipeline import pipeline_apply
         logits = pipeline_apply(model, params, inputs,
                                 microbatches=microbatches)
-        return cross_entropy_loss(logits, labels)
+        loss, num_valid = cross_entropy_loss(logits, labels)
+        return loss, (num_valid, ())
     args = ()
     if cfg is not None and zigzag_layout_active(cfg, inputs.shape[1], sp):
         # Zigzag sequence layout (ops/ring_attention.py): permute the
@@ -164,14 +176,14 @@ def model_loss(model, params, inputs, labels, microbatches: int = 0,
     # forward's output to per-token nll, so masking/normalization and the
     # aux handling cannot diverge between the paths.
     method = "hidden_states" if fused else None
-    if cfg is not None and cfg.moe_experts and train:
-        out, mutated = model.apply({"params": params}, inputs, *args,
-                                   method=method, mutable=["losses"])
-        aux = sum(jnp.sum(leaf) for leaf in
-                  jax.tree_util.tree_leaves(mutated))
-    else:
-        out = model.apply({"params": params}, inputs, *args, method=method)
-        aux = None
+    with_aux = bool(getattr(cfg, "moe_experts", 0)) and train
+    out, mutated = model.apply(
+        {"params": params}, inputs, *args, method=method,
+        mutable=["stats", "losses"] if with_aux else ["stats"])
+    aux = (sum(jnp.sum(leaf) for leaf in
+               jax.tree_util.tree_leaves(mutated["losses"]))
+           if with_aux else None)
+    stats = tuple(jax.tree_util.tree_leaves(mutated.get("stats", {})))
     if fused:
         # Large vocab whose per-device logits + cotangent would not fit:
         # block the head matmul into the loss (ops/fused_ce.py) — logits
@@ -190,7 +202,7 @@ def model_loss(model, params, inputs, labels, microbatches: int = 0,
         loss, num_valid = cross_entropy_loss(out, labels)
     if aux is not None:
         loss = loss + cfg.moe_aux_weight * aux
-    return loss, num_valid
+    return loss, (num_valid, stats)
 
 
 def make_eval_step(model, microbatches: int = 0, grad_accum: int = 1):
@@ -240,7 +252,10 @@ def make_train_step(model, optimizer: optax.GradientTransformation,
     metrics: loss (fp32), grad_norm (fp32; host checks finiteness — the
     torch ``error_if_nonfinite`` raise cannot live inside jit, ref:
     utils.py:61), num_tokens, and packed = stack((loss, grad_norm)) — the
-    single leaf the host loop fetches per step (one D2H transfer).
+    single leaf the host loop fetches per step (one D2H transfer). What
+    the forward sowed into 'stats' (the latent / expert class: its expert
+    layers' pairs and touched experts) is appended to packed, so those
+    counters ride the same transfer; under ``grad_accum`` nothing is.
     ``microbatches`` only matters under pipeline parallelism (0 = one
     microbatch per stage).
 
@@ -252,7 +267,7 @@ def make_train_step(model, optimizer: optax.GradientTransformation,
     """
 
     def loss_fn(params, inputs, labels):
-        return model_loss(model, params, inputs, labels, microbatches)
+        return loss_and_stats(model, params, inputs, labels, microbatches)
 
     cfg = getattr(model, "cfg", None)
     if (cfg is not None and cfg.layer_impl == "scan"
@@ -262,8 +277,9 @@ def make_train_step(model, optimizer: optax.GradientTransformation,
         from ..parallel.pipeline import pipeline_value_and_grad
 
         def value_and_grad(params, inputs, labels):
-            return pipeline_value_and_grad(model, params, inputs, labels,
-                                           microbatches=microbatches)
+            (loss, n), grads = pipeline_value_and_grad(
+                model, params, inputs, labels, microbatches=microbatches)
+            return (loss, (n, ())), grads
     else:
         value_and_grad = jax.value_and_grad(loss_fn, has_aux=True)
 
@@ -276,7 +292,7 @@ def make_train_step(model, optimizer: optax.GradientTransformation,
 
         def body(carry, sl):
             g_acc, nll_acc, n_acc = carry
-            (loss, n), grads = value_and_grad(params, sl[0], sl[1])
+            (loss, (n, _)), grads = value_and_grad(params, sl[0], sl[1])
             nf = n.astype(jnp.float32)
             g_acc = jax.tree_util.tree_map(
                 lambda a, g: a + g.astype(jnp.float32) * nf, g_acc, grads)
@@ -290,11 +306,13 @@ def make_train_step(model, optimizer: optax.GradientTransformation,
         denom = jnp.maximum(n_tot.astype(jnp.float32), 1.0)
         grads = jax.tree_util.tree_map(
             lambda g, p: (g / denom).astype(p.dtype), g_acc, params)
-        return (nll / denom, n_tot), grads
+        return (nll / denom, (n_tot, ())), grads
 
     def train_step(state: TrainState, inputs: jax.Array, labels: jax.Array):
-        (loss, num_tokens), grads = accum_value_and_grad(
+        (loss, (num_tokens, stats)), grads = accum_value_and_grad(
             state.params, inputs, labels)
+        sown = tuple(v for leaf in stats
+                     for v in leaf.astype(jnp.float32).reshape(-1))
         grads, grad_norm = clip_grads_with_norm(grads, grad_max_norm)
         with scope("optimizer"):
             updates, new_opt_state = optimizer.update(
@@ -307,7 +325,7 @@ def make_train_step(model, optimizer: optax.GradientTransformation,
                    # (loss, grad_norm) as one array: the host loop fetches
                    # this single leaf per step — one device-to-host
                    # transfer instead of one per scalar (training/loop.py).
-                   "packed": jnp.stack((loss, grad_norm))}
+                   "packed": jnp.stack((loss, grad_norm) + sown)}
         return new_state, metrics
 
     return train_step

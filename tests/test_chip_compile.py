@@ -515,7 +515,9 @@ SERVING_PROGRAMS_AS_RECORDED = {
         "prefill": "ce1fc9015c94c391a83007118052ee5030d0c103ca3a3cf1e6a7ad76353"
                    "0aecd"}}
 # and the training cell's step (mistral-7b-v0.3-d4, seq 4096 x 3 rows), at
-# commit edfc238 (PR 33), by test_d4_train_step_is_as_recorded
+# commit edfc238 (PR 33), by test_d4_train_step_is_as_recorded. All five
+# held at 4a708ba (PR 34) and hold with PR 36's training path for the
+# latent / expert class beside them (same constants).
 TRAIN_PROGRAM_AS_RECORDED = (
     "517207568acc6f26a940b9129d8b062e2ec0a7cbf61c66a124c2e1b85bba2e3d")
 
@@ -775,3 +777,76 @@ def test_masked_flash_kernel_compiles_at_the_cells_widths(v5e):
             qn, qr, kn, kr, v, keep, n, 0.0722),
         sds((h, s, 128)), sds((h, s, 64)), sds((h, t, 128)), sds((t, 64)),
         sds((h, t, 128)), sds((s, t), jnp.int8), sds((), jnp.int32))
+
+
+def test_kanana_train_step_compiles_at_the_cells_widths(v5e):
+    """The ``kanana2-d6-ep8.moe8k`` cell's training step as the trainer
+    builds it (6 layers at the published widths, 16 held experts, vocab
+    16,032, 4 rows of 8,192 tokens, ``--remat``), compiled for one
+    described v5e: it fits the chip's 16 GiB with room; the attention is
+    the Mosaic kernels of ``flash_attention_bhsd`` named ``attention.N``
+    (what the accepted readers' ``^pallas:attention`` finds) and the held
+    experts' grouped matmuls are the compiler's ragged-dot calls; and the
+    only float32 array as wide as the vocabulary that the program holds
+    outside a fusion is the loss head's logits."""
+    import json
+    import re
+    import sys
+    from pathlib import Path
+
+    root = Path(__file__).resolve().parent.parent
+    sys.path.insert(0, str(root))
+    from perfbench.lib import weights
+
+    from fault_tolerant_llm_training_tpu.training.state import TrainState
+    from fault_tolerant_llm_training_tpu.training.step import (
+        make_optimizer,
+        make_train_step,
+    )
+
+    bench = root / "perfbench"
+    config = json.loads(
+        (bench / "configs" / "kanana-2-30b-a3b-d6-ep8.json").read_text())
+    mix = json.loads((bench / "traffic" / "moe8k.json").read_text())
+    seq, rows = mix["sequence_length"], mix["rows_per_chip"]
+    assert "--remat" in mix["mesh_args"]
+    d = weights.dims_of(config)
+    fam = weights.family_of(d)
+    cfg = fam.preset(config, seq_len=seq, attention_impl="pallas",
+                     remat=True)
+    opt = make_optimizer(mix["learning_rate"], mix["lr_warmup_steps"])
+
+    def init_fn(key):
+        params = weights.make_param_tree(key, d, jnp.bfloat16)
+        return TrainState(step=jnp.zeros((), jnp.int32), params=params,
+                          opt_state=opt.init(params))
+
+    one = SingleDeviceSharding(v5e.devices[0])
+    state = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one),
+        jax.eval_shape(init_fn, jax.random.PRNGKey(0)))
+    tokens = jax.ShapeDtypeStruct((rows, seq), jnp.int32, sharding=one)
+    compiled = jax.jit(make_train_step(fam.model_class()(cfg), opt, 1.0),
+                       donate_argnums=(0,)).lower(
+        state, tokens, tokens).compile()
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15 * 2**30
+    hlo = compiled.as_text()
+    calls = [re.match(r"\s*(?:ROOT )?%([\w.\-]+) = ", line).group(1)
+             for line in hlo.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    flash = [c for c in calls if re.fullmatch(r"attention(\.\d+)?", c)]
+    assert len(flash) >= 3 * d["n_layers"]      # fwd, dq, dk/dv a layer
+    assert all(c.startswith("ragged-dot") for c in calls
+               if c not in flash), calls
+    assert any(c.startswith("ragged-dot") for c in calls)
+    wide = []
+    for comp in re.split(r"\n(?=(?:ENTRY )?%[\w.\-]+ \()", hlo):
+        head = comp.split(" ", 2)[:2]
+        if comp.startswith("%fused") or "fused" in head[0] or (
+                "wrapped" in head[0]):
+            continue
+        for line in comp.splitlines()[1:]:
+            if re.search(rf"= \(?[^=]*f32\[[0-9,]*{d['vocab']}\]", line):
+                wide.append(line)
+    assert wide and all("loss_head" in line for line in wide), wide[:4]

@@ -476,3 +476,134 @@ def test_train_steps_leave_every_training_span(chain):
     # the prefetcher works on its own thread, outside the step spans
     pre = [s for s in spans if s[0] == "ftl:data.prefetch"]
     assert {s[3] for s in pre}.isdisjoint({s[3] for s in steps})
+
+
+# --------------------------- the latent / expert class's training step
+LATENT_TRAIN_SCOPES = ("attention", "moe_route", "moe_experts", "moe_shared",
+                       "loss_head")
+
+
+def test_latent_training_step_opens_its_scopes_in_forward_order():
+    """The lowered training step of the latent / expert class opens, in
+    forward order, attention (layer 0), the expert layer's route, grouped
+    matmuls and shared experts (layer 1 on), then the head with the loss."""
+    import jax
+    import jax.numpy as jnp
+
+    from fault_tolerant_llm_training_tpu.models import build_model, get_config
+    from fault_tolerant_llm_training_tpu.training.state import TrainState
+    from fault_tolerant_llm_training_tpu.training.step import (
+        make_optimizer, make_train_step)
+
+    cfg = get_config("tiny-latent-train", vocab_size=259)
+    model, opt = build_model(cfg), make_optimizer(1e-3, 0)
+
+    def init_fn(key):
+        params = model.init(key, jnp.zeros((1, 64), jnp.int32))["params"]
+        return TrainState(step=jnp.zeros((), jnp.int32), params=params,
+                          opt_state=opt.init(params))
+
+    state = jax.eval_shape(init_fn, jax.random.PRNGKey(0))
+    tok = jax.ShapeDtypeStruct((2, 64), jnp.int32)
+    text = jax.jit(make_train_step(model, opt, 1.0)).lower(
+        state, tok, tok).as_text(debug_info=True)
+    first = {}
+    for i, line in enumerate(text.splitlines()):
+        for name in re.findall(r'loc\("([^"]+)"', line):
+            for word in components([name]) & set(LATENT_TRAIN_SCOPES):
+                first.setdefault(word, i)
+    assert set(first) == set(LATENT_TRAIN_SCOPES), first
+    order = sorted(LATENT_TRAIN_SCOPES, key=first.get)
+    assert order == list(LATENT_TRAIN_SCOPES), order
+
+
+_COUNT_ONE_STEP = r"""
+import json, sys
+import numpy as np
+sys.path.insert(0, sys.argv[1])
+import jax, jax.numpy as jnp
+import train as entry
+from fault_tolerant_llm_training_tpu.obs.registry import REGISTRY
+from fault_tolerant_llm_training_tpu.training import loop as tl
+from fault_tolerant_llm_training_tpu.utils.config import get_args
+from perfbench.lib import reference, weights
+
+seen = {}
+orig = tl.Trainer.__init__
+
+def init(self, *a, **k):
+    orig(self, *a, **k)
+    inner = self._compiled_step
+
+    def step(state, inputs, labels):
+        seen.setdefault("params", weights.flatten(jax.tree_util.tree_map(
+            np.asarray, state.params)))
+        seen.setdefault("inputs", np.asarray(inputs))
+        return inner(state, inputs, labels)
+
+    self._compiled_step = step
+
+tl.Trainer.__init__ = init
+try:
+    entry.train(get_args(sys.argv[2:]))
+except SystemExit as e:
+    assert e.code in (0, None), e.code
+counts = {f"{name}{{{labels}}}": v
+          for name, fam in REGISTRY.snapshot().items()
+          for labels, v in fam["series"].items() if name.startswith("moe_")}
+config = json.load(open(sys.argv[1] + "/perfbench/configs/tiny-kanana2.json"))
+d = weights.dims_of(config)
+fam = weights.family_of(d)
+p = {k: jnp.asarray(v, jnp.float32) for k, v in seen["params"].items()}
+mm = reference.mm_f32
+ref = 0
+with jax.default_matmul_precision("highest"):
+    for row in seen["inputs"]:
+        x = p["tok_embeddings/embedding"][jnp.asarray(row)]
+        for i in range(d["n_layers"]):
+            w = fam.sub(p, f"layers_{i}/")
+            if i >= d["first_dense"]:
+                u = reference.rmsnorm(
+                    x + fam.mixer(fam.sub(w, "attention/"), reference.rmsnorm(
+                        x, w["attention_norm/scale"], d["norm_eps"]), d, mm),
+                    w["ffn_norm/scale"], d["norm_eps"])
+                ref += fam.held_pairs(fam.sub(w, "feed_forward/"), u, d, mm)
+            x = fam.block(w, x, d, mm, i)
+print(json.dumps({"counts": counts, "reference_pairs": ref}))
+"""
+
+
+def test_latent_training_counts_its_held_pairs_as_the_reference_does(
+        tmp_path):
+    """One training step of the latent / expert class through ``train.py``:
+    ``moe_pairs_total{phase=train}`` is the reference's own count of the
+    step's (token, held expert) pairs, and the experts-touched counter
+    moved too — both from the values the loop reads each step anyway."""
+    import json
+    import os
+    import subprocess
+
+    import pyarrow as pa
+    import pyarrow.parquet as pq
+
+    parquet = str(tmp_path / "d.parquet")
+    rng = np.random.default_rng(0)
+    pq.write_table(pa.table({"text": [
+        " ".join(rng.choice(["alpha", "bravo", "charlie"], size=60))
+        for _ in range(16)]}), parquet)
+    env = dict(os.environ, JAX_PLATFORMS="cpu", SLURM_JOB_ID="kc1")
+    env.pop("XLA_FLAGS", None)
+    proc = subprocess.run(
+        [sys.executable, "-c", _COUNT_ONE_STEP, str(REPO), "--dataset",
+         parquet, "--checkpoint-path", str(tmp_path / "ck"),
+         "--tokenizer-name-or-path", "byte", "--model", "tiny-latent-train",
+         "--vocab-size", "512", "--model-dtype", "fp32",
+         "--sequence-length", "64", "--batch-size", "2",
+         "--training-steps", "1", "--compile-cache-dir", ""],
+        cwd=REPO, env=env, capture_output=True, text=True, timeout=300)
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    got = json.loads(proc.stdout.strip().splitlines()[-1])
+    counts = got["counts"]
+    assert counts["moe_pairs_total{phase=train}"] == got["reference_pairs"]
+    assert got["reference_pairs"] > 0
+    assert 0 < counts["moe_experts_touched_total{phase=train}"] <= 2 * 4
