@@ -396,7 +396,7 @@ def phase_kernels(deadline):
     bad = [l["check"] for l in lines if not l["ok"]]
     check("kernels", rc, log, extra=(
         ([f"failed: {bad}"] if bad else [])
-        + ([f"{len(lines)} check lines, want 20"] if len(lines) != 20
+        + ([f"{len(lines)} check lines, want 22"] if len(lines) != 22
            else [])))
     report("kernels", t0, checks=len(lines), all_ok=not bad)
 

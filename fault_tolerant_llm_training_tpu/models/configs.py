@@ -92,7 +92,7 @@ class TransformerConfig:
     # fp32 rope intermediate ever materializes at the XLA level, which
     # removes the rope-adjacent relayout-copy family at the custom-call
     # boundary. "fused" engages only on the single-chip pallas path with
-    # prefix positions AND within the fused-backward S*D budget (the
+    # prefix positions AND within its own measured S*D bound (the
     # streaming kernels re-rope K per tile fetch, measured net-negative
     # past S=4096/D=64 — ops/flash_attention.py rope_fused_profitable);
     # other shapes/paths fall back to "xla" automatically. Default "fused": +3.7% headline and the

@@ -49,27 +49,31 @@ from the do/o tiles (see _delta) — an XLA-side delta materializes fp32
 casts of the full dO and O with layout-change copies at the custom-call
 boundary.
 
-Two kernel families, dispatched on sequence length:
+Two kernel families:
 
-- **Resident** (forward: S <= STREAM_THRESHOLD; backward: S*D within
-  RESIDENT_BWD_SD_BUDGET, which reaches past the forward's cutover): the
-  non-grid operand (K/V, and the dk/dv gradient accumulators) sits whole
-  in VMEM and an in-kernel fori_loop walks it. Fastest at moderate S —
-  no per-block pipeline boundaries — but VMEM-bound: the resident rows
-  grow linearly with S*D. The backward is ONE fused kernel
-  (_bwd_fused_kernel) producing dq, dk and dv from a single pass over
-  the causal tile triangle — the split FA2 scheme recomputes the
-  VPU-bound softmax core (scores, exp2, dO @ V^T, dS) twice per tile,
-  once in dq and once in dk/dv; fusing it measured +10.9% on the
-  headline bench (98.2k -> 109.0k tokens/s), +9.4% at bs 16, and −9.6%
-  fwd+bwd at S=4096 where it outlives the streamed forward
-  (BASELINE.md round 3).
-- **Streaming** (S > STREAM_THRESHOLD): the loop moves into the grid's
+- **Resident** (forward: S <= STREAM_THRESHOLD; backward: wherever its
+  VMEM residency fits the chip, see _fused_bwd_vmem_limit): the non-grid
+  operand (K/V, and the dk/dv gradient accumulators) sits whole in VMEM
+  and an in-kernel fori_loop walks it. Fastest at moderate S — no
+  per-block pipeline boundaries — but VMEM-bound: the resident rows grow
+  linearly with S and the padded widths. The backward is ONE fused kernel
+  (_bwd_fused_kernel) producing dq, dk and dv from a single pass over the
+  causal tile triangle — the split FA2 scheme recomputes the softmax core
+  (scores, exp2, dO @ V^T, dS) twice per tile, once in dq and once in
+  dk/dv, 7 matmuls and 2 exp passes where the fused kernel does 5 and 1;
+  fusing it measured +10.9% on the headline bench (98.2k -> 109.0k
+  tokens/s), +9.4% at bs 16, and −9.6% fwd+bwd at S=4096 where it
+  outlives the streamed forward (BASELINE.md round 3). The fused call
+  asks the compiler for the VMEM its blocks need (vmem_limit_bytes) where
+  that is more than XLA's default scoped limit.
+- **Streaming** (forward: S > STREAM_THRESHOLD; backward: where the fused
+  kernel's residency does not fit): the loop moves into the grid's
   innermost dimension; the online-softmax / gradient accumulators live in
   VMEM scratch that persists across grid steps, and every operand is a
   fixed-size tile. O(1) VMEM in S — this is what makes 32k+ contexts
   compile on a single chip (beyond that, ring attention shards S over the
-  mesh's 'sequence' axis, ops/ring_attention.py).
+  mesh's 'sequence' axis, ops/ring_attention.py). Its backward is split:
+  a dq kernel and a dk/dv kernel.
 """
 
 import functools
@@ -89,6 +93,9 @@ from jax.experimental.pallas import tpu as pltpu
 # bs 16 too; round-3's sweep history: the bs-8 peak 256x1024 collapses 26x
 # at bs 16 — BASELINE.md).
 FWD_BLOCK_Q, FWD_BLOCK_K = 512, 512
+# The fused backward walks the dq tiles. Swept on v5e at S=8192, 32 heads of
+# 192 / 128, batch 4 (one backward call): 512x512 57.9 ms, 512x1024 58.3,
+# 1024x1024 58.4, 1024x512 59.9, 256x512 63.4.
 DQ_BLOCK_Q, DQ_BLOCK_K = 512, 512
 DKV_BLOCK_Q, DKV_BLOCK_K = 512, 1024
 # The mid-range STREAMING regime (STREAM_THRESHOLD < S <
@@ -112,34 +119,27 @@ STREAM_DKV_BLOCK_Q, STREAM_DKV_BLOCK_K = 1024, 512
 # measured on the split dk/dv kernel at S=4096); switch to the streaming
 # kernels.
 STREAM_THRESHOLD = 2048
-# The fused backward stays viable past the forward's threshold — its
-# residency is K/V rows + two (S, D) fp32 dk/dv scratch rows + the
-# double-buffered q-side tiles, all linear in S*D: calibrated at D=64,
-# S=8192 measured 21.0M > the 16M scoped limit while S=4096 fits, so the
-# dispatch bound is S*D <= 4096*64 (a D=128 model hits the same wall at
-# half the S). Round-5 on-chip validation (scripts/kernel_checks.py):
-# the bound holds WITH in-kernel rope at both boundary shapes — S=4096/
-# D=64 and S=2048/D=128 compile and match XLA (the rope path's extra
-# (S, D) rotated-K scratch fits; no derate needed, ADVICE r4). The D=64
-# tile constants also transfer to D=128 unchanged: a 10-combo resident
-# fwd/dq/dkv sweep at S=2048/D=128 (scripts/d128_tile_sweep.py) put the
-# defaults first, every variant 8-11% slower. Within the bound but past STREAM_THRESHOLD, the forward
-# streams while the backward runs fused (one softmax-core pass instead
-# of two).
+# The fused backward is not bound to the forward's threshold: it runs
+# wherever its VMEM residency (_fused_bwd_residency: whole K/V rows and
+# dk/dv outputs, two fp32 (S, D) scratches, the q-side tiles and the score
+# tiles' temporaries) fits the chip, and asks the compiler for that much
+# (vmem_limit_bytes) where XLA's default scoped limit is too small. Past
+# STREAM_THRESHOLD the forward then streams while the backward runs fused
+# (one softmax-core pass instead of two). The D=64 tile constants transfer
+# to D=128 unchanged: a 10-combo resident fwd/dq/dkv sweep at S=2048/D=128
+# (scripts/d128_tile_sweep.py) put the defaults first, every variant
+# 8-11% slower.
 #
-# The 16 MiB figure is XLA's default --xla_tpu_scoped_vmem_limit_kib —
-# the compiler's per-kernel scratch budget, NOT the physical VMEM (which
-# is 128 MiB on v4/v5p/v6 cores and 64+64 MiB on v5e's paired cores; the
-# default limit is the same across current generations, which is why the
-# calibrated bound transfers). An operator raising the XLA flag should
-# set FTL_SCOPED_VMEM_KIB to match and the S*D bound scales linearly
-# with it (the residency is linear in S*D).
-SCOPED_VMEM_BYTES = int(os.environ.get("FTL_SCOPED_VMEM_KIB", "16384")) * 1024
-RESIDENT_BWD_SD_BUDGET = (4096 * 64) * SCOPED_VMEM_BYTES // (16 * 2**20)
-
-
-def _fused_bwd_fits(s: int, d: int) -> bool:
-    return s * d <= RESIDENT_BWD_SD_BUDGET
+# XLA's default --xla_tpu_scoped_vmem_limit_kib: what a Mosaic kernel may
+# hold unless its call asks for more. It is not the physical VMEM, which
+# jax's chip table gives (pltpu.get_tpu_info(): 128 MiB on v5e).
+DEFAULT_SCOPED_VMEM_BYTES = 16 * 2**20
+# v5e's VMEM as that table gives it: the chip the kernels are tuned on, and
+# what a backend with no TPU table is taken to be — the CPU the tests
+# interpret on, and the described v5e they compile for.
+CALIBRATION_VMEM_BYTES = 128 * 2**20
+# In-kernel rope is profitable up to this S*D (rope_fused_profitable).
+ROPE_FUSED_SD_BUDGET = 4096 * 64
 
 
 def rope_fused_profitable(s: int, d: int) -> bool:
@@ -147,13 +147,86 @@ def rope_fused_profitable(s: int, d: int) -> bool:
     at this shape — the dispatch the model's rope_impl='fused' uses.
 
     Measured on v5e (BASELINE.md round 4): +3.7% headline at S=2048 and
-    −2.6% attention time at S=4096 (resident/fused-backward region, where
-    K is roped ONCE per span into scratch), but +2.1% at S=8192 and
-    +3.7% at S=16384 — the streaming kernels re-fetch each K tile per
-    (q-tile, k-step) grid visit and the rotation rides every fetch, so
-    the redundant k-rope grows with S while XLA-side rope stays O(S).
-    The boundary is exactly the fused-backward budget."""
-    return _fused_bwd_fits(s, d)
+    −2.6% attention time at S=4096 (where K is roped ONCE per span into
+    the fused backward's scratch), but +2.1% at S=8192 and +3.7% at
+    S=16384 — the streaming kernels re-fetch each K tile per (q-tile,
+    k-step) grid visit and the rotation rides every fetch, so the
+    redundant k-rope grows with S while XLA-side rope stays O(S). The
+    boundary is a measurement of its own (ROPE_FUSED_SD_BUDGET), not the
+    fused backward's VMEM rule."""
+    return s * d <= ROPE_FUSED_SD_BUDGET
+
+
+def vmem_capacity_bytes() -> int:
+    """VMEM of one core of the TPU the kernels run on, from jax's chip
+    table (keyed on the device kind; a TPU the table does not know raises
+    there); CALIBRATION_VMEM_BYTES where the backend is not a TPU."""
+    if jax.default_backend() != "tpu":
+        return CALIBRATION_VMEM_BYTES
+    return pltpu.get_tpu_info().vmem_capacity_bytes
+
+
+def _lanes(n: int) -> int:
+    """A VMEM row's width: whole 128-lane tiles."""
+    return -(-n // 128) * 128
+
+
+def _fused_bwd_residency(s: int, d: int, dv: int, rope: bool,
+                         itemsize: int) -> int:
+    """Bytes of VMEM the fused backward's call holds, counted from the
+    blocks it builds (widths padded to 128 lanes; q/k width ``d`` and the
+    value's ``dv`` apart):
+
+    - pipeline blocks, each double-buffered: the q, dO, O and dq tiles,
+      whole K and V rows and the dk/dv outputs of one KV head, the lse
+      block (counted as the largest of its layouts, legacy's padded
+      (block_q, 128) column), and with ``rope`` the (block_q, D) and
+      (S, D) fp32 tables;
+    - scratch, single: the fp32 dk/dv accumulators, and with ``rope`` the
+      rotated K;
+    - temporaries of one k step: two fp32 score tiles live at once (P and
+      dP, then dS) and the two cast to the input dtype, the fp32 dq
+      accumulator, and the dk/dv row slices read and written back.
+
+    The GQA group does not enter: a span's blocks are one KV head's rows
+    whatever the group, revisited across it. Against what Mosaic
+    allocates for a described v5e (bf16, the smallest vmem_limit_bytes
+    that compiles): kanana-2's S=8192, 192 / 128 counts 43 MiB, needs
+    41-42; mistral-7b's S=4096, 128 counts 17.75, needs 15-16; below 128
+    lanes the count is high (S=2048, 64: 11.75 counted, 5-6 needed — a
+    64-wide row is not padded there)."""
+    bq, bk = _blocks(s, *_active_tiles(s)[1])
+    dp, dvp = _lanes(d), _lanes(dv)
+    f32 = 4
+    blocks = (2 * bq * dp + 2 * bq * dvp + 2 * s * dp + 2 * s * dvp) * itemsize
+    blocks += bq * 128 * f32 + (2 * bq * dp + 2 * s * dp) * f32 * rope
+    scratch = (s * dp + s * dvp) * f32 + s * dp * itemsize * rope
+    temps = (bq * bk * (2 * f32 + 2 * itemsize) + bq * dp * f32
+             + 2 * bk * (dp + dvp) * f32)
+    return 2 * blocks + scratch + temps
+
+
+def _fused_bwd_vmem_limit(s: int, d: int, dv: int, rope: bool,
+                          itemsize: int):
+    """The fused backward's VMEM request in bytes, or None where it does
+    not fit and the split streaming kernels run.
+
+    It asks for its residency and a quarter more (what the count does not
+    see: Mosaic's internal scratch and relayout copies), in whole MiB and
+    never below XLA's default scoped limit, and runs fused where that is
+    at most three quarters of the chip's VMEM (the rest left to the
+    compiler). On v5e's 128 MiB, bf16: S=8192 at 192 / 128 wide
+    (kanana-2) asks 54 MiB, S=4096 at 128 (mistral-7b) 23 MiB; S=65536 at
+    128 does not fit. Measured on v5e at those two shapes, one backward
+    call: fused 57.9 ms against the split pair's 124.7 (kanana-2, batch 4,
+    32 heads), 6.8 against 18.3 (mistral-7b, batch 3, 32:8 heads)."""
+    need = _fused_bwd_residency(s, d, dv, rope, itemsize) * 5 // 4
+    need = -(-need // 2**20) * 2**20
+    if need > vmem_capacity_bytes() * 3 // 4:
+        return None
+    return max(need, DEFAULT_SCOPED_VMEM_BYTES)
+
+
 NEG_INF = -1e30
 LOG2E = math.log2(math.e)
 LN2 = math.log(2.0)
@@ -281,8 +354,10 @@ def _active_tiles(s: int):
             (DKV_BLOCK_Q, DKV_BLOCK_K))
 
 
-def _lse_layout(s: int, d: int) -> str:
-    """The lse residual's memory layout at sequence length ``s``:
+def _lse_layout(s: int, fused_bwd: bool) -> str:
+    """The lse residual's memory layout at sequence length ``s``, where
+    ``fused_bwd`` says whether the fused backward runs (its VMEM fits,
+    _fused_bwd_vmem_limit):
 
     - ``"packed"`` — (B, H, 1, S), q positions on the lane dim. Streaming
       family (s > STREAM_THRESHOLD), where the legacy layout's padding is
@@ -290,8 +365,7 @@ def _lse_layout(s: int, d: int) -> str:
       (odd sequence lengths degrade tiles below 128 rows, making the
       packed blocks illegal). Consumers (via _read_lse): the streaming
       backward kernels, and the FUSED resident backward when it runs past
-      the forward's threshold (RESIDENT_BWD_SD_BUDGET) — one entry
-      transpose per grid step.
+      the forward's threshold — one entry transpose per grid step.
     - ``"blocked"`` — (B, H, S/128, 128): the resident family's packed
       form (VERDICT r4 weak #3, the one variant the r2/r3 rejection
       sweeps never built). The forward's (block_q,) lse vector wraps to
@@ -307,13 +381,11 @@ def _lse_layout(s: int, d: int) -> str:
             and all(_fit_block(s, bq) % 128 == 0
                     for bq, _ in _active_tiles(s))):
         return "packed"
-    # "blocked" additionally requires the FUSED backward (_fused_bwd_fits
-    # needs d): the streaming backward kernels have no blocked row_spec,
-    # and a shrunken FTL_SCOPED_VMEM_KIB budget (or d >= 256) can route
-    # s <= STREAM_THRESHOLD shapes to them while the forward would have
-    # emitted the blocked plane — a trace-time Pallas failure.
-    if (s <= STREAM_THRESHOLD and s % 128 == 0
-            and _fused_bwd_fits(s, d)
+    # "blocked" additionally requires the FUSED backward: the streaming
+    # backward kernels have no blocked row_spec, and a chip with too little
+    # VMEM can route s <= STREAM_THRESHOLD shapes to them while the forward
+    # would have emitted the blocked plane — a trace-time Pallas failure.
+    if (s <= STREAM_THRESHOLD and s % 128 == 0 and fused_bwd
             and os.environ.get("FTL_LSE_RESIDENT", "blocked") != "legacy"
             and all(_fit_block(s, bq) % 128 == 0
                     for bq, _ in _active_tiles(s))):
@@ -809,7 +881,9 @@ def _flash_fwd_t(qt, kt, vt, causal, interpret, rope_tables=None):
     group = h // kv_heads
     block_q, block_k = _blocks(s, *_active_tiles(s)[0])
     scale = 1.0 / (d ** 0.5)
-    layout = _lse_layout(s, d)
+    rope = rope_tables is not None
+    layout = _lse_layout(s, _fused_bwd_vmem_limit(
+        s, d, dv, rope, kt.dtype.itemsize) is not None)
     if layout == "packed":
         lse_shape = (b, h, 1, s)
         lse_spec = pl.BlockSpec((1, 1, 1, block_q),
@@ -832,7 +906,6 @@ def _flash_fwd_t(qt, kt, vt, causal, interpret, rope_tables=None):
         lse_spec,
     ]
 
-    rope = rope_tables is not None
     if s <= STREAM_THRESHOLD:
         kernel = functools.partial(_fwd_kernel, block_k=block_k, scale=scale,
                                    causal=causal, rope=rope, group=group,
@@ -925,11 +998,12 @@ def _flash_bwd(q, k, v, o, lse, g, causal, interpret):
 
 def _flash_bwd_t(qt, kt, vt, ot, lse, dot, causal, interpret,
                  rope_tables=None):
-    """Pallas backward on head-major operands. Resident family: ONE fused
-    kernel on a (b, h, q-tile) grid producing dq, dk and dv per pass
-    (_bwd_fused_kernel). Streaming family: split kernels — dq via a
-    (head, q-tile, k-step) grid, dk/dv via a (kv-head, k-tile, q-step)
-    grid that accumulates the GQA group in-kernel.
+    """Pallas backward on head-major operands. Where its VMEM fits the chip
+    (_fused_bwd_vmem_limit): ONE fused kernel on a (b, h, q-tile) grid
+    producing dq, dk and dv per pass (_bwd_fused_kernel). Elsewhere split
+    streaming kernels — dq via a (head, q-tile, k-step) grid, dk/dv via a
+    (kv-head, k-tile, q-step) grid that accumulates the GQA group
+    in-kernel.
 
     rope_tables: optional (cos2, sin2) (S, D) fp32 — in-kernel RoPE mode
     (q/k and the saved residuals are RAW; dq/dk come back w.r.t. raw).
@@ -942,16 +1016,17 @@ def _flash_bwd_t(qt, kt, vt, ot, lse, dot, causal, interpret,
     dq_bq, dq_bk = _blocks(s, dq_q, dq_k)
     dkv_bq, dkv_bk = _blocks(s, dkv_q, dkv_k)
     scale = 1.0 / (d ** 0.5)
-    layout = _lse_layout(s, d)
     rope = rope_tables is not None
+    vmem_limit = _fused_bwd_vmem_limit(s, d, dv, rope, kt.dtype.itemsize)
+    layout = _lse_layout(s, vmem_limit is not None)
     # delta (rowwise dO . O) is computed inside the kernels from the do/o
     # tiles (see _delta) — no fp32 materialization at the XLA level.
 
-    if _fused_bwd_fits(s, d):
+    if vmem_limit is not None:
         # Fused single-pass backward (see _bwd_fused_kernel): dq, dk, dv
         # from one walk of the causal tile triangle. Runs past the
-        # forward's STREAM_THRESHOLD (see RESIDENT_BWD_SD_BUDGET) — there
-        # the forward emitted the packed lse layout.
+        # forward's STREAM_THRESHOLD — there the forward emitted the
+        # packed lse layout.
         q_spec = pl.BlockSpec((1, 1, dq_bq, d), lambda bi, hi, qi: (bi, hi, qi, 0))
         kv_full = pl.BlockSpec((1, 1, s, d), lambda bi, hi, qi: (bi, hi // group, 0, 0))
         o_spec = pl.BlockSpec((1, 1, dq_bq, dv),
@@ -990,6 +1065,9 @@ def _flash_bwd_t(qt, kt, vt, ot, lse, dot, causal, interpret,
                        jax.ShapeDtypeStruct(kt.shape, kt.dtype),
                        jax.ShapeDtypeStruct(vt.shape, vt.dtype)],
             scratch_shapes=scratch,
+            compiler_params=(
+                pltpu.CompilerParams(vmem_limit_bytes=vmem_limit)
+                if vmem_limit > DEFAULT_SCOPED_VMEM_BYTES else None),
             interpret=interpret,
         )(*operands)
     else:
@@ -1262,4 +1340,48 @@ def _flash_attention_rope_bwd(causal, residuals, g):
 
 _flash_attention_rope.defvjp(_flash_attention_rope_fwd,
                              _flash_attention_rope_bwd)
+
+
+# the kernel that stands for one attention backward in a traced program, by
+# family (the split family's dk/dv kernel runs beside its dq kernel)
+_BACKWARD_KERNELS = {"_bwd_fused_kernel": "fused",
+                     "_dq_stream_kernel": "split"}
+
+
+def backward_calls(closed_jaxpr):
+    """``({"fused"|"split": calls}, vmem)``: the attention backward calls a
+    traced program makes each time it runs (``jax.jit(f).trace(...).jaxpr``;
+    a scan's body counts its length times), and the most VMEM a fused call
+    asks the compiler for (XLA's default scoped limit where none asks
+    more). Read from the program, so what the rule picked at each shape is
+    what is counted."""
+    from jax.extend import core as jcore
+
+    calls, vmem = {}, DEFAULT_SCOPED_VMEM_BYTES
+
+    def walk(jaxpr, times):
+        nonlocal vmem
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                info = eqn.params["jaxpr"].debug_info
+                family = _BACKWARD_KERNELS.get(
+                    info.func_src_info.split(" ")[0] if info else None)
+                if family:
+                    calls[family] = calls.get(family, 0) + times
+                if family == "fused":
+                    for params in eqn.params["compiler_params"].values():
+                        vmem = max(vmem, params.vmem_limit_bytes or 0)
+                continue
+            n = times * eqn.params["length"] if (
+                eqn.primitive.name == "scan") else times
+            for value in eqn.params.values():
+                for sub in (value if isinstance(value, (list, tuple))
+                            else (value,)):
+                    if isinstance(sub, jcore.ClosedJaxpr):
+                        walk(sub.jaxpr, n)
+                    elif isinstance(sub, jcore.Jaxpr):
+                        walk(sub, n)
+
+    walk(closed_jaxpr.jaxpr, 1)
+    return calls, vmem
 

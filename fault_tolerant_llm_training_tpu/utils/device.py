@@ -7,9 +7,11 @@ own footprint:
 - ``device_hbm_bytes`` — per-device accelerator memory, from
   ``Device.memory_stats()['bytes_limit']`` (consumers: ops/fused_ce.py
   ``auto_min_bytes``).
-- The scoped-VMEM limit has no runtime query; ops/flash_attention.py
-  documents it per-generation and reads the ``FTL_SCOPED_VMEM_KIB`` env
-  override (matching XLA's ``--xla_tpu_scoped_vmem_limit_kib``).
+- VMEM: ops/flash_attention.py ``vmem_capacity_bytes`` reads jax's chip
+  table (``pltpu.get_tpu_info()``, keyed on the device kind) and sizes the
+  fused backward's ``vmem_limit_bytes`` request from it, so XLA's default
+  scoped limit (``--xla_tpu_scoped_vmem_limit_kib``) does not bound which
+  backward runs and no environment variable steers it.
 - ``describe_device`` — the ``platform | kind | count`` triple every entry
   point logs at start-up, so a run that landed on the wrong backend says so
   in its own log.

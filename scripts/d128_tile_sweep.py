@@ -3,8 +3,8 @@
 Every tile constant in ops/flash_attention.py was tuned at D=64 (the
 gpt2-125m bench head width). The flagship llama3-8b preset runs D=128 —
 this sweep times the resident family's fwd+bwd at a llama-shaped GQA
-config (h:kv = 4:1, D=128, S=2048 — the S*D budget boundary, so the
-fused backward is engaged exactly as the flagship would) across tile
+config (h:kv = 4:1, D=128, S=2048 — the resident forward's longest S,
+with the fused backward engaged as the flagship would) across tile
 candidates, on the chip, to decide whether the D=64 constants transfer
 or need a D=128 dispatch branch.
 

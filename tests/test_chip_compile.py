@@ -100,6 +100,35 @@ def test_flash_fwd_bwd_compiles_for_v5e(v5e, width, rope_fused):
     _compile(jax.grad(loss, argnums=(0, 1, 2)), *args)
 
 
+@pytest.mark.parametrize("b,h,kv,s,d,dv", [(4, 32, 32, 8192, 192, 128),
+                                            (3, 32, 8, 4096, 128, 128)],
+                         ids=["kanana2-moe8k", "mistral7b-preempt"])
+def test_fused_flash_backward_compiles_at_the_training_cells_shapes(
+        v5e, monkeypatch, b, h, kv, s, d, dv):
+    """The training cells' attention, forward and backward, compiled by
+    Mosaic for one described v5e: the backward is the ONE fused kernel and
+    compiles under the VMEM limit its call asks for. At kanana-2's shape
+    that limit is what makes it compile: held to XLA's default scoped
+    16 MiB, the compiler runs out of VMEM."""
+    sds = _shapes_on(v5e.devices[0])
+    grad = jax.grad(lambda q, k, v: fa.flash_attention_bhsd(q, k, v).astype(
+        jnp.float32).sum(), argnums=(0, 1, 2))
+    args = (sds((b, h, s, d)), sds((b, kv, s, d)), sds((b, kv, s, dv)))
+    calls, vmem = fa.backward_calls(jax.jit(grad).trace(*args).jaxpr)
+    assert calls == {"fused": 1}
+    assert vmem == fa._fused_bwd_vmem_limit(s, d, dv, False, 2) <= (
+        fa.CALIBRATION_VMEM_BYTES * 3 // 4)
+    compiled = _compile(grad, *args)
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    if d == 192:
+        monkeypatch.setattr(fa, "_fused_bwd_vmem_limit",
+                            lambda *a: fa.DEFAULT_SCOPED_VMEM_BYTES)
+        jax.clear_caches()      # or the backward traced above is reused
+        with pytest.raises(Exception, match="vmem"):
+            jax.jit(grad).lower(*args).compile()
+
+
 @pytest.mark.parametrize("kernel", ["decode", "chunk", "int8_decode",
                                     "int8_chunk", "tree_verify"])
 @pytest.mark.parametrize("width", SERVE_WIDTHS, ids=_ids)
@@ -517,9 +546,12 @@ SERVING_PROGRAMS_AS_RECORDED = {
 # and the training cell's step (mistral-7b-v0.3-d4, seq 4096 x 3 rows), at
 # commit edfc238 (PR 33), by test_d4_train_step_is_as_recorded. All five
 # held at 4a708ba (PR 34) and hold with PR 36's training path for the
-# latent / expert class beside them (same constants).
+# latent / expert class beside them (same constants). The training step is
+# recorded anew since its attention backward became one fused flash call a
+# layer (dq, dk and dv, under the VMEM limit the call asks for), where it
+# was the split dq and dk/dv calls.
 TRAIN_PROGRAM_AS_RECORDED = (
-    "517207568acc6f26a940b9129d8b062e2ec0a7cbf61c66a124c2e1b85bba2e3d")
+    "00bcf022e103242e69f335c6e5ae3be865c90bc745f834155d7abc9df6d4bc2c")
 
 
 def _lowered_serving_hashes(cfg, params, slots, per_slot, bs, blocks, sds):
@@ -785,8 +817,10 @@ def test_kanana_train_step_compiles_at_the_cells_widths(v5e):
     16,032, 4 rows of 8,192 tokens, ``--remat``), compiled for one
     described v5e: it fits the chip's 16 GiB with room; the attention is
     the Mosaic kernels of ``flash_attention_bhsd`` named ``attention.N``
-    (what the accepted readers' ``^pallas:attention`` finds) and the held
-    experts' grouped matmuls are the compiler's ragged-dot calls; and the
+    (what the accepted readers' ``^pallas:attention`` finds), three a
+    layer — two forwards under remat and the one fused backward — and
+    the held experts' grouped matmuls are the compiler's ragged-dot calls;
+    and the
     only float32 array as wide as the vocabulary that the program holds
     outside a fusion is the loss head's logits."""
     import json
@@ -836,7 +870,8 @@ def test_kanana_train_step_compiles_at_the_cells_widths(v5e):
              for line in hlo.splitlines()
              if 'custom_call_target="tpu_custom_call"' in line]
     flash = [c for c in calls if re.fullmatch(r"attention(\.\d+)?", c)]
-    assert len(flash) >= 3 * d["n_layers"]      # fwd, dq, dk/dv a layer
+    # two forwards a layer under remat, one backward returning dq, dk, dv
+    assert len(flash) == 3 * d["n_layers"]
     assert all(c.startswith("ragged-dot") for c in calls
                if c not in flash), calls
     assert any(c.startswith("ragged-dot") for c in calls)

@@ -67,14 +67,14 @@ def test_resident_fused_backward_non_causal(s, h, kv, d):
 
 @pytest.mark.parametrize("s,h,kv,d", [(512, 4, 2, 32), (1024, 2, 2, 64)])
 def test_fused_backward_with_streamed_forward(s, h, kv, d, monkeypatch):
-    """When the forward streams but S*D is within RESIDENT_BWD_SD_BUDGET,
-    the forward emits the PACKED lse layout and the backward runs the
-    fused kernel — its packed entry-transpose path. Forced on at small S
-    by lowering only the forward threshold."""
+    """When the forward streams but the fused backward's VMEM fits, the
+    forward emits the PACKED lse layout and the backward runs the fused
+    kernel — its packed entry-transpose path. Forced on at small S by
+    lowering only the forward threshold."""
     import fault_tolerant_llm_training_tpu.ops.flash_attention as fa
     monkeypatch.setattr(fa, "STREAM_THRESHOLD", 0)
-    assert fa._lse_layout(s, d) == "packed"  # the combination under test
-    assert fa._fused_bwd_fits(s, d)
+    assert fa._fused_bwd_vmem_limit(s, d, d, False, 4) is not None
+    assert fa._lse_layout(s, True) == "packed"  # the combination under test
     _check_gradients(s, h, kv, d, batch=2, seed=2)
 
 
@@ -99,10 +99,10 @@ def test_streaming_kernels_match(s, h, kv, d, causal, long_tiles,
     import fault_tolerant_llm_training_tpu.ops.flash_attention as fa
     monkeypatch.setattr(fa, "STREAM_THRESHOLD", 0)
     # force the SPLIT streaming backward too: with only the forward
-    # threshold lowered, the fused backward (viable within
-    # RESIDENT_BWD_SD_BUDGET) would take over and the streaming dq/dkv
-    # kernels would lose their coverage
-    monkeypatch.setattr(fa, "RESIDENT_BWD_SD_BUDGET", 0)
+    # threshold lowered, the fused backward (its VMEM fits) would take over
+    # and the streaming dq/dkv kernels would lose their coverage — a chip
+    # with no VMEM to spare fits nothing
+    monkeypatch.setattr(fa, "vmem_capacity_bytes", lambda: 0)
     if long_tiles:
         monkeypatch.setattr(fa, "LONG_STREAM_THRESHOLD", 0)
     rng = np.random.default_rng(0)
@@ -141,7 +141,7 @@ def test_rope_fused_matches_xla_rope(s, h, kv, d, family, monkeypatch):
     )
     if family == "streaming":
         monkeypatch.setattr(fa, "STREAM_THRESHOLD", 0)
-        monkeypatch.setattr(fa, "RESIDENT_BWD_SD_BUDGET", 0)
+        monkeypatch.setattr(fa, "vmem_capacity_bytes", lambda: 0)
     rng = np.random.default_rng(3)
     q = jnp.asarray(rng.standard_normal((2, s, h, d)), jnp.float32)
     k = jnp.asarray(rng.standard_normal((2, s, kv, d)), jnp.float32)
@@ -218,9 +218,10 @@ def _check_gradients(s, h, kv, d, causal=True, batch=1, seed=1):
 
 
 def test_rope_fused_dispatch_boundary():
-    """rope_impl='fused' scopes itself to the fused-backward S*D budget:
-    the streaming kernels re-rope K per tile fetch, measured net-negative
-    past S=4096/D=64 on v5e (BASELINE.md round 4)."""
+    """rope_impl='fused' scopes itself to its own measured S*D bound: the
+    streaming kernels re-rope K per tile fetch, measured net-negative past
+    S=4096/D=64 on v5e (BASELINE.md round 4). The fused backward's VMEM
+    rule reaches further and does not move it."""
     import fault_tolerant_llm_training_tpu.ops.flash_attention as fa
 
     assert fa.rope_fused_profitable(2048, 64)
@@ -228,6 +229,56 @@ def test_rope_fused_dispatch_boundary():
     assert not fa.rope_fused_profitable(8192, 64)
     assert fa.rope_fused_profitable(2048, 128)
     assert not fa.rope_fused_profitable(4096, 128)  # D=128 halves the S
+
+
+@pytest.mark.parametrize("b,h,kv,s,d,dv,family", [
+    (4, 32, 32, 8192, 192, 128, "fused"),   # kanana-2: 43 MiB counted
+    (3, 32, 8, 4096, 128, 128, "fused"),    # mistral-7b: 17.75 MiB
+    (1, 8, 8, 65536, 128, 128, "split"),    # 202 MiB: cannot fit
+], ids=["kanana2", "mistral7b", "s65536"])
+def test_backward_family_follows_the_fused_kernels_vmem(b, h, kv, s, d, dv,
+                                                        family):
+    """The training cells' attention shapes, bf16, as the program traces
+    them (no kernel runs): one backward call, of the family the VMEM rule
+    picks for v5e — the fused kernel wherever its residency fits, the
+    split streaming kernels where it cannot."""
+    from fault_tolerant_llm_training_tpu.ops import flash_attention as fa
+
+    sds = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.bfloat16)  # noqa
+    traced = jax.jit(jax.grad(
+        lambda q, k, v: fa.flash_attention_bhsd(q, k, v).astype(
+            jnp.float32).sum(), argnums=(0, 1, 2))).trace(
+        sds(b, h, s, d), sds(b, kv, s, d), sds(b, kv, s, dv))
+    calls, vmem = fa.backward_calls(traced.jaxpr)
+    assert calls == {family: 1}
+    limit = fa._fused_bwd_vmem_limit(s, d, dv, False, 2)
+    if family == "fused":
+        assert vmem == limit > fa.DEFAULT_SCOPED_VMEM_BYTES
+    else:
+        assert limit is None and vmem == fa.DEFAULT_SCOPED_VMEM_BYTES
+
+
+def test_backward_calls_count_each_run_of_a_layer():
+    """A layer stack under remat, unrolled and as a scan: the tally counts
+    every backward the program runs, not the one the tracer met — and not
+    the two forwards remat runs a layer."""
+    from fault_tolerant_llm_training_tpu.ops import flash_attention as fa
+
+    layer = jax.checkpoint(lambda x: fa.flash_attention_bhsd(x, x, x))
+
+    def unrolled(x):
+        for _ in range(3):
+            x = layer(x)
+        return x.sum()
+
+    def scanned(x):
+        return jax.lax.scan(lambda c, _: (layer(c), None), x, None,
+                            length=5)[0].sum()
+
+    x = jax.ShapeDtypeStruct((1, 2, 256, 64), jnp.float32)
+    for loss, n in ((unrolled, 3), (scanned, 5)):
+        calls, _ = fa.backward_calls(jax.jit(jax.grad(loss)).trace(x).jaxpr)
+        assert calls == {"fused": n}
 
 
 def test_lse_layout_dispatch(monkeypatch):
@@ -238,17 +289,24 @@ def test_lse_layout_dispatch(monkeypatch):
     from fault_tolerant_llm_training_tpu.ops import flash_attention as fa
 
     monkeypatch.delenv("FTL_LSE_RESIDENT", raising=False)
-    assert fa._lse_layout(2048, 64) == "blocked"   # resident, 128-aligned
-    assert fa._lse_layout(2048, 128) == "blocked"  # exactly at the budget
-    assert fa._lse_layout(256, 64) == "blocked"
-    assert fa._lse_layout(2000, 64) == "legacy"    # not a 128-multiple
-    assert fa._lse_layout(2048, 256) == "legacy"   # fused bwd won't fit:
+    assert fa._lse_layout(2048, True) == "blocked"   # resident, 128-aligned
+    assert fa._lse_layout(256, True) == "blocked"
+    assert fa._lse_layout(2000, True) == "legacy"    # not a 128-multiple
+    assert fa._lse_layout(2048, False) == "legacy"   # fused bwd won't fit:
     # the streaming backward has no blocked row_spec (review r5)
-    assert fa._lse_layout(4096, 64) == "packed"    # streaming
-    assert fa._lse_layout(65536, 64) == "packed"
+    assert fa._lse_layout(4096, True) == "packed"    # streaming forward
+    assert fa._lse_layout(65536, False) == "packed"
     monkeypatch.setenv("FTL_LSE_RESIDENT", "legacy")
-    assert fa._lse_layout(2048, 64) == "legacy"    # opt-out knob
-    assert fa._lse_layout(4096, 64) == "packed"    # knob is resident-only
+    assert fa._lse_layout(2048, True) == "legacy"    # opt-out knob
+    assert fa._lse_layout(4096, True) == "packed"    # knob is resident-only
+    # what the forward and the backward ask is the same answer: at 2048 x
+    # 256 the fused kernel fits v5e's VMEM (blocked), not a 16 MiB chip's
+    monkeypatch.delenv("FTL_LSE_RESIDENT")
+    fits = fa._fused_bwd_vmem_limit(2048, 256, 256, False, 2) is not None
+    assert fits and fa._lse_layout(2048, fits) == "blocked"
+    monkeypatch.setattr(fa, "vmem_capacity_bytes", lambda: 16 * 2**20)
+    fits = fa._fused_bwd_vmem_limit(2048, 256, 256, False, 2) is not None
+    assert not fits and fa._lse_layout(2048, fits) == "legacy"
 
 
 @pytest.mark.parametrize("mesh_kw", [dict(dp=4), dict(fsdp=4),

@@ -149,18 +149,23 @@ def _plain_attention(q, k, v):
 
 
 @pytest.mark.parametrize("s,stream", [(64, False), (1024, False),
-                                      (1024, True)])
+                                      (1024, True), (1024, "packed")])
 def test_flash_kernel_of_two_widths_matches_plain_attention(
         s, stream, monkeypatch):
     """192-wide query/key, 128-wide value, in interpret mode: the forward
     and all three gradients. The resident kernels at 64 rows (one tile)
-    and 1,024 (2 x 2 tiles); ``stream``: the streaming kernels the cell's
-    8,192 rows take, at 1,024 rows with the thresholds lowered (2 q-tiles x
-    1 k-step forward, 2 x 2 dq, 1 x 2 dk/dv: the causal grid bounds and
-    fetch clamps)."""
+    and 1,024 (2 x 2 tiles); ``stream``: the kernels the cell's 8,192 rows
+    take, at 1,024 rows with the forward's threshold lowered — the
+    streamed forward (2 q-tiles x 1 k-step) and, ``packed``, the fused
+    backward reading its packed lse, or, ``True``, on a chip with no VMEM
+    to spare, the split streaming backward (2 x 2 dq, 1 x 2 dk/dv: the
+    causal grid bounds and fetch clamps)."""
     if stream:
         monkeypatch.setattr(fa, "STREAM_THRESHOLD", 256)
-        monkeypatch.setattr(fa, "RESIDENT_BWD_SD_BUDGET", 0)
+        assert fa._lse_layout(s, True) == "packed"
+        assert fa._fused_bwd_vmem_limit(s, 192, 128, False, 4) is not None
+    if stream is True:
+        monkeypatch.setattr(fa, "vmem_capacity_bytes", lambda: 0)
     ks = jax.random.split(jax.random.PRNGKey(s), 4)
     b, h = 1, 2
     q = jax.random.normal(ks[0], (b, h, s, 192), F32)
@@ -179,6 +184,60 @@ def test_flash_kernel_of_two_widths_matches_plain_attention(
                          grads(_plain_attention)):
         assert got.shape == want.shape
         np.testing.assert_allclose(got, want, atol=1e-4)
+
+
+_COUNT_BACKWARDS = r"""
+import sys
+sys.path.insert(0, sys.argv[1])
+import train as entry
+from fault_tolerant_llm_training_tpu.obs.registry import REGISTRY
+from fault_tolerant_llm_training_tpu.ops import flash_attention as fa
+from fault_tolerant_llm_training_tpu.utils.config import get_args
+from fault_tolerant_llm_training_tpu.utils.logging import init_logger
+
+init_logger()
+if sys.argv[2] == "split":      # a chip with no VMEM to spare
+    fa.vmem_capacity_bytes = lambda: 0
+try:
+    entry.train(get_args(sys.argv[3:]))
+except SystemExit as e:
+    assert e.code in (0, None), e.code
+fam = REGISTRY.snapshot().get("flash_backward_calls_total", {"series": {}})
+print(dict(fam["series"]))
+"""
+
+
+@pytest.mark.parametrize("family", ["fused", "split"])
+def test_flash_backward_counter_reads_layers_times_steps(tmp_path, family):
+    """Two training steps of the 3-layer ``tiny-latent-train`` under
+    ``--remat`` with the Pallas kernels (interpreted here): the start-up
+    line names the backward family the VMEM rule picked, and
+    ``flash_backward_calls_total`` advances by layers x steps under that
+    family alone — the two forwards remat runs a layer are not counted."""
+    import ast
+
+    import pyarrow as pa
+    import pyarrow.parquet as pq
+
+    parquet = str(tmp_path / "d.parquet")
+    pq.write_table(pa.table({"text": ["alpha bravo charlie delta"] * 16}),
+                   parquet)
+    env = dict(os.environ, JAX_PLATFORMS="cpu", SLURM_JOB_ID="kf1")
+    env.pop("XLA_FLAGS", None)
+    proc = subprocess.run(
+        [sys.executable, "-c", _COUNT_BACKWARDS, str(REPO), family,
+         "--dataset", parquet, "--checkpoint-path", str(tmp_path / "ck"),
+         "--tokenizer-name-or-path", "byte", "--model", "tiny-latent-train",
+         "--vocab-size", "512", "--model-dtype", "fp32",
+         "--attention-impl", "pallas", "--remat", "--sequence-length", "64",
+         "--batch-size", "2", "--training-steps", "2",
+         "--compile-cache-dir", ""],
+        cwd=REPO, env=env, capture_output=True, text=True, timeout=600)
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    assert f"Flash backward | {family} x3 a step" in proc.stdout, (
+        proc.stdout[-3000:])
+    got = ast.literal_eval(proc.stdout.strip().splitlines()[-1])
+    assert got == {f"kernel={family}": 3 * 2}
 
 
 # ------------------------------------------------------ the expert layer

@@ -449,23 +449,31 @@ def test_device_budget_dispatch(monkeypatch):
 
 
 def test_scoped_vmem_budget_scales(monkeypatch):
-    """RESIDENT_BWD_SD_BUDGET scales linearly with the scoped-VMEM limit
-    (FTL_SCOPED_VMEM_KIB, matching --xla_tpu_scoped_vmem_limit_kib): at
-    the 16 MiB XLA default it is the calibrated 4096*64; doubling the
-    limit doubles the S*D bound."""
-    import importlib
-    import os
+    """The fused backward's VMEM budget is the chip's, from jax's chip table
+    (``pltpu.get_tpu_info()``) on a TPU: a chip with half of v5e's 128 MiB
+    keeps mistral-7b's shape fused and sends kanana-2's to the split
+    kernels; off a TPU (here) the table is v5e's. The request is the
+    residency and a quarter, never under XLA's default scoped limit."""
+    import types
 
     import fault_tolerant_llm_training_tpu.ops.flash_attention as fa
 
-    assert fa.RESIDENT_BWD_SD_BUDGET == 4096 * 64  # default env
-    monkeypatch.setenv("FTL_SCOPED_VMEM_KIB", str(2 * 16384))
-    mod = importlib.reload(fa)
-    try:
-        assert mod.RESIDENT_BWD_SD_BUDGET == 2 * 4096 * 64
-        assert mod._fused_bwd_fits(8192, 64)
-        assert not mod._fused_bwd_fits(16384, 64)
-    finally:
-        monkeypatch.delenv("FTL_SCOPED_VMEM_KIB")
-        importlib.reload(fa)
-        assert fa.RESIDENT_BWD_SD_BUDGET == 4096 * 64
+    kanana, mistral = (8192, 192, 128, False, 2), (4096, 128, 128, False, 2)
+    assert fa.vmem_capacity_bytes() == fa.CALIBRATION_VMEM_BYTES
+    assert fa._fused_bwd_vmem_limit(*kanana) == 54 * 2**20
+    assert fa._fused_bwd_vmem_limit(*mistral) == 23 * 2**20
+    assert fa._fused_bwd_vmem_limit(256, 64, 64, False, 4) == (
+        fa.DEFAULT_SCOPED_VMEM_BYTES)
+    for shape in (kanana, mistral):
+        need = fa._fused_bwd_residency(*shape) * 5 / 4
+        assert need <= fa._fused_bwd_vmem_limit(*shape) < need + 2**20
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    half = types.SimpleNamespace(vmem_capacity_bytes=64 * 2**20)
+    monkeypatch.setattr(fa.pltpu, "get_tpu_info", lambda: half)
+    assert fa.vmem_capacity_bytes() == 64 * 2**20
+    assert fa._fused_bwd_vmem_limit(*kanana) is None      # 54 > 48 MiB
+    assert fa._fused_bwd_vmem_limit(*mistral) == 23 * 2**20
+    # in-kernel rope keeps its own measured bound, whatever the VMEM
+    assert fa.rope_fused_profitable(4096, 64)
+    assert not fa.rope_fused_profitable(8192, 64)
