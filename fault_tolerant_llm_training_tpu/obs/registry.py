@@ -16,7 +16,7 @@ but use atomic ops cheap enough to leave safe anyway.
 import bisect
 import math
 import threading
-from typing import Dict, Iterable, Optional, Sequence, Tuple
+from typing import Callable, Dict, Iterable, Optional, Sequence, Tuple
 
 # Default duration buckets: spans 5 ms decode iterations to the 120 s USR1
 # checkpoint lead the whole framework is built around.
@@ -78,6 +78,23 @@ class Counter:
     @property
     def value(self) -> float:
         return self._value
+
+
+class ReadCounter(Counter):
+    """A counter that keeps no value of its own: ``.value`` calls ``read``,
+    which sums what a hot path accumulates by itself, with no lock and no
+    call into the registry (``obs/trace.py``'s span tallies). That path is
+    the only writer: ``inc`` is refused."""
+
+    def __init__(self, read: Callable[[], float]):
+        self._read = read
+
+    def inc(self, amount: float = 1.0) -> None:
+        raise TypeError("a ReadCounter is advanced by its source only")
+
+    @property
+    def value(self) -> float:
+        return float(self._read())
 
 
 class Gauge:
@@ -156,6 +173,13 @@ class _Family:
             if child is None:
                 child = self._children[key] = self._make()
             return child
+
+    def adopt(self, child, **labels):
+        """Install ``child`` (a :class:`ReadCounter`, say) as the series of
+        ``labels``; a series that exists already is kept and returned."""
+        key = _label_key(labels)
+        with self._lock:
+            return self._children.setdefault(key, child)
 
     # -- unlabeled convenience (delegates to the () child) --
     def inc(self, amount: float = 1.0) -> None:

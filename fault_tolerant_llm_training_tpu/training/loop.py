@@ -44,7 +44,7 @@ from ..models import build_model, get_config
 from ..deploy.publish import Publisher
 from ..obs import events
 from ..obs.registry import REGISTRY
-from ..obs.trace import AutoTraceWindow, TraceWindow, span
+from ..obs.trace import AutoTraceWindow, TraceWindow, profile_options, span
 from ..ops.attention import describe_attention_impl
 from ..ops.flash_attention import backward_calls, vmem_capacity_bytes
 from ..parallel.mesh import make_mesh, use_mesh
@@ -722,7 +722,8 @@ class Trainer:
             # bare --profile-dir keeps its whole-run capture; --trace-steps
             # and --auto-trace supersede it with a bounded window
             # (obs/trace.py) — one profiler owner at a time
-            jax.profiler.start_trace(cfg.profile_dir)
+            jax.profiler.start_trace(cfg.profile_dir,
+                                     profiler_options=profile_options())
         try:
             self._loop()
         except Exception as e:

@@ -75,7 +75,8 @@ def test_span_and_scope_refuse_names_outside_the_tables():
 
 
 def test_tables_call_sites_and_perf_md_agree():
-    """Every name of SPANS, SCOPES and the new event kinds is in PERF.md §3;
+    """Every name of SPANS, SCOPES and the new event kinds, and the two span
+    counters, is in PERF.md §3;
     every TraceAnnotation / named_scope / span() / scope() call in the
     program uses a name from the tables (literal names only), and every
     name the program may open is opened somewhere."""
@@ -83,7 +84,8 @@ def test_tables_call_sites_and_perf_md_agree():
 
     perf = (REPO / "PERF.md").read_text()
     section = perf.split("## 3.")[1].split("\n## 4.")[0]
-    for name in [*trace.SPANS, *trace.SCOPES, *LIFECYCLE]:
+    for name in [*trace.SPANS, *trace.SCOPES, *LIFECYCLE,
+                 trace.SPAN_SECONDS, trace.SPAN_COUNT]:
         assert f"`{name}`" in section, f"{name} missing from PERF.md §3"
     kinds = (PKG / "obs" / "events.py").read_text().split(
         "class FlightRecorder")[0]
@@ -212,6 +214,89 @@ def test_scheduler_run_leaves_every_serving_span(tiny_engine, tmp_path):
     pre = [s for s in spans if s[0] == "ftl:engine.prefill"]
     assert sum(int(s[4]["new_tokens"]) for s in pre) == 20 + 9 + 11
     assert {int(s[4]["bucket"]) for s in pre} <= {8, 16}
+
+
+def span_tally() -> dict:
+    """{span name: (seconds, count)} of the program's span counters now."""
+    from fault_tolerant_llm_training_tpu.obs import trace
+    from fault_tolerant_llm_training_tpu.obs.registry import REGISTRY
+
+    snap = REGISTRY.snapshot()
+    return {name: (snap[trace.SPAN_SECONDS]["series"][f"span={name}"],
+                   snap[trace.SPAN_COUNT]["series"][f"span={name}"])
+            for name in trace.SPANS}
+
+
+def test_span_counters_and_the_capture_describe_the_same_spans(
+        tiny_engine, tmp_path):
+    """A tiny scheduler under a capture with the program's own options (the
+    Python tracer off): over the capture, each ``ftl:`` span's count is its
+    number of events in the xplane, and its time their summed durations
+    within 2 % and 3 us a span. The profiler reads its own clock inside its
+    own calls, a microsecond or so from where the program reads the host's
+    monotonic clock, on each side of a span: on a CPU host, for the tiny
+    scheduler's 40-150 us spans (admission with nothing to admit, packing,
+    banking), that alone is 1-3 %.
+    tests/test_span_tally.py holds spans of a millisecond to 2 % alone."""
+    import jax
+
+    from fault_tolerant_llm_training_tpu.inference.scheduler import (
+        Request, Scheduler)
+    from fault_tolerant_llm_training_tpu.obs.trace import (SPANS,
+                                                           profile_options)
+    cfg, engine = tiny_engine
+    engine.reset()
+    sched = Scheduler(engine, prefill_batch=2)
+    rng = np.random.default_rng(9)
+    for i, (plen, gen) in enumerate([(20, 6), (9, 8), (11, 5), (14, 7)]):
+        sched.submit(Request(
+            id=f"s{i}", max_new_tokens=gen,
+            prompt=rng.integers(3, cfg.vocab_size, size=plen).tolist()))
+    sched.step()                     # compiles outside the capture
+    before = span_tally()
+    jax.profiler.start_trace(str(tmp_path),
+                             profiler_options=profile_options())
+    try:
+        while sched.pending():
+            sched.step()
+    finally:
+        jax.profiler.stop_trace()
+    after = span_tally()
+    spans = ftl_spans(tmp_path)
+    seen = set()
+    for name in SPANS:
+        events = [s for s in spans if s[0] == name]
+        seconds = after[name][0] - before[name][0]
+        count = after[name][1] - before[name][1]
+        assert count == len(events), (name, count, len(events))
+        if events:
+            seen.add(name)
+            traced = sum(s[2] - s[1] for s in events) / 1e9
+            assert abs(seconds - traced) <= 0.02 * traced + 3e-6 * count, (
+                name, seconds, traced, count)
+    assert {"ftl:sched.step", "ftl:sched.admit", "ftl:sched.pack",
+            "ftl:sched.bank", "ftl:engine.decode",
+            "ftl:engine.decode.dispatch", "ftl:engine.decode.sync",
+            "ftl:engine.prefill"} <= seen, seen
+    # and not one event of the Python tracer's
+    assert not python_call_events(tmp_path)
+
+
+def python_call_events(trace_dir) -> int:
+    """Events the profiler's Python tracer wrote into the newest xplane
+    under ``trace_dir``: their names start with ``$``."""
+    from perfbench.lib import trace_reduce
+    from perfbench.metrics import _program_trace
+
+    space = _program_trace._xspace_class()()
+    with open(trace_reduce.newest_xplane(str(trace_dir)), "rb") as fh:
+        space.ParseFromString(fh.read())
+    n = 0
+    for plane in space.planes:
+        names = {e.key: e.value.name for e in plane.event_metadata}
+        n += sum(1 for line in plane.lines for ev in line.events
+                 if names.get(ev.metadata_id, "").startswith("$"))
+    return n
 
 
 def test_step_seconds_is_bounded_with_a_running_total(tiny_engine):
@@ -476,6 +561,14 @@ def test_train_steps_leave_every_training_span(chain):
     # the prefetcher works on its own thread, outside the step spans
     pre = [s for s in spans if s[0] == "ftl:data.prefetch"]
     assert {s[3] for s in pre}.isdisjoint({s[3] for s in steps})
+
+
+def test_the_trainers_capture_holds_no_python_call(chain):
+    """``--profile-dir``: the trainer's whole-run capture passes the
+    program's options (obs/trace.py ``profile_options``), so its trace holds
+    the spans and not one event of the Python tracer's."""
+    assert any(s[0] == "ftl:train.step" for s in ftl_spans(chain / "trace"))
+    assert not python_call_events(chain / "trace")
 
 
 # --------------------------- the latent / expert class's training step
