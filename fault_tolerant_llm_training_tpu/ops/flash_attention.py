@@ -35,7 +35,8 @@ VPU economy (attention at head_dim 64 is VPU-bound on TPU, not MXU-bound):
 lse is carried padding-free in both families (see _lse_layout): the
 streaming family as (B, H, 1, S) — q positions on the LANE dim — and the
 resident family as (B, H, S/128, 128) — the lse vector wrapped into full
-(8, 128) tiles. The Pallas TPU lowering requires a block's last two dims
+(8, 128) tiles; the family that writes it is the family that reads it.
+The Pallas TPU lowering requires a block's last two dims
 to be (8k, 128m)-tileable or full, and the TPU (8, 128) tile pads
 whatever lands on the trailing dims: the legacy (B, H, S, 1) residual
 (kept for unaligned shapes) pads its singleton lane 128x (measured
@@ -49,31 +50,30 @@ from the do/o tiles (see _delta) — an XLA-side delta materializes fp32
 casts of the full dO and O with layout-change copies at the custom-call
 boundary.
 
-Two kernel families:
+Two kernel families, and ONE rule picks the family at a shape, for the
+forward and the backward alike: the resident family wherever the fused
+backward's VMEM residency fits the chip (_fused_bwd_vmem_limit; the
+forward holds a subset of those blocks), the streaming family elsewhere.
 
-- **Resident** (forward: S <= STREAM_THRESHOLD; backward: wherever its
-  VMEM residency fits the chip, see _fused_bwd_vmem_limit): the non-grid
-  operand (K/V, and the dk/dv gradient accumulators) sits whole in VMEM
-  and an in-kernel fori_loop walks it. Fastest at moderate S — no
-  per-block pipeline boundaries — but VMEM-bound: the resident rows grow
-  linearly with S and the padded widths. The backward is ONE fused kernel
-  (_bwd_fused_kernel) producing dq, dk and dv from a single pass over the
-  causal tile triangle — the split FA2 scheme recomputes the softmax core
-  (scores, exp2, dO @ V^T, dS) twice per tile, once in dq and once in
-  dk/dv, 7 matmuls and 2 exp passes where the fused kernel does 5 and 1;
-  fusing it measured +10.9% on the headline bench (98.2k -> 109.0k
-  tokens/s), +9.4% at bs 16, and −9.6% fwd+bwd at S=4096 where it
-  outlives the streamed forward (BASELINE.md round 3). The fused call
-  asks the compiler for the VMEM its blocks need (vmem_limit_bytes) where
-  that is more than XLA's default scoped limit.
-- **Streaming** (forward: S > STREAM_THRESHOLD; backward: where the fused
-  kernel's residency does not fit): the loop moves into the grid's
-  innermost dimension; the online-softmax / gradient accumulators live in
-  VMEM scratch that persists across grid steps, and every operand is a
-  fixed-size tile. O(1) VMEM in S — this is what makes 32k+ contexts
-  compile on a single chip (beyond that, ring attention shards S over the
-  mesh's 'sequence' axis, ops/ring_attention.py). Its backward is split:
-  a dq kernel and a dk/dv kernel.
+- **Resident**: the non-grid operand (K/V, and the dk/dv gradient
+  accumulators) sits whole in VMEM and an in-kernel fori_loop walks it —
+  no per-block pipeline boundaries, no K/V tile fetched twice — but
+  VMEM-bound: the resident rows grow linearly with S and the padded
+  widths. The forward is _fwd_kernel on a (b, h, q-tile) grid; the
+  backward is ONE fused kernel (_bwd_fused_kernel) producing dq, dk and
+  dv from a single pass over the causal tile triangle — the split FA2
+  scheme recomputes the softmax core (scores, exp2, dO @ V^T, dS) twice
+  per tile, once in dq and once in dk/dv, 7 matmuls and 2 exp passes
+  where the fused kernel does 5 and 1 (+10.9% on the round-3 headline
+  bench, BASELINE.md). Both calls ask the compiler for the family's VMEM
+  (vmem_limit_bytes) where that is more than XLA's default scoped limit.
+- **Streaming**: the loop moves into the grid's innermost dimension; the
+  online-softmax / gradient accumulators live in VMEM scratch that
+  persists across grid steps, and every operand is a fixed-size tile.
+  O(1) VMEM in S — this is what makes 32k+ contexts compile on a single
+  chip (beyond that, ring attention shards S over the mesh's 'sequence'
+  axis, ops/ring_attention.py). Its backward is split: a dq kernel and a
+  dk/dv kernel.
 """
 
 import functools
@@ -91,42 +91,40 @@ from jax.experimental.pallas import tpu as pltpu
 # versa. FWD retuned again in round 4 after the in-kernel rope shifted the
 # balance (512x512: 122.1k vs 120.5k at the round-3 512x1024, and best at
 # bs 16 too; round-3's sweep history: the bs-8 peak 256x1024 collapses 26x
-# at bs 16 — BASELINE.md).
+# at bs 16 — BASELINE.md). Swept again on v5e past 2,048 rows, one
+# resident forward call: at S=8192, 32 heads of 192 / 128, batch 4 — 512x512
+# 28.8 ms, 512x1024 29.1, 256x512 29.7, 1024x1024 29.8, 1024x512 30.6; at
+# S=4096, 32:8 heads of 128, batch 3 — 512x512 4.53, 256x512 4.61,
+# 512x1024 4.71, 1024x1024 4.85, 1024x512 5.07.
 FWD_BLOCK_Q, FWD_BLOCK_K = 512, 512
 # The fused backward walks the dq tiles. Swept on v5e at S=8192, 32 heads of
 # 192 / 128, batch 4 (one backward call): 512x512 57.9 ms, 512x1024 58.3,
 # 1024x1024 58.4, 1024x512 59.9, 256x512 63.4.
 DQ_BLOCK_Q, DQ_BLOCK_K = 512, 512
 DKV_BLOCK_Q, DKV_BLOCK_K = 512, 1024
-# The mid-range STREAMING regime (STREAM_THRESHOLD < S <
-# LONG_STREAM_THRESHOLD) keeps the round-3 forward tiles: its A/B there
+# The STREAMING family's forward below LONG_STREAM_THRESHOLD (the resident
+# family uses FWD_BLOCK_*) keeps the round-3 streamed tiles: its A/B there
 # (S=8192: -13%, S=16384: -10% vs the older 1024x256) was measured with
 # the 1024-wide k-tile, and the round-4 resident retune does not transfer
 # (the grid-streamed pipeline amortizes differently).
 MID_FWD_BLOCK_Q, MID_FWD_BLOCK_K = 512, 1024
-# Very long sequences get their own operating point (tuned at S=32k/64k,
-# B1/H12/D64: -6.6% at 32k, -14.5% at 64k vs the resident tiles — the
-# grid-streamed pipeline prefers larger k-tiles in fwd/dq and a larger
-# q-tile in dkv there). Below LONG_STREAM_THRESHOLD the resident tile
-# sizes measured equal (8k) or clearly better (16k: 132 vs 179 ms), so
-# the streaming kernels keep them.
+# Very long sequences, streaming family, get their own operating point
+# (tuned at S=32k/64k, B1/H12/D64: -6.6% at 32k, -14.5% at 64k vs the
+# resident tiles — the grid-streamed pipeline prefers larger k-tiles in
+# fwd/dq and a larger q-tile in dkv there). Below LONG_STREAM_THRESHOLD
+# the resident dq/dkv tile sizes measured equal (8k) or clearly better
+# (16k: 132 vs 179 ms), so the streaming backward keeps them.
 LONG_STREAM_THRESHOLD = 32768
 STREAM_FWD_BLOCK_Q, STREAM_FWD_BLOCK_K = 1024, 512
 STREAM_DQ_BLOCK_Q, STREAM_DQ_BLOCK_K = 512, 1024
 STREAM_DKV_BLOCK_Q, STREAM_DKV_BLOCK_K = 1024, 512
-# Above this sequence length the resident FORWARD kernel's full-row VMEM
-# operands no longer fit the 16M scoped-vmem limit at D=64 (originally
-# measured on the split dk/dv kernel at S=4096); switch to the streaming
-# kernels.
-STREAM_THRESHOLD = 2048
-# The fused backward is not bound to the forward's threshold: it runs
-# wherever its VMEM residency (_fused_bwd_residency: whole K/V rows and
-# dk/dv outputs, two fp32 (S, D) scratches, the q-side tiles and the score
-# tiles' temporaries) fits the chip, and asks the compiler for that much
-# (vmem_limit_bytes) where XLA's default scoped limit is too small. Past
-# STREAM_THRESHOLD the forward then streams while the backward runs fused
-# (one softmax-core pass instead of two). The D=64 tile constants transfer
-# to D=128 unchanged: a 10-combo resident fwd/dq/dkv sweep at S=2048/D=128
+# The family is decided by VMEM, not by a row count: the resident family
+# runs wherever the fused backward's residency (_fused_bwd_residency:
+# whole K/V rows and dk/dv outputs, two fp32 (S, D) scratches, the q-side
+# tiles and the score tiles' temporaries) fits the chip, and both of its
+# calls ask the compiler for that much (vmem_limit_bytes) where XLA's
+# default scoped limit is too small. The D=64 tile constants transfer to
+# D=128 unchanged: a 10-combo resident fwd/dq/dkv sweep at S=2048/D=128
 # (scripts/d128_tile_sweep.py) put the defaults first, every variant
 # 8-11% slower.
 #
@@ -149,11 +147,14 @@ def rope_fused_profitable(s: int, d: int) -> bool:
     Measured on v5e (BASELINE.md round 4): +3.7% headline at S=2048 and
     −2.6% attention time at S=4096 (where K is roped ONCE per span into
     the fused backward's scratch), but +2.1% at S=8192 and +3.7% at
-    S=16384 — the streaming kernels re-fetch each K tile per (q-tile,
-    k-step) grid visit and the rotation rides every fetch, so the
-    redundant k-rope grows with S while XLA-side rope stays O(S). The
-    boundary is a measurement of its own (ROPE_FUSED_SD_BUDGET), not the
-    fused backward's VMEM rule."""
+    S=16384, measured when every kernel streamed there. That cost is the
+    STREAMING family's: its kernels re-fetch
+    each K tile per (q-tile, k-step) grid visit and the rotation rides
+    every fetch, so the redundant k-rope grows with S while XLA-side rope
+    stays O(S); the resident kernels rope K once per span, and their rope
+    tables add 2 x (S, D) fp32 rows to the residency. The boundary is a
+    measurement of its own (ROPE_FUSED_SD_BUDGET), not the family's VMEM
+    rule, and was not re-measured with the resident forward past 2,048."""
     return s * d <= ROPE_FUSED_SD_BUDGET
 
 
@@ -195,7 +196,7 @@ def _fused_bwd_residency(s: int, d: int, dv: int, rope: bool,
     41-42; mistral-7b's S=4096, 128 counts 17.75, needs 15-16; below 128
     lanes the count is high (S=2048, 64: 11.75 counted, 5-6 needed — a
     64-wide row is not padded there)."""
-    bq, bk = _blocks(s, *_active_tiles(s)[1])
+    bq, bk = _blocks(s, *_active_tiles(s, True)[1])
     dp, dvp = _lanes(d), _lanes(dv)
     f32 = 4
     blocks = (2 * bq * dp + 2 * bq * dvp + 2 * s * dp + 2 * s * dvp) * itemsize
@@ -208,18 +209,24 @@ def _fused_bwd_residency(s: int, d: int, dv: int, rope: bool,
 
 def _fused_bwd_vmem_limit(s: int, d: int, dv: int, rope: bool,
                           itemsize: int):
-    """The fused backward's VMEM request in bytes, or None where it does
-    not fit and the split streaming kernels run.
+    """The resident family's VMEM request in bytes, or None where it does
+    not fit and the streaming family runs — the ONE predicate of the
+    family, forward and backward (the resident forward holds a subset of
+    the fused backward's blocks, so the backward's fit implies its own).
 
-    It asks for its residency and a quarter more (what the count does not
-    see: Mosaic's internal scratch and relayout copies), in whole MiB and
-    never below XLA's default scoped limit, and runs fused where that is
-    at most three quarters of the chip's VMEM (the rest left to the
-    compiler). On v5e's 128 MiB, bf16: S=8192 at 192 / 128 wide
-    (kanana-2) asks 54 MiB, S=4096 at 128 (mistral-7b) 23 MiB; S=65536 at
-    128 does not fit. Measured on v5e at those two shapes, one backward
-    call: fused 57.9 ms against the split pair's 124.7 (kanana-2, batch 4,
-    32 heads), 6.8 against 18.3 (mistral-7b, batch 3, 32:8 heads)."""
+    It asks for the fused backward's residency and a quarter more (what
+    the count does not see: Mosaic's internal scratch and relayout
+    copies), in whole MiB and never below XLA's default scoped limit, and
+    the family is resident where that is at most three quarters of the
+    chip's VMEM (the rest left to the compiler). On v5e's 128 MiB, bf16:
+    S=8192 at 192 / 128 wide (kanana-2) asks 54 MiB, S=4096 at 128
+    (mistral-7b) 23 MiB; S=65536 at 128 does not fit. Measured on v5e at
+    those two shapes, one backward call: fused 57.9 ms against the split
+    pair's 124.7 (kanana-2, batch 4, 32 heads), 6.8 against 18.3
+    (mistral-7b, batch 3, 32:8 heads). The resident forward's own blocks
+    need about 18 MiB at kanana-2's shape (a compile for a described v5e:
+    16 MiB, XLA's default scoped limit, is too little) and under 8 at
+    mistral-7b's."""
     need = _fused_bwd_residency(s, d, dv, rope, itemsize) * 5 // 4
     need = -(-need // 2**20) * 2**20
     if need > vmem_capacity_bytes() * 3 // 4:
@@ -336,68 +343,64 @@ def _online_softmax_step(q2, k, v, carry, q_start, k_start, masked):
     return m_new, l_new, acc_new
 
 
-def _active_tiles(s: int):
-    """The (fwd, dq, dkv) (block_q, block_k) pairs the kernels will use at
-    sequence length ``s`` — the single source of truth for the
-    tile-set dispatch, shared by _flash_fwd, _flash_bwd and _lse_layout
-    (which must validate lane alignment against the SAME q-tiles)."""
+def _active_tiles(s: int, resident: bool):
+    """The (fwd, dq, dkv) (block_q, block_k) pairs the kernel family
+    (``resident``, see _fused_bwd_vmem_limit) uses at sequence length
+    ``s`` — the single source of truth for the tile-set dispatch, shared
+    by _flash_fwd_t, _flash_bwd_t and _lse_layout (which must validate
+    lane alignment against the SAME q-tiles). The resident family has one
+    tile set; the streaming family's forward takes the mid or the long
+    set by LONG_STREAM_THRESHOLD, its backward the long set past it."""
+    if resident:
+        return ((FWD_BLOCK_Q, FWD_BLOCK_K),
+                (DQ_BLOCK_Q, DQ_BLOCK_K),
+                (DKV_BLOCK_Q, DKV_BLOCK_K))
     if s >= LONG_STREAM_THRESHOLD:
         return ((STREAM_FWD_BLOCK_Q, STREAM_FWD_BLOCK_K),
                 (STREAM_DQ_BLOCK_Q, STREAM_DQ_BLOCK_K),
                 (STREAM_DKV_BLOCK_Q, STREAM_DKV_BLOCK_K))
-    if s > STREAM_THRESHOLD:
-        return ((MID_FWD_BLOCK_Q, MID_FWD_BLOCK_K),
-                (DQ_BLOCK_Q, DQ_BLOCK_K),
-                (DKV_BLOCK_Q, DKV_BLOCK_K))
-    return ((FWD_BLOCK_Q, FWD_BLOCK_K),
+    return ((MID_FWD_BLOCK_Q, MID_FWD_BLOCK_K),
             (DQ_BLOCK_Q, DQ_BLOCK_K),
             (DKV_BLOCK_Q, DKV_BLOCK_K))
 
 
-def _lse_layout(s: int, fused_bwd: bool) -> str:
-    """The lse residual's memory layout at sequence length ``s``, where
-    ``fused_bwd`` says whether the fused backward runs (its VMEM fits,
-    _fused_bwd_vmem_limit):
+def _lse_layout(s: int, resident: bool) -> str:
+    """The lse residual's memory layout at sequence length ``s`` in the
+    kernel family ``resident`` (the family writes it and the same family
+    reads it):
 
     - ``"packed"`` — (B, H, 1, S), q positions on the lane dim. Streaming
-      family (s > STREAM_THRESHOLD), where the legacy layout's padding is
-      the point — e.g. 384 MB at S=64k — and every q-tile is 128-aligned
-      (odd sequence lengths degrade tiles below 128 rows, making the
-      packed blocks illegal). Consumers (via _read_lse): the streaming
-      backward kernels, and the FUSED resident backward when it runs past
-      the forward's threshold — one entry transpose per grid step.
+      family, where the legacy layout's padding is the point — e.g.
+      384 MB at S=64k — and every q-tile is 128-aligned (odd sequence
+      lengths degrade tiles below 128 rows, making the packed blocks
+      illegal). Consumers (via _read_lse): the streaming backward
+      kernels.
     - ``"blocked"`` — (B, H, S/128, 128): the resident family's packed
       form (VERDICT r4 weak #3, the one variant the r2/r3 rejection
       sweeps never built). The forward's (block_q,) lse vector wraps to
       (block_q/128, 128) — a lane-preserving reshape, unlike the r3
       relayout/transpose variants (−1.4 to −3%) — and the fused backward
       unwraps it once per q-tile. Zero padding: the fp32 (S/128, 128)
-      plane tiles natively. Requires s and the resident q-tiles to be
-      128-multiples; FTL_LSE_RESIDENT=legacy opts out (A/B knob).
+      plane tiles natively. Requires the resident q-tiles (and so s) to
+      be 128-multiples; FTL_LSE_RESIDENT=legacy opts out (A/B knob).
     - ``"legacy"`` — (B, H, S, 1), whose singleton lane pads 128x
       (~1.1 GB at the bs-8 bench shape). Kept for unaligned shapes.
     """
-    if (s > STREAM_THRESHOLD
-            and all(_fit_block(s, bq) % 128 == 0
-                    for bq, _ in _active_tiles(s))):
+    if not all(_fit_block(s, bq) % 128 == 0
+               for bq, _ in _active_tiles(s, resident)):
+        return "legacy"
+    if not resident:
         return "packed"
-    # "blocked" additionally requires the FUSED backward: the streaming
-    # backward kernels have no blocked row_spec, and a chip with too little
-    # VMEM can route s <= STREAM_THRESHOLD shapes to them while the forward
-    # would have emitted the blocked plane — a trace-time Pallas failure.
-    if (s <= STREAM_THRESHOLD and s % 128 == 0 and fused_bwd
-            and os.environ.get("FTL_LSE_RESIDENT", "blocked") != "legacy"
-            and all(_fit_block(s, bq) % 128 == 0
-                    for bq, _ in _active_tiles(s))):
-        return "blocked"
-    return "legacy"
+    if os.environ.get("FTL_LSE_RESIDENT", "blocked") == "legacy":
+        return "legacy"
+    return "blocked"
 
 
 def _read_lse(ref, g, layout):
     """(block_q, 1) column lse from a kernel ref; ``g`` is the GQA group
     row (0 for per-head refs). Streaming-family layouts only — the
-    resident "blocked" plane is unwrapped inline in _bwd_fused_kernel
-    (the read needs the grid's q-tile index)."""
+    resident family's fused backward reads its own layouts inline (the
+    "blocked" unwrap needs the grid's q-tile index)."""
     if layout == "packed":
         return jnp.transpose(ref[0, g])  # (1, bq) -> (bq, 1)
     return ref[0, g]
@@ -526,7 +529,7 @@ def _fwd_kernel(*refs, block_k: int, scale: float, causal: bool,
 
 
 def _bwd_fused_kernel(*refs, block_k: int, scale: float, causal: bool,
-                      group: int, lse_layout: str, rope: bool = False):
+                      group: int, lse_blocked: bool, rope: bool = False):
     """Fused resident backward: dq, dk and dv from ONE pass over the score
     tiles.
 
@@ -543,7 +546,8 @@ def _bwd_fused_kernel(*refs, block_k: int, scale: float, causal: bool,
     Grid (b, h, qi), qi innermost. q/do/o/dq: (1, 1, block_q, D) at qi;
     k/v: (1, 1, S, D) and dk/dv out: (1, 1, S, D) at KV head h // group
     (their blocks are revisited across the span, written back on the last
-    step); lse: (1, 1, block_q, 1).
+    step); lse: the whole (1, 1, S/128, 128) plane with ``lse_blocked``,
+    else (1, 1, block_q, 1) legacy — as the resident forward wrote it.
 
     rope=True adds (cq, sq) q-row and (ck, sk) full-row RAW table refs plus
     a (S, D) rotated-K scratch: scores recompute the forward's exact
@@ -574,21 +578,19 @@ def _bwd_fused_kernel(*refs, block_k: int, scale: float, causal: bool,
     else:
         q2 = _prescale_q(q_ref[0, 0], scale)
     do = do_ref[0, 0]
-    # lse is read once per grid step, so the non-legacy layouts afford a
-    # single restore each: "packed" (1, block_q) row (above
-    # STREAM_THRESHOLD, where the forward streamed) transposes; "blocked"
-    # (the resident default) unwraps its rows of the full (S/128, 128)
-    # plane back to the (block_q, 1) column.
-    if lse_layout == "blocked":
+    # lse is read once per grid step: the "blocked" plane (the resident
+    # default) unwraps this tile's rows of the full (S/128, 128) plane back
+    # to the (block_q, 1) column; "legacy" is that column already.
+    if lse_blocked:
         # Mosaic cannot shape-cast (rows, 128) -> (block_q, 1) directly;
-        # per-row (1, 128) -> (128, 1) transposes (the op the packed
-        # path uses) + a sublane concat restore the column.
+        # per-row (1, 128) -> (128, 1) transposes + a sublane concat
+        # restore the column.
         rows = q2.shape[0] // 128
         band = lse_ref[0, 0, pl.ds(qi * rows, rows), :]
         lse = jnp.concatenate(
             [jnp.transpose(band[r:r + 1, :]) for r in range(rows)], axis=0)
     else:
-        lse = _read_lse(lse_ref, 0, lse_layout)
+        lse = lse_ref[0, 0]
     delta = _delta(do, o_ref[0, 0])
     block_q, d = q2.shape
     s_k = k_ref.shape[2]
@@ -859,6 +861,14 @@ def _blocks(s, block_q, block_k):
     return _fit_block(s, block_q), _fit_block(s, block_k)
 
 
+def _vmem_params(vmem_limit):
+    """A resident call's compiler params: the family's VMEM request where
+    XLA's default scoped limit is too small, else the default."""
+    if vmem_limit > DEFAULT_SCOPED_VMEM_BYTES:
+        return pltpu.CompilerParams(vmem_limit_bytes=vmem_limit)
+    return None
+
+
 def _flash_fwd(q, k, v, causal, interpret):
     # (B, S, H, D) -> (B, H, S, D) so heads become a grid axis.
     qt = jnp.transpose(q, (0, 2, 1, 3))
@@ -879,11 +889,12 @@ def _flash_fwd_t(qt, kt, vt, causal, interpret, rope_tables=None):
     dv = vt.shape[-1]
     kv_heads = kt.shape[1]
     group = h // kv_heads
-    block_q, block_k = _blocks(s, *_active_tiles(s)[0])
     scale = 1.0 / (d ** 0.5)
     rope = rope_tables is not None
-    layout = _lse_layout(s, _fused_bwd_vmem_limit(
-        s, d, dv, rope, kt.dtype.itemsize) is not None)
+    vmem_limit = _fused_bwd_vmem_limit(s, d, dv, rope, kt.dtype.itemsize)
+    resident = vmem_limit is not None
+    block_q, block_k = _blocks(s, *_active_tiles(s, resident)[0])
+    layout = _lse_layout(s, resident)
     if layout == "packed":
         lse_shape = (b, h, 1, s)
         lse_spec = pl.BlockSpec((1, 1, 1, block_q),
@@ -906,7 +917,7 @@ def _flash_fwd_t(qt, kt, vt, causal, interpret, rope_tables=None):
         lse_spec,
     ]
 
-    if s <= STREAM_THRESHOLD:
+    if resident:
         kernel = functools.partial(_fwd_kernel, block_k=block_k, scale=scale,
                                    causal=causal, rope=rope, group=group,
                                    lse_blocked=(layout == "blocked"))
@@ -931,6 +942,7 @@ def _flash_fwd_t(qt, kt, vt, causal, interpret, rope_tables=None):
             out_specs=out_specs,
             out_shape=out_shape,
             scratch_shapes=scratch,
+            compiler_params=_vmem_params(vmem_limit),
             interpret=interpret,
         )(*operands)
     else:
@@ -1012,31 +1024,28 @@ def _flash_bwd_t(qt, kt, vt, ot, lse, dot, causal, interpret,
     dv = vt.shape[-1]
     kv_heads = kt.shape[1]
     group = h // kv_heads
-    (_, __), (dq_q, dq_k), (dkv_q, dkv_k) = _active_tiles(s)
-    dq_bq, dq_bk = _blocks(s, dq_q, dq_k)
-    dkv_bq, dkv_bk = _blocks(s, dkv_q, dkv_k)
     scale = 1.0 / (d ** 0.5)
     rope = rope_tables is not None
     vmem_limit = _fused_bwd_vmem_limit(s, d, dv, rope, kt.dtype.itemsize)
-    layout = _lse_layout(s, vmem_limit is not None)
+    resident = vmem_limit is not None
+    (_, __), (dq_q, dq_k), (dkv_q, dkv_k) = _active_tiles(s, resident)
+    dq_bq, dq_bk = _blocks(s, dq_q, dq_k)
+    dkv_bq, dkv_bk = _blocks(s, dkv_q, dkv_k)
+    layout = _lse_layout(s, resident)
     # delta (rowwise dO . O) is computed inside the kernels from the do/o
     # tiles (see _delta) — no fp32 materialization at the XLA level.
 
-    if vmem_limit is not None:
+    if resident:
         # Fused single-pass backward (see _bwd_fused_kernel): dq, dk, dv
-        # from one walk of the causal tile triangle. Runs past the
-        # forward's STREAM_THRESHOLD — there the forward emitted the
-        # packed lse layout.
+        # from one walk of the causal tile triangle, reading the lse the
+        # resident forward wrote ("blocked", or "legacy" where unaligned).
         q_spec = pl.BlockSpec((1, 1, dq_bq, d), lambda bi, hi, qi: (bi, hi, qi, 0))
         kv_full = pl.BlockSpec((1, 1, s, d), lambda bi, hi, qi: (bi, hi // group, 0, 0))
         o_spec = pl.BlockSpec((1, 1, dq_bq, dv),
                               lambda bi, hi, qi: (bi, hi, qi, 0))
         v_full = pl.BlockSpec((1, 1, s, dv),
                               lambda bi, hi, qi: (bi, hi // group, 0, 0))
-        if layout == "packed":
-            row_spec = pl.BlockSpec((1, 1, 1, dq_bq),
-                                    lambda bi, hi, qi: (bi, hi, 0, qi))
-        elif layout == "blocked":
+        if layout == "blocked":
             row_spec = pl.BlockSpec((1, 1, s // 128, 128),
                                     lambda bi, hi, qi: (bi, hi, 0, 0))
         else:
@@ -1054,8 +1063,8 @@ def _flash_bwd_t(qt, kt, vt, ot, lse, dot, causal, interpret,
             scratch.append(pltpu.VMEM((s, d), kt.dtype))
         dq, dk, dv = pl.pallas_call(
             functools.partial(_bwd_fused_kernel, block_k=dq_bk, scale=scale,
-                              causal=causal, group=group, lse_layout=layout,
-                              rope=rope),
+                              causal=causal, group=group,
+                              lse_blocked=(layout == "blocked"), rope=rope),
             grid=(b, h, s // dq_bq),
             in_specs=in_specs,
             out_specs=[pl.BlockSpec((1, 1, dq_bq, d),
@@ -1065,9 +1074,7 @@ def _flash_bwd_t(qt, kt, vt, ot, lse, dot, causal, interpret,
                        jax.ShapeDtypeStruct(kt.shape, kt.dtype),
                        jax.ShapeDtypeStruct(vt.shape, vt.dtype)],
             scratch_shapes=scratch,
-            compiler_params=(
-                pltpu.CompilerParams(vmem_limit_bytes=vmem_limit)
-                if vmem_limit > DEFAULT_SCOPED_VMEM_BYTES else None),
+            compiler_params=_vmem_params(vmem_limit),
             interpret=interpret,
         )(*operands)
     else:
@@ -1342,33 +1349,40 @@ _flash_attention_rope.defvjp(_flash_attention_rope_fwd,
                              _flash_attention_rope_bwd)
 
 
-# the kernel that stands for one attention backward in a traced program, by
-# family (the split family's dk/dv kernel runs beside its dq kernel)
-_BACKWARD_KERNELS = {"_bwd_fused_kernel": "fused",
-                     "_dq_stream_kernel": "split"}
+# the kernel that stands for one attention forward or backward in a traced
+# program, by direction and family (the split family's dk/dv kernel runs
+# beside its dq kernel)
+_KERNEL_CALLS = {"_fwd_kernel": ("forward", "resident"),
+                 "_fwd_stream_kernel": ("forward", "stream"),
+                 "_bwd_fused_kernel": ("backward", "fused"),
+                 "_dq_stream_kernel": ("backward", "split")}
 
 
-def backward_calls(closed_jaxpr):
-    """``({"fused"|"split": calls}, vmem)``: the attention backward calls a
-    traced program makes each time it runs (``jax.jit(f).trace(...).jaxpr``;
-    a scan's body counts its length times), and the most VMEM a fused call
-    asks the compiler for (XLA's default scoped limit where none asks
-    more). Read from the program, so what the rule picked at each shape is
-    what is counted."""
+def flash_calls(closed_jaxpr):
+    """``({"forward": {"resident"|"stream": calls}, "backward":
+    {"fused"|"split": calls}}, vmem)``: the attention calls a traced
+    program makes each time it runs (``jax.jit(f).trace(...).jaxpr``; a
+    scan's body counts its length times, and a forward that remat runs
+    again in the backward counts twice), and the most VMEM a call asks
+    the compiler for (XLA's default scoped limit where none asks more).
+    Read from the program, so what the rule picked at each shape is what
+    is counted."""
     from jax.extend import core as jcore
 
-    calls, vmem = {}, DEFAULT_SCOPED_VMEM_BYTES
+    calls = {"forward": {}, "backward": {}}
+    vmem = DEFAULT_SCOPED_VMEM_BYTES
 
     def walk(jaxpr, times):
         nonlocal vmem
         for eqn in jaxpr.eqns:
             if eqn.primitive.name == "pallas_call":
                 info = eqn.params["jaxpr"].debug_info
-                family = _BACKWARD_KERNELS.get(
+                kernel = _KERNEL_CALLS.get(
                     info.func_src_info.split(" ")[0] if info else None)
-                if family:
-                    calls[family] = calls.get(family, 0) + times
-                if family == "fused":
+                if kernel:
+                    direction, family = kernel
+                    tally = calls[direction]
+                    tally[family] = tally.get(family, 0) + times
                     for params in eqn.params["compiler_params"].values():
                         vmem = max(vmem, params.vmem_limit_bytes or 0)
                 continue

@@ -46,7 +46,7 @@ from ..obs import events
 from ..obs.registry import REGISTRY
 from ..obs.trace import AutoTraceWindow, TraceWindow, profile_options, span
 from ..ops.attention import describe_attention_impl
-from ..ops.flash_attention import backward_calls, vmem_capacity_bytes
+from ..ops.flash_attention import flash_calls, vmem_capacity_bytes
 from ..parallel.mesh import make_mesh, use_mesh
 from ..parallel.sharding import batch_pspec, param_pspecs, replicated_pspecs
 from ..training.state import TrainState
@@ -435,17 +435,18 @@ class Trainer:
                                       batch_struct)
         self._compiled_step = traced.lower().compile()
         compile_secs = time.perf_counter() - t_compile
-        # the attention backward calls a step makes, by kernel family (ops/
-        # flash_attention.py picks it per shape from the chip's VMEM): read
-        # from the traced program, counted at each consumed step
-        self._flash_bwd_calls, vmem = backward_calls(traced.jaxpr)
-        if self._flash_bwd_calls:
-            logger.info(
-                "Flash backward | " + ", ".join(
-                    f"{family} x{n} a step"
-                    for family, n in sorted(self._flash_bwd_calls.items()))
-                + f" | vmem limit {vmem / 2**20:g} MiB of "
-                  f"{vmem_capacity_bytes() / 2**20:g} MiB")
+        # the attention forward and backward calls a step makes, by kernel
+        # family (ops/flash_attention.py picks it per shape from the chip's
+        # VMEM): read from the traced program, counted at each consumed step
+        self._flash_calls, vmem = flash_calls(traced.jaxpr)
+        for direction, calls in self._flash_calls.items():
+            if calls:
+                logger.info(
+                    f"Flash {direction} | " + ", ".join(
+                        f"{family} x{n} a step"
+                        for family, n in sorted(calls.items()))
+                    + f" | vmem limit {vmem / 2**20:g} MiB of "
+                      f"{vmem_capacity_bytes() / 2**20:g} MiB")
         # emitted from run(), AFTER the start/resume audit: the flight-
         # recorder trail contract is that a job's first event is
         # start/resume (tests/test_obs.py, goodput stitcher)
@@ -584,11 +585,19 @@ class Trainer:
             "per_device_memory_stats)")
         self._m_hbm_limit = r.gauge("ftl_device_hbm_bytes_limit",
                                     "Per-device HBM limit")
-        self._m_flash_bwd = r.counter(
-            "flash_backward_calls_total",
-            "Flash attention backward calls of the consumed training steps, "
-            "by kernel family (fused = one dq/dk/dv kernel, split = the "
-            "streaming dq and dk/dv kernels; ops/flash_attention.py)")
+        self._m_flash = {
+            "forward": r.counter(
+                "flash_forward_calls_total",
+                "Flash attention forward calls of the consumed training "
+                "steps, a remat recompute included, by kernel family "
+                "(resident = whole K/V rows in VMEM, stream = K/V tiles "
+                "streamed by the grid; ops/flash_attention.py)"),
+            "backward": r.counter(
+                "flash_backward_calls_total",
+                "Flash attention backward calls of the consumed training "
+                "steps, by kernel family (fused = one dq/dk/dv kernel, split "
+                "= the streaming dq and dk/dv kernels; "
+                "ops/flash_attention.py)")}
         self._last_consume_t = None
         # (wall clock, last step) already covered by a step event; the next
         # event's dur/steps are deltas against this.
@@ -980,8 +989,9 @@ class Trainer:
         if len(vals) > 2:   # the latent / expert class's (pairs, touched)
             for counter, v in zip(self._m_moe, vals[2:]):
                 counter.inc(float(v))
-        for family, n in self._flash_bwd_calls.items():
-            self._m_flash_bwd.labels(kernel=family).inc(n)
+        for direction, calls in self._flash_calls.items():
+            for family, n in calls.items():
+                self._m_flash[direction].labels(kernel=family).inc(n)
         if not math.isfinite(grad_norm):
             # ref: utils.py:61 error_if_nonfinite -> routed as code error (-1)
             # grad_norm is a replicated global value: every host raises here

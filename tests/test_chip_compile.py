@@ -106,16 +106,17 @@ def test_flash_fwd_bwd_compiles_for_v5e(v5e, width, rope_fused):
 def test_fused_flash_backward_compiles_at_the_training_cells_shapes(
         v5e, monkeypatch, b, h, kv, s, d, dv):
     """The training cells' attention, forward and backward, compiled by
-    Mosaic for one described v5e: the backward is the ONE fused kernel and
-    compiles under the VMEM limit its call asks for. At kanana-2's shape
-    that limit is what makes it compile: held to XLA's default scoped
-    16 MiB, the compiler runs out of VMEM."""
+    Mosaic for one described v5e: the resident family — the resident
+    forward and the ONE fused backward — compiles under the VMEM limit its
+    calls ask for. At kanana-2's shape that limit is what makes it
+    compile: held to XLA's default scoped 16 MiB, the compiler runs out of
+    VMEM."""
     sds = _shapes_on(v5e.devices[0])
     grad = jax.grad(lambda q, k, v: fa.flash_attention_bhsd(q, k, v).astype(
         jnp.float32).sum(), argnums=(0, 1, 2))
     args = (sds((b, h, s, d)), sds((b, kv, s, d)), sds((b, kv, s, dv)))
-    calls, vmem = fa.backward_calls(jax.jit(grad).trace(*args).jaxpr)
-    assert calls == {"fused": 1}
+    calls, vmem = fa.flash_calls(jax.jit(grad).trace(*args).jaxpr)
+    assert calls == {"forward": {"resident": 1}, "backward": {"fused": 1}}
     assert vmem == fa._fused_bwd_vmem_limit(s, d, dv, False, 2) <= (
         fa.CALIBRATION_VMEM_BYTES * 3 // 4)
     compiled = _compile(grad, *args)
@@ -127,6 +128,34 @@ def test_fused_flash_backward_compiles_at_the_training_cells_shapes(
         jax.clear_caches()      # or the backward traced above is reused
         with pytest.raises(Exception, match="vmem"):
             jax.jit(grad).lower(*args).compile()
+
+
+def test_resident_flash_forward_compiles_at_the_kanana_cells_shape(
+        v5e, monkeypatch):
+    """One kanana-2 layer's attention forward alone (4 rows x 32 heads x
+    8,192, 192 / 128), compiled by Mosaic for one described v5e: the
+    resident forward — whole K and V rows of a head in VMEM, no K/V tile
+    streamed — compiles under the limit its call asks for (the family's,
+    54 MiB), and that limit is what makes it compile: its blocks need
+    about 18 MiB, so held to XLA's default scoped 16 MiB the compiler runs
+    out of VMEM."""
+    import re
+
+    b, h, s, d, dv = 4, 32, 8192, 192, 128
+    sds = _shapes_on(v5e.devices[0])
+    args = (sds((b, h, s, d)), sds((b, h, s, d)), sds((b, h, s, dv)))
+    forward = lambda q, k, v: fa.flash_attention_bhsd(q, k, v)  # noqa: E731
+    calls, vmem = fa.flash_calls(jax.jit(forward).trace(*args).jaxpr)
+    assert calls == {"forward": {"resident": 1}, "backward": {}}
+    assert vmem == fa._fused_bwd_vmem_limit(s, d, dv, False, 2) == 54 * 2**20
+    text = _compile(forward, *args).as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert re.search(rf"f32\[{b},{h},{s // 128},128\]", text)  # blocked lse
+    monkeypatch.setattr(fa, "_fused_bwd_vmem_limit",
+                        lambda *a: fa.DEFAULT_SCOPED_VMEM_BYTES)
+    jax.clear_caches()
+    with pytest.raises(Exception, match="vmem"):
+        jax.jit(forward).lower(*args).compile()
 
 
 @pytest.mark.parametrize("kernel", ["decode", "chunk", "int8_decode",
@@ -549,9 +578,13 @@ SERVING_PROGRAMS_AS_RECORDED = {
 # latent / expert class beside them (same constants). The training step is
 # recorded anew since its attention backward became one fused flash call a
 # layer (dq, dk and dv, under the VMEM limit the call asks for), where it
-# was the split dq and dk/dv calls.
+# was the split dq and dk/dv calls; and anew since its 4,096-row forward is
+# the resident kernel (the VMEM rule picks the family for the forward too),
+# asking the family's limit and writing the blocked (3, 32, 32, 128) lse
+# plane, where it streamed K and V and wrote the packed (3, 32, 1, 4096)
+# row. The three serving programs hold as recorded.
 TRAIN_PROGRAM_AS_RECORDED = (
-    "00bcf022e103242e69f335c6e5ae3be865c90bc745f834155d7abc9df6d4bc2c")
+    "918e61de64837f1bd1f54c434be0b937ab565467ae564987349a81ead6953398")
 
 
 def _lowered_serving_hashes(cfg, params, slots, per_slot, bs, blocks, sds):
@@ -820,7 +853,10 @@ def test_kanana_train_step_compiles_at_the_cells_widths(v5e):
     (what the accepted readers' ``^pallas:attention`` finds), three a
     layer — two forwards under remat and the one fused backward — and
     the held experts' grouped matmuls are the compiler's ragged-dot calls;
-    and the
+    the forwards are the resident kernel, whose lse is the blocked
+    ``(4, 32, 64, 128)`` plane; the compiler's peak is not above the
+    14,625,897,472 B it counted when the forwards streamed (the same count
+    with them resident); and the
     only float32 array as wide as the vocabulary that the program holds
     outside a fusion is the loss head's logits."""
     import json
@@ -865,7 +901,10 @@ def test_kanana_train_step_compiles_at_the_cells_widths(v5e):
         state, tokens, tokens).compile()
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15 * 2**30
+    assert mem.peak_memory_in_bytes <= 14_625_897_472
     hlo = compiled.as_text()
+    assert f"f32[{rows},{d['heads']},{seq // 128},128]" in hlo
+    assert f"f32[{rows},{d['heads']},1,{seq}]" not in hlo
     calls = [re.match(r"\s*(?:ROOT )?%([\w.\-]+) = ", line).group(1)
              for line in hlo.splitlines()
              if 'custom_call_target="tpu_custom_call"' in line]

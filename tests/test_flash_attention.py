@@ -65,16 +65,31 @@ def test_resident_fused_backward_non_causal(s, h, kv, d):
     _check_gradients(s, h, kv, d, causal=False)
 
 
-@pytest.mark.parametrize("s,h,kv,d", [(512, 4, 2, 32), (1024, 2, 2, 64)])
-def test_fused_backward_with_streamed_forward(s, h, kv, d, monkeypatch):
-    """When the forward streams but the fused backward's VMEM fits, the
-    forward emits the PACKED lse layout and the backward runs the fused
-    kernel — its packed entry-transpose path. Forced on at small S by
-    lowering only the forward threshold."""
+@pytest.mark.parametrize("s,h,kv,d", [(2560, 4, 2, 32), (2560, 2, 2, 64)],
+                         ids=["gqa", "mha"])
+def test_resident_family_above_2048_rows(s, h, kv, d):
+    """Past 2,048 rows the family is still the one the VMEM rule picks:
+    where the fused backward's residency fits, the forward is the resident
+    kernel too (whole K/V rows, 5 q-tiles of 512 over a k-loop), writing
+    the blocked lse plane the fused backward reads. Forward and all three
+    gradients against the reference, GQA and equal heads."""
     import fault_tolerant_llm_training_tpu.ops.flash_attention as fa
-    monkeypatch.setattr(fa, "STREAM_THRESHOLD", 0)
     assert fa._fused_bwd_vmem_limit(s, d, d, False, 4) is not None
-    assert fa._lse_layout(s, True) == "packed"  # the combination under test
+    assert fa._lse_layout(s, True) == "blocked"
+    x = jax.ShapeDtypeStruct((2, s, h, d), jnp.float32)
+    kv_x = jax.ShapeDtypeStruct((2, s, kv, d), jnp.float32)
+    calls, _ = fa.flash_calls(jax.jit(jax.grad(
+        lambda *a: jnp.sum(flash_attention(*a, True) ** 2),
+        argnums=(0, 1, 2))).trace(x, kv_x, kv_x).jaxpr)
+    assert calls == {"forward": {"resident": 1}, "backward": {"fused": 1}}
+    rng = np.random.default_rng(5)
+    q = jnp.asarray(rng.standard_normal((2, s, h, d)), jnp.float32)
+    k = jnp.asarray(rng.standard_normal((2, s, kv, d)), jnp.float32)
+    v = jnp.asarray(rng.standard_normal((2, s, kv, d)), jnp.float32)
+    np.testing.assert_allclose(
+        np.asarray(flash_attention(q, k, v, True)),
+        np.asarray(xla_attention(q, k, v, causal=True)),
+        rtol=2e-4, atol=2e-5)
     _check_gradients(s, h, kv, d, batch=2, seed=2)
 
 
@@ -84,24 +99,21 @@ def test_fused_backward_with_streamed_forward(s, h, kv, d, monkeypatch):
                                       (512, 2, 2, 64),
                                       # non-128-aligned tiles -> the
                                       # streaming family's LEGACY lse
-                                      # layout (_lse_layout False), which
-                                      # no other case reaches
+                                      # layout, which no other case
+                                      # reaches
                                       (648, 2, 2, 32)])
 def test_streaming_kernels_match(s, h, kv, d, causal, long_tiles,
                                  monkeypatch):
     """The long-context streaming kernels (grid-streamed loop operand +
-    scratch accumulators; selected above STREAM_THRESHOLD) must agree with
-    the XLA reference, causal and non-causal (the non-causal branch has its
-    own index maps and bounds). Forced on at small S so CI covers them;
-    ``long_tiles`` additionally forces the S>=32k tile set, whose inverted
-    ratios (dq block_k > block_q, dkv block_q > block_k) are geometries the
-    default tiles never produce."""
+    scratch accumulators; selected where the resident family's VMEM does
+    not fit the chip) must agree with the XLA reference, causal and
+    non-causal (the non-causal branch has its own index maps and bounds).
+    Forced on at small S so CI covers them — a chip with no VMEM to spare
+    fits nothing, so the forward streams and the backward is the split
+    dq / dk-dv pair; ``long_tiles`` additionally forces the S>=32k tile
+    set, whose inverted ratios (dq block_k > block_q, dkv block_q >
+    block_k) are geometries the default tiles never produce."""
     import fault_tolerant_llm_training_tpu.ops.flash_attention as fa
-    monkeypatch.setattr(fa, "STREAM_THRESHOLD", 0)
-    # force the SPLIT streaming backward too: with only the forward
-    # threshold lowered, the fused backward (its VMEM fits) would take over
-    # and the streaming dq/dkv kernels would lose their coverage — a chip
-    # with no VMEM to spare fits nothing
     monkeypatch.setattr(fa, "vmem_capacity_bytes", lambda: 0)
     if long_tiles:
         monkeypatch.setattr(fa, "LONG_STREAM_THRESHOLD", 0)
@@ -140,7 +152,6 @@ def test_rope_fused_matches_xla_rope(s, h, kv, d, family, monkeypatch):
         precompute_rope,
     )
     if family == "streaming":
-        monkeypatch.setattr(fa, "STREAM_THRESHOLD", 0)
         monkeypatch.setattr(fa, "vmem_capacity_bytes", lambda: 0)
     rng = np.random.default_rng(3)
     q = jnp.asarray(rng.standard_normal((2, s, h, d)), jnp.float32)
@@ -239,9 +250,10 @@ def test_rope_fused_dispatch_boundary():
 def test_backward_family_follows_the_fused_kernels_vmem(b, h, kv, s, d, dv,
                                                         family):
     """The training cells' attention shapes, bf16, as the program traces
-    them (no kernel runs): one backward call, of the family the VMEM rule
-    picks for v5e — the fused kernel wherever its residency fits, the
-    split streaming kernels where it cannot."""
+    them (no kernel runs): one forward and one backward call, of the
+    family the VMEM rule picks for v5e — the resident forward and the
+    fused backward wherever the residency fits, the streamed forward and
+    the split backward where it cannot."""
     from fault_tolerant_llm_training_tpu.ops import flash_attention as fa
 
     sds = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.bfloat16)  # noqa
@@ -249,8 +261,9 @@ def test_backward_family_follows_the_fused_kernels_vmem(b, h, kv, s, d, dv,
         lambda q, k, v: fa.flash_attention_bhsd(q, k, v).astype(
             jnp.float32).sum(), argnums=(0, 1, 2))).trace(
         sds(b, h, s, d), sds(b, kv, s, d), sds(b, kv, s, dv))
-    calls, vmem = fa.backward_calls(traced.jaxpr)
-    assert calls == {family: 1}
+    calls, vmem = fa.flash_calls(traced.jaxpr)
+    forward = "resident" if family == "fused" else "stream"
+    assert calls == {"forward": {forward: 1}, "backward": {family: 1}}
     limit = fa._fused_bwd_vmem_limit(s, d, dv, False, 2)
     if family == "fused":
         assert vmem == limit > fa.DEFAULT_SCOPED_VMEM_BYTES
@@ -258,10 +271,44 @@ def test_backward_family_follows_the_fused_kernels_vmem(b, h, kv, s, d, dv,
         assert limit is None and vmem == fa.DEFAULT_SCOPED_VMEM_BYTES
 
 
+@pytest.mark.parametrize("vmem", ["v5e", "none"])
+@pytest.mark.parametrize("b,h,kv,s,d,dv", [(4, 32, 32, 8192, 192, 128),
+                                            (3, 32, 8, 4096, 128, 128)],
+                         ids=["kanana2", "mistral7b"])
+def test_forward_family_follows_the_vmem_rule(b, h, kv, s, d, dv, vmem,
+                                              monkeypatch):
+    """The training cells' forward alone, bf16, traced (no kernel runs):
+    on v5e's VMEM the resident ``_fwd_kernel`` — no row count stops it at
+    these 8,192 and 4,096 rows — asking the compiler for the family's
+    limit (above XLA's default scoped 16 MiB) and writing the blocked lse
+    plane; on a chip with no VMEM to spare the streamed forward, asking
+    nothing and writing the packed lse row."""
+    from fault_tolerant_llm_training_tpu.ops import flash_attention as fa
+
+    if vmem == "none":
+        monkeypatch.setattr(fa, "vmem_capacity_bytes", lambda: 0)
+    sds = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.bfloat16)  # noqa
+    args = (sds(b, h, s, d), sds(b, kv, s, d), sds(b, kv, s, dv))
+    calls, limit = fa.flash_calls(     # a fresh function: no trace cached
+        jax.jit(lambda *a: fa.flash_attention_bhsd(*a)).trace(*args).jaxpr)
+    _, lse = jax.eval_shape(
+        lambda q, k, v: fa._flash_fwd_t(q, k, v, True, False), *args)
+    lse = lse.shape
+    if vmem == "v5e":
+        assert calls == {"forward": {"resident": 1}, "backward": {}}
+        assert limit == fa._fused_bwd_vmem_limit(s, d, dv, False, 2) > (
+            fa.DEFAULT_SCOPED_VMEM_BYTES)
+        assert lse == (b, h, s // 128, 128)
+    else:
+        assert calls == {"forward": {"stream": 1}, "backward": {}}
+        assert limit == fa.DEFAULT_SCOPED_VMEM_BYTES
+        assert lse == (b, h, 1, s)
+
+
 def test_backward_calls_count_each_run_of_a_layer():
     """A layer stack under remat, unrolled and as a scan: the tally counts
-    every backward the program runs, not the one the tracer met — and not
-    the two forwards remat runs a layer."""
+    every backward the program runs, not the one the tracer met — and
+    every forward, the one remat runs again in the backward included."""
     from fault_tolerant_llm_training_tpu.ops import flash_attention as fa
 
     layer = jax.checkpoint(lambda x: fa.flash_attention_bhsd(x, x, x))
@@ -277,36 +324,43 @@ def test_backward_calls_count_each_run_of_a_layer():
 
     x = jax.ShapeDtypeStruct((1, 2, 256, 64), jnp.float32)
     for loss, n in ((unrolled, 3), (scanned, 5)):
-        calls, _ = fa.backward_calls(jax.jit(jax.grad(loss)).trace(x).jaxpr)
-        assert calls == {"fused": n}
+        calls, _ = fa.flash_calls(jax.jit(jax.grad(loss)).trace(x).jaxpr)
+        assert calls == {"forward": {"resident": 2 * n},
+                         "backward": {"fused": n}}
 
 
 def test_lse_layout_dispatch(monkeypatch):
-    """The residual layout picker (VERDICT r4 weak #3): resident aligned
-    shapes get the zero-padding blocked plane, streaming aligned shapes
-    keep the packed row, unaligned shapes fall back to legacy, and the
-    FTL_LSE_RESIDENT=legacy escape hatch works."""
+    """The residual layout picker (VERDICT r4 weak #3) follows the kernel
+    family, not a row count: the resident family's aligned shapes get the
+    zero-padding blocked plane at any length (8,192 rows included), the
+    streaming family's aligned shapes the packed row at any length (2,048
+    included), unaligned shapes fall back to legacy, and the
+    FTL_LSE_RESIDENT=legacy escape hatch works on the resident family
+    only."""
     from fault_tolerant_llm_training_tpu.ops import flash_attention as fa
 
     monkeypatch.delenv("FTL_LSE_RESIDENT", raising=False)
     assert fa._lse_layout(2048, True) == "blocked"   # resident, 128-aligned
     assert fa._lse_layout(256, True) == "blocked"
+    assert fa._lse_layout(4096, True) == "blocked"   # past 2,048 rows too
+    assert fa._lse_layout(8192, True) == "blocked"
     assert fa._lse_layout(2000, True) == "legacy"    # not a 128-multiple
-    assert fa._lse_layout(2048, False) == "legacy"   # fused bwd won't fit:
-    # the streaming backward has no blocked row_spec (review r5)
-    assert fa._lse_layout(4096, True) == "packed"    # streaming forward
+    assert fa._lse_layout(2048, False) == "packed"   # streaming family
     assert fa._lse_layout(65536, False) == "packed"
+    assert fa._lse_layout(2000, False) == "legacy"   # 400-row tiles
     monkeypatch.setenv("FTL_LSE_RESIDENT", "legacy")
     assert fa._lse_layout(2048, True) == "legacy"    # opt-out knob
-    assert fa._lse_layout(4096, True) == "packed"    # knob is resident-only
+    assert fa._lse_layout(4096, True) == "legacy"
+    assert fa._lse_layout(4096, False) == "packed"   # knob is resident-only
     # what the forward and the backward ask is the same answer: at 2048 x
-    # 256 the fused kernel fits v5e's VMEM (blocked), not a 16 MiB chip's
+    # 256 the resident family fits v5e's VMEM (blocked), not a 16 MiB
+    # chip's, where both directions stream (packed)
     monkeypatch.delenv("FTL_LSE_RESIDENT")
     fits = fa._fused_bwd_vmem_limit(2048, 256, 256, False, 2) is not None
     assert fits and fa._lse_layout(2048, fits) == "blocked"
     monkeypatch.setattr(fa, "vmem_capacity_bytes", lambda: 16 * 2**20)
     fits = fa._fused_bwd_vmem_limit(2048, 256, 256, False, 2) is not None
-    assert not fits and fa._lse_layout(2048, fits) == "legacy"
+    assert not fits and fa._lse_layout(2048, fits) == "packed"
 
 
 @pytest.mark.parametrize("mesh_kw", [dict(dp=4), dict(fsdp=4),

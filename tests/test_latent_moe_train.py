@@ -149,23 +149,22 @@ def _plain_attention(q, k, v):
 
 
 @pytest.mark.parametrize("s,stream", [(64, False), (1024, False),
-                                      (1024, True), (1024, "packed")])
+                                      (2560, False), (1024, True)])
 def test_flash_kernel_of_two_widths_matches_plain_attention(
         s, stream, monkeypatch):
     """192-wide query/key, 128-wide value, in interpret mode: the forward
-    and all three gradients. The resident kernels at 64 rows (one tile)
-    and 1,024 (2 x 2 tiles); ``stream``: the kernels the cell's 8,192 rows
-    take, at 1,024 rows with the forward's threshold lowered — the
-    streamed forward (2 q-tiles x 1 k-step) and, ``packed``, the fused
-    backward reading its packed lse, or, ``True``, on a chip with no VMEM
-    to spare, the split streaming backward (2 x 2 dq, 1 x 2 dk/dv: the
-    causal grid bounds and fetch clamps)."""
+    and all three gradients. The resident family — the kernels the
+    cell's 8,192 rows take, where its VMEM fits the chip — at 64 rows (one
+    tile), 1,024 (2 x 2 tiles) and 2,560 (5 q-tiles over a k-loop of 5,
+    past the 2,048 rows where a row count once made the forward stream);
+    ``stream``: on a chip with no VMEM to spare, the streaming family at
+    1,024 rows — the streamed forward (2 q-tiles x 1 k-step) and the split
+    backward (2 x 2 dq, 1 x 2 dk/dv: the causal grid bounds and fetch
+    clamps)."""
     if stream:
-        monkeypatch.setattr(fa, "STREAM_THRESHOLD", 256)
-        assert fa._lse_layout(s, True) == "packed"
-        assert fa._fused_bwd_vmem_limit(s, 192, 128, False, 4) is not None
-    if stream is True:
         monkeypatch.setattr(fa, "vmem_capacity_bytes", lambda: 0)
+    resident = fa._fused_bwd_vmem_limit(s, 192, 128, False, 4) is not None
+    assert resident is not stream
     ks = jax.random.split(jax.random.PRNGKey(s), 4)
     b, h = 1, 2
     q = jax.random.normal(ks[0], (b, h, s, 192), F32)
@@ -202,8 +201,9 @@ try:
     entry.train(get_args(sys.argv[3:]))
 except SystemExit as e:
     assert e.code in (0, None), e.code
-fam = REGISTRY.snapshot().get("flash_backward_calls_total", {"series": {}})
-print(dict(fam["series"]))
+print({d: dict(REGISTRY.snapshot().get(f"flash_{d}_calls_total",
+                                       {"series": {}})["series"])
+       for d in ("forward", "backward")})
 """
 
 
@@ -211,9 +211,11 @@ print(dict(fam["series"]))
 def test_flash_backward_counter_reads_layers_times_steps(tmp_path, family):
     """Two training steps of the 3-layer ``tiny-latent-train`` under
     ``--remat`` with the Pallas kernels (interpreted here): the start-up
-    line names the backward family the VMEM rule picked, and
+    lines name the forward and backward families the VMEM rule picked,
     ``flash_backward_calls_total`` advances by layers x steps under that
-    family alone — the two forwards remat runs a layer are not counted."""
+    family alone, and ``flash_forward_calls_total`` by layers x steps x 2
+    under the forward of the same family (remat runs a layer's forward
+    again in the backward)."""
     import ast
 
     import pyarrow as pa
@@ -234,10 +236,13 @@ def test_flash_backward_counter_reads_layers_times_steps(tmp_path, family):
          "--compile-cache-dir", ""],
         cwd=REPO, env=env, capture_output=True, text=True, timeout=600)
     assert proc.returncode == 0, proc.stderr[-3000:]
+    forward = "resident" if family == "fused" else "stream"
     assert f"Flash backward | {family} x3 a step" in proc.stdout, (
         proc.stdout[-3000:])
+    assert f"Flash forward | {forward} x6 a step" in proc.stdout
     got = ast.literal_eval(proc.stdout.strip().splitlines()[-1])
-    assert got == {f"kernel={family}": 3 * 2}
+    assert got == {"forward": {f"kernel={forward}": 3 * 2 * 2},
+                   "backward": {f"kernel={family}": 3 * 2}}
 
 
 # ------------------------------------------------------ the expert layer
